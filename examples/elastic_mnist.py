@@ -32,6 +32,11 @@ def main():
                     help="chain the gradient-noise-scale monitor into the step")
     args = ap.parse_args()
 
+    from kungfu_tpu.env import enable_compile_cache
+
+    # a resize back to a mesh size already seen then skips XLA compilation
+    enable_compile_cache()
+
     def make_loss():
         import jax
 

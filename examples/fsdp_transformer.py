@@ -53,9 +53,10 @@ def main():
                          "(docs/pallas.md; 0 = single fused tree)")
     args = ap.parse_args()
 
-    from kungfu_tpu.env import apply_platform_override
+    from kungfu_tpu.env import apply_platform_override, enable_compile_cache
 
     apply_platform_override()
+    enable_compile_cache()
 
     import numpy as np
     import jax

@@ -24,9 +24,10 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from kungfu_tpu.env import apply_platform_override
+from kungfu_tpu.env import apply_platform_override, enable_compile_cache
 
 apply_platform_override()
+enable_compile_cache()
 
 
 def main() -> int:
@@ -60,12 +61,17 @@ def main() -> int:
     import jax.numpy as jnp
 
     from kungfu_tpu.models.transformer import (
-        TransformerConfig, TransformerLM, lm_loss,
+        TransformerConfig, TransformerLM, _attention_kind, lm_loss,
     )
+    from kungfu_tpu.monitor import programs
     from kungfu_tpu.plan import make_mesh
     from kungfu_tpu.trainer import MeshTrainer
 
+    programs.maybe_install()  # counts every XLA compile in this process
     n_dev = len(jax.devices())
+    dev = jax.devices()[0]
+    print(f"DEVICE: platform={dev.platform} device_kind={dev.device_kind!r} "
+          f"count={n_dev}", flush=True)
     dp = args.dp or max(1, n_dev // args.sp)
     mesh = make_mesh(dp=dp, sp=args.sp) if args.sp > 1 else make_mesh(dp=dp)
     cfg = TransformerConfig(
@@ -140,6 +146,7 @@ def main() -> int:
             loader.close()
 
     it = file_batches() if args.data == "files" else synthetic_batches()
+    t_setup = time.perf_counter()
     state = trainer.init(jax.random.PRNGKey(0), next(it))
 
     manager = None
@@ -202,9 +209,23 @@ def main() -> int:
               f"{args.steps}; nothing to train")
         maybe_generate()  # sampling from a finished run is still useful
         return 0
+    # set-up: init, the compile of the step, and the first step it runs.
+    # The compiled program's text is read here too: with the persistent
+    # cache on, the step's own first call then finds it already compiled.
+    batch = trainer.shard_batch(next(it))
+    hits = programs.compile_watch_state()["cache_hits"]
+    mosaic_calls = trainer.lower_step(state, batch).compile().as_text().count(
+        "tpu_custom_call"
+    )
+    step_cache_hits = programs.compile_watch_state()["cache_hits"] - hits
+    state, metrics = trainer.train_step(state, batch)
+    first_loss = loss = float(jax.block_until_ready(metrics["loss"]))
+    setup_s = time.perf_counter() - t_setup
+    compiles_warm = programs.compile_watch_state()["compiles"]
+    print(f"# step {start_step + 1} loss {loss:.4f}", flush=True)
+    print(f"# set-up (init, compile, first step) {setup_s:.1f}s", flush=True)
     t0 = time.perf_counter()
-    loss = float("nan")
-    for i in range(start_step, args.steps):
+    for i in range(start_step + 1, args.steps):
         state, metrics = trainer.train_step(state, trainer.shard_batch(next(it)))
         if (i + 1) % 10 == 0 or i + 1 == args.steps:
             loss = float(np.asarray(metrics["loss"]))
@@ -215,14 +236,24 @@ def main() -> int:
                 {"params": state.params, "opt_state": state.opt_state},
                 meta={"step": i + 1},
             )
+    jax.block_until_ready(metrics["loss"])
+    dt = time.perf_counter() - t0
+    recompiles = programs.compile_watch_state()["compiles"] - compiles_warm
     if manager is not None:
         manager.wait()
-    dt = time.perf_counter() - t0
-    tok_s = (args.steps - start_step) * args.batch * args.seq_len / dt
+    steady = args.steps - start_step - 1
+    step_ms = dt / steady * 1e3 if steady else float("nan")
+    tok_s = steady * args.batch * args.seq_len / dt if steady else float("nan")
     maybe_generate()
+    param_dtypes = sorted({str(x.dtype) for x in jax.tree.leaves(state.params)})
     print(
-        f"RESULT: example=gpt_train loss={loss:.4f} steps={args.steps} "
-        f"mesh={dict(mesh.shape)} tokens_per_sec={tok_s:.0f}",
+        f"RESULT: example=gpt_train loss={loss:.4f} first_loss={first_loss:.4f} "
+        f"steps={args.steps} mesh={dict(mesh.shape)} "
+        f"attention={_attention_kind(cfg)} dtype={jnp.dtype(cfg.dtype).name} "
+        f"param_dtypes={'+'.join(param_dtypes)} mosaic_calls={mosaic_calls} "
+        f"setup_s={setup_s:.1f} step_ms={step_ms:.1f} "
+        f"step_cache_hits={step_cache_hits} "
+        f"compiles_after_warmup={recompiles} tokens_per_sec={tok_s:.0f}",
         flush=True,
     )
     return 0

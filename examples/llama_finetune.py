@@ -25,9 +25,10 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from kungfu_tpu.env import apply_platform_override
+from kungfu_tpu.env import apply_platform_override, enable_compile_cache
 
 apply_platform_override()
+enable_compile_cache()
 
 
 def main() -> int:

@@ -15,11 +15,13 @@ convention for CI greps).
 import argparse
 import os
 import sys
+import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import kungfu_tpu
 from kungfu_tpu.datasets import ElasticDataAdaptor, synthetic_mnist
+from kungfu_tpu.env import enable_compile_cache
 from kungfu_tpu.models.slp import SLP, accuracy, softmax_cross_entropy
 from kungfu_tpu.optimizers import (
     adaptive_sgd,
@@ -40,6 +42,7 @@ def main():
     )
     args = ap.parse_args()
 
+    enable_compile_cache()
     peer = kungfu_tpu.init()
     rank, size = peer.rank, peer.size
 
@@ -75,14 +78,23 @@ def main():
             rank=rank, size=size,
         )
     )
-    state, metrics = trainer.fit(state, data, steps=args.steps, log_every=25)
+    # the first step compiles: time it as set-up, apart from the steady rate
+    t0 = time.perf_counter()
+    state, metrics = trainer.fit(state, data, steps=1, log_every=0)
+    setup_s = time.perf_counter() - t0
+    state, metrics = trainer.fit(state, data, steps=args.steps - 1, log_every=25)
+    step_ms = args.batch_size * local_devices / metrics["samples_per_sec"] * 1e3
 
     final = trainer.eval_params(state)
     logits = model.apply({"params": final}, images[:1024])
     acc = float(accuracy(logits, labels[:1024]))
+    dev = jax.devices()[0]
     print(
         f"RESULT: rank={rank}/{size} acc={acc:.4f} "
         f"loss={float(metrics['loss']):.4f} "
+        f"platform={dev.platform} device_kind={dev.device_kind!r} "
+        f"devices={jax.device_count()} local_devices={local_devices} "
+        f"setup_s={setup_s:.1f} step_ms={step_ms:.2f} "
         f"throughput={metrics['samples_per_sec']:.0f} samples/s"
     )
 

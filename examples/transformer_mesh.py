@@ -26,9 +26,10 @@ def main():
     args = ap.parse_args()
     steps = max(1, args.steps)
 
-    from kungfu_tpu.env import apply_platform_override
+    from kungfu_tpu.env import apply_platform_override, enable_compile_cache
 
     apply_platform_override()
+    enable_compile_cache()
 
     import numpy as np
     import jax

@@ -146,16 +146,11 @@ def check_and_raise(fn, *args, context: str = "", **kwargs) -> List[Finding]:
     return findings
 
 
-def _axis_env_sizes() -> Optional[dict]:
-    """{axis: size} for the axes bound by the surrounding trace, if the
-    running JAX exposes its axis env (jax 0.4-0.6 internals)."""
-    try:
-        from jax._src import core as _core
+def _axis_env_sizes() -> dict:
+    """{axis: size} for the axes bound by the surrounding trace."""
+    from jax._src import core as _core
 
-        env = _core.get_axis_env()
-        return dict(env.axis_sizes)
-    except Exception:
-        return None
+    return dict(_core.get_axis_env().axis_sizes)
 
 
 def check_axes_in_scope(
@@ -166,37 +161,25 @@ def check_axes_in_scope(
     """In-trace hook: verify declared axes are bound and compression keys
     name bound axes.  Must be called during an outer shard_map/pjit trace
     (exactly like lax.axis_index); raises AnalysisError on violations."""
-    from .. import compat
-
     axes = axis_name if isinstance(axis_name, (tuple, list)) else (axis_name,)
     env = _axis_env_sizes()
     findings: List[Finding] = []
-    if env is not None:
-        in_scope = sorted(env)
-        for a in axes:
-            if a not in env:
+    in_scope = sorted(env)
+    for a in axes:
+        if a not in env:
+            findings.append(Finding(
+                rule=RULE_AXIS, severity=ERROR, axes=(a,),
+                message=(f"axis {a!r} is not bound by the surrounding "
+                         f"mesh; axes in scope: {in_scope}"),
+            ))
+    if isinstance(compression, dict):
+        for k in compression:
+            if k not in env:
                 findings.append(Finding(
-                    rule=RULE_AXIS, severity=ERROR, axes=(a,),
-                    message=(f"axis {a!r} is not bound by the surrounding "
-                             f"mesh; axes in scope: {in_scope}"),
-                ))
-        if isinstance(compression, dict):
-            for k in compression:
-                if k not in env:
-                    findings.append(Finding(
-                        rule=RULE_AXIS, severity=ERROR, axes=(k,),
-                        message=(f"compression key {k!r} names no axis in "
-                                 f"scope ({in_scope}); it would silently "
-                                 "stay full precision"),
-                    ))
-    else:  # pragma: no cover - axis env introspection unavailable
-        for a in axes:
-            try:
-                compat.axis_size(a)
-            except (NameError, KeyError):
-                findings.append(Finding(
-                    rule=RULE_AXIS, severity=ERROR, axes=(a,),
-                    message=f"axis {a!r} is not bound by the surrounding mesh",
+                    rule=RULE_AXIS, severity=ERROR, axes=(k,),
+                    message=(f"compression key {k!r} names no axis in "
+                             f"scope ({in_scope}); it would silently "
+                             "stay full precision"),
                 ))
     assert_clean(findings, context=context)
 

@@ -30,12 +30,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, FrozenSet, List, Optional, Sequence, Tuple
 
-from jax import core
-
-try:  # provenance is best-effort: internal module, stable across 0.4-0.7
-    from jax._src import source_info_util as _src_info
-except Exception:  # pragma: no cover - jax internals moved
-    _src_info = None
+from jax._src import source_info_util as _src_info
+from jax.extend import core
 
 #: primitives that move bytes between devices (collective wire ops)
 WIRE_PRIMS = ("psum", "pmin", "pmax", "ppermute", "all_gather", "all_to_all",
@@ -117,13 +113,16 @@ def _named(axes) -> Tuple[str, ...]:
     return tuple(a for a in axes if isinstance(a, str))
 
 
+def _spec_axes(spec) -> FrozenSet[str]:
+    """The named mesh axes a PartitionSpec shards over."""
+    axes: set = set()
+    for entry in spec:
+        axes.update(_named(entry))
+    return frozenset(axes)
+
+
 def _source_of(eqn) -> str:
-    if _src_info is None:
-        return ""
-    try:
-        return _src_info.summarize(eqn.source_info)
-    except Exception:  # pragma: no cover - defensive
-        return ""
+    return _src_info.summarize(eqn.source_info)
 
 
 def _is_total_permutation(perm, n: Optional[int]) -> bool:
@@ -281,24 +280,12 @@ class _Walker:
                 pass
         inner = p["jaxpr"]
         inner = inner.jaxpr if isinstance(inner, core.ClosedJaxpr) else inner
-        in_names = p.get("in_names", ())
-        out_names = p.get("out_names", ())
-        in_vmas = []
-        for i, _ in enumerate(inner.invars):
-            names = in_names[i] if i < len(in_names) else {}
-            axes: set = set()
-            for ax in dict(names).values():
-                axes.update(_named(ax))
-            in_vmas.append(frozenset(axes))
+        in_vmas = [_spec_axes(spec) for spec in p["in_specs"]]
         sub_path = path + ("shard_map",)
         out_vmas = self.run(inner, in_vmas, sub_path)
         if self.record:
-            for i, vma in enumerate(out_vmas):
-                names = out_names[i] if i < len(out_names) else {}
-                claimed: set = set()
-                for ax in dict(names).values():
-                    claimed.update(_named(ax))
-                leaked = vma - claimed
+            for i, (vma, spec) in enumerate(zip(out_vmas, p["out_specs"])):
+                leaked = vma - _spec_axes(spec)
                 if leaked:
                     self.x.leaks.append(OutputLeak(
                         out_index=i, axes=tuple(sorted(leaked)),

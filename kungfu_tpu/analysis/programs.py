@@ -100,7 +100,7 @@ def _replicated_opt_program(tx, mesh, axes, compression=None):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from ..compat import shard_map
+    from jax import shard_map
 
     params = _toy_params()
     opt_state = tx.init(params)
@@ -111,8 +111,7 @@ def _replicated_opt_program(tx, mesh, axes, compression=None):
         p = optax.apply_updates(p, u)
         return p, s, lax.pmean(loss, axes)
 
-    fn = shard_map(
-        step, mesh, in_specs=(P(), P(), P(axes)), out_specs=(P(), P(), P()),
+    fn = shard_map(step, mesh=mesh, in_specs=(P(), P(), P(axes)), out_specs=(P(), P(), P()),
         check_vma=False,
     )
     world = 1
@@ -133,7 +132,7 @@ def _per_replica_opt_program(tx, mesh, axis):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from ..compat import shard_map
+    from jax import shard_map
 
     n = mesh.shape[axis]
     params = _toy_params()
@@ -156,8 +155,7 @@ def _per_replica_opt_program(tx, mesh, axis):
         return (jax.tree.map(stack_, p), jax.tree.map(stack_, s),
                 lax.pmean(loss, axis))
 
-    fn = shard_map(
-        step, mesh, in_specs=(P(axis), P(axis), P(axis)),
+    fn = shard_map(step, mesh=mesh, in_specs=(P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P()), check_vma=False,
     )
     batch = _sds((n * 4, 32))
@@ -383,7 +381,7 @@ def _b_bench_compression(scheme: str):
         from jax.sharding import PartitionSpec as P
 
         from .. import compression as Comp
-        from ..compat import shard_map
+        from jax import shard_map
 
         if scheme == "fp8" and getattr(jnp, "float8_e4m3fn", None) is None:
             raise ProgramUnavailable("no fp8 dtype in this jax build")
@@ -393,7 +391,7 @@ def _b_bench_compression(scheme: str):
         def body(y):
             return Comp.all_reduce(jnp.squeeze(y, 0), "dp", cfg, op="sum")[None]
 
-        fn = shard_map(body, mesh, in_specs=P("dp"), out_specs=P("dp"),
+        fn = shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
                        check_vma=False)
         x = _sds((8, 1, 4096))
         comp_kw = {"dp": cfg} if cfg.scheme != "none" else None
@@ -464,7 +462,7 @@ def _b_serving_kv_ship():
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
-        from ..compat import shard_map
+        from jax import shard_map
         from ..ops.kv_ship import ship_kv_rows
 
         mesh = _mesh({"dp": 8})
@@ -475,7 +473,7 @@ def _b_serving_kv_ship():
             )
             return shipped["cached_k"][None]
 
-        fn = shard_map(body, mesh, in_specs=P("dp"), out_specs=P("dp"),
+        fn = shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
                        check_vma=False)
         x = _sds((8, 16, 2, 8))
         return fn, (x,), {"mesh": mesh}

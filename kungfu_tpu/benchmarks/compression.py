@@ -50,7 +50,7 @@ def bench_compression(
     from jax.sharding import PartitionSpec as P
 
     from .. import compression as Comp
-    from ..compat import shard_map
+    from jax import shard_map
     from ..plan import make_mesh
 
     mesh = make_mesh(dp=-1)

@@ -55,7 +55,7 @@ def _bench_ops(steps: int, warmup: int) -> List[Dict]:
     from jax import lax
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from ..compat import shard_map
+    from jax import shard_map
     from ..ops import fused_matmul as FM
     from .scaling import step_attribution
 

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .. import compat
 from ..ops import collective as C
 from .config import AxisCompression, CompressionConfig, resolve, resolve_for_axis
 from .quant import QTensor, dequantize, pad_to_block, quantize, sparsify

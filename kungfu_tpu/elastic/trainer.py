@@ -99,10 +99,7 @@ class _MeshPrograms:
         from jax import lax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        try:
-            from jax import shard_map
-        except ImportError:  # pragma: no cover
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from ..ops import collective as C
 
@@ -235,27 +232,6 @@ def _snapshot_local_replica(tree) -> Any:
     return first_local_replica(tree)
 
 
-def _maybe_enable_compile_cache() -> None:
-    """Opt-in persistent XLA compilation cache (KFT_COMPILE_CACHE_DIR).
-
-    Resize latency is dominated by the rebuild/compile phase (measured in
-    the resize_latency record): every resize tears the backend down
-    (jax.clear_caches + _clear_backends), so in-memory compiled fns cannot
-    survive.  The disk cache CAN — it keys on HLO + topology, so a resize
-    back to a previously-seen mesh size skips XLA compilation entirely.
-    The reference has no analog (its TF graphs never recompile on resize;
-    recompilation is the price of the XLA design, and this is its rebate).
-    """
-    d = os.environ.get("KFT_COMPILE_CACHE_DIR")
-    if not d:
-        return
-    import jax
-
-    jax.config.update("jax_compilation_cache_dir", d)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-
-
 def _teardown_backend(graceful: bool = True, peer=None) -> None:
     """Tear down jax.distributed + the XLA backend for a rebuild.
 
@@ -367,7 +343,6 @@ def run_elastic(
     from ..resilience import ladder as _ladder
     from ..train import DataParallelTrainer, TrainState
 
-    _maybe_enable_compile_cache()
     peer = kungfu_tpu.init()
     client = ConfigClient(peer.config.config_server) if peer.config.config_server else None
     schedule = StepBasedSchedule(cfg.schedule)

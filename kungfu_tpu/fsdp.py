@@ -35,7 +35,7 @@ from jax import lax
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .compat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 from .plan import make_mesh
 from .train import TrainState, _put_global
 from .utils import get_logger
@@ -94,12 +94,13 @@ class FSDPTrainer:
              the pair is each other's custom VJP — the backward gradient
              reduce-scatter rides it too, overlapping hop h's transfer
              with the compute consuming hop h-1 instead of serializing
-             the unshard against the matmuls.  The wrappers self-gate
-             (compat.pallas_mode + per-call shape/VMEM checks) and fall
-             back to the exact lax.all_gather/psum_scatter lowering, so
-             None/True is always safe; False keeps the legacy XLA
-             program (the unfused A/B control `--bench fused` measures
-             against).
+             the unshard against the matmuls.  Off by default: the
+             remote-DMA kernels have not yet compiled on a chip
+             (ROADMAP S8), so None/False run the lax.all_gather/
+             psum_scatter program.  True selects the kernels; they still
+             gate per call on shape/VMEM (compat.pallas_mode), and on a
+             TPU a kernel that cannot compile raises the compiler's
+             error.
       analyze: arm the kf-lint trace-time hook (kungfu_tpu.analysis): the
              compiled step is statically checked at its first train_step,
              raising AnalysisError before dispatch on error-severity
@@ -139,11 +140,7 @@ class FSDPTrainer:
             bucket_bytes if bucket_bytes == "auto"
             else int(bucket_bytes) if bucket_bytes else None
         )
-        # None/"auto"/True -> the self-gating DMA wrappers (they fall back
-        # to the lax lowerings wherever the kernels can't run); False pins
-        # the legacy XLA program (the unfused bench control)
-        self.dma_collectives = (dma_collectives is not False
-                                and dma_collectives != "off")
+        self.dma_collectives = bool(dma_collectives)
         self._donate = donate
         self.loss_fn = loss_fn
         self.tx = tx

@@ -29,12 +29,8 @@ from ..ops.chunked_ce import chunked_lm_head_ll
 from ..parallel.sharding import logical_constraint
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..compat import pallas_mode
 from ..parallel.ring_attention import full_attention, ring_attention
-
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -189,9 +185,7 @@ def _attention_kind(cfg: TransformerConfig) -> str:
     path while claiming to test the flash path the tuner tunes."""
     if cfg.attention != "auto":
         return cfg.attention
-    from .. import compat
-
-    return "flash" if compat.pallas_mode() != "off" else "full"
+    return "flash" if pallas_mode() != "off" else "full"
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
@@ -424,15 +418,12 @@ class Attention(nn.Module):
             # the DMA KV rotation (ops.fused_matmul.ring_shift) traces a
             # pallas_call, which has no replication rule: opt out of the
             # rep/vma check exactly when it engages (Session precedent).
-            # compat.shard_map spells the check kwarg portably.
-            from .. import compat as _compat
-
-            attn = _compat.shard_map(
+            attn = jax.shard_map(
                 fn,
                 mesh=cfg.mesh,
                 in_specs=(spec, spec, spec),
                 out_specs=spec,
-                check_vma=False if _compat.pallas_mode() != "off" else None,
+                check_vma=pallas_mode() == "off",
             )
             o = attn(q, k, v)
         elif kind == "flash":
@@ -459,9 +450,7 @@ class Attention(nn.Module):
                 # rep/vma check exactly when the flash kernels engage
                 # (compiled on TPU or KFT_PALLAS=interpret; the XLA
                 # reference path keeps the check)
-                from .. import compat as _compat
-
-                attn = _compat.shard_map(
+                attn = jax.shard_map(
                     partial(flash_attention, causal=cfg.causal,
                             window=cfg.window or None,
                             block_q=bq, block_k=bk,
@@ -469,8 +458,7 @@ class Attention(nn.Module):
                     mesh=cfg.mesh,
                     in_specs=(spec, spec, spec),
                     out_specs=spec,
-                    check_vma=(False if _compat.pallas_mode() != "off"
-                               else None),
+                    check_vma=pallas_mode() == "off",
                 )
                 o = attn(q, k, v)
             else:

@@ -74,6 +74,9 @@ DEFAULT_STORM_MIN = 4
 #: the jax-internal duration event backend_compile wraps every XLA
 #: compilation in (jax/_src/dispatch.py BACKEND_COMPILE_EVENT)
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: recorded when the persistent compilation cache serves an executable —
+#: no backend compile happens then, so the two events never double-count
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
 def programs_enabled() -> bool:
@@ -119,6 +122,7 @@ _watch: Dict[str, Any] = {
     "active": False,      # the jax.monitoring listener is live
     "compile_ms": 0.0,    # cumulative backend-compile ms this process
     "compiles": 0,
+    "cache_hits": 0,      # executables served by the persistent cache
 }
 
 
@@ -138,8 +142,19 @@ def _on_duration_event(event: str, duration_secs: float, **kw: Any) -> None:
         c.observe_hist("compile_ms", ms)
 
 
+def _on_event(event: str, **kw: Any) -> None:
+    if event != CACHE_HIT_EVENT:
+        return
+    with _watch_lock:
+        _watch["cache_hits"] += 1
+    c = _counters()
+    if c is not None:
+        c.inc_event("compile_cache_hits")
+
+
 def compile_watch_state() -> Dict[str, Any]:
-    """Snapshot of the global watch: {installed, active, compile_ms, compiles}."""
+    """Snapshot of the global watch: {installed, active, compile_ms,
+    compiles, cache_hits}."""
     with _watch_lock:
         return dict(_watch)
 
@@ -171,6 +186,7 @@ def maybe_install() -> bool:
         from jax import monitoring as jmon
 
         jmon.register_event_duration_secs_listener(_on_duration_event)
+        jmon.register_event_listener(_on_event)
     except Exception as e:  # noqa: BLE001 - fallback path takes over
         log.debug("jax.monitoring unavailable (%s): track() will wall-clock "
                   "first calls instead", e)
@@ -610,3 +626,4 @@ def _reset_for_tests() -> None:
     with _watch_lock:
         _watch["compile_ms"] = 0.0
         _watch["compiles"] = 0
+        _watch["cache_hits"] = 0

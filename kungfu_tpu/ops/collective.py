@@ -246,20 +246,15 @@ def ppermute_pair_exchange(
 
 
 def _axis_size(axis_name: AxisName) -> int:
-    # `lax.axis_size` does not exist on the pinned JAX; compat routes to it
-    # where available and to the static psum(1, axis) fold otherwise
-    from .. import compat
-
-    return compat.axis_size(axis_name)
+    """Static size of one mesh axis, or the product over a tuple of axes."""
+    return lax.axis_size(axis_name)
 
 
 def _flat_axis_index(axis_name: AxisName) -> jax.Array:
     """Row-major flat index over one or several axes."""
-    from .. import compat
-
     if isinstance(axis_name, (tuple, list)):
         idx = jnp.zeros((), jnp.int32)
         for a in axis_name:
-            idx = idx * compat.axis_size(a) + lax.axis_index(a)
+            idx = idx * lax.axis_size(a) + lax.axis_index(a)
         return idx
     return lax.axis_index(axis_name)

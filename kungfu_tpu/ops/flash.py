@@ -50,6 +50,20 @@ def _mode(interpret: Optional[bool] = None) -> str:
     return compat.pallas_mode(interpret)
 
 
+def _compiler_params() -> pltpu.CompilerParams:
+    """Mosaic enforces the budget the tile gates checked, not its own
+    smaller default.  (The shipped tiles compile under 16 MiB as well — the
+    v5e chip run of PR 21 — so today this changes no outcome; it keeps the
+    gate's number and the compiler's the same number.)"""
+    return pltpu.CompilerParams(vmem_limit_bytes=compat.vmem_budget_bytes())
+
+
+def _vma_of(*xs) -> frozenset:
+    """Union of the varying-manual-axes of `xs`: under shard_map a
+    pallas_call's outputs must declare how they vary across mesh axes."""
+    return frozenset().union(*(jax.typeof(x).vma for x in xs))
+
+
 def _kloop_ranges(qi, block_q: int, block_k: int, nk: int, causal: bool,
                   window: int, seq_len: int):
     """Split a q-block's k-loop [lo, hi) into masked-prefix / unmasked-
@@ -246,7 +260,7 @@ def _flash_fwd(q, k, v, scale: float, causal: bool, block_q: int, block_k: int,
     )
     # under shard_map (check_vma) outputs must declare how they vary across
     # mesh axes: they vary exactly as the union of the inputs
-    vma = compat.vma_of(qp, kp, vp)
+    vma = _vma_of(qp, kp, vp)
     kv_spec = pl.BlockSpec((1, lk, d), lambda b, i: (_kv_row(b, h, hkv), 0, 0))
     o, lse = pl.pallas_call(
         kern,
@@ -261,9 +275,10 @@ def _flash_fwd(q, k, v, scale: float, causal: bool, block_q: int, block_k: int,
             pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
         ],
         out_shape=[
-            compat.shape_dtype_struct((bh, lq, d), q.dtype, vma=vma),
-            compat.shape_dtype_struct((bh, 1, lq), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((bh, lq, d), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, 1, lq), jnp.float32, vma=vma),
         ],
+        compiler_params=_compiler_params(),
         interpret=mode == "interpret",
     )(qp, kp, vp)
     return o[:, :seq_len], lse[:, 0, :seq_len]
@@ -496,7 +511,7 @@ def _bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
     lse_p = rows(lse)
     delta_p = rows(delta)
 
-    vma = compat.vma_of(qp, kp, vp, dop, lse_p, delta_p)
+    vma = _vma_of(qp, kp, vp, dop, lse_p, delta_p)
     dq_kern = functools.partial(
         _bwd_dq_kernel, scale=scale, causal=causal, block_k=block_k,
         seq_len=seq_len, window=window,
@@ -514,7 +529,8 @@ def _bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
             pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-        out_shape=compat.shape_dtype_struct((bh, lq, d), q.dtype, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((bh, lq, d), q.dtype, vma=vma),
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(qp, kp, vp, dop, lse_p, delta_p)
 
@@ -539,9 +555,10 @@ def _bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
                 pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
             ],
             out_shape=[
-                compat.shape_dtype_struct((bh, lk, d), k.dtype, vma=vma),
-                compat.shape_dtype_struct((bh, lk, d), v.dtype, vma=vma),
+                jax.ShapeDtypeStruct((bh, lk, d), k.dtype, vma=vma),
+                jax.ShapeDtypeStruct((bh, lk, d), v.dtype, vma=vma),
             ],
+            compiler_params=_compiler_params(),
             interpret=interpret,
         )(kp, vp, qp, dop, lse_p, delta_p)
     else:
@@ -568,9 +585,10 @@ def _bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
                 pl.BlockSpec((1, block_k, d), lambda b, j, g_: (b, j, 0)),
             ],
             out_shape=[  # fp32: cross-group accumulation must be exact
-                compat.shape_dtype_struct((bhkv, lk, d), jnp.float32, vma=vma),
-                compat.shape_dtype_struct((bhkv, lk, d), jnp.float32, vma=vma),
+                jax.ShapeDtypeStruct((bhkv, lk, d), jnp.float32, vma=vma),
+                jax.ShapeDtypeStruct((bhkv, lk, d), jnp.float32, vma=vma),
             ],
+            compiler_params=_compiler_params(),
             interpret=interpret,
         )(kp, vp, qp, dop, lse_p, delta_p)
         dk = dk.astype(k.dtype)

@@ -51,13 +51,14 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import compat
 from . import collective as C
 from . import pallas_collectives as PC
 from . import ring_kernels as RK
 
 LANES = PC.LANES
 
-_ANY = pltpu.TPUMemorySpace.ANY
+_ANY = pl.ANY
 
 
 def _sublanes(dtype) -> int:
@@ -130,7 +131,7 @@ def all_gather_matmul(
     mp = _pad_up(m, _sublanes(x.dtype))
     itemsize = jnp.dtype(w_shard.dtype).itemsize
     if RK.ag_matmul_scratch_bytes(n, kp, np_, mp, itemsize) \
-            > PC._vmem_budget_bytes():
+            > compat.vmem_budget_bytes():
         return fallback()
     # x blocked by contraction chunk: block c multiplies shard W_c
     xb = _pad2(x.reshape(m, n, ks).transpose(1, 0, 2), mp, kp)
@@ -193,7 +194,7 @@ def matmul_reduce_scatter(
     mcp = _pad_up(mc, _sublanes(x.dtype))
     kp = _pad_up(k, LANES)  # lanes of x and sublanes of w; lcm-safe
     np_ = _pad_up(nn, LANES)
-    if RK.matmul_rs_scratch_bytes(n, mcp, np_) > PC._vmem_budget_bytes():
+    if RK.matmul_rs_scratch_bytes(n, mcp, np_) > compat.vmem_budget_bytes():
         return fallback()
     xb = _pad2(x.reshape(n, mc, k), mcp, kp)
     wb = _pad2(w, kp, np_)
@@ -291,7 +292,7 @@ def _shift_impl(x, axis_name, shift, interpret):
     if (mode == "off" or n <= 1 or not PC._sole_named_axis(axis_name)
             or not PC._supported_dtype(x.dtype)
             or 2 * rows * LANES * jnp.dtype(x.dtype).itemsize
-            > PC._vmem_budget_bytes()):
+            > compat.vmem_budget_bytes()):
         return lax.ppermute(x, axis_name, perm)
     flat = x.reshape(-1)
     pad = rows * LANES - elems
@@ -346,7 +347,7 @@ def _smoke(np_ranks: int) -> int:
     through the custom-VJP wrappers and match the XLA transposes."""
     import numpy as np
 
-    from ..compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     assert PC.pallas_mode() == "off", (

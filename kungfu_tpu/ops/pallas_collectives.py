@@ -58,11 +58,7 @@ LANES = 128
 #: fp32 tile = 8 sublanes x 128 lanes; per-chunk padding unit
 TILE = 8 * LANES
 
-_ANY = pltpu.TPUMemorySpace.ANY
-
-
-def _vmem_budget_bytes() -> int:
-    return int(os.environ.get("KFT_PALLAS_VMEM_MIB", "64")) << 20
+_ANY = pl.ANY
 
 
 def pallas_mode(interpret: Optional[bool] = None) -> str:
@@ -98,15 +94,10 @@ def _sole_named_axis(axis_name) -> bool:
     kernels (`len(self._axes) == 1`).  On a multi-axis manual region
     (e.g. an fsdp ring inside a dp×fsdp shard_map) the wrappers fall
     back to the lax lowering instead of building an untraceable kernel.
-    Best-effort introspection: unknown ⇒ False (fallback, never wedge).
     """
-    try:
-        from jax._src import core as _jcore
+    from jax._src import core as _jcore
 
-        names = tuple(_jcore.get_axis_env().axis_sizes.keys())
-    except Exception:
-        return False
-    return names == (axis_name,)
+    return tuple(_jcore.get_axis_env().axis_sizes) == (axis_name,)
 
 
 def _ring_ok(n: int, chunk: int, dtype, axis_name,
@@ -115,7 +106,7 @@ def _ring_ok(n: int, chunk: int, dtype, axis_name,
         return False
     if cfg is None and not _supported_dtype(dtype):
         return False
-    return RK.scratch_bytes(n, chunk, cfg) <= _vmem_budget_bytes()
+    return RK.scratch_bytes(n, chunk, cfg) <= compat.vmem_budget_bytes()
 
 
 # --- plain ring primitives -------------------------------------------------------------
@@ -229,7 +220,7 @@ def _fused_ok(n: int, cfg: CompressionConfig, chunk: int,
         return False
     if cfg.scheme == "fp8" and RK.FP8_DTYPE is None:
         return False
-    return RK.scratch_bytes(n, chunk, cfg) <= _vmem_budget_bytes()
+    return RK.scratch_bytes(n, chunk, cfg) <= compat.vmem_budget_bytes()
 
 
 def fused_ring_all_reduce(

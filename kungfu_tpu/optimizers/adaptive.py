@@ -15,10 +15,13 @@ import jax.numpy as jnp
 from jax import lax
 import optax
 
-from .. import compat
 from ..ops import collective as C
 
 AxisName = Union[str, Tuple[str, ...]]
+
+
+def _tree_pvary(tree, axis_name: AxisName):
+    return jax.tree.map(lambda x: lax.pcast(x, axis_name, to="varying"), tree)
 
 
 class AdaptiveSGDState(NamedTuple):
@@ -53,9 +56,8 @@ def adaptive_sgd(
             g = jax.tree.map(lambda x: lax.pmean(x, axis_name), g)
             u, s = inner.update(g, istate, p)
             # pmean makes this branch's outputs replicated; mark them varying
-            # so both cond branches have identical vma types (JAX >= 0.7;
-            # identity on pre-vma JAX)
-            return compat.tree_pcast((u, s), axis_name)
+            # so both cond branches have identical vma types
+            return _tree_pvary((u, s), axis_name)
 
         # pmax-fold the step counter: every replica increments it in
         # lockstep, so this is the identity — but it makes the phase-switch
@@ -164,7 +166,7 @@ def noise_adaptive_compression(
             ]
 
         def full_branch(ls):
-            return compat.tree_pcast(
+            return _tree_pvary(
                 [lax.pmean(g, axis_name) for g in ls], axis_name
             )
 

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax import lax
 import optax
 
-from .. import compat
 
 AxisName = Union[str, Tuple[str, ...]]
 
@@ -83,7 +82,7 @@ def gradient_noise_scale(
         )
 
     def update_fn(updates, state, params=None):
-        n = axis_size if axis_size is not None else compat.axis_size(axis_name)
+        n = axis_size if axis_size is not None else lax.axis_size(axis_name)
         if n <= 1:
             # single worker: B == b makes the estimator 0/0 — pass through
             # with noise_scale pinned at 0 rather than poisoning the EMA

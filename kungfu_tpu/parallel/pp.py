@@ -37,7 +37,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import axis_size as _axis_size, pcast as _pcast, shard_map as _shard_map
 from ..plan.graph import validate_permutation
 
 
@@ -59,7 +58,7 @@ def pipeline_spmd(
     Returns [M, mb, ...] (pp-invariant: the last stage's outputs, psum-
     selected across the ring).
     """
-    S = _axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     M = xs.shape[0]
     R = repeats
     if R > 1 and M < S:
@@ -77,9 +76,9 @@ def pipeline_spmd(
 
     # zeros_like inherits xs's vma (it may vary over dp when a data axis
     # rides along); pcast adds the pp axis the carries rotate over
-    h0 = _pcast(jnp.zeros_like(xs[0]), axis_name, to="varying")
-    out0 = _pcast(jnp.zeros_like(xs), axis_name, to="varying")
-    store0 = _pcast(jnp.zeros_like(xs), axis_name, to="varying")
+    h0 = lax.pcast(jnp.zeros_like(xs[0]), axis_name, to="varying")
+    out0 = lax.pcast(jnp.zeros_like(xs), axis_name, to="varying")
+    store0 = lax.pcast(jnp.zeros_like(xs), axis_name, to="varying")
 
     def tick(carry, t):
         h, store, out = carry
@@ -93,7 +92,7 @@ def pipeline_spmd(
         # device 0 input: fresh microbatch t while t < M, else the parked
         # activation whose next round starts now (slot t % M)
         fresh = lax.dynamic_index_in_dim(xs, jnp.minimum(t, M - 1), 0, keepdims=False)
-        fresh = _pcast(fresh, axis_name, to="varying")
+        fresh = lax.pcast(fresh, axis_name, to="varying")
         if R > 1:
             recirc = lax.dynamic_index_in_dim(store, t % M, 0, keepdims=False)
             feed = jnp.where(t < M, fresh, recirc)
@@ -150,7 +149,7 @@ def pipeline_apply_grouped(
             remat=remat,
         )
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(P(axis_name), P()),

@@ -41,7 +41,7 @@ import flax.linen as nn
 from flax.linen import spmd as flax_spmd
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 from ..models.transformer import Block, TransformerConfig, TransformerLM
 from .pp import pipeline_spmd
 

@@ -4,17 +4,16 @@ The reference has NO long-context support (SURVEY.md §5: no ring attention,
 no sequence parallelism anywhere in the tree); this module is the TPU-native
 capability the reference lacks, built the way the hardware wants it: the
 sequence is sharded over the `sp` mesh axis, K/V blocks rotate around the
-ring on the Pallas DMA data plane (`ops.fused_matmul.ring_shift` — one
-remote DMA per neighbor hop, `lax.ppermute` fallback off-TPU), and each
+ring (`lax.ppermute`; see `_rotate_kv`), and each
 device folds one block per hop into a flash-style online-softmax
 accumulator (fp32), so the full sequence never materializes on any chip.
 Peak memory per chip is O(L/n), compute overlaps communication hop by hop
-(hop h+1's DMA streams while the block math for hop h runs).
+(hop h+1's transfer streams while the block math for hop h runs).
 
 Use under shard_map with q/k/v sharded on the sequence dim:
 
     out = shard_map(lambda q, k, v: ring_attention(q, k, v, axis_name="sp"),
-                    mesh, in_specs=P(None, "sp", None, None), ...)
+                    mesh=mesh, in_specs=P(None, "sp", None, None), ...)
 """
 from __future__ import annotations
 
@@ -25,25 +24,27 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size as _axis_size
+from ..compat import pallas_mode
 
 NEG_INF = -1e30
 
 
 def _rotate_kv(k, v, axis_name):
-    """One ring hop of the K/V blocks — on the Pallas DMA data plane.
+    """One ring hop of the K/V blocks.
 
-    `ops.fused_matmul.ring_shift` moves each block as one remote DMA
-    (the same make_async_remote_copy machinery the fused matmul kernels
-    ride) and falls back to the identical `lax.ppermute` lowering
-    whenever the kernels can't run here (compat.pallas_mode off, shapes
-    past the VMEM budget, unsupported dtype) — pure data movement, so
-    the two paths are bit-identical.  Differentiable: ring_shift's VJP
-    rotates the cotangent backwards, matching ppermute's transpose.
+    `lax.ppermute` by default.  Under KFT_PALLAS=interpret the hop rides
+    `ops.fused_matmul.ring_shift` (one remote DMA per block, bit-identical
+    to the ppermute, differentiable) so the tier-1 tests keep exercising
+    that kernel; on a TPU it stays off this path until the remote-DMA
+    kernels have compiled on a chip (ROADMAP S8).
     """
-    from ..ops.fused_matmul import ring_shift
+    if pallas_mode() == "interpret":
+        from ..ops.fused_matmul import ring_shift
 
-    return ring_shift(k, axis_name, 1), ring_shift(v, axis_name, 1)
+        return ring_shift(k, axis_name, 1), ring_shift(v, axis_name, 1)
+    n = lax.axis_size(axis_name)
+    perm = [(i, (i + 1) % n) for i in range(n)]
+    return lax.ppermute(k, axis_name, perm), lax.ppermute(v, axis_name, perm)
 
 
 def _block_attn(q, k, v, m, l, o, q_off, k_off, causal: bool, scale: float):
@@ -145,14 +146,14 @@ def ring_attention(
     called inside shard_map with `axis_name` in scope.
 
     `impl` selects the per-block compute: "flash" streams each hop's block
-    through the Pallas kernel (default on TPU), "einsum" is the plain-XLA
-    path (default elsewhere — the kernel would run interpreted).
+    through the Pallas kernel (default wherever compat.pallas_mode lets the
+    kernels run), "einsum" is the plain-XLA path (default when it is "off").
     """
     if impl is None:
-        impl = "flash" if jax.default_backend() == "tpu" else "einsum"
+        impl = "flash" if pallas_mode() != "off" else "einsum"
     if impl == "flash":
         return _ring_attention_flash(q, k, v, axis_name, causal, scale)
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     B, Lc, H, D = q.shape
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
@@ -190,7 +191,7 @@ def _ring_attention_flash(q, k, v, axis_name, causal, scale):
     normalized (o, lse) pair merges into the running pair (logaddexp), so
     the accumulator math stays out of the kernel and stays differentiable
     (the kernel's VJP handles the lse cotangent)."""
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     B, Lc, H, D = q.shape
     scale = scale if scale is not None else 1.0 / (D ** 0.5)

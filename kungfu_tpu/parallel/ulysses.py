@@ -25,7 +25,7 @@ Trade-off table (both under shard_map, q/k/v sharded on seq dim):
 Use under shard_map exactly like ring_attention:
 
     out = shard_map(lambda q, k, v: ulysses_attention(q, k, v, axis_name="sp"),
-                    mesh, in_specs=P(None, "sp", None, None), ...)
+                    mesh=mesh, in_specs=P(None, "sp", None, None), ...)
 """
 from __future__ import annotations
 
@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size as _axis_size
+from ..compat import pallas_mode
 
 
 def _seq_to_heads(x, axis_name: str):
@@ -73,7 +73,7 @@ def ulysses_attention(
     flash kernel on TPU, plain einsum elsewhere (models/transformer.py's
     "auto" rule) — both are GQA-native.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     b, l_shard, h, d = q.shape
     hkv = k.shape[2]
     if h % n:
@@ -81,7 +81,7 @@ def ulysses_attention(
             f"{axis_name} axis size {n} must divide n_heads={h}"
         )
     if attn_fn is None:
-        if jax.default_backend() == "tpu":
+        if pallas_mode() != "off":
             from ..ops.flash import flash_attention as attn_fn
         else:
             from .ring_attention import full_attention as attn_fn

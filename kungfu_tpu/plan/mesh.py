@@ -74,14 +74,13 @@ def make_mesh(
     sizes_r = spec.resolve(len(devs))
     names = tuple(sizes_r)
     shape = tuple(sizes_r[a] for a in names)
-    try:
+    if devices is None and jax.default_backend() == "tpu":
         from jax.experimental import mesh_utils
 
-        if devices is None and jax.default_backend() == "tpu":
-            arr = mesh_utils.create_device_mesh(shape)
-        else:
-            arr = np.asarray(devs).reshape(shape)
-    except Exception:
+        # a topology the chips cannot form must fail here, not be papered
+        # over with an arbitrary device order
+        arr = mesh_utils.create_device_mesh(shape)
+    else:
         arr = np.asarray(devs).reshape(shape)
     return Mesh(arr, names)
 

@@ -213,7 +213,7 @@ class Planner:
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
-        from ..compat import shard_map
+        from jax import shard_map
         from ..ops import fused_matmul as FM
 
         mesh = self.session.mesh

@@ -122,7 +122,8 @@ def main(argv=None):
     )
     ap.add_argument(
         "-chips-per-host", dest="chips_per_host", type=int, default=0,
-        help="manage TPU_VISIBLE_CHIPS slots per host",
+        help="give each worker one TPU chip of its host (TPU_VISIBLE_CHIPS "
+             "and libtpu's process grid); the host's workers form one slice",
     )
     ap.add_argument("prog", nargs=argparse.REMAINDER, help="worker command")
     args = ap.parse_args(argv)
@@ -143,6 +144,14 @@ def main(argv=None):
     hosts = HostList.parse(args.hosts) if args.hosts else HostList.parse(f"127.0.0.1:{args.np}")
     cluster = Cluster.from_hostlist(hosts, args.np)
     self_host = args.self_host or infer_self_ip(hosts)
+    if args.chips_per_host > 0 and args.platform != "cpu":
+        from .job import chip_env
+
+        try:  # refuse a shape libtpu cannot form before anything is spawned
+            chip_env(0, n_procs=sum(1 for p in cluster.workers
+                                    if p.host == self_host))
+        except ValueError as e:
+            ap.error(str(e))
 
     if args.telemetry:
         # arm the whole fleet: workers inherit these via Job.new_proc's env

@@ -16,6 +16,42 @@ from ..env import worker_env
 from ..plan import Cluster, PeerID, Strategy
 
 
+#: libtpu's grid of one-chip processes on one host, by how many of them
+#: form the slice.  Established on a v5e 2x2 host; other shapes are refused
+#: rather than guessed.
+_PROCESS_BOUNDS = {1: "1,1,1", 4: "2,2,1"}
+#: libtpu's default port for the processes of one slice to find each other
+_TPU_PROCESS_PORT = 8476
+
+
+def chip_env(chip: int, n_procs: int = 1, index: int = 0) -> Dict[str, str]:
+    """The libtpu variables that hand one process one chip of its host.
+
+    TPU_VISIBLE_CHIPS alone leaves libtpu expecting the host's whole
+    topology.  n_procs == 1 makes the chip a world of its own (a serving
+    replica, a lone worker).  n_procs > 1 makes the process number `index`
+    of `n_procs` one-chip processes that together form one slice of the
+    host, so that jax.distributed sees n_procs devices, one local to each.
+    """
+    if n_procs not in _PROCESS_BOUNDS:
+        raise ValueError(
+            f"{n_procs} one-chip TPU workers on one host: libtpu forms a "
+            f"multi-process slice here only from {sorted(_PROCESS_BOUNDS)} "
+            "processes (a v5e 2x2 host); use one worker that owns every chip "
+            "(-np 1 without -chips-per-host), or -platform cpu"
+        )
+    port = _TPU_PROCESS_PORT + (chip if n_procs == 1 else 0)
+    return {
+        "TPU_VISIBLE_CHIPS": str(chip),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": _PROCESS_BOUNDS[n_procs],
+        "TPU_PROCESS_ADDRESSES": ",".join(
+            f"localhost:{port + i}" for i in range(n_procs)),
+        "TPU_PROCESS_PORT": str(port + index),
+        "CLOUD_TPU_TASK_ID": str(index),
+    }
+
+
 class ChipPool:
     """Smallest-free-id device slot allocator (reference gpu_resource.go:10-45)."""
 
@@ -96,15 +132,16 @@ class Job:
                     env["XLA_FLAGS"] = (
                         flags + f" --xla_force_host_platform_device_count={self.devices_per_worker}"
                     ).strip()
-        if self.chips_per_host > 0 and chip >= 0:
-            # reference sets CUDA_VISIBLE_DEVICES (cuda_visible_device.go:17-33),
-            # respecting a pre-set visible list; same contract for TPU chips
+        if self.chips_per_host > 0 and chip >= 0 and self.platform != "cpu":
+            # reference sets CUDA_VISIBLE_DEVICES (cuda_visible_device.go:17-33);
+            # a TPU chip needs libtpu's process grid set beside it
+            # (a pre-set visible list is respected, as the reference does)
             pre = env.get("TPU_VISIBLE_CHIPS")
             if pre:
                 visible = pre.split(",")
-                env["TPU_VISIBLE_CHIPS"] = visible[chip % len(visible)]
-            else:
-                env["TPU_VISIBLE_CHIPS"] = str(chip)
+                chip = int(visible[chip % len(visible)])
+            local = [p for p in cluster.workers if p.host == peer.host]
+            env.update(chip_env(chip, n_procs=len(local), index=local.index(peer)))
         args = [self.prog] + list(self.args)
         return Proc(
             name=f"{cluster.workers.rank(peer)}", args=args, env=env, peer=peer, chip=chip

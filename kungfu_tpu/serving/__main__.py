@@ -52,9 +52,16 @@ def _arm_telemetry(logdir: str) -> None:
 
 class ServeSupervisor:
     def __init__(self, args, cluster: Cluster, client: ConfigClient):
+        from ..run.job import ChipPool
         from ..run.launcher import ProcRunner
 
         self._proc_runner_cls = ProcRunner
+        # one chip for each worker: without it every worker would claim
+        # every chip of the host, and the second one to start would fail
+        self.pool = (ChipPool(args.chips_per_host)
+                     if args.chips_per_host > 0 and args.platform != "cpu"
+                     else None)
+        self.chips: Dict[PeerID, int] = {}
         self.args = args
         self.client = client
         self.cluster = cluster
@@ -93,8 +100,17 @@ class ServeSupervisor:
         return cmd
 
     def _spawn(self, peer: PeerID, incarnation: int) -> None:
-        from ..run.job import Proc
+        from ..run.job import Proc, chip_env
 
+        chip = None
+        if self.pool is not None:
+            chip = self.pool.get()
+            if chip is None:
+                log.error("no free chip for serving worker %s "
+                          "(--chips-per-host %d)", peer,
+                          self.args.chips_per_host)
+                return
+            self.chips[peer] = chip
         if peer not in self.launch_ranks:
             self.launch_ranks[peer] = self._next_rank
             self._next_rank += 1
@@ -110,6 +126,8 @@ class ServeSupervisor:
             env["KFT_PLATFORM"] = self.args.platform
             if self.args.platform == "cpu":
                 env["JAX_PLATFORMS"] = "cpu"
+        if chip is not None:
+            env.update(chip_env(chip))  # each replica a one-chip world
         proc = Proc(name=str(rank),
                     args=self._worker_cmd(peer, rank, incarnation),
                     env=env, peer=peer)
@@ -131,9 +149,14 @@ class ServeSupervisor:
         for peer in sorted(have - want):
             r = self.procs.pop(peer)
             r.terminate()
+            self._release_chip(peer)
             log.info("- serving worker %s (scaled away at v%d)", peer, version)
         for peer in sorted(want - have):
             self._spawn(peer, self.incarnations.get(peer, -1) + 1)
+
+    def _release_chip(self, peer: PeerID) -> None:
+        if self.pool is not None and peer in self.chips:
+            self.pool.put(self.chips.pop(peer))
 
     def collect_dead(self) -> None:
         """A dead worker still in the document respawns in place — the
@@ -148,6 +171,7 @@ class ServeSupervisor:
                 continue
             r.wait()
             del self.procs[peer]
+            self._release_chip(peer)
             if rc != 0:
                 self.failures += 1
                 global_counters().inc_event("serve_worker_failures")
@@ -209,6 +233,9 @@ def main(argv=None) -> int:
     ap.add_argument("--queue-capacity", type=int, default=256)
     ap.add_argument("--worker-queue-capacity", type=int, default=64)
     ap.add_argument("--platform", default="", help="force worker backend (cpu)")
+    ap.add_argument("--chips-per-host", type=int, default=0,
+                    help="give each worker one TPU chip of this host; the "
+                         "fleet then never grows past this many workers")
     ap.add_argument("--timeout", type=float, default=0.0,
                     help="run this long then exit cleanly (0: forever)")
     ap.add_argument("--no-autoscale", action="store_true")
@@ -220,6 +247,11 @@ def main(argv=None) -> int:
     if args.max_size <= 0:
         args.max_size = max(args.np, 4)
     args.max_size = max(args.max_size, args.np)
+    if args.chips_per_host > 0 and args.platform != "cpu":
+        if args.np > args.chips_per_host:
+            ap.error(f"-np {args.np} one-chip workers need more than "
+                     f"--chips-per-host {args.chips_per_host}")
+        args.max_size = min(args.max_size, args.chips_per_host)
     if args.telemetry:
         _arm_telemetry(args.logdir)
         from ..monitor.journal import set_journal_context

@@ -529,9 +529,13 @@ class ServingWorker:
         monitor = maybe_start_monitor(self.args.port, host=self.args.host)
         loop = threading.Thread(target=self._engine_loop, daemon=True)
         loop.start()
+        import jax
+
+        dev = jax.devices()[0]
         print(f"SERVE_WORKER_READY: rank={self.rank} "
               f"url=http://{self.args.host}:{self.args.port} "
-              f"rung={self.weight_rung}"
+              f"rung={self.weight_rung} platform={dev.platform} "
+              f"device_kind={dev.device_kind!r} devices={jax.device_count()}"
               + (f" tier={self.tier}" if self.tier else ""), flush=True)
         try:
             httpd.serve_forever()
@@ -580,6 +584,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--buddy-timeout-s", type=float, default=3.0)
     ap.add_argument("--request-timeout-s", type=float, default=120.0)
     args = ap.parse_args(argv)
+    from ..env import apply_platform_override, enable_compile_cache
+
+    apply_platform_override()
+    enable_compile_cache()
     return ServingWorker(args).serve()
 
 

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .compat import shard_map
+from jax import shard_map
 from .ops import collective as C
 from .plan import PALLAS_IMPLS, Strategy, Impl, impl_of, make_mesh
 from .utils import get_logger, stall_detector
@@ -329,8 +329,8 @@ class Session:
 
         # pallas_call has no replication rule: those programs opt out of
         # the rep/vma check (kf-lint still covers the fallback lowering)
-        check = False if impl in PALLAS_IMPLS else None
-        return jax.jit(shard_map(body, self.mesh, in_specs=spec,
+        check = impl not in PALLAS_IMPLS
+        return jax.jit(shard_map(body, mesh=self.mesh, in_specs=spec,
                                  out_specs=spec, check_vma=check))
 
     # -- public collective API (reference session/{allreduce,allgather,session}.go) ---
@@ -519,8 +519,8 @@ class Session:
             return tuple(reduce_impl(jnp.squeeze(y, 0))[None] for y in ys)
 
         specs = tuple(spec for _ in signature)
-        check = False if impl in PALLAS_IMPLS else None
-        fn = jax.jit(shard_map(body, self.mesh, in_specs=specs,
+        check = impl not in PALLAS_IMPLS
+        fn = jax.jit(shard_map(body, mesh=self.mesh, in_specs=specs,
                                out_specs=specs, check_vma=check))
         self._fns[key] = fn
         return fn

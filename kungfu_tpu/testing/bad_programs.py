@@ -44,14 +44,14 @@ def _b_axis_typo():
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
-        from ..compat import shard_map
+        from jax import shard_map
 
         mesh = _mesh({"dp": 8})
 
         def body(x):
             return lax.psum(x, "dp ")  # trailing space: the classic typo
 
-        fn = shard_map(body, mesh, in_specs=P("dp"), out_specs=P(),
+        fn = shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P(),
                        check_vma=False)
         return fn, (_sds((8, 128)),), {"mesh": mesh}
 
@@ -63,7 +63,7 @@ def _b_cond_divergent():
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
-        from ..compat import shard_map
+        from jax import shard_map
 
         mesh = _mesh({"dp": 8})
 
@@ -74,7 +74,7 @@ def _b_cond_divergent():
                             lambda v: lax.psum(v, "dp"),
                             lambda v: v, x)
 
-        fn = shard_map(body, mesh, in_specs=P("dp"), out_specs=P("dp"),
+        fn = shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
                        check_vma=False)
         return fn, (_sds((8, 128)),), {"mesh": mesh}
 
@@ -86,7 +86,7 @@ def _b_bad_ppermute():
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
-        from ..compat import shard_map
+        from jax import shard_map
 
         mesh = _mesh({"dp": 8})
         # rank 1 receives twice, rank 0 never: double-write + starvation
@@ -95,7 +95,7 @@ def _b_bad_ppermute():
         def body(x):
             return lax.ppermute(x, "dp", perm)
 
-        fn = shard_map(body, mesh, in_specs=P("dp"), out_specs=P("dp"),
+        fn = shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
                        check_vma=False)
         return fn, (_sds((8, 128)),), {"mesh": mesh}
 
@@ -107,7 +107,7 @@ def _b_raw_psum_on_int8_axis():
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
-        from ..compat import shard_map
+        from jax import shard_map
 
         mesh = _mesh({"dp": 8})
 
@@ -115,7 +115,7 @@ def _b_raw_psum_on_int8_axis():
             # full-precision words on an axis deployed with an int8 wire
             return lax.psum(x, "dp")
 
-        fn = shard_map(body, mesh, in_specs=P("dp"), out_specs=P(),
+        fn = shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P(),
                        check_vma=False)
         return fn, (_sds((8, 4096)),), {"mesh": mesh,
                                         "compression": {"dp": "int8"}}
@@ -129,7 +129,7 @@ def _b_unreduced_gradient():
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
-        from ..compat import shard_map
+        from jax import shard_map
 
         mesh = _mesh({"dp": 8})
 
@@ -140,7 +140,7 @@ def _b_unreduced_gradient():
             g = jax.grad(loss)(p, b)  # per-device grads, never psummed
             return p - 0.01 * g       # ...flowing into replicated params
 
-        fn = shard_map(body, mesh, in_specs=(P(), P("dp")), out_specs=P(),
+        fn = shard_map(body, mesh=mesh, in_specs=(P(), P("dp")), out_specs=P(),
                        check_vma=False)
         return fn, (_sds((16, 4)), _sds((32, 16))), {"mesh": mesh}
 

@@ -126,20 +126,26 @@ class MeshTrainer:
             opt_state = jax.jit(self.tx.init)(placed)
             # leaves tx.init created fresh (step counters, scalar
             # schedules) come back default-placed on ONE device, not the
-            # mesh — harmless for the (uncommitted) train step but a
-            # committed single-device sharding after checkpoint restore
-            # conflicts with the mesh.  Pin them replicated on the mesh.
+            # mesh: pin them replicated on the mesh.  Every other leaf is
+            # put where it already is, which COMMITS it: jit keys its cache
+            # on committedness, and the step returns committed arrays, so
+            # an uncommitted initial state compiled the step a second time.
             mesh_devs = set(self.mesh.devices.flat)
             replicated = NamedSharding(self.mesh, P())
 
             def on_mesh(x):
-                if getattr(x, "sharding", None) is None:
-                    return x
                 if set(x.sharding.device_set) != mesh_devs:
                     return jax.device_put(x, replicated)
-                return x
+                return jax.device_put(x, x.sharding)
 
             opt_state = jax.tree.map(on_mesh, opt_state)
+        # the step hands its state back under exactly these shardings.  Left
+        # to the compiler, equal shardings come back spelled differently
+        # (P() vs P(None, None)), which misses jit's cache: the second step
+        # compiled the whole program again
+        self._state_shardings = jax.tree.map(
+            lambda x: x.sharding, (placed, opt_state)
+        )
         self._step_fn = self._build_step()
         return TrainState(params=placed, opt_state=opt_state, step=0)
 
@@ -172,7 +178,15 @@ class MeshTrainer:
             )
             return params, opt_state, {"loss": loss}
 
-        return jax.jit(step, donate_argnums=(0, 1) if self._donate else ())
+        return self._jit(step)
+
+    def _jit(self, fn):
+        """jit a (params, opt_state, ...) -> (params, opt_state, metrics) fn
+        that hands the state back under the shardings it was placed with."""
+        return jax.jit(
+            fn, donate_argnums=(0, 1) if self._donate else (),
+            out_shardings=(*self._state_shardings, None),
+        )
 
     # -- host API ---------------------------------------------------------------------
 
@@ -201,6 +215,18 @@ class MeshTrainer:
             )
         return TrainState(params, opt_state, state.step + 1), metrics
 
+    def lower_step(self, state: TrainState, batch: Any):
+        """The train step lowered for `state` and a placed `batch`
+        (`jax.stages.Lowered`): `.as_text()` is the program handed to the
+        compiler, `.compile().as_text()` the one it produced."""
+        if self._step_fn is None:
+            raise RuntimeError("call init() before lower_step()")
+        with self.mesh:
+            return self._step_fn.lower(
+                state.params, state.opt_state, batch,
+                self._step_rng(state.step),
+            )
+
     def _build_multi_step(self, n: int):
         base = self._base_rng
 
@@ -219,7 +245,7 @@ class MeshTrainer:
             )
             return params, opt_state, {"loss": losses[-1]}
 
-        return jax.jit(many, donate_argnums=(0, 1) if self._donate else ())
+        return self._jit(many)
 
     def train_steps(self, state: TrainState, batch: Any, n: int) -> Tuple[TrainState, Dict]:
         """Run `n` steps on one device-resident batch in a single dispatch

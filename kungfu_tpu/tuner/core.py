@@ -26,7 +26,7 @@ to the search machinery:
                  donate, bucket_bytes); the decision journals
                  `tuner_selected` and persists to the prior cache keyed
                  (shape digest | backend | jax version) — tuning survives
-                 restarts and the unattended TPU queue.
+                 restarts.
 
 `resolve_flash_blocks` is the read path the model layer uses: a
 TransformerConfig with `flash_block_q/k=None` asks the prior cache (file
@@ -44,7 +44,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 from ..monitor.journal import journal_event
 from ..utils import get_logger
 from . import footprint, measure
-from .cache import PriorCache, backend_name, jax_version
+from .cache import CACHE_ENV, PriorCache, backend_name, jax_version
 from .space import ShapeKey, StepConfig, default_config, enumerate_configs
 
 log = get_logger("kungfu.tuner")
@@ -274,8 +274,12 @@ def _cached_prior_cache(path: str) -> PriorCache:
 
 
 def _prior_cache() -> PriorCache:
-    return _cached_prior_cache(os.path.abspath(
-        os.environ.get("KFT_TUNER_CACHE", "") or ".kft_tuner_cache.json"))
+    """The priors the model layer reads at trace time: the file
+    KFT_TUNER_CACHE names, else the shipped priors alone.  Never a default
+    path in the current directory — a stray file there would change the
+    tiles a run compiles."""
+    path = os.environ.get(CACHE_ENV, "")
+    return _cached_prior_cache(os.path.abspath(path) if path else "")
 
 
 def _reset_prior_cache_for_tests() -> None:
@@ -324,25 +328,22 @@ def resolve_flash_blocks(cfg, batch: int, seq_len: int) -> Tuple[int, int]:
     """The flash tile sizes a model config actually runs with.
 
     Explicit ints always win (`flash_block_q/k` set on the config);
-    `None` asks, in order: the prior cache's winner for this exact
-    (shape, backend, jax version), the shipped round-5 hunt priors, the
-    shape-conditional default table — then clamps the answer to the
+    `None` asks, in order: the winner for this exact (shape, backend,
+    jax version) in the file KFT_TUNER_CACHE names, the shipped round-5
+    hunt priors, the shape-conditional default table — then clamps the
+    answer to the
     VMEM budget.  Called at trace time from Attention; cheap (the cache
     file loads once per path).
     """
     if cfg.flash_block_q is not None and cfg.flash_block_k is not None:
         return int(cfg.flash_block_q), int(cfg.flash_block_k)
     head_dim = cfg.d_model // cfg.n_heads
-    bq = bk = None
-    try:
-        shape = ShapeKey.of(cfg, batch_per_chip=batch, seq_len=seq_len)
-        prior = _prior_cache().get_config(
-            shape.digest(), backend_name(), jax_version())
-        if prior is not None and prior.head_dim == head_dim:
-            bq, bk = prior.block_q, prior.block_k
-    except Exception:  # the read path must never sink a trace
-        pass
-    if bq is None:
+    shape = ShapeKey.of(cfg, batch_per_chip=batch, seq_len=seq_len)
+    prior = _prior_cache().get_config(
+        shape.digest(), backend_name(), jax_version())
+    if prior is not None and prior.head_dim == head_dim:
+        bq, bk = prior.block_q, prior.block_k
+    else:
         bq, bk = default_flash_blocks(head_dim, seq_len)
     # an explicit single knob still wins on its own axis
     if cfg.flash_block_q is not None:

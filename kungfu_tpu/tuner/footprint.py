@@ -26,11 +26,8 @@ import math
 import os
 from typing import Dict, Optional, Tuple
 
+from ..compat import VMEM_ENV, vmem_budget_bytes
 from .space import ShapeKey, StepConfig
-
-#: VMEM scratch budget (MiB) — shared with ops/pallas_collectives.py
-VMEM_ENV = "KFT_PALLAS_VMEM_MIB"
-DEFAULT_VMEM_MIB = 64
 
 #: HBM budget (GiB) for the footprint gate
 HBM_ENV = "KFT_TUNER_HBM_GIB"
@@ -54,13 +51,6 @@ PEAK_SPECS = {
 #: contraction (RESULTS.md r4 timing decomposition), 128 is MXU-native.
 #: Calibrated so the flagship 16×64 arm lands near its measured 0.27 MFU.
 _HEAD_DIM_EFF = {64: 0.45, 128: 0.62}
-
-
-def vmem_budget_bytes() -> int:
-    try:
-        return int(os.environ.get(VMEM_ENV, str(DEFAULT_VMEM_MIB))) << 20
-    except ValueError:
-        return DEFAULT_VMEM_MIB << 20
 
 
 def hbm_budget_bytes() -> int:

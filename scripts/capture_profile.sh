@@ -11,5 +11,5 @@ set -e
 cd "$(dirname "$0")/.."
 OUT=${1:-bench_artifacts/resnet50_xprof}
 KFT_BENCH_PROFILE="$OUT" KFT_BENCH_BATCH=128 KFT_BENCH_STEPS=20 \
-  KFT_BENCH_DEADLINE=800 python bench.py | tee "$OUT.bench.json"
+  python bench.py | tee "$OUT.bench.json"
 echo "profile + bench line written under $OUT"

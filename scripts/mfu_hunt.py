@@ -2,10 +2,8 @@
 """On-chip MFU hunt — thin wrapper over the compute tuner's probes.
 
 The dependent-chain MXU peak probe and the flash tile/layout/backward
-sweep moved in-library (`kungfu_tpu/tuner/measure.py`, PR 10) so the
-tuner's measured runoff and the unattended queue share one implementation.
-This script keeps the historical CLI and the `HUNT:` JSON-line contract
-(`scripts/tpu_queue_r*.txt` and the tpu_retry loop grep for it):
+sweep moved in-library (`kungfu_tpu/tuner/measure.py`, PR 10).  This
+script keeps the historical CLI and the `HUNT:` JSON-line contract:
 
     python scripts/mfu_hunt.py [peak|flash|all]   (default all)
 

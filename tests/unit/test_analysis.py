@@ -24,7 +24,7 @@ from kungfu_tpu.analysis.programs import (
     builtin_programs,
     check_program,
 )
-from kungfu_tpu.compat import shard_map
+from jax import shard_map
 from kungfu_tpu.plan.graph import permutation_errors, validate_permutation
 from kungfu_tpu.testing import bad_programs
 
@@ -87,7 +87,7 @@ class TestRuleMechanics:
             go = lax.pmax(x[0, 0] > 0, "dp")
             return lax.cond(go, lambda v: lax.psum(v, "dp"), lambda v: v, x)
 
-        fn = shard_map(body, mesh, in_specs=P("dp"), out_specs=P("dp"),
+        fn = shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
                        check_vma=False)
         findings = analysis.check(fn, _sds((8, 16)), mesh=mesh)
         assert not analysis.errors(findings), analysis.format_findings(findings)
@@ -99,7 +99,7 @@ class TestRuleMechanics:
         def body(x):
             return lax.ppermute(x, "dp", perm)
 
-        fn = shard_map(body, mesh, in_specs=P("dp"), out_specs=P("dp"),
+        fn = shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
                        check_vma=False)
         findings = analysis.check(fn, _sds((8, 16)), mesh=mesh)
         assert not analysis.errors(findings), analysis.format_findings(findings)
@@ -110,9 +110,9 @@ class TestRuleMechanics:
         def body(x):
             return lax.psum(x, "dp")
 
-        fn = shard_map(body, mesh, in_specs=P("dp"), out_specs=P(),
+        fn = shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P(),
                        check_vma=False)
-        with jax.experimental.enable_x64():  # default config downcasts f64
+        with jax.enable_x64(True):  # default config downcasts f64
             findings = analysis.check(fn, _sds((8, 64), "float64"), mesh=mesh)
         errs = analysis.errors(findings)
         assert [f.rule for f in errs] == [analysis.RULE_WIRE_DTYPE]
@@ -130,7 +130,7 @@ class TestRuleMechanics:
         def body(x):
             return comp.all_reduce(jnp.squeeze(x, 0), "dp", cfg, op="mean")[None]
 
-        fn = shard_map(body, mesh, in_specs=P("dp"), out_specs=P("dp"),
+        fn = shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
                        check_vma=False)
         findings = analysis.check(fn, _sds((8, 1, 4096)), mesh=mesh,
                                   compression={"dp": cfg})
@@ -236,7 +236,7 @@ class TestTraceTimeHooks:
             u, _ = tx.update(g, state, None)
             return u
 
-        fn = shard_map(body, mesh, in_specs=P(), out_specs=P(),
+        fn = shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
                        check_vma=False)
         with pytest.raises(analysis.AnalysisError, match="pd"):
             jax.eval_shape(fn, grads)
@@ -254,7 +254,7 @@ class TestTraceTimeHooks:
             u, _ = tx.update(g, state, p)
             return u
 
-        fn = shard_map(body, mesh, in_specs=(P(), P()), out_specs=P(),
+        fn = shard_map(body, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
                        check_vma=False)
         with pytest.raises(analysis.AnalysisError, match="pd"):
             jax.eval_shape(fn, params, params)

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from kungfu_tpu import compression as comp
-from kungfu_tpu.compat import shard_map
+from jax import shard_map
 from kungfu_tpu.plan import make_mesh, make_hierarchical_mesh
 
 pytestmark = pytest.mark.compression

@@ -23,10 +23,7 @@ from kungfu_tpu.optimizers import (
 )
 from kungfu_tpu.initializer import broadcast_params, sync_check
 
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 N = 8
 

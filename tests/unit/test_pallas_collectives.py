@@ -27,7 +27,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from kungfu_tpu import compression as comp
-from kungfu_tpu.compat import shard_map
+from jax import shard_map
 from kungfu_tpu.ops import collective as C
 from kungfu_tpu.ops import pallas_collectives as PC
 

@@ -10,10 +10,7 @@ import flax.linen as nn
 from flax.linen import spmd as flax_spmd
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from kungfu_tpu.parallel.ring_attention import full_attention, ring_attention
 from kungfu_tpu.parallel.sharding import rules_for_mesh
@@ -93,7 +90,6 @@ class TestRingAttention:
         must be BIT-IDENTICAL to the ppermute fallback and match the
         single-device reference.  The enclosing shard_map opts out of the
         rep check (pallas_call has no replication rule — docs/pallas.md)."""
-        from kungfu_tpu.compat import shard_map as kft_shard_map
 
         mesh = make_mesh(sp=4, devices=jax.devices()[:4])
         B, L, H, D = 2, 64, 4, 16
@@ -103,7 +99,7 @@ class TestRingAttention:
         spec = P(None, "sp", None, None)
 
         def run():
-            return np.asarray(jax.jit(kft_shard_map(
+            return np.asarray(jax.jit(shard_map(
                 lambda q, k, v: ring_attention(q, k, v, axis_name="sp",
                                                causal=causal),
                 mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
@@ -122,7 +118,6 @@ class TestRingAttention:
         """Gradients through the scan + custom-VJP rotation (the VJP
         rotates the cotangent backwards) must match the single-device
         reference when the DMA hop is engaged."""
-        from kungfu_tpu.compat import shard_map as kft_shard_map
 
         monkeypatch.setenv("KFT_PALLAS", "interpret")
         mesh = make_mesh(sp=4, devices=jax.devices()[:4])
@@ -133,7 +128,7 @@ class TestRingAttention:
         spec = P(None, "sp", None, None)
 
         def loss_ring(q, k, v):
-            o = kft_shard_map(
+            o = shard_map(
                 lambda q, k, v: ring_attention(q, k, v, axis_name="sp"),
                 mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
                 check_vma=False)(q, k, v)

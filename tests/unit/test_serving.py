@@ -704,7 +704,7 @@ class TestDisagg:
         ahead (the ppermute lowering off-TPU, bit-identical contract)."""
         from jax.sharding import PartitionSpec as P
 
-        from kungfu_tpu.compat import shard_map
+        from jax import shard_map
         from kungfu_tpu.ops.kv_ship import ship_kv_rows
 
         if len(jax.devices()) < 2:
@@ -717,7 +717,7 @@ class TestDisagg:
         def body(rows):
             return ship_kv_rows({"k": jnp.squeeze(rows, 0)}, "dp", 1)["k"][None]
 
-        out = shard_map(body, mesh, in_specs=P("dp"), out_specs=P("dp"),
+        out = shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
                         check_vma=False)(x)
         np.testing.assert_array_equal(np.asarray(out)[0], np.asarray(x)[1])
         np.testing.assert_array_equal(np.asarray(out)[1], np.asarray(x)[0])

@@ -684,7 +684,7 @@ class TestBenchRunner:
             # fresh-env second chance must both fail before a requeue
             rec = run_section(
                 Section(name="flaky", fn=lambda: {"value": 7}),
-                probe=self._probe(["tunnel wedged", "still wedged", None]),
+                probe=self._probe(["chip wedged", "still wedged", None]),
                 retries=2, sleep=lambda s: None,
             )
             assert rec["measured_this_run"] is True and rec["value"] == 7

@@ -580,11 +580,11 @@ class TestProbeRetry:
         # make the probe child die loudly without touching jax
         monkeypatch.setattr(
             runner, "PROBE_SRC",
-            "import sys; sys.stderr.write('tunnel wedged hard'); sys.exit(7)")
+            "import sys; sys.stderr.write('chip wedged hard'); sys.exit(7)")
         diag = runner.probe_backend_ex(timeout_s=30.0)
         assert diag is not None
         assert diag["exit"] == 7
-        assert "tunnel wedged hard" in diag["stderr"]
+        assert "chip wedged hard" in diag["stderr"]
         assert runner.probe_backend(timeout_s=30.0) == "probe exited 7"
 
 

@@ -8,10 +8,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from kungfu_tpu.parallel.ring_attention import full_attention
 from kungfu_tpu.parallel.ulysses import ulysses_attention
