@@ -59,10 +59,12 @@ class RateWindow:
         return (b1 - b0) / (t1 - t0)
 
 
-# latency-oriented exponential-ish bucket bounds, milliseconds
+# latency-oriented exponential-ish bucket bounds, milliseconds; denser from
+# 10 to 50, where a decode step of a billion-parameter model on one chip
+# lies (21 ms), so that its percentiles are not one bucket's interpolation
 DEFAULT_BUCKETS_MS = (
-    0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 500.0,
-    1000.0, 2500.0, 5000.0, 10000.0, 30000.0,
+    0.5, 1.0, 2.5, 5.0, 10.0, 15.0, 20.0, 25.0, 30.0, 40.0, 50.0, 100.0,
+    250.0, 500.0, 1000.0, 2500.0, 5000.0, 10000.0, 30000.0,
 )
 
 

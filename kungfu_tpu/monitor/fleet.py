@@ -37,7 +37,8 @@ single endpoint over the whole job:
              signatures, budgets, storms per rank.
   /profile   on-demand fleet profiling: `?secs=N` fans the workers'
              jax.profiler capture out in parallel under its own deadline
-             (a capture blocks for N seconds by design).
+             (a capture blocks for N seconds by design; `&python=1` is
+             passed on to the workers).
 
 Scrapes fan out in PARALLEL with a per-target timeout, so one wedged worker
 costs one timeout — not a timeout per wedged rank serialized — and can never
@@ -324,7 +325,9 @@ class FleetAggregator:
                             secs = float((query.get("secs") or ["2"])[0])
                         except ValueError:
                             secs = 2.0
-                        body = json.dumps(outer.profile_fleet(secs)).encode()
+                        python = (query.get("python") or ["0"])[0] == "1"
+                        body = json.dumps(
+                            outer.profile_fleet(secs, python=python)).encode()
                         ctype = "application/json"
                     else:
                         self.send_response(404)
@@ -561,11 +564,12 @@ class FleetAggregator:
         with urllib.request.urlopen(url, timeout=timeout_s) as r:
             return r.read().decode()
 
-    def profile_fleet(self, secs: float) -> Dict[str, Any]:
+    def profile_fleet(self, secs: float, python: bool = False) -> Dict[str, Any]:
         """Fan /profile?secs=N out to every rank concurrently and collect
-        each capture's result JSON.  Uses its own deadline — a capture
-        legitimately blocks for `secs`, which the ordinary scrape timeout
-        would cut off mid-profile."""
+        each capture's result JSON (`python` passes `&python=1` on: the
+        workers' captures then run the profiler's Python tracer).  Uses its
+        own deadline — a capture legitimately blocks for `secs`, which the
+        ordinary scrape timeout would cut off mid-profile."""
         try:
             secs = min(max(float(secs), 0.05), 120.0)
         except (TypeError, ValueError):
@@ -576,8 +580,8 @@ class FleetAggregator:
         # top of the capture itself
         per_target = secs + self.timeout_s + 30.0
         futs = [(rank, self._pool.submit(
-                    self._fetch_slow, f"{base}/profile?secs={secs:g}",
-                    per_target))
+                    self._fetch_slow, f"{base}/profile?secs={secs:g}"
+                    + ("&python=1" if python else ""), per_target))
                 for rank, base in self.targets_fn()]
         out: Dict[str, Any] = {"secs": secs, "ranks": {}, "errors": {}}
         deadline = time.monotonic() + per_target + 0.5

@@ -31,7 +31,8 @@ actually allocates.  This module is that plane:
                     with rel_err — the cost model's honesty loop).
   capture_profile   on-demand `jax.profiler` capture behind the worker
                     `/profile?secs=N` endpoint (monitor.server; fleet
-                    fan-out in monitor.fleet): atomic dump next to the
+                    fan-out in monitor.fleet; `&python=1` adds the
+                    profiler's Python tracer): atomic dump next to the
                     trace dumps, the capture window recorded as a
                     `profile:capture` span so it lands in /timeline, and
                     an interpreter-safe no-op fallback (the JSON says
@@ -40,13 +41,16 @@ actually allocates.  This module is that plane:
 Gating: KFT_PROGRAMS=0 disables everything — `track()` returns the fn
 unchanged (no wrapper, no digest work), `maybe_install` is a no-op, the
 census never registers.  Enabled (the default), the per-call cost is one
-pytree flatten + a short hash on the host, and counters are only touched
-when monitoring is on (counters_if_enabled).
+pytree flatten and a dictionary lookup on the leaves' (shape, dtype)
+objects — the digest string is computed once for each new signature — and
+shows in a profile as the `programs:digest` span; counters are only
+touched when monitoring is on (counters_if_enabled).
 """
 from __future__ import annotations
 
 import hashlib
 import os
+import socket
 import sys
 import tempfile
 import threading
@@ -459,11 +463,35 @@ class _Tracked:
         self.__wrapped__ = fn
         self._kft_program = name
         self._kft_registry = reg
+        # signature key -> digest string: grows by one entry for each new
+        # signature, which is a compile, so the budget bounds it
+        self._kft_digests: Dict[Any, str] = {}
+
+    def _digest(self, args: tuple, kwargs: Dict[str, Any]) -> str:
+        """`signature_digest` of the call, memoised: a steady-state call
+        hashes the treedef and the leaves' (shape, dtype) objects and never
+        builds the text the digest is a SHA-1 of."""
+        import jax
+
+        leaves, treedef = jax.tree_util.tree_flatten((args, kwargs))
+        sig: List[Any] = []
+        for leaf in leaves:
+            try:
+                sig.append((leaf.shape, leaf.dtype))
+            except AttributeError:  # a python scalar: its type, as the digest
+                sig.append(type(leaf))
+        key = (treedef, tuple(sig))
+        digest = self._kft_digests.get(key)
+        if digest is None:
+            digest = self._kft_digests[key] = signature_digest(args, kwargs)
+        return digest
 
     def __call__(self, *args: Any, **kwargs: Any) -> Any:
         fn, reg, name = self.__wrapped__, self._kft_registry, self._kft_program
-        digest = signature_digest(args, kwargs)
-        if not reg.note_call(name, digest):
+        with trace_scope("programs:digest", cat="programs"):
+            digest = self._digest(args, kwargs)
+            new = reg.note_call(name, digest)
+        if not new:
             return fn(*args, **kwargs)
         listener = bool(_watch["active"])
         anchor = _compile_ms_anchor() if listener else 0.0
@@ -573,9 +601,15 @@ _profile_lock = threading.Lock()
 _profile_seq = 0
 
 
-def capture_profile(secs: float, out_dir: Optional[str] = None) -> Dict[str, Any]:
+def capture_profile(secs: float, out_dir: Optional[str] = None,
+                    python: bool = False) -> Dict[str, Any]:
     """Capture a jax.profiler device trace for `secs` seconds and dump it
     atomically next to the trace dumps (KFT_TRACE_DUMP_DIR).  The capture
+    holds the device's operations, the runtime's own host events and every
+    `trace_scope` of the program; the profiler's Python tracer, whose
+    frames would bury those scopes (a reader names a device-idle gap by
+    the shortest host event open in it), runs only with `python=True`, for
+    hunting a frame no scope names.  The capture
     window is recorded as a `profile:capture` span so it shows up in
     /timeline next to whatever it overlapped.  Any failure — profiler
     absent, already running, interpreter-only build — degrades to a no-op
@@ -593,7 +627,8 @@ def capture_profile(secs: float, out_dir: Optional[str] = None) -> Dict[str, Any
     from .journal import _identity
 
     dest = os.path.join(out_dir, f"profile-{_identity()}-{n}")
-    result: Dict[str, Any] = {"secs": secs, "t_start": round(job_now(), 4)}
+    result: Dict[str, Any] = {"secs": secs, "python": bool(python),
+                              "t_start": round(job_now(), 4)}
     with trace_scope("profile:capture", cat="profile",
                      args={"secs": secs, "seq": n}):
         try:
@@ -604,18 +639,51 @@ def capture_profile(secs: float, out_dir: Optional[str] = None) -> Dict[str, Any
             # os.replace is atomic — a mid-capture kill leaves only a
             # .profile-tmp-* dir, never a half-written artifact
             tmp = tempfile.mkdtemp(prefix=".profile-tmp-", dir=out_dir)
-            jax.profiler.start_trace(tmp)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 1 if python else 0
+            jax.profiler.start_trace(tmp, profiler_options=options)
             try:
                 time.sleep(secs)
             finally:
-                jax.profiler.stop_trace()
+                t_end = time.monotonic()
+                _stop_trace_xplane_only(tmp)
             os.replace(tmp, dest)
-            result.update(ok=True, noop=False, path=dest)
+            # capture's end to artifact ready: the process serves on
+            # meanwhile, slower, so this is part of what a capture costs
+            result.update(ok=True, noop=False, path=dest,
+                          dump_s=round(time.monotonic() - t_end, 3))
         except Exception as e:  # noqa: BLE001 - no-op fallback is the contract
             log.warning("profile capture degraded to no-op: %s", e)
             result.update(ok=False, noop=True, error=str(e))
     result["t_end"] = round(job_now(), 4)
     return result
+
+
+def _stop_trace_xplane_only(log_dir: str) -> None:
+    """`jax.profiler.stop_trace()` less its trace-viewer export: the
+    session is stopped and its XSpace written where the public call puts
+    it (`<log_dir>/plugins/profile/<time>/<host>.xplane.pb`, what
+    TensorBoard, `ProfileData` and the benchmark's reduction read), and
+    the conversion to `trace.json.gz` is left out.  For 3 s of a serving
+    worker (450,000 device events) that conversion took 10 of the 18 s
+    between the capture's end and the artifact, on the cores the worker
+    serves from (PERF.md section 6, PR 23)."""
+    from jax._src import profiler as jax_profiler  # no public stop without export
+
+    state = jax_profiler._profile_state
+    with state.lock:
+        if state.profile_session is None:
+            raise RuntimeError("No profile started")
+        try:
+            xspace = state.profile_session.stop()
+        finally:
+            state.reset()
+    run_dir = os.path.join(log_dir, "plugins", "profile",
+                           time.strftime("%Y_%m_%d_%H_%M_%S"))
+    os.makedirs(run_dir, exist_ok=True)
+    with open(os.path.join(run_dir, f"{socket.gethostname()}.xplane.pb"),
+              "wb") as f:
+        f.write(xspace)
 
 
 def _reset_for_tests() -> None:

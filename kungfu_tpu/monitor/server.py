@@ -15,7 +15,8 @@ and /history: this worker's self-sampled time-series store
 The program observatory (monitor.programs) adds /programs — the compiled-
 program registry report (signatures, budgets, storms) — and
 /profile?secs=N: an on-demand jax.profiler capture dumped atomically to
-KFT_TRACE_DUMP_DIR (no-op JSON when the profiler can't run).
+KFT_TRACE_DUMP_DIR (no-op JSON when the profiler can't run; `&python=1`
+adds the profiler's Python tracer, off by default).
 """
 from __future__ import annotations
 
@@ -99,7 +100,9 @@ class MonitorServer:
                         secs = float((query.get("secs") or ["2"])[0])
                     except ValueError:
                         secs = 2.0
-                    body = json.dumps(P.capture_profile(secs)).encode()
+                    python = (query.get("python") or ["0"])[0] == "1"
+                    body = json.dumps(
+                        P.capture_profile(secs, python=python)).encode()
                     ctype = "application/json"
                 else:
                     self.send_response(404)

@@ -280,6 +280,7 @@ def _flash_fwd(q, k, v, scale: float, causal: bool, block_q: int, block_k: int,
         ],
         compiler_params=_compiler_params(),
         interpret=mode == "interpret",
+        name="kft_flash_fwd",
     )(qp, kp, vp)
     return o[:, :seq_len], lse[:, 0, :seq_len]
 
@@ -532,6 +533,7 @@ def _bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
         out_shape=jax.ShapeDtypeStruct((bh, lq, d), q.dtype, vma=vma),
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="kft_flash_bwd_dq",
     )(qp, kp, vp, dop, lse_p, delta_p)
 
     if group == 1:
@@ -560,6 +562,7 @@ def _bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
             ],
             compiler_params=_compiler_params(),
             interpret=interpret,
+            name="kft_flash_bwd_dkdv",
         )(kp, vp, qp, dop, lse_p, delta_p)
     else:
         def qrow(b, g_):
@@ -590,6 +593,7 @@ def _bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
             ],
             compiler_params=_compiler_params(),
             interpret=interpret,
+            name="kft_flash_bwd_dkdv",
         )(kp, vp, qp, dop, lse_p, delta_p)
         dk = dk.astype(k.dtype)
         dv = dv.astype(v.dtype)

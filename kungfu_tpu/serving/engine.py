@@ -303,6 +303,18 @@ class ServingEngine:
             done.append(self._finish(req, status="expired"))
         if self.tenants is not None:
             self._maybe_preempt()
+        if self.slot_mgr.active_count or self.queue.depth():
+            # the span marks an iteration with work in it; one that finds
+            # nothing to do is the worker loop's `serve:idle`
+            with trace_scope("serve:step", cat="serving"):
+                done.extend(self._admit_and_decode())
+        for req in self.queue.drain_expired():
+            done.append(self._finish(req, status="expired"))
+        self._gauge()
+        return done
+
+    def _admit_and_decode(self) -> List[Result]:
+        done: List[Result] = []
         while self.slot_mgr.free_count:
             req = self.queue.pop()
             if req is None:
@@ -313,9 +325,6 @@ class ServingEngine:
             self._admit(req)
         if self.slot_mgr.active_count:
             done.extend(self._decode_step())
-        for req in self.queue.drain_expired():
-            done.append(self._finish(req, status="expired"))
-        self._gauge()
         return done
 
     def run_until_idle(self, timeout_s: float = 120.0) -> List[Result]:
@@ -373,11 +382,7 @@ class ServingEngine:
                 tuple(req.prefill_tokens[:cursor]),
                 lambda: extract_slot_rows(self.cache, slot, cursor))
         self.slot_mgr.release(slot)
-        self.cache = reset_slot(self.cache, slot)
-        self._next_tok[slot] = 0
-        self._cursor[slot] = 0
-        if self.spec is not None:
-            self.spec.release_slot(slot)
+        self._reset_slot(slot)
         req._preempted = True  # type: ignore[attr-defined]
         # re-tag as a fresh arrival: the victim already consumed service, so
         # keeping its old (minimal) fair tag would pop it straight back into
@@ -407,6 +412,13 @@ class ServingEngine:
     def _admit(self, req: Request) -> None:
         slot = self.slot_mgr.allocate(req)
         assert slot is not None
+        with trace_scope("serve:admit", cat="serving",
+                         args={"slot": slot,
+                               "tokens": len(req.prefill_tokens)}):
+            self._admit_to(slot, req)
+
+    def _admit_to(self, slot: int, req: Request) -> None:
+        """From the allocated slot to the request's first token pushed."""
         ctx = self._req_ctx(req)
         if ctx is not None:
             child_span("queue:wait", req.queued_t, trace_id=ctx.trace_id,
@@ -427,7 +439,9 @@ class ServingEngine:
         toks = req.prefill_tokens
         with trace_context(ctx):
             first, small, total, hit = self._run_prefill(toks, req.temperature)
-        self.cache = write_slot(self.cache, small, slot)
+        with trace_scope("serve:slot_write", cat="serving",
+                         args={"slot": slot}):
+            self.cache = write_slot(self.cache, small, slot)
         self._cursor[slot] = total
         if self.spec is not None:
             self.spec.prefill_slot(slot, toks)
@@ -452,15 +466,17 @@ class ServingEngine:
                          args={"tokens": total, "hit": hit,
                                "bucket": bucket}):
             t0 = time.monotonic()
-            small_in = self._small_cache0
-            if lease is not None:
-                # device-resident, memoized per (prefix, hit): repeat hits
-                # of a hot prefix skip the host assembly entirely
-                small_in = self.prefix.warm_small(self._small_cache0, lease)
-            last_logits, small = self._prefill(
-                self.params, small_in, jnp.asarray(padded),
-                len(suffix), total,
-            )
+            with trace_scope("serve:prefill.dispatch", cat="serving"):
+                small_in = self._small_cache0
+                if lease is not None:
+                    # device-resident, memoized per (prefix, hit): repeat
+                    # hits of a hot prefix skip the host assembly entirely
+                    small_in = self.prefix.warm_small(self._small_cache0,
+                                                      lease)
+                last_logits, small = self._prefill(
+                    self.params, small_in, jnp.asarray(padded),
+                    len(suffix), total,
+                )
             if self.prefix is not None:
                 # lazy rows: the device->host copy only happens when the
                 # insert actually creates a node (cache-hot admissions skip)
@@ -468,7 +484,8 @@ class ServingEngine:
                                    lambda: extract_rows(small, total))
             if lease is not None:
                 lease.release()
-            first = self._pick(np.asarray(last_logits), temperature)
+            with trace_scope("serve:prefill.fetch", cat="serving"):
+                first = self._pick(np.asarray(last_logits), temperature)
             dt = time.monotonic() - t0
         self.total_prefill_tokens += len(suffix)
         self._observe("prefill_ms", dt * 1e3)
@@ -519,7 +536,8 @@ class ServingEngine:
     def _decode_step(self) -> List[Result]:
         if self._spec_step_ok():
             return self._spec_decode_step()
-        toks = jnp.asarray(self._next_tok[:, None])
+        with trace_scope("serve:decode.upload", cat="serving"):
+            toks = jnp.asarray(self._next_tok[:, None])
         active = sorted(self.slot_mgr.active().items())
         targs: Dict[str, Any] = {"active": len(active)}
         ids = [r.trace_id for _, r in active if r.trace_id]
@@ -532,23 +550,28 @@ class ServingEngine:
         with trace_scope("serve:decode", cat="serving", args=targs,
                          track=bool(ids)):
             t0 = time.monotonic()
-            logits, self.cache = self._decode(self.params, self.cache, toks)
-            logits = np.asarray(logits)
+            with trace_scope("serve:decode.dispatch", cat="serving"):
+                logits, self.cache = self._decode(self.params, self.cache,
+                                                  toks)
+            with trace_scope("serve:decode.fetch", cat="serving"):
+                logits = np.asarray(logits)
             dt = time.monotonic() - t0
         self._observe("tok_latency_ms", dt * 1e3)
-        self._cursor += 1  # every row consumed one token (free rows too)
-        for _, r in active:
-            r.decode_rounds += 1
-        if self.spec is not None:
-            # the target advanced without the draft: those slots' draft
-            # caches are behind until their next admission
-            self.spec.on_plain_step([s for s, _ in active])
         done: List[Result] = []
-        for slot, req in active:
-            nxt = self._pick(logits[slot], req.temperature)
-            finished = self._push_token(slot, req, int(nxt), from_decode=True)
-            if finished is not None:
-                done.append(finished)
+        with trace_scope("serve:decode.sample", cat="serving"):
+            self._cursor += 1  # every row consumed one token (free rows too)
+            for _, r in active:
+                r.decode_rounds += 1
+            if self.spec is not None:
+                # the target advanced without the draft: those slots' draft
+                # caches are behind until their next admission
+                self.spec.on_plain_step([s for s, _ in active])
+            for slot, req in active:
+                nxt = self._pick(logits[slot], req.temperature)
+                finished = self._push_token(slot, req, int(nxt),
+                                            from_decode=True)
+                if finished is not None:
+                    done.append(finished)
         return done
 
     def _spec_step_ok(self) -> bool:
@@ -597,12 +620,15 @@ class ServingEngine:
         with trace_scope("serve:verify", cat="serving", args=vargs,
                          track=bool(ids)):
             t0 = time.monotonic()
-            g_dev, n_acc_dev, self.cache = self._verify(
-                self.params, self.cache, jnp.asarray(ver.astype(np.int32)),
-                jnp.asarray(proposals.astype(np.int32)),
-            )
-            g = np.asarray(g_dev)
-            n_acc = np.asarray(n_acc_dev)
+            with trace_scope("serve:verify.dispatch", cat="serving"):
+                g_dev, n_acc_dev, self.cache = self._verify(
+                    self.params, self.cache,
+                    jnp.asarray(ver.astype(np.int32)),
+                    jnp.asarray(proposals.astype(np.int32)),
+                )
+            with trace_scope("serve:verify.fetch", cat="serving"):
+                g = np.asarray(g_dev)
+                n_acc = np.asarray(n_acc_dev)
             dt = time.monotonic() - t0
             if ids:
                 # per-round acceptance, aligned with trace_ids (args is
@@ -644,14 +670,21 @@ class ServingEngine:
         hit_eos = req.eos_id >= 0 and tok == req.eos_id
         if len(req.generated) >= req.remaining_new_tokens or hit_eos:
             self.slot_mgr.release(slot)
-            self.cache = reset_slot(self.cache, slot)
-            self._next_tok[slot] = 0
-            self._cursor[slot] = 0
-            if self.spec is not None:
-                self.spec.release_slot(slot)
+            self._reset_slot(slot)
             return self._finish(req, status="ok")
         self._next_tok[slot] = tok
         return None
+
+    def _reset_slot(self, slot: int) -> None:
+        """A released slot's row back to its free state: cursor 0 on the
+        device and on the host, the dummy ride-along token."""
+        with trace_scope("serve:slot_reset", cat="serving",
+                         args={"slot": slot}):
+            self.cache = reset_slot(self.cache, slot)
+        self._next_tok[slot] = 0
+        self._cursor[slot] = 0
+        if self.spec is not None:
+            self.spec.release_slot(slot)
 
     def _pick(self, logits: np.ndarray, temperature: float) -> int:
         if temperature <= 0.0:

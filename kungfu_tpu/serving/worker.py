@@ -50,6 +50,7 @@ this process mid-stream with requests in flight on either tier.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import json
 import pickle
@@ -309,18 +310,33 @@ class ServingWorker:
 
     def _engine_loop(self) -> None:
         last_ship = 0.0
-        while not self._stop.is_set():
-            self._chaos_phase("decode")
-            done = self.engine.step()
-            self._chaos_tick()
-            now = time.monotonic()
-            if (self.args.config_server
-                    and now - last_ship > self.args.warm_ship_s):
-                last_ship = now
-                self._ship_warm()
-            if not done and not self.engine.slot_mgr.active_count \
-                    and not self.engine.queue.depth():
-                time.sleep(0.002)
+        engine = self.engine
+        # one `serve:idle` span for each stretch with no queue and no active
+        # slot (not one for each 2 ms sleep), closed when work arrives: a
+        # profile then tells a device idle for lack of load from one the
+        # engine keeps waiting
+        with contextlib.ExitStack() as idle:
+            idling = False
+            while not self._stop.is_set():
+                if idling and (engine.queue.depth()
+                               or engine.slot_mgr.active_count):
+                    idle.close()
+                    idling = False
+                self._chaos_phase("decode")
+                done = engine.step()
+                self._chaos_tick()
+                now = time.monotonic()
+                if (self.args.config_server
+                        and now - last_ship > self.args.warm_ship_s):
+                    last_ship = now
+                    self._ship_warm()
+                if not done and not engine.slot_mgr.active_count \
+                        and not engine.queue.depth():
+                    if not idling:
+                        idle.enter_context(
+                            T.trace_scope("serve:idle", cat="serving"))
+                        idling = True
+                    time.sleep(0.002)
 
     def _ship_warm(self) -> None:
         """Best-effort POST of in-flight progress to the ring buddy; a dead

@@ -37,6 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .parallel.sharding import param_shardings, rules_for_mesh
 from .plan import make_mesh
 from .train import TrainState, _put_local_shard
+from .utils.trace import trace_scope
 
 
 class MeshTrainer:
@@ -198,7 +199,8 @@ class MeshTrainer:
         """
         spec = P(self.batch_axes if self.batch_axes else None)
         sharding = NamedSharding(self.mesh, spec)
-        return jax.tree.map(lambda x: _put_local_shard(x, sharding), batch)
+        with trace_scope("train:shard_batch", cat="train"):
+            return jax.tree.map(lambda x: _put_local_shard(x, sharding), batch)
 
     def _step_rng(self, step: int):
         """Per-step loss rng: the init key folded with the step counter —
@@ -208,7 +210,10 @@ class MeshTrainer:
     def train_step(self, state: TrainState, batch: Any) -> Tuple[TrainState, Dict]:
         if self._step_fn is None:
             raise RuntimeError("call init() before train_step()")
-        with self.mesh:
+        # the host's part of a step: the rng fold and the jitted call until
+        # it returns (the device runs on after it)
+        with trace_scope("train:step", cat="train",
+                         args={"step": state.step}), self.mesh:
             params, opt_state, metrics = self._step_fn(
                 state.params, state.opt_state, batch,
                 self._step_rng(state.step),

@@ -4,7 +4,7 @@ Reference: include/kungfu/utils/trace.hpp (TRACE_SCOPE macros compiled in
 behind KUNGFU_ENABLE_TRACE) and the Python event logger stamping times since
 proc/job start (srcs/python/kungfu/_utils.py:33-50).
 
-The reference's TRACE_SCOPE only logs; here every scope additionally lands
+The reference's TRACE_SCOPE only logs; here every scope lands instead
 in a per-process ring buffer of `Span`s with *job-relative monotonic*
 timestamps, exportable as Chrome-trace/Perfetto JSON (`export_chrome_trace`)
 — so pod-scale debugging gets the merged cross-host timeline the MLPerf
@@ -20,11 +20,13 @@ Wall-clock is stamped exactly once per process as *anchor metadata* (the
 proc-start wall/mono pair below) so offline tooling can align timelines
 from hosts whose monotonic clocks are unrelated.
 
-`trace_scope(name)` is a no-op unless KFT_CONFIG_ENABLE_TRACE is set, in
-which case it records a span (and logs enter/exit) and, with device=True,
-also opens a `jax.profiler.TraceAnnotation` so the scope shows up in TPU
-profiler timelines.  `profile_to(dir)` wraps a block in a full
-`jax.profiler.trace` capture.
+`trace_scope(name)` always opens a `jax.profiler.TraceAnnotation` (in a
+process that has imported jax), so every scope lands on the profiler's own
+timeline beside the device's operations whenever a capture is running, and
+costs a few microseconds when none is.  With KFT_CONFIG_ENABLE_TRACE set it
+also records a `Span` in the ring buffer.  `record_span` / `child_span` are
+timed by hand, after the fact, so they reach the ring only.
+`profile_to(dir)` wraps a block in a full `jax.profiler.trace` capture.
 
 Distributed trace context (docs/observability.md "Request tracing"): a
 `TraceContext` is a (trace_id, span_id) pair in the W3C traceparent shape
@@ -44,6 +46,7 @@ import contextlib
 import dataclasses
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -453,13 +456,28 @@ def log_event(name: str, **args: Any) -> None:
     ))
 
 
+def _annotation(name: str, args: Optional[Dict[str, Any]]):
+    """The scope as the profiler sees it: a `TraceAnnotation` carrying the
+    scalar `args` as stats.  A process that has not imported jax itself
+    (launcher and router parents) never imports it through here: it gets a
+    null context."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return contextlib.nullcontext()
+    stats = {k: v for k, v in args.items()
+             if isinstance(v, (bool, int, float, str))} if args else {}
+    return profiler.TraceAnnotation(name, **stats)
+
+
 @contextlib.contextmanager
-def trace_scope(name: str, device: bool = False, cat: str = "",
+def trace_scope(name: str, cat: str = "",
                 args: Optional[Dict[str, Any]] = None,
                 track: bool = False) -> Iterator[None]:
-    """Scoped span: recorded in the ring buffer + timing log; with
-    device=True also annotates the XLA timeline.  Nesting is free — Chrome
-    trace viewers nest "X" events by ts/dur containment per thread.
+    """Scoped span: always an annotation on the profiler's timeline (seen by
+    whatever capture is running, the device's operations beside it), and
+    with KFT_CONFIG_ENABLE_TRACE also a `Span` in the ring buffer.  Nesting
+    is free — Chrome trace viewers nest "X" events by ts/dur containment
+    per thread.
 
     Under an active TraceContext the scope allocates a child span id and
     becomes the current context for its body, so nested scopes chain into
@@ -468,38 +486,28 @@ def trace_scope(name: str, device: bool = False, cat: str = "",
     requests) that need a stable dedup identity without belonging to a
     single trace.  `args` is held by reference and serialized at scrape
     time, so a scope body may fill in outcome fields (e.g. per-round
-    acceptance) before it closes."""
-    if not enabled():
-        yield
-        return
-    ann = None
-    if device:
-        try:
-            import jax.profiler
-
-            ann = jax.profiler.TraceAnnotation(name)
-            ann.__enter__()
-        except Exception:  # pragma: no cover - profiler backend optional
-            ann = None
-    parent = current_context()
-    sid = new_span_id() if (parent is not None or track) else ""
-    child = TraceContext(parent.trace_id, sid) if parent is not None else None
-    t0 = time.monotonic()
-    try:
-        with trace_context(child):
+    acceptance) before it closes; the annotation takes its scalars as they
+    are when the scope opens."""
+    with _annotation(name, args):
+        if not enabled():
             yield
-    finally:
-        t1 = time.monotonic()
-        if ann is not None:
-            ann.__exit__(None, None, None)
-        global_trace_buffer().add(Span(
-            name=name, t_start=job_now(t0), dur=t1 - t0, cat=cat,
-            tid=threading.get_ident() & 0x7FFFFFFF, args=args,
-            trace_id=parent.trace_id if parent else "",
-            span_id=sid,
-            parent_id=parent.span_id if parent else "",
-        ))
-        log.info("[trace] %s took %.3f ms", name, (t1 - t0) * 1e3)
+            return
+        parent = current_context()
+        sid = new_span_id() if (parent is not None or track) else ""
+        child = TraceContext(parent.trace_id, sid) if parent is not None else None
+        t0 = time.monotonic()
+        try:
+            with trace_context(child):
+                yield
+        finally:
+            t1 = time.monotonic()
+            global_trace_buffer().add(Span(
+                name=name, t_start=job_now(t0), dur=t1 - t0, cat=cat,
+                tid=threading.get_ident() & 0x7FFFFFFF, args=args,
+                trace_id=parent.trace_id if parent else "",
+                span_id=sid,
+                parent_id=parent.span_id if parent else "",
+            ))
 
 
 @contextlib.contextmanager
