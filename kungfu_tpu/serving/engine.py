@@ -15,7 +15,10 @@ fixed-shape slot batch:
   * decode: one fixed-shape [slots, 1] step advances every active slot one
     token; free slots ride along on a dummy token and their outputs are
     ignored.  No recompile ever happens after warmup: the decode program is
-    a single (shape, dtype) signature regardless of the request mix
+    a single (shape, dtype) signature regardless of the request mix.  The
+    step updates the slot cache it is given (donated) and the host reads
+    [slots] greedy token ids; the [slots, vocab] logits leave the device
+    only in a step where a request samples (`decode_logit_fetches`)
   * completion: a slot frees on max_new_tokens or eos; its row is reused by
     the next admission (slots.reset_slot keeps the free row's ride-along
     cursor at 0)
@@ -54,6 +57,7 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
+from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -138,6 +142,7 @@ class ServingEngine:
             self.queue = AdmissionQueue(queue_capacity)
         self.slot_mgr = SlotManager(slots)
         self.preemptions = 0
+        self.decode_logit_fetches = 0  # decode steps that fetched logits
         self.counters = counters
         self.buckets = tuple(sorted(prefill_buckets or default_buckets(cfg.max_len)))
         assert self.buckets[-1] <= cfg.max_len
@@ -187,6 +192,13 @@ class ServingEngine:
 
             return jax.tree_util.tree_map_with_path(fix, cache)
 
+        # Every step program hands the host its greedy tokens (argmax of
+        # the float32 logits: first index on ties, a NaN first, as
+        # np.argmax) beside the logits themselves, which stay on the device
+        # unless a request samples.  _decode and _verify_accept update the
+        # slot cache in place: it is donated, as slots.py's programs donate
+        # it, so the caller rebinds self.cache on the line that passes it.
+
         @jax.jit
         def _prefill(params, cache_small, tokens, n_new, total_len):
             # tokens [1, bucket]; right-padding is causally invisible to the
@@ -201,18 +213,21 @@ class ServingEngine:
             last = jax.lax.dynamic_index_in_dim(
                 logits, n_new - 1, axis=1, keepdims=False
             )[0].astype(jnp.float32)  # [V]
-            return last, _fix_cursor(st["cache"], total_len)
+            first = jnp.argmax(last).astype(jnp.int32)
+            return first, last, _fix_cursor(st["cache"], total_len)
 
-        @jax.jit
+        @partial(jax.jit, donate_argnums=(1,))
         def _decode(params, cache, toks):
             # toks [slots, 1] — THE fixed decode signature; free slots carry
             # a dummy token whose output is never read
             logits, st = model.apply(
                 {"params": params, "cache": cache}, toks, mutable=["cache"]
             )
-            return logits[:, -1].astype(jnp.float32), st["cache"]
+            last = logits[:, -1].astype(jnp.float32)  # [slots, V]
+            greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
+            return greedy, last, st["cache"]
 
-        @jax.jit
+        @partial(jax.jit, donate_argnums=(1,))
         def _verify_accept(params, cache, toks, proposals):
             # toks [slots, k] — the ONE extra compiled decode signature of
             # speculative decoding: per-slot cursors make a k-token call
@@ -473,7 +488,7 @@ class ServingEngine:
                     # hits of a hot prefix skip the host assembly entirely
                     small_in = self.prefix.warm_small(self._small_cache0,
                                                       lease)
-                last_logits, small = self._prefill(
+                greedy, last_logits, small = self._prefill(
                     self.params, small_in, jnp.asarray(padded),
                     len(suffix), total,
                 )
@@ -485,7 +500,10 @@ class ServingEngine:
             if lease is not None:
                 lease.release()
             with trace_scope("serve:prefill.fetch", cat="serving"):
-                first = self._pick(np.asarray(last_logits), temperature)
+                if temperature <= 0.0:
+                    first = int(greedy)
+                else:
+                    first = self._sample(np.asarray(last_logits), temperature)
             dt = time.monotonic() - t0
         self.total_prefill_tokens += len(suffix)
         self._observe("prefill_ms", dt * 1e3)
@@ -547,16 +565,24 @@ class ServingEngine:
             # belonging to one tree; the assembler counts it as a decode
             # round for each listed trace
             targs["trace_ids"] = ids
+        # the host reads [slots] token ids; the [slots, vocab] logits come
+        # back only in a step where a request samples from them
+        sampling = any(r.temperature > 0.0 for _, r in active)
         with trace_scope("serve:decode", cat="serving", args=targs,
                          track=bool(ids)):
             t0 = time.monotonic()
             with trace_scope("serve:decode.dispatch", cat="serving"):
-                logits, self.cache = self._decode(self.params, self.cache,
-                                                  toks)
+                greedy, logits, self.cache = self._decode(
+                    self.params, self.cache, toks)
             with trace_scope("serve:decode.fetch", cat="serving"):
-                logits = np.asarray(logits)
+                greedy = np.asarray(greedy)
+                if sampling:
+                    logits = np.asarray(logits)
             dt = time.monotonic() - t0
         self._observe("tok_latency_ms", dt * 1e3)
+        if sampling:
+            self.decode_logit_fetches += 1
+            self._count("decode_logit_fetches")
         done: List[Result] = []
         with trace_scope("serve:decode.sample", cat="serving"):
             self._cursor += 1  # every row consumed one token (free rows too)
@@ -567,8 +593,11 @@ class ServingEngine:
                 # caches are behind until their next admission
                 self.spec.on_plain_step([s for s, _ in active])
             for slot, req in active:
-                nxt = self._pick(logits[slot], req.temperature)
-                finished = self._push_token(slot, req, int(nxt),
+                if req.temperature <= 0.0:
+                    nxt = int(greedy[slot])
+                else:
+                    nxt = self._sample(logits[slot], req.temperature)
+                finished = self._push_token(slot, req, nxt,
                                             from_decode=True)
                 if finished is not None:
                     done.append(finished)
@@ -686,9 +715,10 @@ class ServingEngine:
         if self.spec is not None:
             self.spec.release_slot(slot)
 
-    def _pick(self, logits: np.ndarray, temperature: float) -> int:
-        if temperature <= 0.0:
-            return int(np.argmax(logits))
+    def _sample(self, logits: np.ndarray, temperature: float) -> int:
+        """One draw at `temperature` > 0 from the engine's own generator;
+        greedy requests never come here (the step programs return their
+        argmax)."""
         z = logits.astype(np.float64) / temperature
         z -= z.max()
         p = np.exp(z)
@@ -758,6 +788,7 @@ class ServingEngine:
             "total_prefill_tokens": self.total_prefill_tokens,
             "total_completed": self.total_completed,
             "preemptions": self.preemptions,
+            "decode_logit_fetches": self.decode_logit_fetches,
         }
         if self.prefix is not None:
             out["prefix"] = self.prefix.stats()

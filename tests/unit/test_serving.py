@@ -37,6 +37,7 @@ from kungfu_tpu.serving import (
     SpecDecoder,
     default_buckets,
 )
+from kungfu_tpu.serving.slots import set_cursors, write_slot
 
 pytestmark = pytest.mark.serving
 
@@ -298,6 +299,196 @@ class TestEngine:
     def test_default_buckets_cover_max_len(self):
         assert default_buckets(96) == (16, 32, 64, 96)
         assert default_buckets(16) == (16,)
+
+
+# -- step programs: greedy tokens on the device, the slot cache donated -----------------
+
+
+def _host_pick(rng, logits, temperature):
+    """What the host did with fetched logits before the programs returned
+    token ids: argmax, or one draw from the engine's generator."""
+    if temperature <= 0.0:
+        return int(np.argmax(logits))
+    z = logits.astype(np.float64) / temperature
+    z -= z.max()
+    p = np.exp(z)
+    p /= p.sum()
+    return int(rng.choice(len(p), p=p))
+
+
+def _host_path_tokens(cfg, params, reqs, buckets):
+    """The host path as a plain loop over the same programs: every request
+    admitted before the first decode step, every step's logits fetched and
+    picked on the host from a generator seeded as the engine's."""
+    ref = ServingEngine(cfg, params, slots=len(reqs), prefill_buckets=buckets)
+    rng = np.random.default_rng(0)
+    cache, nxt, out = ref.cache, np.zeros(len(reqs), np.int32), []
+    for slot, (prompt, new, temp) in enumerate(reqs):
+        padded = np.zeros((1, buckets[-1]), np.int32)
+        padded[0, :len(prompt)] = prompt
+        _, last, small = ref._prefill(ref.params, ref._small_cache0,
+                                      jnp.asarray(padded), len(prompt),
+                                      len(prompt))
+        cache = write_slot(cache, small, slot)
+        nxt[slot] = _host_pick(rng, np.asarray(last), temp)
+        out.append([int(nxt[slot])])
+    fetches = 0
+    while any(len(o) < new for o, (_, new, _) in zip(out, reqs)):
+        live = [s for s, (_, new, _) in enumerate(reqs) if len(out[s]) < new]
+        fetches += any(reqs[s][2] > 0.0 for s in live)
+        _, logits, cache = ref._decode(ref.params, cache,
+                                       jnp.asarray(nxt[:, None]))
+        logits = np.asarray(logits)
+        for s in live:  # a finished slot rides along on its last token
+            nxt[s] = _host_pick(rng, logits[s], reqs[s][2])
+            out[s].append(int(nxt[s]))
+    return out, fetches
+
+
+class TestStepPrograms:
+    """ServingEngine's step programs hand the host token ids and keep their
+    state on the device: `_decode` and `_verify` update the donated slot
+    cache, `_prefill`/`_decode` return argmax beside logits that are fetched
+    only for a sampling request."""
+
+    @pytest.mark.parametrize("case", ["plain", "tie", "nan_overflow_row"])
+    @pytest.mark.parametrize("program", ["decode", "prefill"])
+    def test_greedy_tokens_are_argmax_of_returned_logits(
+            self, model_and_params, program, case):
+        cfg, _, params = model_and_params
+        if case == "tie":
+            # the head's upper half repeats its lower half: every logit v
+            # has an equal twin at v + 32, so every maximum is a tie
+            k = params["lm_head"]["kernel"]
+            params = dict(params, lm_head={
+                "kernel": jnp.concatenate([k[:, :32], k[:, :32]], axis=1)})
+        eng = ServingEngine(cfg, params, slots=3, prefill_buckets=(8,))
+        toks = jnp.asarray([[5], [9], [40]], jnp.int32)
+        if program == "decode":
+            cache = eng.cache
+            if case == "nan_overflow_row":
+                # slot 1's write runs past max_len: the model poisons that
+                # row with NaN and leaves the others clean
+                cache = set_cursors(cache, jnp.asarray([0, cfg.max_len, 0]))
+            got, logits, _ = eng._decode(eng.params, cache, toks)
+            got, logits = np.asarray(got), np.asarray(logits)
+            assert got.shape == (3,) and got.dtype == np.int32
+            assert logits.shape == (3, cfg.vocab_size)
+        else:
+            small = eng._small_cache0
+            if case == "nan_overflow_row":
+                small = set_cursors(small, jnp.asarray([cfg.max_len]))
+            padded = jnp.asarray([[5, 9, 40, 2, 0, 0, 0, 0]], jnp.int32)
+            got, logits, _ = eng._prefill(eng.params, small, padded, 4, 4)
+            got, logits = np.asarray(got)[None], np.asarray(logits)[None]
+        np.testing.assert_array_equal(got, np.argmax(logits, axis=-1))
+        if case == "tie":
+            np.testing.assert_array_equal(logits[:, :32], logits[:, 32:])
+            assert (got < 32).all()  # the first of the two, as np.argmax
+        if case == "nan_overflow_row":
+            row = 1 if program == "decode" else 0
+            assert np.isnan(logits[row]).all() and got[row] == 0
+            assert np.isfinite(np.delete(logits, row, axis=0)).all()
+
+    @pytest.mark.parametrize("mix", [
+        pytest.param(((6, 0.0), (6, 0.0)), id="all_greedy"),
+        pytest.param(((3, 0.8), (6, 0.0)), id="sampler_leaves_first"),
+        pytest.param(((6, 0.0), (5, 1.3)), id="sampler_second_slot"),
+        pytest.param(((4, 0.7), (6, 1.1)), id="all_sampling"),
+    ])
+    def test_mixed_batch_matches_host_path_and_counts_logit_fetches(
+            self, model_and_params, mix):
+        from kungfu_tpu.monitor.counters import Counters
+
+        cfg, _, params = model_and_params
+        prompts = ((3, 1, 4, 1, 5), (9, 2, 6))
+        reqs = [(p, new, t) for p, (new, t) in zip(prompts, mix)]
+        want, want_fetches = _host_path_tokens(cfg, params, reqs, (8,))
+        c = Counters()
+        eng = ServingEngine(cfg, params, slots=2, prefill_buckets=(8,),
+                            counters=c)
+        pend = [eng.submit(Request(prompt=p, max_new_tokens=new,
+                                   temperature=t)) for p, new, t in reqs]
+        eng.run_until_idle()
+        for (p, _, _), pd, w in zip(reqs, pend, want):
+            assert list(pd.result.tokens) == list(p) + w
+        # a step fetches logits exactly when a sampling slot is active in it
+        assert want_fetches == max(
+            [new - 1 for _, new, t in reqs if t > 0.0], default=0)
+        assert eng.decode_logit_fetches == want_fetches
+        assert eng.stats()["decode_logit_fetches"] == want_fetches
+        assert c.events().get("decode_logit_fetches", 0) == want_fetches
+
+    @pytest.mark.parametrize("program", ["decode", "verify"])
+    def test_cache_is_donated(self, model_and_params, program):
+        cfg, _, params = model_and_params
+        eng = ServingEngine(cfg, params, slots=2, prefill_buckets=(8,))
+        n_leaves = len(jax.tree.leaves(eng.cache))
+        if program == "decode":
+            fn, args = eng._decode, (jnp.zeros((2, 1), jnp.int32),)
+        else:
+            fn, args = eng._verify, (jnp.zeros((2, 4), jnp.int32),
+                                     jnp.zeros((2, 3), jnp.int32))
+        # the lowering marks every cache leaf a donor (aliased to an output
+        # where it can say which), whatever the backend makes of it
+        text = fn.lower(eng.params, eng.cache, *args).as_text()
+        head = next(ln for ln in text.splitlines() if "@main(" in ln)
+        sig = head.split("->")[0]
+        assert (sig.count("tf.aliasing_output")
+                + sig.count("jax.buffer_donor")) == n_leaves
+        old = eng.cache
+        out = fn(eng.params, eng.cache, *args)
+        assert all(not x.is_deleted() for x in jax.tree.leaves(out[-1]))
+        deleted = [x.is_deleted() for x in jax.tree.leaves(old)]
+        if any(deleted):  # this backend implements donation
+            assert all(deleted)
+        else:
+            pytest.skip("this backend ignores donation")
+
+    @pytest.mark.parametrize("reader", ["preempt_readmit", "prefix_insert"])
+    def test_cache_readers_after_a_decode_step_see_live_buffers(
+            self, model_and_params, reader):
+        """The two places that read the slot cache outside a step program,
+        after decode steps have donated earlier caches away."""
+        cfg, _, params = model_and_params
+        rs = np.random.RandomState(7)
+        a = tuple(int(t) for t in rs.randint(1, 64, (7,)))
+        if reader == "preempt_readmit":
+            from kungfu_tpu.serving.tenancy import TenantRegistry, TenantSpec
+
+            reg = TenantRegistry(specs={
+                "bulk": TenantSpec(name="bulk", priority=0),
+                "gold": TenantSpec(name="gold", priority=2)})
+            eng = ServingEngine(cfg, params, slots=1, prefill_buckets=(8, 16),
+                                prefix_cache=PrefixCache(1 << 20),
+                                tenants=reg)
+            b = tuple(int(t) for t in rs.randint(1, 64, (4,)))
+            first = eng.submit(Request(prompt=a, max_new_tokens=8,
+                                       tenant="bulk"))
+            for _ in range(4):
+                eng.step()
+            second = eng.submit(Request(prompt=b, max_new_tokens=4,
+                                        tenant="gold"))
+            eng.run_until_idle()
+            # the victim's rows came out of the live cache into the radix
+            # tree, and its readmission was a warm hit on them
+            assert eng.preemptions == 1
+            assert eng.prefix.stats()["hit_tokens"] > 0
+        else:
+            eng = ServingEngine(cfg, params, slots=2, prefill_buckets=(8, 16),
+                                prefix_cache=PrefixCache(1 << 20))
+            b = a[:5] + (11, 12)
+            first = eng.submit(Request(prompt=a, max_new_tokens=8))
+            for _ in range(4):
+                eng.step()
+            second = eng.submit(Request(prompt=b, max_new_tokens=4))
+            eng.run_until_idle()
+            assert eng.prefix.stats()["hit_tokens"] >= 5
+        for prompt, pd, new in ((a, first, 8), (b, second, 4)):
+            assert pd.result.status == "ok"
+            ref = np.asarray(generate(cfg, params,
+                                      jnp.asarray(prompt)[None], new))[0]
+            np.testing.assert_array_equal(np.asarray(pd.result.tokens), ref)
 
 
 # -- radix prefix cache ----------------------------------------------------------------
