@@ -9,7 +9,7 @@ TPU-first flagship exercising every parallelism axis the framework offers:
            ("heads", "mlp", "vocab" -> tp); XLA inserts the psums
   sp       ring attention over the "sp" axis (parallel/ring_attention.py) —
            the sequence never materializes on one chip
-  ep       MoE blocks with expert-parallel all_to_all (parallel/moe.py)
+  ep       sparse-expert blocks, experts sharded over ep (parallel/moe.py)
 
 Params carry flax logical-axis metadata; map them onto a mesh with
 parallel/sharding.py's rules.
@@ -111,10 +111,26 @@ class TransformerConfig:
     # large-vocab memory/HBM lever.  The param tree is identical either
     # way (the head kernel is created at init in both modes).
     head: str = "dense"
-    # MoE: every `moe_every`-th block uses experts (0 = dense model)
+    # sparse experts (parallel/moe.py): every `moe_every`-th block routes
+    # each token to `experts_per_token` of `n_experts` SwiGLU experts of
+    # width d_ff, dropless (0 experts = dense model; moe_every=1 = every
+    # block).  `norm_topk_prob`: the chosen gate weights renormalised to
+    # sum to 1 (the published key of the same name)
     n_experts: int = 0
     moe_every: int = 2
-    capacity_factor: float = 1.25
+    experts_per_token: int = 1
+    norm_topk_prob: bool = False
+    # QK-norm: a norm of the config's flavor over the WHOLE q and k
+    # projections (own scales, norm_eps), before the head split and rope
+    qk_norm: bool = False
+    # standard deviation the token embedding is initialised with (every
+    # other matrix: 0.02).  It decides what seeded stand-in weights route
+    # by: at 0.02 an attention layer's context average outweighs the
+    # token in the residual stream, so a router picks one expert set for a
+    # whole sequence and greedy decoding repeats one token; at 1.0 the
+    # token leads and routing changes with it, as in a trained model
+    # (PERF.md section 6, PR 25).  A checkpoint overwrites it
+    embed_init_std: float = 0.02
     # mesh is needed for attention="ring"/"ulysses" (shard_map region)
     mesh: Optional[Mesh] = None
     sp_axis: str = "sp"
@@ -145,7 +161,6 @@ class TransformerConfig:
         assert self.d_model % self.n_heads == 0
         if self.decode:
             assert self.rope, "decode mode requires rope positions"
-            assert self.n_experts == 0, "decode mode supports dense models"
         if self.n_kv_heads:
             assert self.n_heads % self.n_kv_heads == 0, (
                 "query heads must be a multiple of kv heads"
@@ -161,6 +176,10 @@ class TransformerConfig:
                 "sliding window is supported on the flash/full paths"
             )
         assert self.ffn in ("gelu", "swiglu"), self.ffn
+        if self.n_experts:
+            assert 1 <= self.experts_per_token <= self.n_experts, (
+                "experts per token must lie in 1..n_experts"
+            )
         assert self.norm in ("layer", "rms"), self.norm
         assert self.remat_policy in ("none", "full", "dots"), self.remat_policy
         assert self.flash_backward in (None, "pallas", "xla"), (
@@ -237,9 +256,13 @@ class Attention(nn.Module):
         B, L, _ = x.shape
         qkv_axes = ("embed", "heads")
         ab = cfg.attention_bias
-        q = _dense(cfg.d_model, "q", qkv_axes, cfg.dtype, ab)(x).reshape(B, L, H, D)
-        k = _dense(Hkv * D, "k", qkv_axes, cfg.dtype, ab)(x).reshape(B, L, Hkv, D)
-        v = _dense(Hkv * D, "v", qkv_axes, cfg.dtype, ab)(x).reshape(B, L, Hkv, D)
+        def project(name, heads):
+            y = _dense(heads * D, name, qkv_axes, cfg.dtype, ab)(x)
+            if cfg.qk_norm and name != "v":
+                y = _norm(cfg, name + "_norm")(y).astype(cfg.dtype)
+            return y.reshape(B, L, heads, D)
+
+        q, k, v = project("q", H), project("k", Hkv), project("v", Hkv)
 
         if cfg.decode:
             # KV-cache decode: write this call's k/v at the cache cursor,
@@ -515,9 +538,9 @@ class Block(nn.Module):
         drop = nn.Dropout(cfg.dropout, deterministic=not train)
         x = x + drop(Attention(cfg, name="attn")(ln(name="ln1")(x)))
         if self.use_moe:
-            from ..parallel.moe import MoEMLP
+            from ..parallel.moe import MoE
 
-            x = x + drop(MoEMLP(cfg, name="moe")(ln(name="ln2")(x)))
+            x = x + drop(MoE(cfg, name="moe")(ln(name="ln2")(x)))
         else:
             x = x + drop(MLP(cfg, name="mlp")(ln(name="ln2")(x)))
         return logical_constraint(x, ("batch", "seq", "act_embed"), cfg.mesh)
@@ -566,7 +589,8 @@ class TransformerLM(nn.Module):
         emb = nn.Embed(
             cfg.vocab_size, cfg.d_model, dtype=cfg.dtype, name="embed",
             embedding_init=nn.with_logical_partitioning(
-                nn.initializers.normal(stddev=0.02), ("vocab", "embed")
+                nn.initializers.normal(stddev=cfg.embed_init_std),
+                ("vocab", "embed")
             ),
         )
         # pin the lookup output to the activation layout immediately: the
@@ -850,17 +874,23 @@ def mlm_corrupt(
 
 def lm_loss_with_aux(
     model: TransformerLM, params, tokens: jax.Array, aux_weight: float = 0.01,
-    z_loss: float = 0.0,
+    z_loss: float = 0.0, router_z_weight: float = 0.001,
 ) -> jax.Array:
-    """LM loss + Switch load-balancing auxiliary loss (required for MoE
-    configs — without it the router collapses onto one expert)."""
+    """LM loss + the experts' two auxiliary losses, each a mean over the
+    expert layers (parallel/moe.py sows them): `aux_weight` x the
+    load-balancing loss in its top-k form (without it the router collapses
+    onto few experts) and `router_z_weight` x the router z-loss.  The
+    defaults are OLMoE's published coefficients."""
     logits, state = model.apply({"params": params}, tokens, mutable=["intermediates"])
     loss = lm_loss(logits, tokens, z_loss=z_loss)
-    aux = jnp.zeros((), jnp.float32)
-    for path, leaves in _iter_sown(state.get("intermediates", {})):
-        if path.endswith("moe_aux_loss"):
-            aux = aux + sum(jnp.asarray(l, jnp.float32) for l in leaves)
-    return loss + aux_weight * aux
+    sown = _iter_sown(state.get("intermediates", {}))
+    for name, weight in (("moe_aux_loss", aux_weight),
+                         ("moe_router_z", router_z_weight)):
+        terms = [jnp.asarray(l, jnp.float32) for path, leaves in sown
+                 if path.endswith(name) for l in leaves]
+        if terms and weight:
+            loss = loss + weight * sum(terms) / len(terms)
+    return loss
 
 
 def _iter_sown(tree, prefix=""):

@@ -14,7 +14,7 @@ import math
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 
 class RateWindow:
@@ -146,6 +146,12 @@ METRIC_HELP: Dict[str, str] = {
     "collective_latency_ms": "Per-collective wall latency histogram (ms).",
     "collective_overlap":
         "Bucketed gradient-sync dispatch-to-ready latency histogram (ms).",
+    "kft_moe_assignments_total":
+        "Rows the slot-cache programs routed to each expert, by layer.",
+    "kft_moe_experts_hit_total":
+        "Distinct experts that owned a row, summed over decode calls and layers.",
+    "kft_moe_decode_layer_calls_total":
+        "Expert-layer calls of the slot-cache programs (steps x expert layers).",
     "kungfu_fleet_ranks_scraped": "1 if the rank answered the fleet scrape.",
     "kungfu_fleet_scrape_errors_total": "Failed fleet scrape fan-out fetches.",
 }
@@ -181,6 +187,8 @@ class Counters:
         # or ("collective_latency_ms", "grad-allreduce").  All writes/reads
         # go through the single Counters lock.
         self._hists: Dict[Tuple[str, str], Histogram] = {}
+        # counters kept elsewhere (on the device) and read only at a scrape
+        self._sources: List[Callable[[], Dict[str, Dict[str, float]]]] = []
         # incarnation epoch: reset_for_reinit bumps it so delta-based
         # consumers (the time-series sampler) re-anchor instead of reading
         # negative rates against a dead incarnation's totals
@@ -233,6 +241,21 @@ class Counters:
     def quant_errors(self) -> Dict[str, float]:
         with self._lock:
             return dict(self._quant_err)
+
+    def add_source(self, fn: Callable[[], Dict[str, Dict[str, float]]]) -> None:
+        """Register counters this object does not hold: `fn()` is called at
+        every scrape (and at both ends of a profile capture) and returns
+        {family name: {label text ('' or 'k="v",...'): value}}."""
+        with self._lock:
+            self._sources.append(fn)
+
+    def source_families(self) -> Dict[str, Dict[str, float]]:
+        with self._lock:
+            sources = list(self._sources)
+        out: Dict[str, Dict[str, float]] = {}
+        for fn in sources:
+            out.update(fn())
+        return out
 
     def inc_event(self, key: str, n: int = 1) -> None:
         """Count one lifecycle event (worker failure, heal, restart, ...)."""
@@ -415,6 +438,11 @@ class Counters:
             lines.extend(help_and_type("kungfu_gauge", "gauge"))
             for key in sorted(ga):
                 lines.append(f'kungfu_gauge{{name="{key}"}} {ga[key]}')
+        for family, table in sorted(self.source_families().items()):
+            lines.extend(help_and_type(family, "counter"))
+            for labels, value in table.items():
+                lab = f"{{{labels}}}" if labels else ""
+                lines.append(f"{family}{lab} {value}")
         with self._lock:
             # snapshot under the lock, render outside it
             hists = [

@@ -49,6 +49,7 @@ touched when monitoring is on (counters_if_enabled).
 from __future__ import annotations
 
 import hashlib
+import json
 import os
 import socket
 import sys
@@ -614,7 +615,12 @@ def capture_profile(secs: float, out_dir: Optional[str] = None,
     /timeline next to whatever it overlapped.  Any failure — profiler
     absent, already running, interpreter-only build — degrades to a no-op
     result (ok=false, noop=true), never an exception: this sits behind an
-    HTTP endpoint and a fleet fan-out."""
+    HTTP endpoint and a fleet fan-out.
+
+    Counters that live on the device (`Counters.add_source`: the experts'
+    routing counts) are read at both ends of the capture into
+    `<capture>/counters.json` ({"start": families, "end": families}), so a
+    reader has them over the very window the device trace covers."""
     global _profile_seq
     try:
         secs = min(max(float(secs), 0.05), PROFILE_MAX_SECS)
@@ -641,12 +647,19 @@ def capture_profile(secs: float, out_dir: Optional[str] = None,
             tmp = tempfile.mkdtemp(prefix=".profile-tmp-", dir=out_dir)
             options = jax.profiler.ProfileOptions()
             options.python_tracer_level = 1 if python else 0
+            c = _counters()
+            read_sources = c.source_families if c is not None else dict
             jax.profiler.start_trace(tmp, profiler_options=options)
             try:
+                start = read_sources()
                 time.sleep(secs)
+                end = read_sources()
             finally:
                 t_end = time.monotonic()
                 _stop_trace_xplane_only(tmp)
+            if end:
+                with open(os.path.join(tmp, "counters.json"), "w") as f:
+                    json.dump({"start": start, "end": end}, f)
             os.replace(tmp, dest)
             # capture's end to artifact ready: the process serves on
             # meanwhile, slower, so this is part of what a capture costs

@@ -9,14 +9,14 @@ from .pp import (
     stack_group_params,
     stack_stage_params,
 )
-from .moe import MoEMLP
+from .moe import MoE
 
 __all__ = [
     "ring_attention", "full_attention", "ulysses_attention",
     "DEFAULT_RULES", "rules_for_mesh", "param_shardings", "logical_constraint",
     "pipeline_apply", "pipeline_apply_grouped", "pipeline_spmd",
     "stack_stage_params", "stack_group_params", "PipelinedLM",
-    "MoEMLP",
+    "MoE",
 ]
 
 

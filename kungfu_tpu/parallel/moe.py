@@ -1,12 +1,33 @@
-"""Mixture-of-Experts with expert parallelism.
+"""Sparse-expert feed-forward layer: top-k routing, dropless, SwiGLU experts.
 
-Absent from the reference (DP-only); TPU-first design: experts are sharded
-over the "ep" (or "tp" fallback) mesh axis via the logical "expert" axis, and
-token routing uses dense einsum dispatch/combine masks (the TPU-friendly
-formulation — dynamic scatter/gather defeats XLA tiling; a dense dispatch
-einsum is MXU work).  Top-1 switch routing with capacity factor + load-
-balancing auxiliary loss (Switch Transformer style); XLA turns the sharded
-dispatch einsums into the expert all_to_all on ICI.
+    p      = softmax(u W_r)                  float32, over all experts
+    top-k  = the k largest p (lower index first on a tie)
+    g_e    = p_e, or p_e / sum of the chosen p when `norm_topk_prob`
+    MoE(u) = sum over the chosen e of g_e * W_down,e (silu(W_gate,e u) * W_up,e u)
+
+No capacity and no dropped token.  One algorithm for a decode step's
+[slots, 1], a 1,536-token prefill and a training batch: the tokens x k
+assignments are sorted by expert and each projection is one grouped matmul
+over those rows (ops/gmm.py: the Mosaic kernel `kft_moe_gmm` on TPU,
+`jax.lax.ragged_dot` elsewhere), combined by the gate weights in float32.
+Only experts that own rows are read, and they are read as stored.
+
+Experts carry the logical axes ("expert", "embed", "mlp"), so an `ep` mesh
+axis shards them; GSPMD then partitions the grouped matmul as it sees fit
+(PERF.md section 7: an expert-parallel form is open).
+
+Sown for the trainer's loss (`lm_loss_with_aux`), harmless when
+"intermediates" is not mutable: `moe_aux_loss`, the load-balancing loss in
+its top-k form (E * sum_e f_e P_e, f_e the share of tokens that chose e,
+P_e the mean router probability: k when balanced), `moe_router_z`,
+mean(logsumexp(router logits)^2), and `moe_experts` [B, L, k], the chosen
+experts (what the routing-flip measurement compares with the reference's).
+
+Counted on the device in decode mode, in the "moe_stats" collection
+(declared only there, updated only when the caller makes it mutable: the
+serving engine's slot-cache programs): `assignments` [experts], rows routed
+to each expert; `experts_hit`, distinct experts that owned a row, summed
+over calls; `calls`.
 """
 from __future__ import annotations
 
@@ -15,77 +36,126 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import flax.linen as nn
-from .sharding import logical_constraint
+
+from ..ops.gmm import grouped_matmul
+
+STATS = "moe_stats"
 
 
-class MoEMLP(nn.Module):
+def route(probs: jax.Array, k: int, renormalise: bool):
+    """(gates [T, k] float32, experts [T, k] int32) from probs [T, E].
+    `lax.top_k` is by value, and puts the lower index first on a tie."""
+    gates, experts = jax.lax.top_k(probs, k)
+    if renormalise:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return gates, experts.astype(jnp.int32)
+
+
+class MoE(nn.Module):
     cfg: Any  # TransformerConfig
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
         B, L, Dm = x.shape
-        E = cfg.n_experts
-        tokens = B * L
-        capacity = max(1, int(cfg.capacity_factor * tokens / E))
+        E, k, width = cfg.n_experts, cfg.experts_per_token, cfg.d_ff
+        T = B * L
+        init = nn.initializers.normal(stddev=0.02)
 
-        # router in fp32 (routing decisions are precision-sensitive)
-        gate_w = self.param(
-            "router",
-            nn.with_logical_partitioning(nn.initializers.normal(stddev=0.02), ("embed", "expert")),
-            (Dm, E),
-            jnp.float32,
-        )
-        flat = x.reshape(tokens, Dm)
-        logits = flat.astype(jnp.float32) @ gate_w  # [T, E]
-        probs = jax.nn.softmax(logits, axis=-1)
-        expert_idx = jnp.argmax(probs, axis=-1)  # [T]
-        gate = jnp.max(probs, axis=-1)  # [T]
+        def expert_param(name, shape, axes):
+            return self.param(name, nn.with_logical_partitioning(init, axes),
+                              shape, jnp.float32)
 
-        # capacity-limited position of each token within its expert
-        onehot = jax.nn.one_hot(expert_idx, E, dtype=jnp.float32)  # [T, E]
-        pos_in_expert = (jnp.cumsum(onehot, axis=0) - 1.0) * onehot  # [T, E]
-        keep = (pos_in_expert < capacity) & (onehot > 0)  # [T, E]
-        pos = jnp.sum(pos_in_expert * keep, axis=-1).astype(jnp.int32)  # [T]
+        router = self.param(
+            "router", nn.with_logical_partitioning(init, ("embed", "expert")),
+            (Dm, E), jnp.float32)
+        w_gate = expert_param("w_gate", (E, Dm, width), ("expert", "embed", "mlp"))
+        w_up = expert_param("w_up", (E, Dm, width), ("expert", "embed", "mlp"))
+        w_down = expert_param("w_down", (E, width, Dm), ("expert", "mlp", "embed"))
 
-        # dense dispatch tensor [T, E, C]: MXU-friendly scatter
-        dispatch = (
-            keep.astype(x.dtype)[..., None]
-            * jax.nn.one_hot(pos, capacity, dtype=x.dtype)[:, None, :]
-        )
-        expert_in = jnp.einsum("td,tec->ecd", flat, dispatch)  # [E, C, Dm]
-        expert_in = logical_constraint(
-            expert_in, ("expert", None, "act_embed"), self.cfg.mesh
-        )
+        with jax.named_scope("moe"):
+            flat = x.reshape(T, Dm)
+            with jax.named_scope("moe.router"):
+                # float32 in full: a TPU otherwise multiplies float32
+                # matrices in bf16 passes, and a rounding here is a
+                # different expert, a discrete change of the output
+                logits = jnp.dot(flat.astype(jnp.float32), router,
+                                 precision=jax.lax.Precision.HIGHEST)
+                probs = jax.nn.softmax(logits, axis=-1)  # [T, E]
+                gates, experts = route(probs, k, cfg.norm_topk_prob)
+                # the T*k assignments sorted by expert (stable: by token
+                # within an expert); row r of the grouped matmuls is token
+                # order[r] // k
+                order = jnp.argsort(experts.reshape(-1), stable=True)
+                counts = jnp.bincount(experts.reshape(-1), length=E)  # [E]
+            with jax.named_scope("moe.experts"):
+                rows = flat.astype(cfg.dtype)[order // k]             # [T*k, Dm]
+                gate = grouped_matmul(rows, w_gate, counts, jnp.float32)
+                up = grouped_matmul(rows, w_up, counts, jnp.float32)
+                h = (nn.silu(gate) * up).astype(cfg.dtype)
+                y = grouped_matmul(h, w_down, counts, jnp.float32)    # [T*k, Dm]
+                unsort = jnp.argsort(order)
+                y = y[unsort].reshape(T, k, Dm)
+                out = jnp.einsum("tkd,tk->td", y, gates).astype(cfg.dtype)
 
-        # per-expert FFN, experts sharded over the expert axis
-        w_in = self.param(
-            "w_in",
-            nn.with_logical_partitioning(nn.initializers.normal(stddev=0.02), ("expert", "embed", "mlp")),
-            (E, Dm, cfg.d_ff),
-            jnp.float32,
-        )
-        w_out = self.param(
-            "w_out",
-            nn.with_logical_partitioning(nn.initializers.normal(stddev=0.02), ("expert", "mlp", "embed")),
-            (E, cfg.d_ff, Dm),
-            jnp.float32,
-        )
-        h = jnp.einsum("ecd,edf->ecf", expert_in, w_in.astype(x.dtype))
-        h = nn.gelu(h)
-        expert_out = jnp.einsum("ecf,efd->ecd", h, w_out.astype(x.dtype))  # [E, C, Dm]
+        frac_tokens = counts.astype(jnp.float32) / T
+        self.sow("intermediates", "moe_aux_loss",
+                 E * jnp.sum(frac_tokens * jnp.mean(probs, axis=0)))
+        self.sow("intermediates", "moe_router_z",
+                 jnp.mean(jax.scipy.special.logsumexp(logits, axis=-1) ** 2))
+        self.sow("intermediates", "moe_experts", experts.reshape(B, L, k))
+        if cfg.decode and (self.is_initializing()
+                           or self.is_mutable_collection(STATS)):
+            zero = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+            assignments = self.variable(STATS, "assignments", zero, E)
+            hit = self.variable(STATS, "experts_hit", zero)
+            calls = self.variable(STATS, "calls", zero)
+            if not self.is_initializing():
+                assignments.value = assignments.value + counts
+                hit.value = hit.value + jnp.sum(counts > 0, dtype=jnp.int32)
+                calls.value = calls.value + 1
+        return out.reshape(B, L, Dm)
 
-        # combine back, weighted by the gate
-        combine = dispatch * gate.astype(x.dtype)[:, None, None]  # [T, E, C]
-        out = jnp.einsum("ecd,tec->td", expert_out, combine).reshape(B, L, Dm)
 
-        # Switch load-balancing loss: E * sum_e f_e * p_e
-        frac_tokens = jnp.mean(onehot, axis=0)
-        frac_probs = jnp.mean(probs, axis=0)
-        aux = E * jnp.sum(frac_tokens * frac_probs)
-        self.sow("intermediates", "moe_aux_loss", aux)
-        self.sow(
-            "intermediates", "moe_dropped",
-            1.0 - jnp.sum(keep.astype(jnp.float32)) / tokens,
-        )
-        return out
+def stats_totals(stats) -> dict:
+    """The "moe_stats" collection of a decode-mode model (host copy) as
+    totals: {"assignments": int [layers, experts], "experts_hit": int,
+    "layer_calls": int}, layers in block order; None for a dense model."""
+    import numpy as np
+
+    layers = sorted((int(name.rsplit("_", 1)[1]), block["moe"])
+                    for name, block in (stats or {}).items())
+    if not layers:
+        return None
+    return {
+        "assignments": np.stack([np.asarray(m["assignments"]) for _, m in layers]),
+        "experts_hit": int(sum(int(m["experts_hit"]) for _, m in layers)),
+        "layer_calls": int(sum(int(m["calls"]) for _, m in layers)),
+    }
+
+
+def stats_health(stats):
+    """The same as a small block for /healthz; None for a dense model."""
+    t = stats_totals(stats)
+    if t is None:
+        return None
+    return {"assignments_total": int(t["assignments"].sum()),
+            "assignments_by_expert_max": int(t["assignments"].sum(0).max()),
+            "experts_hit_total": t["experts_hit"],
+            "decode_layer_calls_total": t["layer_calls"]}
+
+
+def stats_families(stats) -> dict:
+    """The same as Prometheus families for `Counters.add_source`:
+    {family: {label text: value}}."""
+    t = stats_totals(stats)
+    if t is None:
+        return {}
+    return {
+        "kft_moe_assignments_total": {
+            f'layer="{layer}",expert="{e}"': int(n)
+            for layer, row in enumerate(t["assignments"])
+            for e, n in enumerate(row)},
+        "kft_moe_experts_hit_total": {"": t["experts_hit"]},
+        "kft_moe_decode_layer_calls_total": {"": t["layer_calls"]},
+    }

@@ -40,6 +40,12 @@ greedy output (docs/serving.md):
     `submit_prefilled` admits shipped KV rows straight into a slot with no
     local prefill (the decode-tier surface)
 
+What a decode-mode model counts on the device (the "moe_stats" collection
+of a model with sparse experts, parallel/moe.py) rides beside the slot
+cache: `_decode` and `_verify` take it donated and hand it back updated,
+nothing else touches it, and `device_counters()` is its one read, made when
+a scrape asks.  The engine knows no more of the model than that.
+
 The per-slot cache cursors this relies on live in models/transformer.py
 (decode mode).  The int8 KV-cache storage dtype comes straight from the
 model config (`kv_cache_dtype="int8"`): the serving cache stores quantized
@@ -147,17 +153,30 @@ class ServingEngine:
         self.buckets = tuple(sorted(prefill_buckets or default_buckets(cfg.max_len)))
         assert self.buckets[-1] <= cfg.max_len
 
-        probe = jnp.zeros((slots, 1), jnp.int32)
-        variables = self.model.init(jax.random.PRNGKey(0), probe)
-        self.cache = variables["cache"]
-        self._small_cache0 = self.model.init(
-            jax.random.PRNGKey(0), probe[:1]
-        )["cache"]
+        # the state a decode-mode model declares beside its parameters, all
+        # of it zeros at init: the KV cache, and whatever else it counts on
+        # the device (the experts' "moe_stats").  Taken from the shapes of
+        # an abstract init, so no second set of parameters is ever made
+        def zeros(batch: int) -> Tuple[Any, Dict[str, Any]]:
+            shapes = jax.eval_shape(self.model.init, jax.random.PRNGKey(0),
+                                    jnp.zeros((batch, 1), jnp.int32))
+            return shapes["params"], {
+                col: jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), tree)
+                for col, tree in shapes.items() if col != "params"}
+
+        abstract_params, state = zeros(slots)
+        self.cache = state.pop("cache")
+        # device counters: carried (donated, updated) by the slot-cache
+        # programs _decode and _verify only, read by device_counters()
+        self._dev_counters = state
+        self._dev_counters_host = jax.device_get(state)
+        self._dev_lock = threading.Lock()
+        self._small_cache0 = zeros(1)[1]["cache"]
         if mesh is not None:
             from ..parallel.sharding import decode_cache_shardings, param_shardings
 
             params = jax.device_put(
-                params, param_shardings(mesh, variables["params"], rules)
+                params, param_shardings(mesh, abstract_params, rules)
             )
             self.cache = jax.device_put(
                 self.cache, decode_cache_shardings(mesh, self.cache)
@@ -216,28 +235,32 @@ class ServingEngine:
             first = jnp.argmax(last).astype(jnp.int32)
             return first, last, _fix_cursor(st["cache"], total_len)
 
-        @partial(jax.jit, donate_argnums=(1,))
-        def _decode(params, cache, toks):
+        def _apply_slots(params, cache, counters, toks):
+            """The model over the slot cache: (logits, cache, counters)."""
+            logits, st = model.apply(
+                {"params": params, "cache": cache, **counters}, toks,
+                mutable=["cache", *counters]
+            )
+            return logits, st["cache"], {c: st[c] for c in counters}
+
+        @partial(jax.jit, donate_argnums=(1, 2))
+        def _decode(params, cache, counters, toks):
             # toks [slots, 1] — THE fixed decode signature; free slots carry
             # a dummy token whose output is never read
-            logits, st = model.apply(
-                {"params": params, "cache": cache}, toks, mutable=["cache"]
-            )
+            logits, cache, counters = _apply_slots(params, cache, counters, toks)
             last = logits[:, -1].astype(jnp.float32)  # [slots, V]
             greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
-            return greedy, last, st["cache"]
+            return greedy, last, cache, counters
 
-        @partial(jax.jit, donate_argnums=(1,))
-        def _verify_accept(params, cache, toks, proposals):
+        @partial(jax.jit, donate_argnums=(1, 2))
+        def _verify_accept(params, cache, counters, toks, proposals):
             # toks [slots, k] — the ONE extra compiled decode signature of
             # speculative decoding: per-slot cursors make a k-token call
             # exactly k chained 1-token calls.  Greedy acceptance and the
             # per-slot cursor rollback fold into the same program: one
             # dispatch, one host sync per speculative round.
             k = toks.shape[1]
-            logits, st = model.apply(
-                {"params": params, "cache": cache}, toks, mutable=["cache"]
-            )
+            logits, cache, counters = _apply_slots(params, cache, counters, toks)
             g = jnp.argmax(
                 logits.astype(jnp.float32), axis=-1
             ).astype(jnp.int32)  # [slots, k]: the target's own greedy run
@@ -251,8 +274,8 @@ class ServingEngine:
                     return leaf - (k - 1 - n_acc).astype(leaf.dtype)
                 return leaf
 
-            cache2 = jax.tree_util.tree_map_with_path(roll, st["cache"])
-            return g, n_acc, cache2
+            cache2 = jax.tree_util.tree_map_with_path(roll, cache)
+            return g, n_acc, cache2, counters
 
         # the program observatory holds the engine to its own compile
         # promises: one prefill program per bucket, ONE decode signature,
@@ -571,9 +594,10 @@ class ServingEngine:
         with trace_scope("serve:decode", cat="serving", args=targs,
                          track=bool(ids)):
             t0 = time.monotonic()
-            with trace_scope("serve:decode.dispatch", cat="serving"):
-                greedy, logits, self.cache = self._decode(
-                    self.params, self.cache, toks)
+            with trace_scope("serve:decode.dispatch", cat="serving"), \
+                    self._dev_lock:
+                greedy, logits, self.cache, self._dev_counters = self._decode(
+                    self.params, self.cache, self._dev_counters, toks)
             with trace_scope("serve:decode.fetch", cat="serving"):
                 greedy = np.asarray(greedy)
                 if sampling:
@@ -649,9 +673,10 @@ class ServingEngine:
         with trace_scope("serve:verify", cat="serving", args=vargs,
                          track=bool(ids)):
             t0 = time.monotonic()
-            with trace_scope("serve:verify.dispatch", cat="serving"):
-                g_dev, n_acc_dev, self.cache = self._verify(
-                    self.params, self.cache,
+            with trace_scope("serve:verify.dispatch", cat="serving"), \
+                    self._dev_lock:
+                g_dev, n_acc_dev, self.cache, self._dev_counters = self._verify(
+                    self.params, self.cache, self._dev_counters,
                     jnp.asarray(ver.astype(np.int32)),
                     jnp.asarray(proposals.astype(np.int32)),
                 )
@@ -778,6 +803,24 @@ class ServingEngine:
             d["generated"] = list(req.generated)
             out.append(d)
         return out
+
+    def device_counters(self, refresh: bool = True) -> Dict[str, Any]:
+        """What the model counted on the device, copied to the host:
+        {collection: tree of numpy} (empty for a model that counts nothing).
+        The one read of these arrays, made when /metrics or a profile
+        capture asks, never by a step.  The lock keeps the step programs
+        from donating the arrays under the copy; the copy waits for the
+        step in flight.  A caller that cannot have the lock soon (a step
+        program is compiling under it) gets the last copy, so a scrape never
+        outwaits its timeout here.  `refresh=False` is the last copy and
+        touches neither lock nor device: /healthz, which the router probes
+        four times a second beside the decode loop."""
+        if refresh and self._dev_counters and self._dev_lock.acquire(timeout=0.25):
+            try:
+                self._dev_counters_host = jax.device_get(self._dev_counters)
+            finally:
+                self._dev_lock.release()
+        return self._dev_counters_host
 
     def stats(self) -> Dict[str, Any]:
         out = {

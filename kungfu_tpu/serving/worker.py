@@ -201,6 +201,11 @@ class ServingWorker:
             queue_capacity=args.queue_capacity, counters=self.counters,
             prefix_cache=prefix, spec=spec, tenants=tenants,
         )
+        if self.counters is not None:
+            from ..parallel.moe import stats_families
+
+            self.counters.add_source(
+                lambda: stats_families(self._moe_stats()))
         self.decode_pool = None
         if self.tier == "prefill" and args.config_server:
             from ..elastic.config_client import ConfigClient
@@ -211,14 +216,32 @@ class ServingWorker:
                              retry_deadline_s=3.0),
                 self_spec=f"{args.host}:{args.port}",
             )
-        # the blob served on /weights: packed once (params are immutable)
+        # the blob served on /weights: packed at the first request, once
+        # (params are immutable).  Packing at boot held three host copies
+        # of the parameters, which a 10.9 GB model does not leave room for
+        self._weights_blob: Optional[bytes] = None
+        self._weights_lock = threading.Lock()
+
+    def _weights(self) -> bytes:
         from ..resilience.buddy import pack_snapshot
 
-        self._weights_blob = pack_snapshot(
-            step=self.incarnation, offset=0,
-            state={"params": _to_numpy(params)},
-            origin_rank=self.rank, cluster_version=0,
-        ).tobytes()
+        with self._weights_lock:
+            if self._weights_blob is None:
+                self._weights_blob = pack_snapshot(
+                    step=self.incarnation, offset=0,
+                    state={"params": _to_numpy(self.engine.params)},
+                    origin_rank=self.rank, cluster_version=0,
+                ).tobytes()
+            return self._weights_blob
+
+    def _moe_stats(self, refresh: bool = True):
+        """The experts' counts (parallel/moe.py's `stats_*` turn them into
+        /metrics families and a /healthz block), read from the device now
+        or, with `refresh=False`, as the last read left them; None for a
+        dense model."""
+        from ..parallel.moe import STATS
+
+        return self.engine.device_counters(refresh).get(STATS)
 
     # -- weight ladder -------------------------------------------------------------
 
@@ -380,13 +403,20 @@ class ServingWorker:
                 path = self.path.split("?", 1)[0].rstrip("/")
                 if path == "/healthz":
                     stats = dict(outer.engine.stats())
+                    from ..parallel.moe import stats_health
+
+                    # the router's probe lands here every 250 ms: the block
+                    # is as of the last /metrics scrape or profile capture
+                    moe = stats_health(outer._moe_stats(refresh=False))
+                    if moe is not None:
+                        stats["moe"] = moe
                     stats.update(ok=True, rank=outer.rank,
                                  incarnation=outer.incarnation,
                                  weight_rung=outer.weight_rung,
                                  tier=outer.tier)
                     self._send(200, json.dumps(stats).encode())
                 elif path == "/weights":
-                    self._send(200, outer._weights_blob,
+                    self._send(200, outer._weights(),
                                "application/octet-stream")
                 elif path == "/kv_result":
                     q = self.path.partition("?")[2]

@@ -216,7 +216,8 @@ class TestTransformerTP:
         cfg = TransformerConfig(
             vocab_size=128, d_model=64, n_layers=2, n_heads=4, d_ff=128,
             max_len=64, dtype=jnp.float32, attention=attention,
-            n_experts=n_experts, mesh=mesh,
+            n_experts=n_experts, experts_per_token=min(2, n_experts),
+            mesh=mesh,
         )
         return TransformerLM(cfg), cfg
 
@@ -269,40 +270,33 @@ class TestTransformerTP:
         np.testing.assert_allclose(logits_r, logits_f, rtol=2e-3, atol=2e-4)
 
     def test_moe_model_runs(self):
-        from kungfu_tpu.models.transformer import lm_loss
+        """dp x ep mesh, experts placed over ep: the loss with its two router
+        terms and its gradients equal the unsharded model's."""
+        from kungfu_tpu.models.transformer import lm_loss_with_aux
+        from kungfu_tpu.parallel.sharding import param_shardings
 
         mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("dp", "ep"))
         rules = rules_for_mesh(mesh)
         model, cfg = self._build(mesh, n_experts=4)
         tokens = np.random.RandomState(2).randint(0, 128, size=(4, 16)).astype(np.int32)
+        step = jax.jit(jax.value_and_grad(
+            lambda p, t: lm_loss_with_aux(model, p, t)))
         with nn.logical_axis_rules(rules):
-            params = nn.meta.unbox(model.init(jax.random.PRNGKey(0), tokens)["params"])
+            boxed = model.init(jax.random.PRNGKey(0), tokens)["params"]
+            params = nn.meta.unbox(boxed)
             with mesh:
-                loss, grads = jax.jit(
-                    jax.value_and_grad(lambda p, t: lm_loss(model.apply({"params": p}, t), t))
-                )(params, tokens)
-        assert np.isfinite(float(loss))
-        # expert weights sharded over ep
-        w_in = params["block_1"]["moe"]["w_in"]
-        assert w_in.shape[0] == 4
-
-
-class TestMoEUnit:
-    def test_routing_capacity_and_combine(self):
-        from kungfu_tpu.models.transformer import TransformerConfig
-        from kungfu_tpu.parallel.moe import MoEMLP
-
-        cfg = TransformerConfig(
-            vocab_size=16, d_model=8, n_layers=1, n_heads=2, d_ff=16,
-            n_experts=2, capacity_factor=2.0, dtype=jnp.float32,
-        )
-        m = MoEMLP(cfg)
-        x = jnp.asarray(np.random.RandomState(3).randn(2, 4, 8), jnp.float32)
-        vars_ = m.init(jax.random.PRNGKey(0), x)
-        y, state = m.apply(vars_, x, mutable=["intermediates"])
-        assert y.shape == x.shape
-        aux = state["intermediates"]["moe_aux_loss"][0]
-        assert float(aux) >= 1.0 - 1e-5  # >= 1 by Cauchy-Schwarz, == 1 if balanced
+                placed = jax.device_put(params, param_shardings(mesh, boxed))
+                loss, grads = step(placed, tokens)
+        w_gate = placed["block_1"]["moe"]["w_gate"]
+        assert w_gate.shape == (4, 64, 128)
+        assert w_gate.sharding.spec[0] == "ep", w_gate.sharding
+        plain, _ = self._build(None, n_experts=4)
+        want, want_grads = jax.value_and_grad(
+            lambda p, t: lm_loss_with_aux(plain, p, t))(params, tokens)
+        assert abs(float(loss) - float(want)) < 1e-5
+        for g, w in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       atol=1e-5, rtol=1e-4)
 
 
 class TestPipeline:

@@ -336,8 +336,8 @@ def _host_path_tokens(cfg, params, reqs, buckets):
     while any(len(o) < new for o, (_, new, _) in zip(out, reqs)):
         live = [s for s, (_, new, _) in enumerate(reqs) if len(out[s]) < new]
         fetches += any(reqs[s][2] > 0.0 for s in live)
-        _, logits, cache = ref._decode(ref.params, cache,
-                                       jnp.asarray(nxt[:, None]))
+        _, logits, cache, _ = ref._decode(ref.params, cache, {},
+                                          jnp.asarray(nxt[:, None]))
         logits = np.asarray(logits)
         for s in live:  # a finished slot rides along on its last token
             nxt[s] = _host_pick(rng, logits[s], reqs[s][2])
@@ -370,7 +370,7 @@ class TestStepPrograms:
                 # slot 1's write runs past max_len: the model poisons that
                 # row with NaN and leaves the others clean
                 cache = set_cursors(cache, jnp.asarray([0, cfg.max_len, 0]))
-            got, logits, _ = eng._decode(eng.params, cache, toks)
+            got, logits, _, _ = eng._decode(eng.params, cache, {}, toks)
             got, logits = np.asarray(got), np.asarray(logits)
             assert got.shape == (3,) and got.dtype == np.int32
             assert logits.shape == (3, cfg.vocab_size)
@@ -431,14 +431,15 @@ class TestStepPrograms:
                                      jnp.zeros((2, 3), jnp.int32))
         # the lowering marks every cache leaf a donor (aliased to an output
         # where it can say which), whatever the backend makes of it
-        text = fn.lower(eng.params, eng.cache, *args).as_text()
+        # (the third argument holds a model's device counters: none here)
+        text = fn.lower(eng.params, eng.cache, {}, *args).as_text()
         head = next(ln for ln in text.splitlines() if "@main(" in ln)
         sig = head.split("->")[0]
         assert (sig.count("tf.aliasing_output")
                 + sig.count("jax.buffer_donor")) == n_leaves
         old = eng.cache
-        out = fn(eng.params, eng.cache, *args)
-        assert all(not x.is_deleted() for x in jax.tree.leaves(out[-1]))
+        out = fn(eng.params, eng.cache, {}, *args)
+        assert all(not x.is_deleted() for x in jax.tree.leaves(out[-2]))
         deleted = [x.is_deleted() for x in jax.tree.leaves(old)]
         if any(deleted):  # this backend implements donation
             assert all(deleted)
