@@ -41,7 +41,7 @@ import urllib.request
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 
-#: the flagship's width (BENCH_CONFIGS.json gpt-lm-mfu): never cut
+#: the flagship's width (the bring-up LM of PR 21, no published model): never cut
 WIDTH = dict(d_model=1024, n_heads=16, d_ff=4096, vocab=32000, seq_len=2048,
              batch_per_chip=4)
 FLAGSHIP_LAYERS = 24

@@ -149,7 +149,7 @@ def main(argv=None) -> int:
         n_warn += sum(1 for f in findings if f.severity != ERROR)
 
     if run_env:
-        from .envaudit import env_findings
+        from .envaudit import code_env, env_findings
 
         t0 = time.perf_counter()
         findings = [f for f in env_findings() if f.rule not in suppress]
@@ -157,6 +157,9 @@ def main(argv=None) -> int:
         n_units += 1
         n_err += _report("env-audit", findings, ms, args.verbose,
                          format_findings)
+        # ROADMAP D5: the count only falls (tests/unit/test_analysis.py
+        # holds the ceiling)
+        print(f"      {len(code_env())} KFT_* names read in code")
 
     programs: List = []
     schedules: List = []

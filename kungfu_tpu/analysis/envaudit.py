@@ -59,7 +59,6 @@ INTERNAL_ENV: Dict[str, str] = {
 
 #: directories (relative to repo root) whose source counts as "code"
 CODE_DIRS = ("kungfu_tpu", "scripts", "examples")
-CODE_FILES = ("bench.py",)
 #: docs scanned for the documented set
 DOC_DIRS = ("docs",)
 DOC_FILES = ("README.md",)
@@ -100,7 +99,6 @@ def _scan(paths: Iterable[str], exts: tuple) -> Set[str]:
 def code_env(root: Optional[str] = None) -> Set[str]:
     root = _repo_root(root)
     paths = [os.path.join(root, d) for d in CODE_DIRS]
-    paths += [os.path.join(root, f) for f in CODE_FILES]
     return _scan([p for p in paths if os.path.exists(p)],
                  (".py", ".sh"))
 

@@ -262,7 +262,7 @@ def _b_session(strategy_name, mesh_shape, host_count, compression=None):
 
 
 def _b_session_group():
-    """The fused group-allreduce program (benchmarks/__main__ scaling arm)."""
+    """The fused group-allreduce program (Session.group_all_reduce)."""
 
     def build():
         from ..plan import Impl
@@ -374,7 +374,7 @@ def _b_mnist_slp():
 
 
 def _b_bench_compression(scheme: str):
-    """benchmarks/compression.py's timed allreduce body, per scheme."""
+    """One compressed all-reduce over the dp axis, per wire scheme."""
 
     def build():
         import jax.numpy as jnp
@@ -564,13 +564,13 @@ def builtin_programs() -> List[Program]:
         Program("example-fsdp-transformer", ("example", "bench"),
                 _b_fsdp(True, compression="int8"),
                 "examples/fsdp_transformer.py hybrid step, int8 dp leg "
-                "(the largest corpus program; bench.py times this one)"),
+                "(the largest corpus program)"),
         Program("bench-compression-int8", ("bench", "compression"),
                 _b_bench_compression("int8"),
-                "benchmarks/compression.py int8 allreduce arm"),
+                "compression.all_reduce under shard_map, int8 wire"),
         Program("bench-compression-bf16", ("bench", "compression"),
                 _b_bench_compression("bf16"),
-                "benchmarks/compression.py bf16 allreduce arm"),
+                "compression.all_reduce under shard_map, bf16 wire"),
         # serving v2 compiled programs (docs/serving.md)
         Program("serving-verify-k", ("serving",), _b_serving_verify_k(),
                 "speculative decoding's [slots, k] verify step: decode-mode "

@@ -23,8 +23,7 @@ Consumers: optimizers/sync.py (compression= on the gradient allreduce),
 optimizers/gossip.py (sparse pair exchange), fsdp.py (compressed dp leg),
 optimizers/adaptive.py (GNS-driven bit-width switching in-program),
 policy.py (host-side switching), Session.all_reduce(compression=...),
-monitor/counters.py (bytes-on-wire + quantization-error gauges), and
-benchmarks/compression.py (fp32 vs bf16 vs int8 A/B).
+and monitor/counters.py (bytes-on-wire + quantization-error gauges).
 """
 from .config import (
     AxisCompression,

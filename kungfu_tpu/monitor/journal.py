@@ -150,11 +150,6 @@ EVENT_KINDS: Dict[str, tuple] = {
     "recompile_storm": ("program", "recompiles", "window_s"),
     "sig_budget_exceeded": ("program", "budget", "signatures"),
     "hbm_footprint": ("program", "predicted_bytes", "measured_bytes", "rel_err"),
-    # benchmark harness (benchmarks/runner.py)
-    "bench_probe_failed": ("section",),
-    "bench_probe_recovered": ("section",),
-    "bench_requeued": ("section",),
-    "bench_section_failed": ("section",),
 }
 
 

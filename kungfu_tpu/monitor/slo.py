@@ -32,11 +32,12 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 from ..utils import get_logger
 from ..utils.trace import job_now
 from .journal import journal_event
+from .timeseries import TimeSeriesStore
 
 log = get_logger("kungfu.slo")
 
@@ -346,3 +347,28 @@ def resolve_exit_code(rc: int, breach_total: int) -> int:
     if rc == 0 and breach_total > 0:
         return SLO_EXIT_CODE
     return rc
+
+
+# -- scaling-efficiency gate (the `scaling_efficiency` rule's caller) ------------------
+
+
+def evaluate_scaling_slo(efficiency_samples: Sequence[float],
+                         rules=None, journal=None):
+    """Feed an efficiency sequence through the SLO engine and return
+    (engine, breached).  The shipped `scaling_efficiency` floor rule
+    (sustain 0) is the gate; synthetic timestamps one second apart make
+    each sample its own evaluation window."""
+    if rules is None:
+        rules = [r for r in load_rules()
+                 if r.metric == "gauge:allreduce_scaling_efficiency"]
+        if not rules:  # an operator file without the rule keeps the gate
+            rules = [r for r in DEFAULT_RULES
+                     if r.name == "scaling_efficiency"]
+    store = TimeSeriesStore()
+    kw = {"journal": journal} if journal is not None else {}
+    engine = SLOEngine(store, rules=rules, clock=lambda: 0.0, **kw)
+    for i, eff in enumerate(efficiency_samples):
+        t = float(i + 1)
+        store.record("gauge:allreduce_scaling_efficiency", t, eff)
+        engine.evaluate(now=t)
+    return engine, engine.breach_total > 0

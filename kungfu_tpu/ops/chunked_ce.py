@@ -34,8 +34,7 @@ def resolve_ce_block(block: Optional[int], n_tokens: Optional[int] = None,
     """The vocab chunk size the streaming head actually runs with.
 
     An explicit int always wins; None asks, in order: the KFT_CE_BLOCK
-    env knob (the unattended-queue override baseline_matrix used to read
-    itself), then the tuner's footprint default (streams ~64 MiB logit
+    env knob, then the tuner's footprint default (streams ~64 MiB logit
     blocks, clamped to [512, 8192] — kungfu_tpu/tuner/footprint.py).
     Malformed env values fall through rather than wedge a trace.
     """

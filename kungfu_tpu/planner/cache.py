@@ -1,8 +1,6 @@
 """Persistent plan cache — tuning survives restarts.
 
-The pattern scripts/apply_hunt_winner.py established for kernel-tiling
-hunts, promoted to a first-class store: winning plans persist to one JSON
-file keyed by
+Winning plans persist to one JSON file keyed by
 
     (world size, topology digest, tensor-size bucket)
 

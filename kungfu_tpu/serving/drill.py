@@ -16,9 +16,9 @@ serving contract end to end:
      server's conditional PUT, a burst then commits a scale-UP; both are
      read back via the cheap /health document-version endpoint
 
-Returns a metrics dict (bench.py's `--bench serving` section feeds from it:
-steady tokens/sec, TTFT/decode percentiles, failover_requeue_s, rejoin
-rung/latency).  Exit-code semantics live in the chaos CLI wrapper
+Returns a metrics dict (steady tokens/sec, TTFT/decode percentiles,
+failover_requeue_s, rejoin rung/latency: CPU-drill numbers, not measured
+on the chip; the serving cells of benchmark/ are).  Exit-code semantics live in the chaos CLI wrapper
 (`python -m kungfu_tpu.chaos --serve-drill`).
 """
 from __future__ import annotations

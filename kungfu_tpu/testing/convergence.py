@@ -2,10 +2,11 @@
 reference's headline convergence table (README.md:191-197: at 16 workers
 Horovod/S-SGD drop to 59% ImageNet top-1 while SMA and PairAveraging hold
 75%).  One command trains the same synthetic task with every distributed
-optimizer family on the 8-virtual-device CPU mesh and records loss curves
-plus final train/eval accuracy:
+optimizer family on the 8-virtual-device CPU mesh and prints a table of
+final loss and eval accuracy (`--out F` also writes the loss curves as JSON,
+`--markdown F` the table as a file; neither is written unless asked for):
 
-    python -m kungfu_tpu.benchmarks.convergence --out CONVERGENCE.json
+    python -m kungfu_tpu.testing.convergence --steps 60
 
 Configs:
   ssgd              synchronous_sgd          (replicated params)
@@ -23,7 +24,7 @@ Configs:
 
 The task is datasets.synthetic_mnist (deterministic, linearly separable
 with noise): every optimizer must beat chance by a wide margin, and the
-artifact records how fast each family closes the gap.
+table shows how far each family closed the gap.
 """
 from __future__ import annotations
 
@@ -148,7 +149,7 @@ def run_host_gossip(steps: int, batch: int, lr: float, log_every: int = 50,
     env.pop("XLA_FLAGS", None)  # 1 device per worker process
     cmd = [
         sys.executable, "-m", "kungfu_tpu.run", "-np", str(np_workers),
-        sys.executable, "-m", "kungfu_tpu.benchmarks.convergence",
+        sys.executable, "-m", "kungfu_tpu.testing.convergence",
         "--host-gossip-worker",
         "--steps", str(steps), "--batch", str(batch), "--lr", str(lr),
         "--log-every", str(log_every),
@@ -255,13 +256,15 @@ def _sgd_step(loss_fn, tx, params, opt, batch):
 
 
 def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(prog="kungfu_tpu.benchmarks.convergence")
+    ap = argparse.ArgumentParser(prog="kungfu_tpu.testing.convergence")
     ap.add_argument("--steps", type=int, default=400)
     ap.add_argument("--batch", type=int, default=32, help="per-replica batch")
     ap.add_argument("--lr", type=float, default=0.1)
     ap.add_argument("--log-every", type=int, default=50)
-    ap.add_argument("--out", default="CONVERGENCE.json")
-    ap.add_argument("--markdown", default="CONVERGENCE.md")
+    ap.add_argument("--out", default=None,
+                    help="also write results and loss curves here as JSON")
+    ap.add_argument("--markdown", default=None,
+                    help="also write the table here")
     ap.add_argument("--skip-host-gossip", action="store_true")
     ap.add_argument("--host-gossip-worker", action="store_true",
                     help=argparse.SUPPRESS)
@@ -294,27 +297,28 @@ def main(argv=None) -> int:
                 print(f"# {arm} FAILED: {r['error']}", file=sys.stderr)
             results.append(r)
 
-    with open(args.out, "w") as f:
-        json.dump({"task": "synthetic_mnist", "results": results}, f, indent=1)
-    with open(args.markdown, "w") as f:
-        f.write(
-            "# Optimizer convergence — synthetic MNIST, 8-replica mesh\n\n"
-            "Regenerate: `python -m kungfu_tpu.benchmarks.convergence`\n\n"
-            "Reference analog: README.md:191-197 (S-SGD vs SMA vs "
-            "PairAveraging ImageNet convergence).\n\n"
-            "| optimizer | world | steps | final loss | eval accuracy |\n"
-            "|---|---|---|---|---|\n"
+    table = (
+        "# Optimizer convergence — synthetic MNIST, 8-replica mesh\n\n"
+        "Reference analog: the reference's README.md:191-197 (S-SGD vs SMA vs "
+        "PairAveraging ImageNet convergence).\n\n"
+        "| optimizer | world | steps | final loss | eval accuracy |\n"
+        "|---|---|---|---|---|\n"
+    )
+    for r in results:
+        if "error" in r:
+            table += f"| {r['optimizer']} | - | - | FAILED | FAILED |\n"
+            continue
+        table += (
+            f"| {r['optimizer']} | {r['world']} | {r['steps']} "
+            f"| {r['final_loss']} | {r['eval_accuracy']} |\n"
         )
-        for r in results:
-            if "error" in r:
-                f.write(f"| {r['optimizer']} | - | - | FAILED | FAILED |\n")
-                continue
-            f.write(
-                f"| {r['optimizer']} | {r['world']} | {r['steps']} "
-                f"| {r['final_loss']} | {r['eval_accuracy']} |\n"
-            )
-    print(json.dumps({"wrote": [args.out, args.markdown],
-                      "configs": len(results)}))
+    print(table, end="", flush=True)
+    if args.out:
+        with open(args.out, "w") as f:
+            json.dump({"task": "synthetic_mnist", "results": results}, f, indent=1)
+    if args.markdown:
+        with open(args.markdown, "w") as f:
+            f.write(table)
     return 0
 
 

@@ -12,14 +12,9 @@ Modes::
     # second run against the same cache must skip the runoff entirely:
     python -m kungfu_tpu.tuner --smoke --cache PATH --expect-cache-hit
 
-    # the on-chip measurement probes (scripts/mfu_hunt.py's contract:
-    # one `HUNT:` JSON line per record, TPU required):
+    # the on-chip measurement probes (one `HUNT:` JSON line per record,
+    # TPU required):
     python -m kungfu_tpu.tuner --probe peak|flash|all
-
-    # close the loop on an unattended hunt log: winner -> prior cache
-    # (+ optional guarded config-9 re-run, apply_hunt_winner.py's flow):
-    python -m kungfu_tpu.tuner --apply-hunt-log /tmp/tpuq/hunt.log \
-        [--out BENCH_CONFIGS.json] [--rerun] [--cache PATH]
 """
 from __future__ import annotations
 
@@ -31,7 +26,7 @@ import tempfile
 
 
 def _probe(which: str) -> int:
-    """The mfu_hunt probe contract: HUNT: lines, nonzero off-TPU."""
+    """The probe contract: HUNT: lines, nonzero off-TPU."""
     import jax
 
     from . import measure
@@ -49,31 +44,6 @@ def _probe(which: str) -> int:
             flush=True))
         print("HUNT: " + json.dumps(rec), flush=True)
     return 0
-
-
-def _apply_hunt_log(args) -> int:
-    from . import hunt
-    from .cache import PriorCache
-
-    best = hunt.find_best(args.log)
-    if best is None:
-        print("# no flash-hunt summary found; nothing to apply")
-        return 0
-    if best.get("impl") not in ("ours", "ours_xla_bwd"):
-        print(f"# hunt winner is {best.get('impl')}; no tiling to apply")
-        return 0
-    cache = PriorCache(args.cache)
-    n = hunt.ingest_winner(best, cache)
-    print(f"# hunt winner {best.get('block_q')}x{best.get('block_k')} "
-          f"({best.get('impl')}) -> {n} prior-cache keys in {cache.path}")
-    bq, bk = int(best.get("block_q", 0)), int(best.get("block_k", 0))
-    if not args.rerun:
-        return 0
-    if (bq, bk) in ((0, 0), (128, 128)):
-        print(f"# winner uses default tiling ({bq}x{bk}); config 9 already "
-              "measured it")
-        return 0
-    return hunt.rerun_config9(best, args.out)
 
 
 def _smoke(args) -> int:
@@ -251,13 +221,6 @@ def main(argv=None) -> int:
     ap.add_argument("--keep-journal", action="store_true")
     ap.add_argument("--probe", default=None, metavar="peak|flash|all",
                     help="on-chip measurement probes (HUNT: line contract)")
-    ap.add_argument("--apply-hunt-log", dest="log", default=None,
-                    metavar="LOG", help="ingest a hunt log's winner into "
-                    "the prior cache")
-    ap.add_argument("--rerun", action="store_true",
-                    help="with --apply-hunt-log: guarded config-9 re-run")
-    ap.add_argument("--out", default="BENCH_CONFIGS.json",
-                    help="record file for --rerun")
     args = ap.parse_args(argv)
 
     if args.probe:
@@ -266,8 +229,6 @@ def main(argv=None) -> int:
                   "(expected peak|flash|all)", file=sys.stderr)
             return 2
         return _probe(args.probe)
-    if args.log:
-        return _apply_hunt_log(args)
     if args.smoke:
         return _smoke(args)
     ap.print_help()

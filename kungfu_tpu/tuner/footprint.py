@@ -33,9 +33,9 @@ from .space import ShapeKey, StepConfig
 HBM_ENV = "KFT_TUNER_HBM_GIB"
 DEFAULT_HBM_GIB = 16.0
 
-#: peak dense bf16 FLOP/s and HBM B/s per chip by device_kind prefix —
-#: the bench.py table, duplicated here because the library must not
-#: import the repo-root script (longest prefix wins at lookup)
+#: peak dense bf16 FLOP/s and HBM B/s per chip by device_kind prefix
+#: (longest prefix wins at lookup); the benchmark keeps its own table,
+#: benchmark/lib/peaks.json, and the library must not import it
 PEAK_SPECS = {
     "TPU v2": (45e12, 700e9),
     "TPU v3": (123e12, 900e9),

@@ -1,13 +1,13 @@
-"""The tuner's measurement primitives — scripts/mfu_hunt.py moved in-library.
+"""The tuner's measurement primitives.
 
 Three probes, each returning a plain record (callers decide how to print;
-the CLI keeps the `HUNT:` line contract the unattended TPU queue greps):
+the CLI prints one `HUNT:` JSON line per record):
 
   probe_peak     true MXU rate per (m, k, n) via a dependent matmul chain —
                  every iteration's output feeds the next input, so XLA can
                  neither hoist the matmul nor slice through an unused
-                 output (both happened with naive timing loops; RESULTS.md
-                 r4).  The measured peak seeds the footprint model's
+                 output (both happened with naive timing loops).  The
+                 measured peak seeds the footprint model's
                  roofline instead of the spec-sheet number.
   flash_sweep    the Pallas flash fwd+grad at a given attention shape,
                  swept over (block_q, block_k) tiles, head layout (16×64
@@ -18,9 +18,9 @@ the CLI keeps the `HUNT:` line contract the unattended TPU queue greps):
                  StepConfig) — the runoff's ground truth: step_ms, 6ND
                  tokens/sec and MFU where the chip's peak is known.
 
-Every number here is measured in-process by the caller; honesty stamping
-(`measured_this_run`) belongs to the PR-8 bench runner these primitives
-run under (kungfu_tpu/benchmarks/runner.py).
+Every number here is measured in-process by the caller.  No cell of the
+benchmark runs these probes: their records are the tuner's own, not
+measured on this stack until ROADMAP S7 takes them to the chip.
 """
 from __future__ import annotations
 

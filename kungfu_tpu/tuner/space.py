@@ -6,8 +6,7 @@ move; this space describes how the *step itself* computes.  One
 
   flash tiling    (block_q, block_k) of the Pallas flash kernels plus the
                   backward arm ("pallas" two-kernel split vs "xla" blocked
-                  scan) — the knobs scripts/mfu_hunt.py used to sweep
-                  out-of-library;
+                  scan);
   head layout     head_dim factorization of d_model for MHA models
                   (16×64 vs 8×128 at d_model 1024): the parameter count
                   and math are identical, but head_dim 64 half-fills the
@@ -38,7 +37,7 @@ import hashlib
 import json
 from typing import List, Optional, Sequence, Tuple
 
-#: flash tile sweep — the same arms scripts/mfu_hunt.py ran on-chip
+#: flash tile sweep (measure.flash_sweep's arms)
 DEFAULT_BLOCKS: Tuple[Tuple[int, int], ...] = (
     (128, 128), (256, 256), (512, 512), (256, 512), (512, 1024),
 )
@@ -108,8 +107,7 @@ class ShapeKey:
         return self.n_layers * per_layer + 2 * self.vocab_size * self.d_model
 
     def flops_per_token(self) -> int:
-        """Standard 6N + attention-matrix accounting (the GPT bench's
-        formula, baseline_matrix._lm_throughput)."""
+        """Standard 6N + attention-matrix accounting."""
         attn = 12 * self.n_layers * self.seq_len * self.d_model
         if self.causal:
             attn //= 2

@@ -15,9 +15,9 @@ echo "== ruff =="
 # unconditional gate: a missing linter must fail loudly, not silently
 # wave the tree through (CI installs ruff; see .github/workflows/ci.yaml)
 if command -v ruff >/dev/null 2>&1; then
-    ruff check kungfu_tpu tests examples scripts bench.py
+    ruff check kungfu_tpu tests examples scripts
 elif python -c "import ruff" >/dev/null 2>&1; then
-    python -m ruff check kungfu_tpu tests examples scripts bench.py
+    python -m ruff check kungfu_tpu tests examples scripts
 else
     echo "ERROR: ruff is not installed — the lint gate cannot run" >&2
     echo "       (pip install ruff; config lives in pyproject.toml)" >&2

@@ -233,7 +233,7 @@ def run_bench(args) -> int:
     """Weak-scaling arm: fault-free fleets across host counts x strategies
     on the shaped fabric; efficiency vs the single-host baseline; the
     shipped `scaling_efficiency` SLO floor gates the curve."""
-    from kungfu_tpu.benchmarks.scaling import evaluate_scaling_slo
+    from kungfu_tpu.monitor.slo import evaluate_scaling_slo
     from kungfu_tpu.testing.pod import LinkShape, Pod, PodSpec
 
     sizes = sorted({int(s) for s in args.sizes.split(",") if s})

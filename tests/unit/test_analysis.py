@@ -337,3 +337,19 @@ class TestCLI:
     def test_unknown_program_rejected(self):
         with pytest.raises(SystemExit):
             cli.main(["--program", "no-such-program"])
+
+
+# -- env audit: the count of KFT_* names only falls (ROADMAP D5) ------------------------
+
+#: the number PR 27 left.  Lower it when you remove a name; raising it needs
+#: the two callers at the parent commit that need different values.
+KFT_NAMES_CEILING = 76
+
+
+def test_env_audit_is_clean_and_prints_a_count_under_the_ceiling(capsys):
+    from kungfu_tpu.analysis import envaudit
+
+    assert cli.main(["--env"]) == 0
+    names = envaudit.code_env()
+    assert f"{len(names)} KFT_* names read in code" in capsys.readouterr().out
+    assert len(names) <= KFT_NAMES_CEILING, sorted(names)

@@ -69,9 +69,15 @@ class TestAllReduce:
         for x, o in zip(xs, outs):
             np.testing.assert_allclose(np.asarray(o)[0], x.sum(axis=0), rtol=1e-5)
 
-    def test_group_fused_matches_unfused(self, sess):
+    @pytest.mark.parametrize(
+        "strategy",
+        [Strategy.AUTO, Strategy.STAR, Strategy.RING, Strategy.CLIQUE,
+         Strategy.BINARY_TREE_STAR],
+        ids=lambda s: s.name)
+    def test_group_fused_matches_unfused(self, sess, strategy):
         """Fused (one compiled program) == per-tensor dispatch == numpy,
-        across strategies, mixed dtypes/shapes, and a non-sum op."""
+        for each lowering family (auto, psum, ring, rs+ag, hierarchical),
+        over mixed dtypes and shapes."""
         rng = np.random.RandomState(7)
         n = sess.size
         xs_np = [
@@ -80,18 +86,18 @@ class TestAllReduce:
             rng.randint(0, 100, size=(n, 7)).astype(np.int32),
             rng.randn(n).astype(np.float32),
         ]
-        for strat in (None, Strategy.RING, Strategy.CLIQUE):
-            fused = sess.group_all_reduce(xs_np, fuse=True, strategy=strat)
-            unfused = sess.group_all_reduce(xs_np, fuse=False, strategy=strat)
-            for x_np, f, u in zip(xs_np, fused, unfused):
-                want = np.broadcast_to(
-                    x_np.sum(axis=0, keepdims=True), x_np.shape
-                )
-                np.testing.assert_allclose(np.asarray(f), want, rtol=1e-5)
-                np.testing.assert_allclose(
-                    np.asarray(f), np.asarray(u), rtol=1e-6
-                )
-        mx = sess.group_all_reduce(xs_np[:2], op="max", fuse=True)
+        fused = sess.group_all_reduce(xs_np, fuse=True, strategy=strategy)
+        unfused = sess.group_all_reduce(xs_np, fuse=False, strategy=strategy)
+        for x_np, f, u in zip(xs_np, fused, unfused):
+            want = np.broadcast_to(x_np.sum(axis=0, keepdims=True), x_np.shape)
+            np.testing.assert_allclose(np.asarray(f), want, rtol=1e-5)
+            np.testing.assert_allclose(np.asarray(f), np.asarray(u), rtol=1e-6)
+
+    def test_group_fused_non_sum_op(self, sess):
+        rng = np.random.RandomState(7)
+        xs_np = [rng.randn(sess.size, 5).astype(np.float32),
+                 rng.randn(sess.size, 3, 4).astype(np.float64)]
+        mx = sess.group_all_reduce(xs_np, op="max", fuse=True)
         np.testing.assert_allclose(
             np.asarray(mx[0]),
             np.broadcast_to(xs_np[0].max(axis=0, keepdims=True), xs_np[0].shape),

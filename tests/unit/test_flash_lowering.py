@@ -74,7 +74,7 @@ def test_sliding_window_lowers_for_tpu():
 
 def test_large_blocks_head128_lower_for_tpu():
     """Large asymmetric tiling — 256x512 blocks at head_dim 128 (the
-    mfu_hunt sweep's candidate shapes) — lowers to Mosaic fwd+bwd.  The
+    tuner's flash sweep's candidate shapes) — lowers to Mosaic fwd+bwd.  The
     TransformerConfig flash_block plumb-through is guarded one level up
     (test_tpu_lowering.test_transformer_custom_blocks_lower)."""
     q = jnp.zeros((1, 2048, 2, 128), jnp.bfloat16)

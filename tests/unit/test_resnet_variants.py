@@ -1,8 +1,8 @@
 """ResNet roofline-lever variants: space_to_depth stem + per-block remat.
 
-These paths otherwise run only on-chip behind env vars (baseline_matrix
-config 11); this keeps a chip-independent guard on the reshape/transpose
-math and on param-tree parity across the remat flag.
+No cell of the benchmark runs these paths; this keeps a chip-independent
+guard on the reshape/transpose math and on param-tree parity across the
+remat flag.
 """
 import pytest
 

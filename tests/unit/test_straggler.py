@@ -1,6 +1,6 @@
 """Straggler observatory: attribution math, skew detector, anomaly
-watchdog, hotspot classification, fleet /stragglers endpoint, graded
-policies, and the measurement-resilient bench runner.
+watchdog, hotspot classification, fleet /stragglers endpoint, and graded
+policies.
 
 Synthetic span streams drive the detector contracts from the issue: a
 clean fleet produces ZERO flags, one slow rank is flagged with the correct
@@ -649,162 +649,6 @@ class TestReplanStragglerTrigger:
         pol = ReplanPolicy(fp, straggler_fn=sp.any_flagged, cooldown_steps=0)
         pol.after_step({})
         assert fp.calls == ["straggler"]
-
-
-# -- healer graded judgment (unit level; e2e in the chaos drill) -----------------------
-
-
-class TestBenchRunner:
-    def _probe(self, verdicts):
-        it = iter(verdicts)
-
-        def probe(timeout_s, env=None):
-            return next(it)
-
-        return probe
-
-    def test_section_measured_when_probe_passes(self):
-        from kungfu_tpu.benchmarks.runner import Section, run_section
-
-        rec = run_section(
-            Section(name="ok", fn=lambda: {"value": 42}),
-            probe=self._probe([None]), sleep=lambda s: None,
-        )
-        assert rec == {"value": 42, "measured_this_run": True}
-
-    def test_probe_failure_requeues_then_succeeds(self, tmp_path, monkeypatch):
-        from kungfu_tpu.benchmarks.runner import Section, run_section
-        from kungfu_tpu.monitor import journal as J
-
-        jpath = str(tmp_path / "j.jsonl")
-        monkeypatch.setenv(J.JOURNAL_FILE_ENV, jpath)
-        J._reset_for_tests()
-        try:
-            # two verdicts per failed attempt: the initial probe AND its
-            # fresh-env second chance must both fail before a requeue
-            rec = run_section(
-                Section(name="flaky", fn=lambda: {"value": 7}),
-                probe=self._probe(["chip wedged", "still wedged", None]),
-                retries=2, sleep=lambda s: None,
-            )
-            assert rec["measured_this_run"] is True and rec["value"] == 7
-            events = [e["event"] for e in J.read_journal(jpath)]
-            assert "bench_probe_failed" in events
-            assert "bench_requeued" in events
-        finally:
-            J._reset_for_tests()
-
-    def test_exhausted_budget_stamps_false(self, tmp_path, monkeypatch):
-        from kungfu_tpu.benchmarks.runner import Section, run_section
-        from kungfu_tpu.monitor import journal as J
-
-        jpath = str(tmp_path / "j.jsonl")
-        monkeypatch.setenv(J.JOURNAL_FILE_ENV, jpath)
-        J._reset_for_tests()
-        try:
-            rec = run_section(
-                Section(name="dead", fn=lambda: {"v": 1}),
-                # 2 probe calls (initial + fresh-env retry) x 3 attempts
-                probe=self._probe(["down"] * 6),
-                retries=2, sleep=lambda s: None,
-            )
-            assert rec["measured_this_run"] is False
-            assert "down" in rec["error"]
-            events = [e["event"] for e in J.read_journal(jpath)]
-            assert events.count("bench_probe_failed") == 3
-            assert "bench_section_failed" in events
-        finally:
-            J._reset_for_tests()
-
-    def test_failed_section_goes_to_back_of_queue(self):
-        from kungfu_tpu.benchmarks.runner import Section, run_sections
-
-        order = []
-        state = {"a_fails": 1}
-
-        def make(name):
-            def fn():
-                order.append(name)
-                if name == "a" and state["a_fails"] > 0:
-                    state["a_fails"] -= 1
-                    return None
-                return {"name": name}
-            return fn
-
-        out = run_sections(
-            [Section(name="a", fn=make("a")), Section(name="b", fn=make("b"))],
-            probe=lambda t, env=None: None, retries=2, sleep=lambda s: None,
-        )
-        assert order == ["a", "b", "a"]  # b took its turn before a's retry
-        assert out["a"]["measured_this_run"] and out["b"]["measured_this_run"]
-
-    def test_probe_timeout_env_resolution(self, monkeypatch):
-        from kungfu_tpu.benchmarks import runner as R
-
-        monkeypatch.delenv(R.PROBE_TIMEOUT_ENV, raising=False)
-        assert R.probe_timeout_s() == R.DEFAULT_PROBE_TIMEOUT_S
-        monkeypatch.setenv(R.PROBE_TIMEOUT_ENV, "12.5")
-        assert R.probe_timeout_s() == 12.5
-        monkeypatch.setenv(R.PROBE_TIMEOUT_ENV, "0.001")
-        assert R.probe_timeout_s() == 1.0  # floor: a 1ms deadline is a typo
-        monkeypatch.setenv(R.PROBE_TIMEOUT_ENV, "ninety")
-        assert R.probe_timeout_s() == R.DEFAULT_PROBE_TIMEOUT_S
-
-    def test_probe_timeout_kills_wedged_child_with_cause(self, monkeypatch):
-        """A wedged probe must come back as cause=timeout (not crash), with
-        the whole process group SIGKILLed before the deadline's grace runs
-        out — the BENCH r03-r05 wedge, now diagnosable from the json."""
-        from kungfu_tpu.benchmarks import runner as R
-
-        monkeypatch.setattr(R, "PROBE_SRC", "import time; time.sleep(600)")
-        t0 = time.monotonic()
-        diag = R.probe_backend_ex(timeout_s=1.0)
-        assert time.monotonic() - t0 < 15.0  # killed, not waited out
-        assert diag is not None
-        assert diag["cause"] == "timeout" and diag["exit"] == "timeout"
-        assert "timed out after 1s" in diag["reason"]
-
-    def test_probe_crash_cause_distinct_from_timeout(self, monkeypatch):
-        from kungfu_tpu.benchmarks import runner as R
-
-        monkeypatch.setattr(
-            R, "PROBE_SRC",
-            "import sys; print('boom', file=sys.stderr); sys.exit(3)")
-        diag = R.probe_backend_ex(timeout_s=30.0)
-        assert diag["cause"] == "crash" and diag["exit"] == 3
-        assert "boom" in diag["stderr"]
-
-    def test_argv_section_reads_out_json(self, tmp_path):
-        import sys
-
-        from kungfu_tpu.benchmarks.runner import Section, run_section
-
-        out = tmp_path / "rec.json"
-        rec = run_section(
-            Section(
-                name="subproc",
-                argv=[sys.executable, "-c",
-                      f"import json; json.dump({{'x': 1}}, "
-                      f"open({str(out)!r}, 'w'))"],
-                out_json=str(out), timeout_s=30.0,
-            ),
-            probe=lambda t, env=None: None, sleep=lambda s: None,
-        )
-        assert rec == {"x": 1, "measured_this_run": True}
-
-    def test_argv_section_parses_stdout_json(self):
-        import sys
-
-        from kungfu_tpu.benchmarks.runner import Section, run_section
-
-        rec = run_section(
-            Section(name="stdout",
-                    argv=[sys.executable, "-c",
-                          "print('noise'); print('{\"y\": 2}')"],
-                    timeout_s=30.0),
-            probe=lambda t, env=None: None, sleep=lambda s: None,
-        )
-        assert rec == {"y": 2, "measured_this_run": True}
 
 
 # -- e2e drill (slow tier; scripts/check.sh runs it too) -------------------------------

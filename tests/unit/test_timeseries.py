@@ -4,9 +4,8 @@
 Covers: sampler bounds/downsampling/retention, counter-rate and windowed
 histogram-percentile sampling (incl. the reset_for_reinit epoch re-anchor
 the heal path exercises), the fleet `/history` and `/slo` endpoints, SLO
-arm/clear hysteresis + exit-code mode, journal size-capped rotation, the
-probed-runner fresh-env retry, and the scaling-efficiency math on
-synthetic throughput curves.
+arm/clear hysteresis + exit-code mode, journal size-capped rotation, and
+the scaling-efficiency math on synthetic throughput curves.
 """
 import json
 import urllib.request
@@ -513,122 +512,12 @@ class TestJournalRotation:
         assert segment_paths(p) == [p]
 
 
-# -- probed-runner fresh-env retry -----------------------------------------------------
-
-
-class TestProbeRetry:
-    def test_fresh_env_retry_recovers(self, tmp_path, monkeypatch):
-        from kungfu_tpu.benchmarks.runner import Section, run_section
-        from kungfu_tpu.monitor import journal as J
-
-        jpath = str(tmp_path / "j.jsonl")
-        monkeypatch.setenv(J.JOURNAL_FILE_ENV, jpath)
-        J._reset_for_tests()
-        envs = []
-
-        def probe(timeout_s, env=None):
-            envs.append(dict(env or {}))
-            # first call (inherited env) fails; the scrubbed retry passes
-            return None if len(envs) > 1 else {
-                "reason": "probe exited 1", "exit": 1,
-                "stderr": "libtpu: device wedged"}
-
-        try:
-            rec = run_section(
-                Section(name="s", fn=lambda: {"v": 1},
-                        env={"XLA_FLAGS": "--stale-flag"}),
-                probe=probe, sleep=lambda s: None,
-            )
-            assert rec["measured_this_run"] is True
-            # the retry env scrubbed the poisoned override
-            assert envs[1].get("XLA_FLAGS") == ""
-            events = J.read_journal(jpath)
-            kinds = [e["event"] for e in events]
-            assert "bench_probe_recovered" in kinds
-            assert "bench_probe_failed" not in kinds
-        finally:
-            J._reset_for_tests()
-
-    def test_probe_failure_journals_stderr_and_exit(self, tmp_path, monkeypatch):
-        from kungfu_tpu.benchmarks.runner import Section, run_section
-        from kungfu_tpu.monitor import journal as J
-
-        jpath = str(tmp_path / "j.jsonl")
-        monkeypatch.setenv(J.JOURNAL_FILE_ENV, jpath)
-        J._reset_for_tests()
-        diag = {"reason": "probe exited 3", "exit": 3,
-                "stderr": "RESOURCE_EXHAUSTED: tpu busy"}
-        try:
-            rec = run_section(
-                Section(name="s", fn=lambda: {"v": 1}),
-                probe=lambda t, env=None: dict(diag),
-                retries=0, sleep=lambda s: None,
-            )
-            assert rec["measured_this_run"] is False
-            ev = [e for e in J.read_journal(jpath)
-                  if e["event"] == "bench_probe_failed"][0]
-            assert ev["exit"] == 3
-            assert "RESOURCE_EXHAUSTED" in ev["stderr"]
-            assert ev["retried"] is True
-            assert "probe exited 3" in ev["retry_error"]
-        finally:
-            J._reset_for_tests()
-
-    def test_probe_backend_ex_captures_real_stderr(self, monkeypatch):
-        from kungfu_tpu.benchmarks import runner
-
-        # make the probe child die loudly without touching jax
-        monkeypatch.setattr(
-            runner, "PROBE_SRC",
-            "import sys; sys.stderr.write('chip wedged hard'); sys.exit(7)")
-        diag = runner.probe_backend_ex(timeout_s=30.0)
-        assert diag is not None
-        assert diag["exit"] == 7
-        assert "chip wedged hard" in diag["stderr"]
-        assert runner.probe_backend(timeout_s=30.0) == "probe exited 7"
-
-
 # -- scaling-efficiency math -----------------------------------------------------------
 
 
 class TestScalingMath:
-    def test_efficiency_curve_on_synthetic_rows(self):
-        from kungfu_tpu.benchmarks.scaling import efficiency_curve
-
-        rows = [
-            {"np": 1, "busbw_gibps": 10.0},
-            {"np": 2, "busbw_gibps": 8.0},
-            {"np": 4, "busbw_gibps": 4.0},
-        ]
-        out = efficiency_curve(rows)
-        assert "scaling_efficiency" not in out[0]  # n=1 never baselines
-        assert out[1]["scaling_efficiency"] == pytest.approx(1.0)
-        assert out[2]["scaling_efficiency"] == pytest.approx(0.5)
-
-    def test_flat_curve_is_perfect(self):
-        from kungfu_tpu.benchmarks.scaling import efficiency_curve
-
-        rows = [{"np": n, "busbw_gibps": 6.0} for n in (2, 4, 8)]
-        out = efficiency_curve(rows)
-        assert all(r["scaling_efficiency"] == pytest.approx(1.0) for r in out)
-
-    def test_step_attribution_decomposition(self):
-        from kungfu_tpu.benchmarks.scaling import step_attribution
-
-        att = step_attribution(step_ms=10.0, compute_ms=6.0, data_ms=1.0)
-        assert att["compute_frac"] == pytest.approx(0.6)
-        assert att["data_frac"] == pytest.approx(0.1)
-        assert att["collective_wait_frac"] == pytest.approx(0.3)
-        assert att["efficiency"] == pytest.approx(0.6)
-        # fractions always partition the step
-        assert att["compute_frac"] + att["data_frac"] + \
-            att["collective_wait_frac"] == pytest.approx(1.0)
-        # compute clamped to the step: never a negative wait
-        att = step_attribution(step_ms=5.0, compute_ms=9.0)
-        assert att["collective_wait_frac"] == 0.0
-
     def test_slo_gate_on_synthetic_curves(self):
-        from kungfu_tpu.benchmarks.scaling import evaluate_scaling_slo
+        from kungfu_tpu.monitor.slo import evaluate_scaling_slo
 
         engine, breached = evaluate_scaling_slo([0.95, 0.9, 0.85])
         assert not breached
@@ -638,19 +527,3 @@ class TestScalingMath:
         assert breached and engine.breach_total == 1
         assert journal[0][0] == "slo_breach"
         assert journal[0][1]["rule"] == "scaling_efficiency"
-
-    @pytest.mark.slow
-    def test_bench_scaling_end_to_end_with_chaos(self):
-        """The acceptance contract: an induced (chaos-slowed) collective
-        regression must collapse the curve and trip the floor."""
-        from kungfu_tpu.benchmarks.scaling import bench_scaling
-
-        # chaos lands on the LARGEST size only, so the 2-rank baseline
-        # stays clean and the 4-rank point collapses against it
-        rec = bench_scaling(
-            sizes=(1, 2, 4), algorithms=("ring",), buckets={"small": 1 << 12},
-            steps=2, warmup=1, chaos_collective_ms=80.0, slo=True,
-        )
-        assert rec["slo_breached"] is True
-        assert rec["allreduce_scaling_efficiency"] < 0.4
-        assert rec["loss_attribution"]["collective_wait_frac"] > 0.5

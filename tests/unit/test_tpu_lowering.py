@@ -98,7 +98,7 @@ def test_transformer_train_step_lowers():
 
 
 def test_resnet_train_step_lowers():
-    """The bench.py ResNet-50 S-SGD step (bf16 BN, batch_stats threaded)."""
+    """The ResNet-50 S-SGD step (bf16 BN, batch_stats threaded)."""
     from kungfu_tpu.models.resnet import ResNet50
     from kungfu_tpu.models.slp import softmax_cross_entropy
 
