@@ -1,0 +1,42 @@
+"""The decode-step attention's share of its roofline, in percent.
+
+Numerator: the least time the chip could take to read what the attention
+of the traced decode steps needs: the rows its BUSY slots had written,
+summed over the steps of the capture (the program's counter
+`kft_serve_decode_attn_rows_total`: `{kind="written"}` less
+`{kind="written_free"}`, the rows under free slots' ride-along cursors,
+which are nobody's), times the bytes of one row's K and V in every layer
+(benchmark/lib/decode_attn_costs.py), over the bandwidth peak.  The kernel
+is memory-bound: 16 query rows a slot against its rows.  Rows a request
+wrote, not rows fetched, are what the algorithm needs: a block fetched for
+the 40 rows it holds, and every row fetched for a free slot, are the
+kernel's cost, not its work.
+
+Denominator: the device time of the `kft_decode_attn` events that start
+inside a `jit__decode` program of the capture.  The counter is read after
+the trace starts and before it stops, so the rows cover at most the steps
+the kernel time covers: the share errs low and cannot pass 100%.
+"""
+import os
+
+from benchmark.lib import xplane as X
+from benchmark.lib.decode_attn_costs import (
+    bytes_per_row, kernel_events_in_program, rows_delta)
+from benchmark.lib.moe_costs import run_dir
+
+
+def read(ctx):
+    path = os.path.join(run_dir(ctx), "events.json.gz")
+    rows = rows_delta(ctx)
+    if not rows or ctx["peaks"] is None or not os.path.exists(path):
+        return None
+    trace = X.read_trace(path)
+    if not trace.get("devices"):
+        return None
+    count, seconds = kernel_events_in_program(trace)
+    if not count or not seconds:
+        return None
+    needed = rows["written"] - rows["written_free"]
+    least = needed * bytes_per_row(ctx["config"]) \
+        / ctx["peaks"]["hbm_bytes_per_s"]
+    return 100.0 * least / seconds

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from ..ops.chunked_ce import chunked_lm_head_ll
+from ..ops.decode_attn import decode_attention, decode_attention_reference
 from ..parallel.sharding import logical_constraint
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -266,7 +267,7 @@ class Attention(nn.Module):
 
         if cfg.decode:
             # KV-cache decode: write this call's k/v at the cache cursor,
-            # attend q against the whole cache, advance the cursor
+            # attend q against the rows written so far, advance the cursor
             quant = cfg.kv_cache_dtype == "int8"
             cdtype = jnp.int8 if quant else cfg.dtype
             cache_k = self.variable(
@@ -332,12 +333,10 @@ class Attention(nn.Module):
                 )(cache_var.value, x, idx0)
 
             def load(cache_var, scale_var):
-                """Full cache in the model dtype.  int8: the dequant (exact
+                """The int8 cache in the model dtype: the dequant (exact
                 for magnitudes <= 127 in bf16) fuses into the attention
                 einsum's operand read, so the cache crosses HBM as int8
                 bytes."""
-                if not quant:
-                    return cache_var.value
                 return cache_var.value.astype(cfg.dtype) * (
                     scale_var.value.astype(cfg.dtype)[..., None]
                 )
@@ -351,31 +350,26 @@ class Attention(nn.Module):
                 cache_ovf.value = jnp.logical_or(
                     cache_ovf.value, idx0 + L > cfg.max_len
                 )
-            kf = load(cache_k, kscale)
-            vf = load(cache_v, vscale)
-            scale = 1.0 / (D ** 0.5)
-            # grouped-query einsum against the UN-repeated cache: decode is
-            # cache-read-bound, so neither a jnp.repeat materialization
-            # (x H/Hkv bytes under GQA) nor an f32 cast (x2 bytes) of the
-            # cache is acceptable — group the query heads instead and keep
-            # operands in the cache dtype with f32 accumulation
-            G = H // Hkv
-            qg = q.reshape(B, L, Hkv, G, D)
-            s = jnp.einsum(
-                "blkgd,bmkd->bkglm", qg, kf,
-                preferred_element_type=jnp.float32,
-            ) * scale
-            q_pos = pos[:, :, None]                        # [B, L, 1]
-            c_pos = jnp.arange(cfg.max_len)[None, None, :]  # [1, 1, max_len]
-            valid = c_pos <= q_pos                          # [B, L, max_len]
-            if cfg.window:  # sliding-window models decode windowed too
-                valid = jnp.logical_and(valid, q_pos - c_pos < cfg.window)
-            s = jnp.where(valid[:, None, None], s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum(
-                "bkglm,bmkd->blkgd", p.astype(vf.dtype), vf,
-                preferred_element_type=jnp.float32,
-            ).reshape(B, L, H, D)
+            # attention over the stored leaves, each query row against the
+            # rows of its slot up to its own position (ops/decode_attn.py):
+            # on TPU a decode or verify step reads only the blocks a slot
+            # has written; a prefill bucket and every backend without the
+            # kernels take the dense einsum over max_len.  So does
+            # attention="full", the plain einsum here as in training: what
+            # a cache sharded over a mesh needs (GSPMD splits an einsum,
+            # not a Mosaic call), and what ServingEngine and generate()
+            # ask for there.  An int8 cache is read through its scales, by
+            # the einsum, into whose operand read the dequantisation fuses
+            if quant:
+                o = decode_attention_reference(
+                    q, load(cache_k, kscale), load(cache_v, vscale), pos,
+                    cfg.window)
+            elif cfg.attention == "full" or cfg.mesh is not None:
+                o = decode_attention_reference(
+                    q, cache_k.value, cache_v.value, pos, cfg.window)
+            else:
+                o = decode_attention(q, cache_k.value, cache_v.value, pos,
+                                     cfg.window)
             # a cursor past max_len clamps that row's cache write and
             # clobbers its older slots — poison the ROW with NaN so overflow
             # is LOUD instead of silently-wrong logits (generate() bounds
@@ -796,11 +790,14 @@ def generate(
         f"{prompt_len}+{max_new_tokens} exceeds max_len={cfg.max_len}"
     )
     # decode overrides: full attention on the cache, no shard_map region
-    # (under `mesh`, sharding is GSPMD-propagated instead), and a dense
-    # head (a head="hidden"-trained config shares the same param tree, so
-    # its params decode unchanged)
+    # (under `mesh`, sharding is GSPMD-propagated instead, so the cache
+    # read stays the plain einsum, "full"; on one device it may be the
+    # length-aware kernel, "auto"), and a dense head (a head="hidden"-
+    # trained config shares the same param tree, so its params decode
+    # unchanged)
     dcfg = dataclasses.replace(
-        cfg, decode=True, attention="full", mesh=None, head="dense"
+        cfg, decode=True, attention="full" if mesh is not None else "auto",
+        mesh=None, head="dense"
     )
     if rng is None:
         rng = jax.random.PRNGKey(0)

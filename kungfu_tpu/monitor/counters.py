@@ -154,6 +154,10 @@ METRIC_HELP: Dict[str, str] = {
         "Expert-layer calls of the slot-cache programs (steps x expert layers).",
     "kft_serve_param_bytes":
         "Bytes of parameters the serving engine holds on the device, by dtype.",
+    "kft_serve_decode_attn_rows_total":
+        "Cache rows of the decode-step attention a layer, summed over steps: "
+        "spanned (cache), written and fetched, and the part of each that "
+        "is free slots' (written_free, fetched_free).",
     "kungfu_fleet_ranks_scraped": "1 if the rank answered the fleet scrape.",
     "kungfu_fleet_scrape_errors_total": "Failed fleet scrape fan-out fetches.",
 }

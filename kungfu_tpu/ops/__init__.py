@@ -35,6 +35,10 @@ from .fused_matmul import (
     ring_shift,
 )
 
+# Decode-step attention over the slot cache (ops/decode_attn.py): the
+# length-aware kernel on TPU, the dense einsum everywhere else.
+from .decode_attn import decode_attention, decode_attention_reference
+
 __all__ = [
     "all_reduce", "psum_all_reduce", "rs_ag_all_reduce", "ring_all_reduce",
     "hierarchical_all_reduce", "broadcast", "all_gather", "reduce_scatter",
@@ -43,4 +47,5 @@ __all__ = [
     "pallas_ring_reduce_scatter", "pallas_ring_all_gather",
     "all_gather_matmul", "matmul_reduce_scatter",
     "dma_all_gather", "dma_reduce_scatter", "ring_shift",
+    "decode_attention", "decode_attention_reference",
 ]

@@ -1,0 +1,250 @@
+"""Decode-step attention over the slot cache, by the rows a slot has written.
+
+`decode_attention(q, cache_k, cache_v, q_pos, window)`: q [B, L, H, D] holds
+the few query rows each of B slots brings to a step (a decode step's one, a
+speculative verify's k), cache_k / cache_v [B, max_len, Hkv, D] the slot
+cache with this step's rows already stored, q_pos [B, L] each query row's
+position.  Query row (b, l) attends the cache rows m <= q_pos[b, l] of slot
+b (and q_pos - m < window when the model has one).  The result is float32
+[B, L, H, D].  Grouped queries read the un-repeated cache: G = H // Hkv
+query heads against one KV head.
+
+`decode_attention_reference` is the definition: the dense einsum over all
+max_len rows under a mask, K and V operands in the cache dtype, scores,
+softmax and accumulation in float32, probabilities cast to the cache dtype
+before the product over V.  It reads the whole cache whatever the cursors
+say.  It is what every shape the kernel does not take runs (a prefill
+bucket, an int8 cache after its dequantisation), what the model keeps for
+a cache sharded over a mesh (a Mosaic call is not partitioned by GSPMD)
+and what runs off TPU (`compat.pallas_mode() == "off"`).
+
+On TPU the same contract is a Mosaic kernel the trace names
+`kft_decode_attn`; under KFT_PALLAS=interpret its body runs in the Pallas
+interpreter.  The grid walks (slot, KV block).  Each slot's first and last
+live block are scalar-prefetched; the block index of a grid step past the
+last live block repeats that block, so no new DMA is issued for it, and
+its body is skipped: a slot at cursor 300 of 2,048 reads two blocks of
+256 rows, not eight.  Blocks are combined by online softmax, in the
+einsum's arithmetic.  A K block [block, Hkv, D] is read as the matrix
+[block x Hkv, D] it already is in memory: one matmul gives every query
+head's score against every (row, KV head) pair, the pairs of another KV
+head are masked like the rows beyond the cursor, and the probabilities,
+zero there, multiply the V block the same way.  That spends Hkv times the
+multiplications the scores need, on a step whose matmul unit is idle
+(16 query rows), and never moves a cache block out of the layout it is
+stored in.
+"""
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .. import compat
+
+#: the kernel's name in a device trace (benchmark/layer_metrics/decode_attn_*)
+KERNEL_NAME = "kft_decode_attn"
+
+#: query rows a slot the kernel takes: a decode step's 1, a verify's k.
+#: The smallest prefill bucket is 16 (serving/engine.py `default_buckets`)
+MAX_QUERY_ROWS = 8
+#: bytes of one K (or V) block: 256 rows of 16 x 128 bf16.  Two operands,
+#: double-buffered, are 4 MiB of VMEM; a DMA of 1 MiB is long enough for
+#: the bandwidth-bound read, and 256 rows bound what rounding a cursor up
+#: to the block fetches for nothing
+_BLOCK_BYTES = 1 << 20
+_MASKED = -1e30
+
+
+def decode_attention_reference(q, k, v, q_pos, window: int = 0):
+    """The dense form: every query row against all max_len rows of its
+    slot, masked.  k, v [B, max_len, Hkv, D] in any float dtype (an int8
+    cache arrives dequantised; the product fuses into the operand read)."""
+    B, L, H, D = q.shape
+    max_len, Hkv = k.shape[1], k.shape[2]
+    # grouped-query einsum against the UN-repeated cache: decode is
+    # cache-read-bound, so neither a jnp.repeat materialization
+    # (x H/Hkv bytes under GQA) nor an f32 cast (x2 bytes) of the
+    # cache is acceptable — group the query heads instead and keep
+    # operands in the cache dtype with f32 accumulation
+    qg = q.reshape(B, L, Hkv, H // Hkv, D)
+    s = jnp.einsum(
+        "blkgd,bmkd->bkglm", qg, k,
+        preferred_element_type=jnp.float32,
+    ) * (1.0 / (D ** 0.5))
+    q_pos = q_pos[:, :, None]                       # [B, L, 1]
+    c_pos = jnp.arange(max_len)[None, None, :]      # [1, 1, max_len]
+    valid = c_pos <= q_pos                          # [B, L, max_len]
+    if window:  # sliding-window models decode windowed too
+        valid = jnp.logical_and(valid, q_pos - c_pos < window)
+    s = jnp.where(valid[:, None, None], s, _MASKED)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum(
+        "bkglm,bmkd->blkgd", p.astype(v.dtype), v,
+        preferred_element_type=jnp.float32,
+    ).reshape(B, L, H, D)
+
+
+def kernel_block(query_rows: int, cache_shape, cache_dtype,
+                 interpret=None) -> Optional[int]:
+    """Rows of one KV block when the kernel takes this call here, None when
+    the reference does.  From what a caller can see of the call: how many
+    query rows a slot brings, the cache leaf's shape and dtype, and the
+    Pallas gate.  The engine asks the same question to count the rows a
+    step fetches.  (A cache sharded over a mesh is the caller's to keep
+    away: models/transformer.py, attention="full".)"""
+    if compat.pallas_mode(interpret) == "off":
+        return None
+    dtype = jnp.dtype(cache_dtype)
+    if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
+        return None  # an int8 cache is read through its scales
+    _, max_len, kv_heads, head_dim = cache_shape
+    # a block is read as [block x Hkv, D] with no relayout only when the
+    # KV heads fill whole sublane tiles (8 rows of 32 bits) and D whole lanes
+    if (query_rows > MAX_QUERY_ROWS or head_dim % 128
+            or kv_heads % (32 // dtype.itemsize)):
+        return None
+    block = 1 << ((_BLOCK_BYTES // (kv_heads * head_dim * dtype.itemsize))
+                  .bit_length() - 1)
+    while block > 8 and (block > max_len or max_len % block):
+        block //= 2
+    return block if max_len % block == 0 else None
+
+
+def live_blocks(xp, q_lo, q_hi, block: int, max_len: int, window: int = 0):
+    """(first, last) KV block a slot's query rows at positions q_lo..q_hi
+    can see: rows 0..q_hi, less the rows every query's window has left.
+    `xp` is numpy on the host (the engine's count of rows fetched) and
+    jax.numpy in the program, so the two cannot drift apart."""
+    last = xp.minimum(q_hi, max_len - 1) // block
+    if window:
+        first = xp.maximum(q_lo - window + 1, 0) // block
+    else:
+        first = xp.zeros_like(last)
+    return xp.minimum(first, last), last
+
+
+def _attn_pallas(q, cache_k, cache_v, q_pos, window: int, block: int,
+                 interpret: bool):
+    B, L, H, D = q.shape
+    max_len, Hkv = cache_k.shape[1], cache_k.shape[2]
+    assert H % Hkv == 0 and max_len % block == 0, (H, Hkv, max_len, block)
+    G, R, lanes = H // Hkv, L * H, block * Hkv
+    scale = 1.0 / (D ** 0.5)
+    q_pos = q_pos.astype(jnp.int32)
+    first, last = live_blocks(jnp, q_pos.min(axis=1), q_pos.max(axis=1),
+                              block, max_len, window)
+
+    def kernel(first, last, pos, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+               acc_ref):
+        b, j = pl.program_id(0), pl.program_id(1)
+
+        @pl.when(j == 0)
+        def _():
+            m_ref[...] = jnp.full_like(m_ref, _MASKED)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        blk = first[b] + j
+        lo = hi = pos[b, 0]  # the slot's lowest and highest query position
+        for l in range(1, L):
+            lo, hi = jnp.minimum(lo, pos[b, l]), jnp.maximum(hi, pos[b, l])
+        live = blk <= last[b]
+        # an inner block holds only rows every query of the slot attends: no
+        # row of it is beyond a cursor or out of a window
+        inner = (blk + 1) * block - 1 <= lo
+        if window:
+            inner = jnp.logical_and(inner, blk * block > hi - window)
+
+        def attend(edge: bool):
+            # row r of the scores is query (l, h) = (r // H, r % H); lane c
+            # is cache (row, KV head) = (blk * block + c // Hkv, c % Hkv)
+            row = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (R, lanes), 1)
+            valid = (jax.lax.rem(lane, Hkv)
+                     == jax.lax.div(jax.lax.rem(row, H), G))
+            v = v_ref[...].reshape(lanes, D)
+            if edge:
+                at = jnp.full((R, 1), pos[b, 0], jnp.int32)
+                for l in range(1, L):
+                    at = jnp.where(row >= l * H, pos[b, l], at)
+                at = at - blk * block  # query position, block-relative
+                valid = jnp.logical_and(valid, lane < (at + 1) * Hkv)
+                if window:
+                    valid = jnp.logical_and(
+                        valid, lane >= (at - window + 1) * Hkv)
+                # rows no query of the slot attends meet a probability of 0,
+                # and 0 x NaN is NaN: whatever lies beyond the cursor (the
+                # last request's rows, a prefill's padding) reads as 0
+                c = jax.lax.broadcasted_iota(jnp.int32, (lanes, 1), 0)
+                v = jnp.where(c < (hi - blk * block + 1) * Hkv, v,
+                              jnp.zeros_like(v))
+            s = jax.lax.dot_general(
+                q_ref[...], k_ref[...].reshape(lanes, D),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid, s, _MASKED)
+            m_prev = m_ref[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_ref[...] = m_new
+
+        pl.when(jnp.logical_and(live, inner))(lambda: attend(False))
+        pl.when(jnp.logical_and(live, jnp.logical_not(inner)))(
+            lambda: attend(True))
+
+        @pl.when(j == pl.num_programs(1) - 1)
+        def _():
+            norm = l_ref[...]
+            o_ref[...] = acc_ref[...] / jnp.where(norm == 0.0, 1.0, norm)
+
+    def kv_index(b, j, first, last, pos):
+        return b, jnp.minimum(first[b] + j, last[b]), 0, 0
+
+    def row_index(b, j, first, last, pos):
+        return b, 0, 0
+
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((B, R, D), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, max_len // block),
+            in_specs=[
+                pl.BlockSpec((None, R, D), row_index),
+                pl.BlockSpec((None, block, Hkv, D), kv_index),
+                pl.BlockSpec((None, block, Hkv, D), kv_index),
+            ],
+            out_specs=pl.BlockSpec((None, R, D), row_index),
+            scratch_shapes=[pltpu.VMEM((R, 1), jnp.float32),
+                            pltpu.VMEM((R, 1), jnp.float32),
+                            pltpu.VMEM((R, D), jnp.float32)],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=compat.vmem_budget_bytes()),
+        interpret=interpret,
+        name=KERNEL_NAME,
+    )(first, last, q_pos, q.astype(cache_k.dtype).reshape(B, R, D),
+      cache_k, cache_v)
+    return out.reshape(B, L, H, D)
+
+
+def decode_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
+                     q_pos: jax.Array, window: int = 0,
+                     interpret=None) -> jax.Array:
+    """q [B, L, H, D] against the slot cache [B, max_len, Hkv, D] -> float32
+    [B, L, H, D]: the kernel where `kernel_block` says it takes the call,
+    the reference einsum elsewhere."""
+    block = kernel_block(q.shape[1], cache_k.shape, cache_k.dtype, interpret)
+    if block is None:
+        return decode_attention_reference(q, cache_k, cache_v, q_pos, window)
+    return _attn_pallas(q, cache_k, cache_v, q_pos, window, block,
+                        compat.pallas_mode(interpret) == "interpret")

@@ -19,6 +19,8 @@ fixed-shape slot batch:
     step updates the slot cache it is given (donated) and the host reads
     [slots] greedy token ids; the [slots, vocab] logits leave the device
     only in a step where a request samples (`decode_logit_fetches`)
+    On TPU the step's attention reads each slot's cache up to its cursor
+    (ops/decode_attn.py); `decode_attn_rows` says how many rows that was
   * completion: a slot frees on max_new_tokens or eos; its row is reused by
     the next admission (slots.reset_slot keeps the free row's ride-along
     cursor at 0)
@@ -81,6 +83,7 @@ from ..models.transformer import (
     resident_params,
 )
 from ..monitor.journal import journal_event
+from ..ops.decode_attn import kernel_block, live_blocks
 from ..utils import get_logger
 from ..utils.trace import TraceContext, child_span, trace_context, trace_scope
 from .queue import AdmissionQueue
@@ -143,9 +146,12 @@ class ServingEngine:
     ):
         assert cfg.rope, "serving decode requires a rope config (cache cursors)"
         # decode overrides mirror generate(): full attention on the cache, a
-        # dense head, GSPMD (not shard_map) sharding under `mesh`
+        # dense head, GSPMD (not shard_map) sharding under `mesh`: there the
+        # cache read stays the plain einsum GSPMD can split ("full"); on
+        # one device it may be the length-aware kernel ("auto")
         self.dcfg = dataclasses.replace(
-            cfg, decode=True, attention="full", mesh=None, head="dense"
+            cfg, decode=True, attention="full" if mesh is not None else "auto",
+            mesh=None, head="dense"
         )
         self.model = TransformerLM(self.dcfg)
         self.n_slots = slots
@@ -158,6 +164,10 @@ class ServingEngine:
         self.slot_mgr = SlotManager(slots)
         self.preemptions = 0
         self.decode_logit_fetches = 0  # decode steps that fetched logits
+        # cache rows the decode-step attention spans, holds and reads,
+        # summed over steps (`_count_attn_rows`, `decode_attn_rows`)
+        self._attn_rows = dict.fromkeys(
+            ("cache", "written", "written_free", "fetched", "fetched_free"), 0)
         self.counters = counters
         self.buckets = tuple(sorted(prefill_buckets or default_buckets(cfg.max_len)))
         assert self.buckets[-1] <= cfg.max_len
@@ -203,6 +213,17 @@ class ServingEngine:
         # serving v2 composition
         self.prefix = prefix_cache
         self.spec = spec
+        # rows of one KV block of the attention in `_decode` (one query row
+        # a slot) and `_verify` (k): asked as the model asks when it is
+        # traced.  One block of max_len rows a slot is the dense einsum
+        leaf = next(x for path, x in
+                    jax.tree_util.tree_leaves_with_path(self.cache)
+                    if getattr(path[-1], "key", None) == "cached_k")
+        self._attn_block = {
+            rows: (None if self.dcfg.attention == "full"
+                   else kernel_block(rows, leaf.shape, leaf.dtype))
+            or self.dcfg.max_len
+            for rows in {1, spec.k if spec is not None else 1}}
         self._grafts: Dict[str, tuple] = {}  # req_id -> (meta, rows) shipped KV
         self.params_version = 0
 
@@ -618,6 +639,7 @@ class ServingEngine:
         done: List[Result] = []
         with trace_scope("serve:decode.sample", cat="serving"):
             self._cursor += 1  # every row consumed one token (free rows too)
+            self._count_attn_rows(self._cursor - 1, 1, active)
             for _, r in active:
                 r.decode_rounds += 1
             if self.spec is not None:
@@ -700,7 +722,9 @@ class ServingEngine:
         self._observe("tok_latency_ms", dt * 1e3)
         # every slot's cursor (free rows included) moved to committed
         # length: + accepted drafts + the correction token
+        before = self._cursor
         self._cursor = self._cursor + n_acc + 1
+        self._count_attn_rows(before, k, active)
         for _, r in active:
             r.decode_rounds += 1
         done: List[Result] = []
@@ -829,6 +853,39 @@ class ServingEngine:
             out.append(d)
         return out
 
+    def _count_attn_rows(self, before: np.ndarray, query_rows: int,
+                         active) -> None:
+        """Add one slot-cache step to `decode_attn_rows`: `before` the
+        cursors it started from (`self._cursor` those it ended at), each
+        slot bringing `query_rows` query rows, `active` the busy slots."""
+        max_len = self.dcfg.max_len
+        block = self._attn_block[query_rows]
+        first, last = live_blocks(np, before, before + query_rows - 1, block,
+                                  max_len, self.dcfg.window)
+        fetched = (last - first + 1) * block
+        written = np.minimum(self._cursor, max_len)
+        free = np.ones(self.n_slots, np.int64)
+        free[[slot for slot, _ in active]] = 0
+        add = (self.n_slots * max_len, written.sum(), written @ free,
+               fetched.sum(), fetched @ free)
+        # rebound whole, so a reader on another thread (/metrics, a
+        # profile capture) sees the totals of one step or of the next
+        self._attn_rows = {kind: n + int(a) for (kind, n), a
+                           in zip(self._attn_rows.items(), add)}
+
+    def decode_attn_rows(self) -> Dict[str, int]:
+        """Cache rows of the decode-step attention, summed over the decode
+        and verify steps so far, a layer: `cache` the rows a step spans
+        (slots x max_len), `written` the rows the cursors stand at after
+        it and `written_free` those of them under free slots' cursors,
+        which ride along from 0 on a dummy token (nobody's rows: `written`
+        less `written_free` is what the attention NEEDS to read),
+        `fetched` the rows the program reads (each slot's live blocks,
+        ops/decode_attn.py: the whole cache when the program was built
+        with the dense einsum, so `fetched == cache` says the kernel is not
+        what runs), `fetched_free` those of them read for free slots."""
+        return dict(self._attn_rows)
+
     def device_counters(self, refresh: bool = True) -> Dict[str, Any]:
         """What the model counted on the device, copied to the host:
         {collection: tree of numpy} (empty for a model that counts nothing).
@@ -858,6 +915,7 @@ class ServingEngine:
             "preemptions": self.preemptions,
             "decode_logit_fetches": self.decode_logit_fetches,
             "param_bytes": dict(self.param_bytes),
+            "decode_attn_rows": self.decode_attn_rows(),
         }
         if self.prefix is not None:
             out["prefix"] = self.prefix.stats()

@@ -80,7 +80,7 @@ class SpecDecoder:
         self.disable_below = float(disable_below)
         self.disable_after = int(disable_after)
         self.dcfg = dataclasses.replace(
-            draft_cfg, decode=True, attention="full", mesh=None, head="dense"
+            draft_cfg, decode=True, attention="auto", mesh=None, head="dense"
         )
         self.model = TransformerLM(self.dcfg)
         self.params = draft_params

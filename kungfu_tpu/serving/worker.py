@@ -226,6 +226,10 @@ class ServingWorker:
             self.counters.add_source(lambda: {"kft_serve_param_bytes": {
                 f'dtype="{name}"': n
                 for name, n in self.engine.param_bytes.items()}})
+            self.counters.add_source(
+                lambda: {"kft_serve_decode_attn_rows_total": {
+                    f'kind="{kind}"': n for kind, n in
+                    self.engine.decode_attn_rows().items()}})
         self.decode_pool = None
         if self.tier == "prefill" and args.config_server:
             from ..elastic.config_client import ConfigClient

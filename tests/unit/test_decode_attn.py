@@ -1,0 +1,316 @@
+"""Decode-step attention (kungfu_tpu/ops/decode_attn.py).
+
+The kernel's body in the Pallas interpreter against the dense einsum it
+replaces on TPU, over ragged cursors, the verify shape, grouped queries, a
+window and both cache dtypes, with NaN in every row beyond a cursor (a
+kernel that read a dead row, or softmaxed an empty block, would show it);
+its Mosaic lowering at the serving cells' shapes; and the choice between
+kernel and einsum from what a call shows of itself.
+"""
+import dataclasses
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.export  # noqa: F401  (not an attribute until imported, on the pinned JAX)
+import jax.numpy as jnp
+import flax.linen as nn
+from jax.sharding import Mesh
+
+from kungfu_tpu.models.transformer import TransformerConfig, TransformerLM
+from kungfu_tpu.ops import decode_attention, decode_attention_reference
+from kungfu_tpu.ops import decode_attn as da
+
+MAX_LEN, BLOCK, D = 64, 16, 16
+
+CURSORS = {  # eight slots each, so that one traced kernel serves them all
+    "zero": [0] * 8,
+    "one": [1] * 8,
+    "block_minus_1": [BLOCK - 1] * 8,
+    "block": [BLOCK] * 8,
+    "block_plus_1": [BLOCK + 1] * 8,
+    "last_row": [MAX_LEN - 1] * 8,
+    # a slot at cursor 0 beside full ones, and every block count between
+    "mix": [0, MAX_LEN - 1, 1, BLOCK, 3 * BLOCK - 1, 30, MAX_LEN - 1, 0],
+}
+
+
+def _case(cursors, L, H, Hkv, dtype, seed=0):
+    """(q, cache_k, cache_v, positions, the cache with NaN beyond each
+    slot's last position).  A cursor too near max_len for L rows is pulled
+    back so the last query sits on the last row."""
+    B = len(cursors)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(kq, (B, L, H, D), jnp.float32).astype(dtype)
+    ck = jax.random.normal(kk, (B, MAX_LEN, Hkv, D), jnp.float32).astype(dtype)
+    cv = jax.random.normal(kv, (B, MAX_LEN, Hkv, D), jnp.float32).astype(dtype)
+    idx0 = jnp.minimum(jnp.asarray(cursors, jnp.int32), MAX_LEN - L)
+    pos = idx0[:, None] + jnp.arange(L)[None, :]
+    dead = (jnp.arange(MAX_LEN)[None, :] > pos[:, -1:])[:, :, None, None]
+    return q, ck, cv, pos, jnp.where(dead, jnp.nan, ck), jnp.where(dead, jnp.nan, cv)
+
+
+@functools.lru_cache(maxsize=None)
+def _interpreted(window):
+    """The kernel's body in the Pallas interpreter, traced once a shape."""
+    return jax.jit(lambda q, k, v, pos: da._attn_pallas(
+        q, k, v, pos, window, BLOCK, True))
+
+
+def _check(cursors, L, H, Hkv, dtype, window):
+    q, ck, cv, pos, ck_nan, cv_nan = _case(cursors, L, H, Hkv, dtype)
+    want = decode_attention_reference(q, ck, cv, pos, window)
+    got = _interpreted(window)(q, ck_nan, cv_nan, pos)
+    assert got.shape == (len(cursors), L, H, D) and got.dtype == jnp.float32
+    assert np.isfinite(np.asarray(got)).all()
+    # float32: summation order only.  bf16: the probabilities are rounded
+    # to bf16 before the running normaliser divides, not after
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("heads", [(16, 16), (8, 2)], ids=["mha16", "gqa8_2"])
+@pytest.mark.parametrize("L", [1, 4])
+@pytest.mark.parametrize("cursors", list(CURSORS), ids=list(CURSORS))
+def test_kernel_matches_the_einsum_over_ragged_cursors(cursors, L, heads):
+    _check(CURSORS[cursors], L, *heads, jnp.float32, 0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("window", [0, 20])
+@pytest.mark.parametrize("L", [1, 4])
+def test_kernel_matches_the_einsum_with_a_window_and_a_bf16_cache(
+        L, window, dtype):
+    _check(CURSORS["mix"], L, 8, 2, dtype, window)
+
+
+def test_a_row_of_the_verify_shape_sees_up_to_its_own_position():
+    """Row l of a k-row call attends rows <= idx0 + l: changing cache row
+    idx0 + 2 moves query rows 2 and 3 and leaves rows 0 and 1 alone."""
+    q, ck, cv, pos, _, _ = _case([20] + [5] * 7, 4, 8, 2, jnp.float32)
+    base = _interpreted(0)(q, ck, cv, pos)
+    moved = _interpreted(0)(q, ck, cv.at[0, 22].add(1.0), pos)
+    same = np.isclose(np.asarray(base), np.asarray(moved)).all(axis=(2, 3))
+    assert same.tolist() == [[True, True, False, False]] + [[True] * 4] * 7
+
+
+def test_live_blocks_are_the_same_on_the_host_and_in_the_program():
+    lo = np.array([0, 15, 16, 40, 63, 70])
+    hi = lo + np.array([0, 3, 0, 3, 0, 3])
+    for window in (0, 20):
+        host = da.live_blocks(np, lo, hi, BLOCK, MAX_LEN, window)
+        prog = da.live_blocks(jnp, jnp.asarray(lo), jnp.asarray(hi), BLOCK,
+                              MAX_LEN, window)
+        for h, p in zip(host, prog):
+            assert h.tolist() == np.asarray(p).tolist()
+    first, last = da.live_blocks(np, lo, hi, BLOCK, MAX_LEN, 20)
+    assert last.tolist() == [0, 1, 1, 2, 3, 3]     # rows beyond max_len clip
+    assert first.tolist() == [0, 0, 0, 1, 2, 3]    # rows every window has left
+
+
+# -- the lowering for the chip ---------------------------------------------------------
+
+
+@pytest.mark.parametrize("L,dtype,window", [
+    (1, jnp.bfloat16, 0),    # `_decode` of both serving cells
+    (4, jnp.bfloat16, 0),    # `_verify_accept`
+    (1, jnp.float32, 0),
+    (1, jnp.bfloat16, 512),
+], ids=["decode", "verify", "float32", "window"])
+def test_lowers_for_tpu_at_the_cells_shapes(L, dtype, window):
+    """`jax.export` runs the Pallas -> Mosaic lowering on this host:
+    [8, L, 16, 128] against the [8, 2048, 16, 128] leaves of the cells."""
+    q = jax.ShapeDtypeStruct((8, L, 16, 128), dtype)
+    leaf = jax.ShapeDtypeStruct((8, 2048, 16, 128), dtype)
+    pos = jax.ShapeDtypeStruct((8, L), jnp.int32)
+    assert da.kernel_block(L, leaf.shape, dtype, interpret=False) == (
+        256 if dtype == jnp.bfloat16 else 128)
+
+    def f(q, k, v, p):
+        return decode_attention(q, k, v, p, window, interpret=False)
+
+    exp = jax.export.export(jax.jit(f), platforms=["tpu"])(q, leaf, leaf, pos)
+    assert da.KERNEL_NAME in exp.mlir_module()
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a described (not attached) v5e: the TPU compiler runs on
+    this host.  Described inside the fixture, so only the worker that runs
+    this file loads the TPU's library."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no compiler here: nothing to test
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_the_engines_decode_program_reads_the_donated_cache_where_it_lies(
+        v5e_chip, monkeypatch):
+    """`ServingEngine._decode` at the cells' widths (two layers), compiled
+    for the chip: one kernel call a layer, no copy, transpose or convert of
+    a whole cache leaf ahead of it (a layout change of the operand would
+    move 64 MiB a leaf a step), and the donated cache still aliases the
+    program's output."""
+    import re
+
+    from kungfu_tpu import compat
+    from kungfu_tpu.serving import ServingEngine
+
+    # the program asks jax.default_backend(), the CPU here: the test (not
+    # the program) steers it onto the path the chip takes
+    monkeypatch.setattr(compat, "pallas_mode", lambda interpret=None: "compiled")
+    cfg = TransformerConfig(vocab_size=512, d_model=2048, n_layers=2,
+                            n_heads=16, d_ff=256, max_len=2048, rope=True,
+                            attention="full", dtype=jnp.bfloat16)
+    described = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip), tree)
+    params = described(nn.meta.unbox(jax.eval_shape(
+        TransformerLM(cfg).init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 1), jnp.int32))["params"]))
+    eng = ServingEngine(cfg, params, slots=8)
+    compiled = eng._decode.lower(
+        described(eng.params), described(eng.cache), {},
+        jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=v5e_chip)).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"= \S+ custom-call\([^\n]*kft_decode_attn", text)
+    assert len(calls) == cfg.n_layers
+    moved = []
+    for comp in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text):
+        if "fused_computation" in comp.split("\n", 1)[0]:
+            continue  # inside a fusion nothing is written to HBM
+        moved += re.findall(
+            r"= \S*\[8,2048,16,128\]\S* (?:copy|transpose|convert)\(", comp)
+    assert moved == []
+    cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(eng.cache))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // (2 * cfg.n_layers)
+
+
+# -- which of the two a call gets ------------------------------------------------------
+
+LEAF = (8, 2048, 16, 128)
+
+
+def test_selection_from_shape_and_dtype():
+    pick = lambda rows=1, shape=LEAF, dtype=jnp.bfloat16, mode=True: (  # noqa: E731
+        da.kernel_block(rows, shape, dtype, interpret=mode))
+    assert pick() == 256
+    assert pick(rows=da.MAX_QUERY_ROWS) == 256              # a verify's k rows
+    assert pick(rows=16) is None                            # a prefill bucket
+    assert pick(dtype=jnp.int8) is None                     # read through its scales
+    assert pick(mode=None) is None                          # no kernels on this backend
+    assert pick(shape=(8, 2048, 2, 128)) is None            # KV heads fill no tile
+    assert pick(shape=(8, 2048, 16, 64)) is None            # head_dim fills no lane tile
+    assert pick(shape=(8, 2048, 8, 128), dtype=jnp.float32) == 256
+    assert pick(shape=(8, 96, 16, 128)) == 32               # the block divides max_len
+    assert pick(shape=(8, 100, 16, 128)) is None
+
+
+def _decode_program_text(cfg, L, monkeypatch, mode="interpret"):
+    """The jaxpr of one decode-mode call of an attention layer."""
+    monkeypatch.setenv("KFT_PALLAS", mode)
+    model = TransformerLM(cfg)
+    toks = jnp.zeros((2, L), jnp.int32)
+    variables = jax.eval_shape(model.init, jax.random.PRNGKey(0), toks[:, :1])
+    return str(jax.make_jaxpr(
+        lambda v, t: model.apply(v, t, mutable=["cache"]))(variables, toks))
+
+
+def test_the_model_step_takes_the_kernel_where_selection_says(monkeypatch):
+    """`Attention.__call__` hands the stored leaves and the cursors to
+    `decode_attention`: a decode or verify step traces the kernel, a
+    prefill bucket, an int8 cache, attention="full" (what a cache on a
+    mesh is served with), a config on a mesh and a backend without the
+    kernels trace the einsum."""
+    cfg = TransformerConfig(vocab_size=32, d_model=1024, n_layers=1, n_heads=8,
+                            d_ff=32, max_len=64, rope=True, decode=True,
+                            dtype=jnp.float32)
+    mesh = Mesh(np.array(jax.devices()[:1]), ("tp",))
+    text = lambda c=cfg, L=1, **kw: _decode_program_text(c, L, monkeypatch, **kw)  # noqa: E731
+    assert da.KERNEL_NAME in text()
+    assert da.KERNEL_NAME in text(L=4)
+    assert da.KERNEL_NAME not in text(L=16)
+    assert da.KERNEL_NAME not in text(
+        dataclasses.replace(cfg, kv_cache_dtype="int8"))
+    assert da.KERNEL_NAME not in text(dataclasses.replace(cfg, attention="full"))
+    assert da.KERNEL_NAME not in text(dataclasses.replace(cfg, mesh=mesh))
+    assert da.KERNEL_NAME not in text(mode="off")
+
+
+def test_serving_over_a_mesh_keeps_the_einsum_and_places_no_constraint(
+        monkeypatch):
+    """`ServingEngine(mesh=...)` and `generate(mesh=...)` ask for the plain
+    einsum (attention="full") and, as before the kernel, give the decode
+    model no mesh: traced inside a caller's `nn.logical_axis_rules` the
+    program carries no sharding constraint, and is the program traced
+    outside one.  Without a mesh the same engine traces the kernel."""
+    from kungfu_tpu.models import transformer as T
+    from kungfu_tpu.parallel.sharding import DEFAULT_RULES
+    from kungfu_tpu.serving import ServingEngine
+
+    monkeypatch.setenv("KFT_PALLAS", "interpret")
+    cfg = TransformerConfig(vocab_size=32, d_model=1024, n_layers=1, n_heads=8,
+                            d_ff=32, max_len=512, rope=True, dtype=jnp.float32)
+    params = nn.meta.unbox(TransformerLM(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"])
+    mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
+    toks = jnp.zeros((2, 1), jnp.int32)
+    traced = lambda eng: str(jax.make_jaxpr(eng._decode)(  # noqa: E731
+        eng.params, eng.cache, {}, toks))
+
+    with nn.logical_axis_rules(DEFAULT_RULES):
+        sharded = ServingEngine(cfg, params, slots=2, mesh=mesh)
+        under_rules = traced(sharded)
+    assert (sharded.dcfg.attention, sharded.dcfg.mesh) == ("full", None)
+    assert da.KERNEL_NAME not in under_rules
+    assert "sharding_constraint" not in under_rules
+    assert traced(ServingEngine(cfg, params, slots=2, mesh=mesh)) == under_rules
+    assert sharded._attn_block == {1: cfg.max_len}  # counts the whole cache
+
+    single = ServingEngine(cfg, params, slots=2)
+    assert (single.dcfg.attention, single.dcfg.mesh) == ("auto", None)
+    assert da.KERNEL_NAME in traced(single)
+    assert single._attn_block == {1: 256}
+
+    seen = []
+    monkeypatch.setattr(T, "_generate_compiled", lambda dcfg, *a: seen.append(
+        dcfg) or (lambda params, cache, prompt, rng: prompt))
+    prompt = jnp.zeros((2, 4), jnp.int32)
+    T.generate(cfg, params, prompt, 4)
+    T.generate(cfg, params, prompt, 4, mesh=mesh)
+    assert [(c.attention, c.mesh) for c in seen] == [("auto", None), ("full", None)]
+
+
+def test_decode_step_logits_equal_the_einsums(monkeypatch):
+    """One model, the same cache: a prefill through the einsum, then decode
+    steps through the kernel, against the same steps through the einsum."""
+    cfg = TransformerConfig(vocab_size=32, d_model=1024, n_layers=2, n_heads=8,
+                            d_ff=32, max_len=512, rope=True, decode=True,
+                            dtype=jnp.float32)
+    model = TransformerLM(cfg)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 300), 1, 32)
+    variables = nn.meta.unbox(model.init(jax.random.PRNGKey(0), toks[:, :1]))
+
+    def run(mode):
+        monkeypatch.setenv("KFT_PALLAS", mode)
+        params, cache = variables["params"], variables["cache"]
+        _, st = model.apply({"params": params, "cache": cache},
+                            toks[:, :290], mutable=["cache"])
+        out = []
+        for i in range(290, 294):  # the cursor passes the first block's end
+            logits, st = model.apply({"params": params, "cache": st["cache"]},
+                                     toks[:, i:i + 1], mutable=["cache"])
+            out.append(np.asarray(logits))
+        return np.stack(out)
+
+    np.testing.assert_allclose(run("interpret"), run("off"), rtol=2e-4, atol=2e-4)

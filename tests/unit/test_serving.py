@@ -492,6 +492,75 @@ class TestStepPrograms:
             np.testing.assert_array_equal(np.asarray(pd.result.tokens), ref)
 
 
+# -- the decode-step attention the engine's programs are built with ---------------------
+
+
+def _staggered_run_with_a_preemption(monkeypatch, pallas):
+    """Five requests over two slots of a model whose cache the decode
+    kernel takes (8 KV heads x 128, float32; two blocks of 256 rows):
+    admissions between decode steps, one priority preemption, prompts on
+    both sides of the first block's end.  -> (tokens of each, the engine)"""
+    from kungfu_tpu.serving.tenancy import TenantRegistry, TenantSpec
+
+    monkeypatch.setenv("KFT_PALLAS", pallas)
+    cfg = _cfg(d_model=1024, n_heads=8, n_kv_heads=8, d_ff=32, max_len=512)
+    params = nn.meta.unbox(TransformerLM(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"])
+    reg = TenantRegistry(specs={
+        "bulk": TenantSpec(name="bulk", priority=0),
+        "gold": TenantSpec(name="gold", priority=2)})
+    eng = ServingEngine(cfg, params, slots=2, prefill_buckets=(8, 16, 512),
+                        prefix_cache=PrefixCache(1 << 24), tenants=reg)
+    rs = np.random.RandomState(3)
+    prompt = lambda n: tuple(int(t) for t in rs.randint(1, 64, (n,)))  # noqa: E731
+    pend = [eng.submit(Request(prompt=prompt(5), max_new_tokens=12,
+                               tenant="bulk"))]
+    eng.step()
+    pend.append(eng.submit(Request(prompt=prompt(250), max_new_tokens=10,
+                                   tenant="bulk")))
+    for _ in range(3):
+        eng.step()
+    pend.append(eng.submit(Request(prompt=prompt(7), max_new_tokens=5,
+                                   tenant="gold")))  # evicts a bulk request
+    pend.append(eng.submit(Request(prompt=prompt(12), max_new_tokens=6,
+                                   tenant="bulk")))
+    for _ in range(4):
+        eng.step()
+    pend.append(eng.submit(Request(prompt=prompt(3), max_new_tokens=4,
+                                   tenant="bulk")))
+    eng.run_until_idle()
+    assert eng.preemptions == 1
+    assert all(p.result.status == "ok" for p in pend)
+    return [tuple(p.result.tokens) for p in pend], eng
+
+
+def test_engine_tokens_and_row_counts_with_the_kernel_and_with_the_einsum(
+        monkeypatch):
+    """The engine returns token for token the same with the length-aware
+    kernel (its body in the Pallas interpreter) as with the dense einsum
+    forced, and `decode_attn_rows` says which of the two its decode
+    program was built with."""
+    got, kernel = _staggered_run_with_a_preemption(monkeypatch, "interpret")
+    want, einsum = _staggered_run_with_a_preemption(monkeypatch, "off")
+    assert got == want
+    k, e = kernel.stats()["decode_attn_rows"], einsum.decode_attn_rows()
+    for rows in (k, e):
+        assert 0 < rows["written"] <= rows["fetched"] <= rows["cache"]
+        assert 0 < rows["fetched_free"] <= rows["fetched"]
+        # a free slot's cursor rides along: its rows are nobody's
+        assert 0 < rows["written_free"] < rows["written"]
+        assert rows["written_free"] <= rows["fetched_free"]
+    assert e["fetched"] == e["cache"]          # the einsum reads every row
+    assert k["fetched"] < k["cache"]           # the kernel the live blocks
+    # the same steps on both sides: the cursors do not depend on the path
+    assert [k[kind] for kind in ("cache", "written", "written_free")] == [
+        e[kind] for kind in ("cache", "written", "written_free")]
+    assert k["cache"] % (2 * 512) == 0
+    # every step fetched one or two blocks of 256 rows a slot
+    assert k["fetched"] % 256 == 0
+    assert k["cache"] // 2 <= k["fetched"]
+
+
 # -- radix prefix cache ----------------------------------------------------------------
 
 
@@ -1531,6 +1600,33 @@ def test_worker_reports_resident_bytes_by_dtype(monkeypatch):
     assert "# TYPE kft_serve_param_bytes gauge" in text
     for name, n in held.items():
         assert f'kft_serve_param_bytes{{dtype="{name}"}} {n}' in text
+
+
+def test_worker_reports_decode_attn_rows(monkeypatch):
+    """`kft_serve_decode_attn_rows_total{kind=...}` on /metrics (a counter)
+    is the engine's `decode_attn_rows`, which a profile capture reads at
+    both ends through the same source; a speculative round counts its k
+    query rows a slot like a decode step its one."""
+    worker = _worker(monkeypatch, "", spec_draft="same", slots=1)
+    eng = worker.engine
+    assert set(eng.decode_attn_rows().values()) == {0}
+    eng.submit(Request(prompt=(1, 2, 3), max_new_tokens=9))
+    eng.run_until_idle()
+    assert worker.counters.events().get("spec_rounds", 0) >= 1
+    rows = eng.decode_attn_rows()
+    steps = worker.counters.hist_summaries()["tok_latency_ms"][""]["count"]
+    assert rows["cache"] == steps * eng.dcfg.max_len
+    # no kernels on this backend: every step read the whole cache
+    assert rows["fetched"] == rows["cache"] and rows["fetched_free"] == 0
+    assert rows["written_free"] == 0  # its one slot is busy in every step
+    # a lone request: 3 + 9 - 1 rows stand written after its last step
+    assert 0 < rows["written"] <= steps * 11
+    text = worker.counters.prometheus_text()
+    assert "# TYPE kft_serve_decode_attn_rows_total counter" in text
+    for kind, n in rows.items():
+        assert f'kft_serve_decode_attn_rows_total{{kind="{kind}"}} {n}' in text
+    assert worker.counters.source_families()[
+        "kft_serve_decode_attn_rows_total"]['kind="cache"'] == rows["cache"]
 
 
 # -- program observatory regression ----------------------------------------------------
