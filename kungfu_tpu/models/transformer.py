@@ -250,7 +250,7 @@ class Attention(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, live=None):
         cfg = self.cfg
         H, D = cfg.n_heads, cfg.d_model // cfg.n_heads
         Hkv = cfg.kv_heads
@@ -346,9 +346,15 @@ class Attention(nn.Module):
                 # must not write tokens or advance the cursors
                 store(cache_k, kscale, k)
                 store(cache_v, vscale, v)
-                cache_idx.value = idx0 + L
+                # `live` [B] bool says which rows hold a request (None:
+                # all).  A row that is not live keeps its cursor and its
+                # flag: a free serving slot stays at 0, its dummy k/v land
+                # on row 0 of its own slot, which the next admission
+                # replaces, and the read below stays inside its first block
+                step = L if live is None else jnp.where(live, L, 0)
+                cache_idx.value = idx0 + step
                 cache_ovf.value = jnp.logical_or(
-                    cache_ovf.value, idx0 + L > cfg.max_len
+                    cache_ovf.value, idx0 + step > cfg.max_len
                 )
             # attention over the stored leaves, each query row against the
             # rows of its slot up to its own position (ops/decode_attn.py):
@@ -526,15 +532,15 @@ class Block(nn.Module):
     use_moe: bool = False
 
     @nn.compact
-    def __call__(self, x, train: bool = False):
+    def __call__(self, x, train: bool = False, live=None):
         cfg = self.cfg
         ln = partial(_norm, cfg)
         drop = nn.Dropout(cfg.dropout, deterministic=not train)
-        x = x + drop(Attention(cfg, name="attn")(ln(name="ln1")(x)))
+        x = x + drop(Attention(cfg, name="attn")(ln(name="ln1")(x), live))
         if self.use_moe:
             from ..parallel.moe import MoE
 
-            x = x + drop(MoE(cfg, name="moe")(ln(name="ln2")(x)))
+            x = x + drop(MoE(cfg, name="moe")(ln(name="ln2")(x), live))
         else:
             x = x + drop(MLP(cfg, name="mlp")(ln(name="ln2")(x)))
         return logical_constraint(x, ("batch", "seq", "act_embed"), cfg.mesh)
@@ -577,7 +583,14 @@ class TransformerLM(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, tokens, train: bool = False):
+    def __call__(self, tokens, train: bool = False, live=None):
+        """`live` [B] bool, for the slot-cache programs of the serving
+        engine: a row that is not live holds no request, and does no work
+        that another row or a later call could see: in decode mode its
+        cache cursor and overflow flag stay where they are (`Attention`),
+        and no expert is read for it (`parallel/moe.py`); what comes back
+        for it means nothing.  None, what every other caller passes, is
+        every row live, and traces the program it always did."""
         cfg = self.cfg
         B, L = tokens.shape
         # nn.Embed(dtype=cfg.dtype) converts the whole [vocab, d_model] table
@@ -640,7 +653,8 @@ class TransformerLM(nn.Module):
             block_cls = Block
         for i in range(cfg.n_layers):
             use_moe = cfg.n_experts > 0 and (i % cfg.moe_every == cfg.moe_every - 1)
-            x = block_cls(cfg, use_moe=use_moe, name=f"block_{i}")(x, train)
+            x = block_cls(cfg, use_moe=use_moe, name=f"block_{i}")(
+                x, train, live)
         x = _norm(cfg, "ln_f")(x)
         if cfg.head == "hidden":
             # deferred head: the streaming loss (lm_loss_chunked) consumes

@@ -158,6 +158,9 @@ METRIC_HELP: Dict[str, str] = {
         "Cache rows of the decode-step attention a layer, summed over steps: "
         "spanned (cache), written and fetched, and the part of each that "
         "is free slots' (written_free, fetched_free).",
+    "kft_serve_decode_rows_total":
+        "Slot-steps of the decode and verify steps: live (the slot held a "
+        "request) and free (its row did no work).",
     "kungfu_fleet_ranks_scraped": "1 if the rank answered the fleet scrape.",
     "kungfu_fleet_scrape_errors_total": "Failed fleet scrape fan-out fetches.",
 }

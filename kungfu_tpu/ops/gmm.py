@@ -2,9 +2,18 @@
 
 `grouped_matmul(lhs, rhs, group_sizes)`: lhs [m, k] holds rows sorted by
 group (expert), rhs [groups, k, n] one matrix a group, group_sizes [groups]
-how many consecutive rows each group owns (they sum to m).  Row r of the
-result is lhs[r] @ rhs[group of r].  No capacity, no dropped row: a group
-may own every row or none.
+how many consecutive rows each group owns.  Row r of the result is
+lhs[r] @ rhs[group of r].  No capacity, no dropped row: a group may own
+every row or none.
+
+The group sizes sum to m, or, when the caller says `leftover=True`, to
+less (to nothing, even): the rows after the last group's belong to no
+group and come back as zeros, whatever lhs holds there (a NaN too), and
+take no gradient.  The layer routes the rows of free serving slots there
+(parallel/moe.py), so the experts read are those live rows hit.  The
+kernel never stores such a row (no visit owns it), so the zero is a
+select on the [m, n] result, the same on every path below; a caller whose
+sizes sum to m does not ask for it and gets the program it always got.
 
 On TPU this is a Mosaic kernel the trace names `kft_moe_gmm`.  The grid
 walks (n tile, visit, k tile); a *visit* is one (row tile, group) pair that
@@ -137,19 +146,32 @@ def _ragged(lhs, rhs, group_sizes, out_dtype):
         preferred_element_type=jnp.float32).astype(out_dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _gmm(lhs, rhs, group_sizes, out_dtype, mode):
+def _owned(x, group_sizes):
+    """x [m, n] with the rows that belong to no group set to zero."""
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return jnp.where(row < jnp.sum(group_sizes, dtype=jnp.int32), x,
+                     jnp.zeros((), x.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _gmm(lhs, rhs, group_sizes, out_dtype, mode, leftover):
     if mode == "off":
-        return _ragged(lhs, rhs, group_sizes, out_dtype)
-    return _gmm_pallas(lhs, rhs, group_sizes, out_dtype, mode == "interpret")
+        out = _ragged(lhs, rhs, group_sizes, out_dtype)
+    else:
+        out = _gmm_pallas(lhs, rhs, group_sizes, out_dtype,
+                          mode == "interpret")
+    return _owned(out, group_sizes) if leftover else out
 
 
-def _gmm_fwd(lhs, rhs, group_sizes, out_dtype, mode):
-    return _gmm(lhs, rhs, group_sizes, out_dtype, mode), (lhs, rhs, group_sizes)
+def _gmm_fwd(lhs, rhs, group_sizes, out_dtype, mode, leftover):
+    return (_gmm(lhs, rhs, group_sizes, out_dtype, mode, leftover),
+            (lhs, rhs, group_sizes))
 
 
-def _gmm_bwd(out_dtype, mode, res, g):
+def _gmm_bwd(out_dtype, mode, leftover, res, g):
     lhs, rhs, group_sizes = res
+    if leftover:
+        g = _owned(g, group_sizes)
     _, vjp = jax.vjp(lambda a, b: _ragged(a, b, group_sizes, out_dtype), lhs, rhs)
     d_lhs, d_rhs = vjp(g)
     return d_lhs, d_rhs, None
@@ -159,7 +181,10 @@ _gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
-                   out_dtype=None, interpret=None) -> jax.Array:
-    """lhs [m, k] (rows sorted by group) x rhs [groups, k, n] -> [m, n]."""
+                   out_dtype=None, interpret=None,
+                   leftover: bool = False) -> jax.Array:
+    """lhs [m, k] (rows sorted by group) x rhs [groups, k, n] -> [m, n].
+    `leftover`: the group sizes may sum to less than m; the rows after the
+    last group's come back as zeros."""
     return _gmm(lhs, rhs, group_sizes, jnp.dtype(out_dtype or lhs.dtype),
-                compat.pallas_mode(interpret))
+                compat.pallas_mode(interpret), bool(leftover))

@@ -12,6 +12,16 @@ over those rows (ops/gmm.py: the Mosaic kernel `kft_moe_gmm` on TPU,
 `jax.lax.ragged_dot` elsewhere), combined by the gate weights in float32.
 Only experts that own rows are read, and they are read as stored.
 
+`MoE(cfg)(u, live)`: `live` [B] bool says which batch rows hold a request
+(the serving engine's slot-cache programs pass it; None, everywhere else,
+is every row and the program as it always was).  The tokens of a row that
+is not live are routed to nobody: their k assignments take the expert id
+`n_experts`, which the stable sort puts after every real one and the count
+drops, so the grouped matmuls get group sizes that sum to k x live tokens
+(ops/gmm.py: the rows left over come back as zeros) and read only the
+experts live rows hit.  Such a row's output is zero, and finite whatever
+its input.
+
 Experts carry the logical axes ("expert", "embed", "mlp"), so an `ep` mesh
 axis shards them; GSPMD then partitions the grouped matmul as it sees fit
 (PERF.md section 7: an expert-parallel form is open).
@@ -27,10 +37,12 @@ Counted on the device in decode mode, in the "moe_stats" collection
 (declared only there, updated only when the caller makes it mutable: the
 serving engine's slot-cache programs): `assignments` [experts], rows routed
 to each expert; `experts_hit`, distinct experts that owned a row, summed
-over calls; `calls`.
+over calls; `calls`.  Live rows only: a free slot's row is routed to
+nobody and counts nowhere.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Any
 
 import jax
@@ -55,7 +67,7 @@ class MoE(nn.Module):
     cfg: Any  # TransformerConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, live=None):
         cfg = self.cfg
         B, L, Dm = x.shape
         E, k, width = cfg.n_experts, cfg.experts_per_token, cfg.d_ff
@@ -83,17 +95,26 @@ class MoE(nn.Module):
                                  precision=jax.lax.Precision.HIGHEST)
                 probs = jax.nn.softmax(logits, axis=-1)  # [T, E]
                 gates, experts = route(probs, k, cfg.norm_topk_prob)
+                if live is not None:
+                    # a token of a row that is not live is nobody's: expert
+                    # id E sorts last and is not counted, gate weight 0
+                    mine = jnp.repeat(live, L)[:, None]               # [T, 1]
+                    gates = jnp.where(mine, gates, 0.0)
+                    experts = jnp.where(mine, experts, E)
                 # the T*k assignments sorted by expert (stable: by token
                 # within an expert); row r of the grouped matmuls is token
                 # order[r] // k
                 order = jnp.argsort(experts.reshape(-1), stable=True)
                 counts = jnp.bincount(experts.reshape(-1), length=E)  # [E]
             with jax.named_scope("moe.experts"):
+                gmm = partial(grouped_matmul, group_sizes=counts,
+                              out_dtype=jnp.float32,
+                              leftover=live is not None)
                 rows = flat.astype(cfg.dtype)[order // k]             # [T*k, Dm]
-                gate = grouped_matmul(rows, w_gate, counts, jnp.float32)
-                up = grouped_matmul(rows, w_up, counts, jnp.float32)
+                gate = gmm(rows, w_gate)
+                up = gmm(rows, w_up)
                 h = (nn.silu(gate) * up).astype(cfg.dtype)
-                y = grouped_matmul(h, w_down, counts, jnp.float32)    # [T*k, Dm]
+                y = gmm(h, w_down)                                    # [T*k, Dm]
                 unsort = jnp.argsort(order)
                 y = y[unsort].reshape(T, k, Dm)
                 out = jnp.einsum("tkd,tk->td", y, gates).astype(cfg.dtype)

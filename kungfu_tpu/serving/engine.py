@@ -13,17 +13,24 @@ fixed-shape slot batch:
     is grafted into the big cache at the slot (slots.write_slot), cursor set
     to the TRUE length
   * decode: one fixed-shape [slots, 1] step advances every active slot one
-    token; free slots ride along on a dummy token and their outputs are
-    ignored.  No recompile ever happens after warmup: the decode program is
+    token.  No recompile ever happens after warmup: the decode program is
     a single (shape, dtype) signature regardless of the request mix.  The
     step updates the slot cache it is given (donated) and the host reads
     [slots] greedy token ids; the [slots, vocab] logits leave the device
     only in a step where a request samples (`decode_logit_fetches`)
     On TPU the step's attention reads each slot's cache up to its cursor
     (ops/decode_attn.py); `decode_attn_rows` says how many rows that was
+  * free slots: a slot with no request holds the token id FREE (-1) in the
+    [slots, 1] upload, and that is how the step program knows: it hands
+    the model `live = token >= 0` (models/transformer.py), so a free row's
+    cursor stays at 0 on the device (the host's mirror does the same), its
+    attention reads the first block of its slot and nothing more, and no
+    expert is read for it.  The row still fills its place in the fixed
+    shape and its outputs are ignored.  `decode_rows` says how many
+    slot-steps were of each kind
   * completion: a slot frees on max_new_tokens or eos; its row is reused by
-    the next admission (slots.reset_slot keeps the free row's ride-along
-    cursor at 0)
+    the next admission (slots.reset_slot puts the free row's cursor at 0,
+    where it stays)
 
 Serving v2 composes three multipliers onto that loop, each at bit-identical
 greedy output (docs/serving.md):
@@ -100,6 +107,10 @@ from .tenancy import TenantRegistry, WeightedFairQueue
 
 log = get_logger("kungfu.serving")
 
+#: the token id a free slot holds in the upload of a slot-cache step: no
+#: token in this row.  The step programs read liveness from it
+FREE = -1
+
 
 def default_buckets(max_len: int, lo: int = 16) -> Tuple[int, ...]:
     """Powers of two from `lo` up to (and always including) max_len."""
@@ -165,9 +176,11 @@ class ServingEngine:
         self.preemptions = 0
         self.decode_logit_fetches = 0  # decode steps that fetched logits
         # cache rows the decode-step attention spans, holds and reads,
-        # summed over steps (`_count_attn_rows`, `decode_attn_rows`)
+        # summed over steps (`_count_step`, `decode_attn_rows`)
         self._attn_rows = dict.fromkeys(
             ("cache", "written", "written_free", "fetched", "fetched_free"), 0)
+        # slot-steps of the same steps by what the slot held (`decode_rows`)
+        self._decode_rows = {"live": 0, "free": 0}
         self.counters = counters
         self.buckets = tuple(sorted(prefill_buckets or default_buckets(cfg.max_len)))
         assert self.buckets[-1] <= cfg.max_len
@@ -202,7 +215,9 @@ class ServingEngine:
         self._install(params)
 
         # host-side per-slot decode state (fixed [slots] arrays)
-        self._next_tok = np.zeros(slots, np.int32)
+        # FREE from a slot's release to its next admission, a token (>= 0)
+        # in between: what the step programs and the mirror below both go by
+        self._next_tok = np.full(slots, FREE, np.int32)
         self._cursor = np.zeros(slots, np.int64)  # mirror of cache idx
         self._rng = np.random.default_rng(0)
         self._pending: Dict[str, _Pending] = {}
@@ -265,18 +280,23 @@ class ServingEngine:
             return first, last, _fix_cursor(st["cache"], total_len)
 
         def _apply_slots(params, cache, counters, toks):
-            """The model over the slot cache: (logits, cache, counters)."""
+            """The model over the slot cache: (logits, cache, counters,
+            live).  A slot whose first token is FREE holds no request: the
+            model is told (`live`), and looks up token 0 for it."""
+            live = toks[:, 0] >= 0
             logits, st = model.apply(
-                {"params": params, "cache": cache, **counters}, toks,
+                {"params": params, "cache": cache, **counters},
+                jnp.maximum(toks, 0), live=live,
                 mutable=["cache", *counters]
             )
-            return logits, st["cache"], {c: st[c] for c in counters}
+            return logits, st["cache"], {c: st[c] for c in counters}, live
 
         @partial(jax.jit, donate_argnums=(1, 2))
         def _decode(params, cache, counters, toks):
-            # toks [slots, 1] — THE fixed decode signature; free slots carry
-            # a dummy token whose output is never read
-            logits, cache, counters = _apply_slots(params, cache, counters, toks)
+            # toks [slots, 1] — THE fixed decode signature; a free slot's
+            # row holds FREE, does no work and its output is never read
+            logits, cache, counters, _ = _apply_slots(
+                params, cache, counters, toks)
             last = logits[:, -1].astype(jnp.float32)  # [slots, V]
             greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
             return greedy, last, cache, counters
@@ -289,7 +309,8 @@ class ServingEngine:
             # per-slot cursor rollback fold into the same program: one
             # dispatch, one host sync per speculative round.
             k = toks.shape[1]
-            logits, cache, counters = _apply_slots(params, cache, counters, toks)
+            logits, cache, counters, live = _apply_slots(
+                params, cache, counters, toks)
             g = jnp.argmax(
                 logits.astype(jnp.float32), axis=-1
             ).astype(jnp.int32)  # [slots, k]: the target's own greedy run
@@ -297,10 +318,12 @@ class ServingEngine:
             n_acc = jnp.cumprod(ok, axis=1).sum(axis=1)  # accepted prefix
 
             def roll(path, leaf):
-                # the apply advanced every cursor by k; committed length is
-                # n_acc + 1 (accepted drafts + the correction token)
+                # the apply advanced every live cursor by k; committed
+                # length is n_acc + 1 (accepted drafts + the correction
+                # token).  A free slot's did not move and does not
                 if getattr(path[-1], "key", None) == "idx":
-                    return leaf - (k - 1 - n_acc).astype(leaf.dtype)
+                    return leaf - jnp.where(live, k - 1 - n_acc, 0).astype(
+                        leaf.dtype)
                 return leaf
 
             cache2 = jax.tree_util.tree_map_with_path(roll, cache)
@@ -638,8 +661,11 @@ class ServingEngine:
             self._count("decode_logit_fetches")
         done: List[Result] = []
         with trace_scope("serve:decode.sample", cat="serving"):
-            self._cursor += 1  # every row consumed one token (free rows too)
-            self._count_attn_rows(self._cursor - 1, 1, active)
+            # every live row consumed one token; a free row's cursor stays
+            live = self._next_tok >= 0
+            before = self._cursor
+            self._cursor = before + live
+            self._count_step(before, 1, live)
             for _, r in active:
                 r.decode_rounds += 1
             if self.spec is not None:
@@ -698,7 +724,9 @@ class ServingEngine:
             vargs["trace_ids"] = ids
         with trace_scope("serve:draft", cat="serving", args=dargs,
                          track=bool(ids)):
-            proposals = self.spec.propose(t0_toks, self._cursor)
+            # the draft is told nothing of free slots: token 0 from row 0
+            proposals = self.spec.propose(np.maximum(t0_toks, 0),
+                                          self._cursor)
         ver = np.concatenate([t0_toks[:, None], proposals], axis=1)
         with trace_scope("serve:verify", cat="serving", args=vargs,
                          track=bool(ids)):
@@ -720,11 +748,12 @@ class ServingEngine:
                 vargs["accepted"] = [int(n_acc[s]) for s, r in active
                                      if r.trace_id]
         self._observe("tok_latency_ms", dt * 1e3)
-        # every slot's cursor (free rows included) moved to committed
-        # length: + accepted drafts + the correction token
+        # every live slot's cursor moved to committed length: + accepted
+        # drafts + the correction token; a free slot's stays
+        live = t0_toks >= 0
         before = self._cursor
-        self._cursor = self._cursor + n_acc + 1
-        self._count_attn_rows(before, k, active)
+        self._cursor = before + (n_acc + 1) * live
+        self._count_step(before, k, live)
         for _, r in active:
             r.decode_rounds += 1
         done: List[Result] = []
@@ -763,11 +792,12 @@ class ServingEngine:
 
     def _reset_slot(self, slot: int) -> None:
         """A released slot's row back to its free state: cursor 0 on the
-        device and on the host, the dummy ride-along token."""
+        device and on the host, where both stay until the next admission,
+        and FREE for its token."""
         with trace_scope("serve:slot_reset", cat="serving",
                          args={"slot": slot}):
             self.cache = reset_slot(self.cache, slot)
-        self._next_tok[slot] = 0
+        self._next_tok[slot] = FREE
         self._cursor[slot] = 0
         if self.spec is not None:
             self.spec.release_slot(slot)
@@ -853,38 +883,50 @@ class ServingEngine:
             out.append(d)
         return out
 
-    def _count_attn_rows(self, before: np.ndarray, query_rows: int,
-                         active) -> None:
-        """Add one slot-cache step to `decode_attn_rows`: `before` the
-        cursors it started from (`self._cursor` those it ended at), each
-        slot bringing `query_rows` query rows, `active` the busy slots."""
+    def _count_step(self, before: np.ndarray, query_rows: int,
+                         live: np.ndarray) -> None:
+        """Add one slot-cache step to `decode_attn_rows` and `decode_rows`:
+        `before` the cursors it started from (`self._cursor` those it ended
+        at), each slot bringing `query_rows` query rows, `live` [slots]
+        bool the slots that held a request."""
         max_len = self.dcfg.max_len
         block = self._attn_block[query_rows]
         first, last = live_blocks(np, before, before + query_rows - 1, block,
                                   max_len, self.dcfg.window)
         fetched = (last - first + 1) * block
         written = np.minimum(self._cursor, max_len)
-        free = np.ones(self.n_slots, np.int64)
-        free[[slot for slot, _ in active]] = 0
+        free = ~live
         add = (self.n_slots * max_len, written.sum(), written @ free,
                fetched.sum(), fetched @ free)
         # rebound whole, so a reader on another thread (/metrics, a
         # profile capture) sees the totals of one step or of the next
         self._attn_rows = {kind: n + int(a) for (kind, n), a
                            in zip(self._attn_rows.items(), add)}
+        n_live = int(live.sum())
+        self._decode_rows = {
+            "live": self._decode_rows["live"] + n_live,
+            "free": self._decode_rows["free"] + self.n_slots - n_live}
 
     def decode_attn_rows(self) -> Dict[str, int]:
         """Cache rows of the decode-step attention, summed over the decode
         and verify steps so far, a layer: `cache` the rows a step spans
         (slots x max_len), `written` the rows the cursors stand at after
-        it and `written_free` those of them under free slots' cursors,
-        which ride along from 0 on a dummy token (nobody's rows: `written`
-        less `written_free` is what the attention NEEDS to read),
-        `fetched` the rows the program reads (each slot's live blocks,
-        ops/decode_attn.py: the whole cache when the program was built
-        with the dense einsum, so `fetched == cache` says the kernel is not
-        what runs), `fetched_free` those of them read for free slots."""
+        it and `written_free` those of them under free slots' cursors: 0,
+        because a free slot's cursor stays at 0 (so `written` is what the
+        attention NEEDS to read), `fetched` the rows the program reads
+        (each slot's live blocks, ops/decode_attn.py: the whole cache when
+        the program was built with the dense einsum, so `fetched == cache`
+        says the kernel is not what runs), `fetched_free` those of them
+        read for free slots: one block (the verify step's k rows: the
+        blocks they span) a free slot a step, read and not used."""
         return dict(self._attn_rows)
+
+    def decode_rows(self) -> Dict[str, int]:
+        """Slot-steps of the decode and verify steps so far: `live` those
+        whose slot held a request, `free` those whose slot did not (its
+        row did no work: cursor held, one cache block read, no expert).
+        `live + free` = slots x steps."""
+        return dict(self._decode_rows)
 
     def device_counters(self, refresh: bool = True) -> Dict[str, Any]:
         """What the model counted on the device, copied to the host:
@@ -916,6 +958,7 @@ class ServingEngine:
             "decode_logit_fetches": self.decode_logit_fetches,
             "param_bytes": dict(self.param_bytes),
             "decode_attn_rows": self.decode_attn_rows(),
+            "decode_rows": self.decode_rows(),
         }
         if self.prefix is not None:
             out["prefix"] = self.prefix.stats()

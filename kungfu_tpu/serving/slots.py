@@ -8,9 +8,10 @@ the cache surgery:
   write_slot   graft a freshly prefilled single-request cache (batch row 0 of
                a [1, max_len, ...] tree) into the big cache at `slot`, cursor
                set to the request's true (un-padded) length
-  reset_slot   zero a released slot's cursor + overflow flag so a free row's
-               ride-along decode writes restart from row 0 instead of
-               marching toward max_len
+  reset_slot   zero a released slot's cursor + overflow flag: a free row
+               stays there (the step programs do not advance a slot that
+               holds no request, serving/engine.py), so its dummy k/v land
+               on row 0 and its attention reads one block
   set_cursors  write every slot's cursor at once from a host [slots] array —
                the speculative-decoding rollback (serving/spec.py): a verify
                step advances every cursor by k, then per-slot acceptance

@@ -197,9 +197,10 @@ class SpecDecoder:
         """Draft proposals [slots, k-1] continuing each slot's pending
         token from its committed cursor (the in-program re-anchor makes a
         separate rollback dispatch unnecessary).  Free and stale slots ride
-        along — their proposals only ever COST acceptance, never
-        correctness: a proposal commits only when it equals the target's
-        own greedy token."""
+        along (a free one from cursor 0 and token 0: the draft is told
+        nothing of liveness) — their proposals only ever COST acceptance,
+        never correctness: a proposal commits only when it equals the
+        target's own greedy token."""
         drafts, self.cache = self._propose(
             self.params, self.cache,
             jnp.asarray(next_tok[:, None].astype(np.int32)),

@@ -226,10 +226,13 @@ class ServingWorker:
             self.counters.add_source(lambda: {"kft_serve_param_bytes": {
                 f'dtype="{name}"': n
                 for name, n in self.engine.param_bytes.items()}})
-            self.counters.add_source(
-                lambda: {"kft_serve_decode_attn_rows_total": {
-                    f'kind="{kind}"': n for kind, n in
-                    self.engine.decode_attn_rows().items()}})
+            self.counters.add_source(lambda: {
+                family: {f'kind="{kind}"': n for kind, n in rows().items()}
+                for family, rows in (
+                    ("kft_serve_decode_attn_rows_total",
+                     self.engine.decode_attn_rows),
+                    ("kft_serve_decode_rows_total",
+                     self.engine.decode_rows))})
         self.decode_pool = None
         if self.tier == "prefill" and args.config_server:
             from ..elastic.config_client import ConfigClient

@@ -153,16 +153,19 @@ def v5e_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.mark.parametrize("experts", [0, 8], ids=["dense", "experts"])
 def test_the_engines_decode_program_reads_the_donated_cache_where_it_lies(
-        v5e_chip, monkeypatch):
+        v5e_chip, monkeypatch, experts):
     """`ServingEngine._decode` at the cells' widths (two layers), compiled
     for the chip: one kernel call a layer, no copy, transpose or convert of
     a whole cache leaf ahead of it (a layout change of the operand would
     move 64 MiB a leaf a step), and the donated cache still aliases the
-    program's output."""
+    program's output.  The mask that tells it which slots are free rides in
+    the token upload: the one array a call brings from the host."""
     import re
 
     from kungfu_tpu import compat
+    from kungfu_tpu.ops.gmm import KERNEL_NAME as GMM
     from kungfu_tpu.serving import ServingEngine
 
     # the program asks jax.default_backend(), the CPU here: the test (not
@@ -170,19 +173,29 @@ def test_the_engines_decode_program_reads_the_donated_cache_where_it_lies(
     monkeypatch.setattr(compat, "pallas_mode", lambda interpret=None: "compiled")
     cfg = TransformerConfig(vocab_size=512, d_model=2048, n_layers=2,
                             n_heads=16, d_ff=256, max_len=2048, rope=True,
-                            attention="full", dtype=jnp.bfloat16)
+                            attention="full", dtype=jnp.bfloat16,
+                            n_experts=experts, experts_per_token=2, moe_every=1,
+                            ffn="swiglu")
     described = lambda tree: jax.tree.map(  # noqa: E731
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip), tree)
     params = described(nn.meta.unbox(jax.eval_shape(
         TransformerLM(cfg).init, jax.random.PRNGKey(0),
         jnp.zeros((1, 1), jnp.int32))["params"]))
     eng = ServingEngine(cfg, params, slots=8)
-    compiled = eng._decode.lower(
-        described(eng.params), described(eng.cache), {},
-        jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=v5e_chip)).compile()
+    lowered = eng._decode.lower(
+        described(eng.params), described(eng.cache),
+        described(eng._dev_counters),
+        jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=v5e_chip))
+    resident = (eng.params, eng.cache, eng._dev_counters)
+    assert len(jax.tree.leaves(lowered.args_info)) == len(
+        jax.tree.leaves(resident)) + 1
+    compiled = lowered.compile()
     text = compiled.as_text()
-    calls = re.findall(r"= \S+ custom-call\([^\n]*kft_decode_attn", text)
-    assert len(calls) == cfg.n_layers
+    calls = lambda kernel: re.findall(  # noqa: E731
+        rf"= \S+ custom-call\([^\n]*{kernel}", text)
+    assert len(calls("kft_decode_attn")) == cfg.n_layers
+    # gate, up and down of every expert layer: one grouped matmul each
+    assert len(calls(GMM)) == (3 * cfg.n_layers if experts else 0)
     moved = []
     for comp in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text):
         if "fused_computation" in comp.split("\n", 1)[0]:

@@ -166,6 +166,11 @@ def test_engine_serves_requests_and_reports_counters():
         assert deficit.max() < F32_TOL  # the served token is the reference's argmax
     totals = stats_totals(eng.device_counters()[STATS])
     assert totals["layer_calls"] == 2 * 6  # 6 decode steps (the longer answer), 2 layers
+    # the shorter answer's slot is free in the last two steps, and a free
+    # row is routed to nobody: 2 experts a layer for each LIVE slot-step
+    assert eng.decode_rows() == {"live": 4 + 6, "free": 2}
+    assert totals["assignments"].sum() == 2 * 2 * (4 + 6)
+    assert totals["experts_hit"] <= 2 * 2 * (4 + 6)
 
 
 def test_verify_k_equals_chained_calls():
@@ -289,16 +294,104 @@ def test_gmm_kernel_interpreted_matches_ragged_dot(sizes):
     np.testing.assert_allclose(np.asarray(off), want, atol=1e-3, rtol=1e-3)
 
 
-def test_gmm_kernel_lowers_for_tpu_at_published_widths():
+@pytest.mark.parametrize("mode", ["off", "interpret"])
+@pytest.mark.parametrize("sizes", [[0, 10, 0, 20, 1, 9, 0, 0], [3] + [0] * 7, [0] * 8,
+                                   [8] * 8],
+                         ids=["ragged", "one_group", "no_group", "every_row"])
+def test_gmm_rows_of_no_group_come_back_as_zeros(sizes, mode, monkeypatch):
+    """`leftover=True`: the group sizes sum to less than m (to 0, even) and
+    the rows after the last group's are zeros, with NaN in lhs there, from
+    `ragged_dot` and from the kernel's body alike; the rows that have a
+    group are what they are when the sizes sum to m; no gradient reaches
+    a row of no group."""
+    monkeypatch.setenv("KFT_PALLAS", mode)
+    rs = np.random.RandomState(1)
+    owned = sum(sizes)
+    lhs = jnp.asarray(rs.randn(64, 128), jnp.bfloat16)
+    rhs = jnp.asarray(rs.randn(8, 128, 256), jnp.float32)
+    gs = jnp.asarray(sizes, jnp.int32)
+    got = grouped_matmul(lhs.at[owned:].set(jnp.nan), rhs, gs, jnp.float32,
+                         leftover=True)
+    assert not np.asarray(got[owned:]).any()
+    # the same rows with every row owned: the last group takes the rest
+    full = grouped_matmul(lhs, rhs, gs.at[-1].add(64 - owned), jnp.float32)
+    np.testing.assert_array_equal(np.asarray(got[:owned]), np.asarray(full[:owned]))
+    d_lhs = jax.grad(lambda a: grouped_matmul(
+        a, rhs, gs, jnp.float32, leftover=True).sum())(lhs.astype(jnp.float32))
+    assert not np.asarray(d_lhs[owned:]).any()
+    assert owned == 0 or np.asarray(d_lhs[:owned]).any()
+
+
+def _decode_layer(live_rows=(True, False, True, False), L=3):
+    """The layer in decode mode (it counts), its parameters, zeroed
+    counters, x [4, L, d] and the mask."""
+    layer, params, _, _ = moe_layer()
+    layer = MoE(dataclasses.replace(layer.cfg, decode=True, rope=True))
+    x = jnp.asarray(np.random.RandomState(7).randn(4, L, 8), jnp.float32)
+    stats = jax.tree.map(jnp.zeros_like, layer.init(jax.random.PRNGKey(0), x)[STATS])
+    return layer, params, stats, x, jnp.asarray(live_rows)
+
+
+@pytest.mark.parametrize("mode", ["off", "interpret"])
+def test_a_row_that_is_not_live_is_routed_to_nobody(mode, monkeypatch):
+    """`MoE(cfg)(x, live)`: a live row's output is bit for bit the unmasked
+    call's; a row that is not live comes back exactly zero with NaN for its
+    input; the counters count the live rows' assignments and experts only."""
+    monkeypatch.setenv("KFT_PALLAS", mode)
+    layer, params, stats, x, live = _decode_layer()
+    L, k = x.shape[1], layer.cfg.experts_per_token
+    run = lambda x, *mask: layer.apply(  # noqa: E731
+        {"params": params, STATS: stats}, x, *mask, mutable=[STATS, "intermediates"])
+    want, plain = run(x)
+    got, masked = run(jnp.where(live[:, None, None], x, jnp.nan), live)
+    keep = np.asarray(live)
+    np.testing.assert_array_equal(np.asarray(got)[keep], np.asarray(want)[keep])
+    assert not np.asarray(got)[~keep].any()
+    assert np.isfinite(np.asarray(got)).all()
+    assert int(masked[STATS]["assignments"].sum()) == k * keep.sum() * L
+    assert int(plain[STATS]["assignments"].sum()) == k * len(keep) * L
+    # the experts the live rows chose in the unmasked call, and no other
+    chosen = np.asarray(plain["intermediates"]["moe_experts"][0])[keep]
+    np.testing.assert_array_equal(
+        np.asarray(masked[STATS]["assignments"]),
+        np.bincount(chosen.reshape(-1), minlength=layer.cfg.n_experts))
+    assert int(masked[STATS]["experts_hit"]) == len(np.unique(chosen))
+    assert int(masked[STATS]["calls"]) == 1
+
+
+@pytest.mark.parametrize("live_rows", [(True,) * 4, (False,) * 4],
+                         ids=["every_row_live", "no_row_live"])
+def test_mask_at_its_two_ends(live_rows):
+    """Every row live reproduces the call without a mask, counters too; no
+    row live reads nothing and returns zeros."""
+    layer, params, stats, x, live = _decode_layer(live_rows)
+    run = lambda *mask: layer.apply(  # noqa: E731
+        {"params": params, STATS: stats}, x, *mask, mutable=[STATS])
+    (want, plain), (got, masked) = run(), run(live)
+    if all(live_rows):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        for name in ("assignments", "experts_hit", "calls"):
+            np.testing.assert_array_equal(np.asarray(masked[STATS][name]),
+                                          np.asarray(plain[STATS][name]))
+    else:
+        assert not np.asarray(got).any()
+        assert int(masked[STATS]["assignments"].sum()) == 0
+        assert int(masked[STATS]["experts_hit"]) == 0
+
+
+@pytest.mark.parametrize("leftover", [False, True],
+                         ids=["sizes_sum_to_m", "rows_of_no_group"])
+def test_gmm_kernel_lowers_for_tpu_at_published_widths(leftover):
     """A decode step's 64 rows and a prefill's 2,048 over 64 experts of
     [2048, 1024] float32: the kernel lowers to a Mosaic call named
-    `kft_moe_gmm` (jax.export, no chip)."""
+    `kft_moe_gmm` (jax.export, no chip), with and without the select that
+    zeroes the rows of no group."""
     from kungfu_tpu.ops.gmm import KERNEL_NAME
 
     for m in (64, 2048):
         exp = jax.export.export(
-            jax.jit(lambda a, b, g: grouped_matmul(a, b, g, jnp.float32,
-                                                   interpret=False)),
+            jax.jit(lambda a, b, g: grouped_matmul(
+                a, b, g, jnp.float32, interpret=False, leftover=leftover)),
             platforms=["tpu"])(
             jax.ShapeDtypeStruct((m, 2048), jnp.bfloat16),
             jax.ShapeDtypeStruct((64, 2048, 1024), jnp.float32),

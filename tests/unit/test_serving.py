@@ -16,6 +16,7 @@ drills — a serving rank killed mid-stream (monolithic and per-tier), zero
 dropped requests, buddy-weight rejoin, scale-down/up commits.
 """
 import dataclasses
+import functools
 import threading
 import time
 
@@ -230,6 +231,40 @@ class TestEngine:
             ref = np.asarray(
                 generate(cfg, params, jnp.asarray(p)[None], 8))[0]
             np.testing.assert_array_equal(np.asarray(pd.result.tokens), ref)
+
+    @pytest.mark.parametrize("draft", [False, True],
+                             ids=["plain", "speculative"])
+    def test_a_slot_free_for_longer_than_the_cache_stays_at_zero(
+            self, model_and_params, draft):
+        """Slot 1 serves one short request and is then free for 86 steps
+        of slot 0, more than `max_len` 48: its cursor does not ride along
+        (it would pass `max_len` and raise its overflow flag), on the
+        device or on the host, and slot 0's tokens are `generate()`'s."""
+        cfg, _, params = model_and_params
+        spec = SpecDecoder(cfg, params, slots=2, k=4,
+                           prefill_buckets=(8,)) if draft else None
+        eng = ServingEngine(cfg, params, slots=2, prefill_buckets=(8,),
+                            spec=spec)
+
+        def serve(*requests):
+            pend = [eng.submit(Request(prompt=p, max_new_tokens=new))
+                    for p, new in requests]
+            while eng.queue.depth() or eng.slot_mgr.active_count:
+                eng.step()
+                _assert_free_slots_stand_still(eng)
+            for (p, new), pd in zip(requests, pend):
+                ref = np.asarray(generate(cfg, params, jnp.asarray(p)[None],
+                                          new))[0]
+                np.testing.assert_array_equal(np.asarray(pd.result.tokens),
+                                              ref)
+
+        serve(((1, 2, 3), 3), ((4, 5), 2))     # both slots have been used
+        before = eng.decode_rows()["free"]
+        serve(((7, 8, 9, 10), 44))             # slot 0 to its last row ...
+        serve(((11, 12, 13, 14), 44))          # ... twice; slot 1 looks on
+        if not draft:
+            assert eng.decode_rows()["free"] - before == 2 * 43 > cfg.max_len
+        assert eng.decode_attn_rows()["written_free"] == 0
 
     def test_warm_resume_matches_uninterrupted(self, model_and_params):
         """prior_tokens (the re-queue warm path) must continue the stream
@@ -495,43 +530,90 @@ class TestStepPrograms:
 # -- the decode-step attention the engine's programs are built with ---------------------
 
 
-def _staggered_run_with_a_preemption(monkeypatch, pallas):
+def _slot_leaves(eng, name):
+    """The `name` leaf ("idx", "overflowed") of every layer of the slot cache."""
+    return [np.asarray(leaf) for path, leaf
+            in jax.tree_util.tree_leaves_with_path(eng.cache)
+            if getattr(path[-1], "key", None) == name]
+
+
+def _assert_free_slots_stand_still(eng):
+    """Between two steps: the device's cursors are the host's mirror, and a
+    slot that holds no request has token FREE, cursor 0 and a clear flag."""
+    busy = np.zeros(eng.n_slots, bool)
+    busy[list(eng.slot_mgr.active())] = True
+    np.testing.assert_array_equal(eng._next_tok >= 0, busy)
+    for idx in _slot_leaves(eng, "idx"):
+        np.testing.assert_array_equal(idx, eng._cursor)
+    assert not eng._cursor[~busy].any()
+    for flag in _slot_leaves(eng, "overflowed"):
+        assert not flag[~busy].any()
+
+
+def _staggered_run_with_a_preemption(monkeypatch, pallas, draft=False):
     """Five requests over two slots of a model whose cache the decode
     kernel takes (8 KV heads x 128, float32; two blocks of 256 rows):
     admissions between decode steps, one priority preemption, prompts on
-    both sides of the first block's end.  -> (tokens of each, the engine)"""
+    both sides of the first block's end, a slot left free while the other
+    decodes on; with `draft`, speculative rounds (the model its own draft).
+    Free slots are looked at after every step.
+    -> (requests, tokens of each, the engine, its counters)"""
+    from kungfu_tpu.monitor.counters import Counters
     from kungfu_tpu.serving.tenancy import TenantRegistry, TenantSpec
 
     monkeypatch.setenv("KFT_PALLAS", pallas)
-    cfg = _cfg(d_model=1024, n_heads=8, n_kv_heads=8, d_ff=32, max_len=512)
-    params = nn.meta.unbox(TransformerLM(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"])
+    cfg, params = _kernel_sized_model()
     reg = TenantRegistry(specs={
         "bulk": TenantSpec(name="bulk", priority=0),
         "gold": TenantSpec(name="gold", priority=2)})
+    counters = Counters()
+    spec = SpecDecoder(cfg, params, slots=2, k=4,
+                       prefill_buckets=(8, 16, 512)) if draft else None
     eng = ServingEngine(cfg, params, slots=2, prefill_buckets=(8, 16, 512),
-                        prefix_cache=PrefixCache(1 << 24), tenants=reg)
+                        prefix_cache=PrefixCache(1 << 24), tenants=reg,
+                        spec=spec, counters=counters)
     rs = np.random.RandomState(3)
     prompt = lambda n: tuple(int(t) for t in rs.randint(1, 64, (n,)))  # noqa: E731
-    pend = [eng.submit(Request(prompt=prompt(5), max_new_tokens=12,
-                               tenant="bulk"))]
-    eng.step()
-    pend.append(eng.submit(Request(prompt=prompt(250), max_new_tokens=10,
-                                   tenant="bulk")))
-    for _ in range(3):
-        eng.step()
-    pend.append(eng.submit(Request(prompt=prompt(7), max_new_tokens=5,
-                                   tenant="gold")))  # evicts a bulk request
-    pend.append(eng.submit(Request(prompt=prompt(12), max_new_tokens=6,
-                                   tenant="bulk")))
-    for _ in range(4):
-        eng.step()
-    pend.append(eng.submit(Request(prompt=prompt(3), max_new_tokens=4,
-                                   tenant="bulk")))
-    eng.run_until_idle()
+    reqs = []
+
+    def submit(n, new, tenant="bulk"):
+        reqs.append(Request(prompt=prompt(n), max_new_tokens=new, tenant=tenant))
+        return eng.submit(reqs[-1])
+
+    def step(times=1):
+        for _ in range(times):
+            eng.step()
+            _assert_free_slots_stand_still(eng)
+
+    pend = [submit(5, 12)]
+    step()
+    pend.append(submit(250, 10))
+    step(1 if draft else 3)  # a round commits up to 4 tokens: both still busy
+    pend.append(submit(7, 5, "gold"))  # evicts a bulk request
+    pend.append(submit(12, 6))
+    step(4)
+    pend.append(submit(3, 4))
+    while eng.queue.depth() or eng.slot_mgr.active_count:
+        step()
     assert eng.preemptions == 1
     assert all(p.result.status == "ok" for p in pend)
-    return [tuple(p.result.tokens) for p in pend], eng
+    return reqs, [tuple(p.result.tokens) for p in pend], eng, counters
+
+
+@functools.lru_cache(maxsize=1)
+def _kernel_sized_model():
+    cfg = _cfg(d_model=1024, n_heads=8, n_kv_heads=8, d_ff=32, max_len=512)
+    return cfg, nn.meta.unbox(TransformerLM(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"])
+
+
+@functools.lru_cache(maxsize=None)
+def _generated_alone(prompt, new):
+    """What `generate()` returns for one request of the staggered run, by
+    itself through the dense einsum (asked once a request, not once a case)."""
+    cfg, params = _kernel_sized_model()
+    return tuple(int(t) for t in np.asarray(
+        generate(cfg, params, jnp.asarray(prompt)[None], new))[0])
 
 
 def test_engine_tokens_and_row_counts_with_the_kernel_and_with_the_einsum(
@@ -540,25 +622,56 @@ def test_engine_tokens_and_row_counts_with_the_kernel_and_with_the_einsum(
     kernel (its body in the Pallas interpreter) as with the dense einsum
     forced, and `decode_attn_rows` says which of the two its decode
     program was built with."""
-    got, kernel = _staggered_run_with_a_preemption(monkeypatch, "interpret")
-    want, einsum = _staggered_run_with_a_preemption(monkeypatch, "off")
+    _, got, kernel, _ = _staggered_run_with_a_preemption(monkeypatch, "interpret")
+    _, want, einsum, _ = _staggered_run_with_a_preemption(monkeypatch, "off")
     assert got == want
     k, e = kernel.stats()["decode_attn_rows"], einsum.decode_attn_rows()
     for rows in (k, e):
         assert 0 < rows["written"] <= rows["fetched"] <= rows["cache"]
         assert 0 < rows["fetched_free"] <= rows["fetched"]
-        # a free slot's cursor rides along: its rows are nobody's
-        assert 0 < rows["written_free"] < rows["written"]
-        assert rows["written_free"] <= rows["fetched_free"]
+        # a free slot's cursor stays at 0: no row stands written under one
+        assert rows["written_free"] == 0
     assert e["fetched"] == e["cache"]          # the einsum reads every row
     assert k["fetched"] < k["cache"]           # the kernel the live blocks
     # the same steps on both sides: the cursors do not depend on the path
     assert [k[kind] for kind in ("cache", "written", "written_free")] == [
         e[kind] for kind in ("cache", "written", "written_free")]
+    assert kernel.decode_rows() == einsum.decode_rows()
     assert k["cache"] % (2 * 512) == 0
     # every step fetched one or two blocks of 256 rows a slot
     assert k["fetched"] % 256 == 0
     assert k["cache"] // 2 <= k["fetched"]
+
+
+@pytest.mark.parametrize("draft", [False, True], ids=["plain", "speculative"])
+@pytest.mark.parametrize("pallas", ["off", "interpret"])
+def test_a_free_slot_does_no_work_and_busy_slots_serve_generates_tokens(
+        monkeypatch, pallas, draft):
+    """Staggered admissions, releases and a preemption, with and without a
+    speculative draft: after every step the device's cursors equal the
+    host's, a free slot's is 0 and its overflow flag clear (looked at
+    inside the run); no row stands written under a free slot, a free slot's
+    step reads one block, every slot-step is counted live or free; and each
+    request's tokens are its own `generate()`'s, token for token."""
+    reqs, got, eng, counters = _staggered_run_with_a_preemption(
+        monkeypatch, pallas, draft)
+    monkeypatch.setenv("KFT_PALLAS", "off")
+    assert got == [_generated_alone(r.prompt, r.max_new_tokens) for r in reqs]
+    steps = counters.hist_summaries()["tok_latency_ms"][""]["count"]
+    rows, kinds = eng.decode_attn_rows(), eng.stats()["decode_rows"]
+    assert kinds["live"] + kinds["free"] == 2 * steps
+    assert 0 < kinds["free"] < kinds["live"]
+    assert rows["cache"] == steps * 2 * 512
+    assert rows["written_free"] == 0
+    block = 256 if pallas == "interpret" else 512  # the einsum: the whole slot
+    assert rows["fetched_free"] == kinds["free"] * block
+    if draft:
+        assert eng.spec.rounds > 0
+    else:
+        # one token a live slot-step; each of the six admissions (five
+        # requests, the evicted one twice) brought one from its prefill
+        assert kinds["live"] == sum(
+            len(t) - len(r.prompt) for t, r in zip(got, reqs)) - 5 - 1
 
 
 # -- radix prefix cache ----------------------------------------------------------------
@@ -1604,9 +1717,10 @@ def test_worker_reports_resident_bytes_by_dtype(monkeypatch):
 
 def test_worker_reports_decode_attn_rows(monkeypatch):
     """`kft_serve_decode_attn_rows_total{kind=...}` on /metrics (a counter)
-    is the engine's `decode_attn_rows`, which a profile capture reads at
-    both ends through the same source; a speculative round counts its k
-    query rows a slot like a decode step its one."""
+    is the engine's `decode_attn_rows`, and `kft_serve_decode_rows_total`
+    its `decode_rows`, which a profile capture reads at both ends through
+    the same source; a speculative round counts its k query rows a slot
+    like a decode step its one."""
     worker = _worker(monkeypatch, "", spec_draft="same", slots=1)
     eng = worker.engine
     assert set(eng.decode_attn_rows().values()) == {0}
@@ -1625,8 +1739,15 @@ def test_worker_reports_decode_attn_rows(monkeypatch):
     assert "# TYPE kft_serve_decode_attn_rows_total counter" in text
     for kind, n in rows.items():
         assert f'kft_serve_decode_attn_rows_total{{kind="{kind}"}} {n}' in text
-    assert worker.counters.source_families()[
-        "kft_serve_decode_attn_rows_total"]['kind="cache"'] == rows["cache"]
+    families = worker.counters.source_families()
+    assert families["kft_serve_decode_attn_rows_total"]['kind="cache"'] \
+        == rows["cache"]
+    # slot-steps by what the slot held: the same source, /metrics and stats()
+    assert eng.stats()["decode_rows"] == {"live": steps, "free": 0}
+    assert "# TYPE kft_serve_decode_rows_total counter" in text
+    assert f'kft_serve_decode_rows_total{{kind="live"}} {steps}' in text
+    assert families["kft_serve_decode_rows_total"] == {
+        'kind="live"': steps, 'kind="free"': 0}
 
 
 # -- program observatory regression ----------------------------------------------------
