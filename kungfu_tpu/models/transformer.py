@@ -26,7 +26,13 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from ..ops.chunked_ce import chunked_lm_head_ll
-from ..ops.decode_attn import decode_attention, decode_attention_reference
+from ..ops.decode_attn import (
+    MAX_QUERY_ROWS,
+    decode_attention,
+    decode_attention_reference,
+    mla_decode_attention,
+    mla_decode_attention_reference,
+)
 from ..parallel.sharding import logical_constraint
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -132,6 +138,53 @@ class TransformerConfig:
     # token leads and routing changes with it, as in a trained model
     # (PERF.md section 6, PR 25).  A checkpoint overwrites it
     embed_init_std: float = 0.02
+    # standard deviation the untied head is initialised with.  Seeded
+    # logits have the standard deviation head_init_std x sqrt(d_model)
+    # (the final norm leaves unit RMS), so a wider model on stand-in
+    # weights keeps its logits' scale with a smaller value.  A checkpoint
+    # overwrites it
+    head_init_std: float = 0.02
+    # latent attention (MLA), chosen by `kv_lora_rank` > 0: queries through
+    # a `q_lora_rank` bottleneck, keys and values through ONE shared latent
+    # of `kv_lora_rank` numbers a token plus `qk_rope_head_dim` rotary
+    # numbers all heads share; a head's key is [qk_nope_head_dim from the
+    # latent | the shared rotary part], its value v_head_dim from the
+    # latent.  `n_heads` are the heads held here (a deployment's tensor-
+    # parallel share: the output projection's partial sum goes on), none of
+    # the head sizes derives from d_model.  `mla_scale_*_lora` are the
+    # published switches: the query times (d_model / q_lora_rank)^0.5, the
+    # normed latent times (d_model / kv_lora_rank)^0.5.  In decode mode the
+    # cache is one [B, max_len, kv_lora_rank + qk_rope_head_dim] leaf a
+    # sublayer (`MLA` below)
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
+    # the block: "standard" (attention, then FFN or experts) or
+    # "shortcut_moe" (`ShortcutMoEBlock`: two attention and two dense FFN
+    # sublayers, the expert branch leaving after the first attention and
+    # rejoining after the second FFN)
+    block: str = "standard"
+    # the experts' width when it is not the dense FFN's (0 = d_ff)
+    d_ff_expert: int = 0
+    # zero-compute (identity) experts: the router is n_experts +
+    # n_zero_experts wide and an assignment to one of the last
+    # n_zero_experts returns its gate weight times the token itself
+    n_zero_experts: int = 0
+    # every gate weight times this (after the choice, no renormalisation)
+    routed_scaling_factor: float = 1.0
+    # choice by p + b, gate by p: a [router width] bias, initialised 0
+    router_bias: bool = False
+    # the share of the routed experts this process holds: `experts_held`
+    # consecutive experts from `expert_offset` (0 held = all n_experts).
+    # The router keeps its width and its experts per token; an assignment
+    # to a routed expert that is not held is another chip's, and its part
+    # of the result is left out here (parallel/moe.py)
+    experts_held: int = 0
+    expert_offset: int = 0
     # mesh is needed for attention="ring"/"ulysses" (shard_map region)
     mesh: Optional[Mesh] = None
     sp_axis: str = "sp"
@@ -178,7 +231,8 @@ class TransformerConfig:
             )
         assert self.ffn in ("gelu", "swiglu"), self.ffn
         if self.n_experts:
-            assert 1 <= self.experts_per_token <= self.n_experts, (
+            assert 1 <= self.experts_per_token <= (
+                self.n_experts + self.n_zero_experts), (
                 "experts per token must lie in 1..n_experts"
             )
         assert self.norm in ("layer", "rms"), self.norm
@@ -190,10 +244,44 @@ class TransformerConfig:
         assert self.kv_cache_dtype in ("model", "int8"), self.kv_cache_dtype
         if self.decode:
             assert self.head == "dense", "decode/generation needs logits"
+        assert self.block in ("standard", "shortcut_moe"), self.block
+        if self.block == "shortcut_moe":
+            assert self.n_experts > 0, "the shortcut branch is an expert layer"
+        # the one model that has either has both: no block of another pairing
+        # is built or tested
+        assert (self.block == "shortcut_moe") == bool(self.kv_lora_rank), (
+            "latent attention comes with the shortcut block, and only with it")
+        if self.kv_lora_rank:
+            assert (self.q_lora_rank > 0 and self.qk_nope_head_dim > 0
+                    and self.v_head_dim > 0), "latent attention needs its sizes"
+            assert self.rope and self.qk_rope_head_dim > 0 \
+                and self.qk_rope_head_dim % 2 == 0, (
+                    "latent attention carries positions in its rotary part")
+            assert self.causal and not self.window and not self.qk_norm \
+                and not self.attention_bias and not self.n_kv_heads \
+                and self.kv_cache_dtype == "model" \
+                and self.attention in ("auto", "full"), (
+                    "latent attention: causal, unwindowed, einsum or kernel")
+        if self.experts_held or self.expert_offset:
+            assert 0 < self.experts_held and \
+                self.expert_offset + self.experts_held <= self.n_experts, (
+                    "the experts held lie among the routed experts")
+        if self.n_zero_experts or self.router_bias or self.experts_held \
+                or self.routed_scaling_factor != 1.0:
+            assert self.n_experts > 0, "router options need experts"
 
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def expert_width(self) -> int:
+        return self.d_ff_expert or self.d_ff
+
+    @property
+    def local_experts(self) -> int:
+        """Routed experts whose weights this process holds."""
+        return self.experts_held or self.n_experts
 
 
 def _attention_kind(cfg: TransformerConfig) -> str:
@@ -497,6 +585,151 @@ class Attention(nn.Module):
         return _dense(cfg.d_model, "out", ("heads", "embed"), cfg.dtype)(o)
 
 
+def _masked_attention(q, k, v, q_pos):
+    """softmax(q k^T / sqrt(Dk)) v under the causal mask, materialised:
+    q [B, L, H, Dk] at positions q_pos [B or 1, L] against k [B, M, H, Dk],
+    v [B, M, H, Dv] at positions 0..M-1.  Key and value widths may differ.
+    Scores, softmax and accumulation in float32."""
+    s = jnp.einsum("blhd,bmhd->bhlm", q, k,
+                   preferred_element_type=jnp.float32) * (q.shape[-1] ** -0.5)
+    valid = jnp.arange(k.shape[1]) <= q_pos[..., None]      # [B or 1, L, M]
+    p = jax.nn.softmax(jnp.where(valid[:, None], s, -1e30), axis=-1)
+    return jnp.einsum("bhlm,bmhd->blhd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)
+
+
+class MLA(nn.Module):
+    """Latent attention (`cfg.kv_lora_rank` > 0), u the normed input:
+
+        c_q = N_q(u W_qa)                     q = s_q c_q W_qb -> per head [q_nope | q_rope]
+        [c | k_r] = u W_kva                   c = s_kv N_kv(c)
+        q_rope, k_r rotated (all heads share k_r)
+        [k_nope | v] = c W_kvb per head       score = (q_nope k_nope + q_rope k_r) / sqrt(Dn + Dr)
+        out = (softmax(score) v) W_o          s_q, s_kv: `mla_scale_*_lora`
+
+    Two formulations of the same sums.  Materialised (training, a whole
+    sequence, and a prefill bucket in decode mode): k_nope and v are built
+    for every row attended and the attention is the masked einsum with
+    Dn + Dr wide keys and Dv wide values; compute-bound.  Absorbed (a
+    decode or verify step, at most `ops.decode_attn.MAX_QUERY_ROWS` rows a
+    slot): W_kvb's key half moves onto the query, q_lat = q_nope W_uk^T
+    [rank], the score is (q_lat c + q_rope k_r) / sqrt(Dn + Dr) against
+    the cached row itself, the value sum o_lat = sum p c is taken over the
+    same rows, and W_kvb's value half is applied after: out_head = o_lat
+    W_uv.  No K or V of a cached row is ever built.
+
+    The decode-mode cache is ONE leaf `cached_latent`
+    [B, max_len, rank + Dr] in cfg.dtype: c after its norm and scale, then
+    k_r after its rotation, beside the per-slot cursors `idx` and flags
+    `overflowed` that `Attention` keeps (same contract: `live`, overflow
+    poisoning, verify-k)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, live=None):
+        cfg = self.cfg
+        H, r = cfg.n_heads, cfg.kv_lora_rank
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        B, L, _ = x.shape
+        with jax.named_scope("mla"):
+            c_q = _dense(cfg.q_lora_rank, "q_a", ("embed", None), cfg.dtype)(x)
+            c_q = _norm(cfg, "q_a_norm")(c_q).astype(cfg.dtype)
+            q = _dense(H * (dn + dr), "q_b", (None, "heads"), cfg.dtype)(c_q)
+            if cfg.mla_scale_q_lora:
+                q = q * jnp.asarray(
+                    (cfg.d_model / cfg.q_lora_rank) ** 0.5, q.dtype)
+            q = q.reshape(B, L, H, dn + dr)
+            kv_a = _dense(r + dr, "kv_a", ("embed", None), cfg.dtype)(x)
+            c = _norm(cfg, "kv_a_norm")(kv_a[..., :r])          # float32
+            if cfg.mla_scale_kv_lora:
+                c = c * (cfg.d_model / r) ** 0.5
+            c = c.astype(cfg.dtype)
+            kv_b = _dense(H * (dn + dv), "kv_b", (None, "heads"), cfg.dtype)
+            out = _dense(cfg.d_model, "out", ("heads", "embed"), cfg.dtype)
+
+            def materialise(latent):
+                """[k_nope | v] of every head for latent rows [B, M, r]."""
+                kv = kv_b(latent).reshape(B, latent.shape[1], H, dn + dv)
+                return kv[..., :dn], kv[..., dn:]
+
+            def with_shared(k_nope, k_rot):
+                """[B, M, H, Dn + Dr] keys: every head's own part beside
+                the rotary part they share."""
+                return jnp.concatenate(
+                    [k_nope, jnp.broadcast_to(
+                        k_rot, k_nope.shape[:3] + (dr,))], axis=-1)
+
+            if cfg.decode:
+                cache = self.variable("cache", "cached_latent", jnp.zeros,
+                                      (B, cfg.max_len, r + dr), cfg.dtype)
+                cache_idx = self.variable(
+                    "cache", "idx", lambda: jnp.zeros((B,), jnp.int32))
+                cache_ovf = self.variable(
+                    "cache", "overflowed", lambda: jnp.zeros((B,), jnp.bool_))
+                idx0 = cache_idx.value                          # [B]
+                pos = idx0[:, None] + jnp.arange(L)[None, :]    # [B, L]
+            else:
+                pos = jnp.arange(L)[None, :]
+            k_rot = apply_rope(kv_a[..., None, r:], pos, cfg.rope_theta)
+            queries = jnp.concatenate(
+                [q[..., :dn], apply_rope(q[..., dn:], pos, cfg.rope_theta)],
+                axis=-1)
+            if not cfg.decode:
+                k_nope, v = materialise(c)
+                o = _masked_attention(
+                    queries, with_shared(k_nope, k_rot), v, pos)
+                return out(o.astype(cfg.dtype).reshape(B, L, H * dv))
+
+            if self.is_initializing():
+                # init() traces one token: make the projection the absorbed
+                # form reads as a matrix, write nothing, move no cursor
+                materialise(c)
+            else:
+                rows = jnp.concatenate([c, k_rot[:, :, 0]], axis=-1)
+                cache.value = jax.vmap(
+                    lambda m, u, i: jax.lax.dynamic_update_slice(m, u, (i, 0))
+                )(cache.value, rows.astype(cache.value.dtype), idx0)
+                step = L if live is None else jnp.where(live, L, 0)
+                cache_idx.value = idx0 + step
+                cache_ovf.value = jnp.logical_or(
+                    cache_ovf.value, idx0 + step > cfg.max_len)
+            stored = cache.value
+            if L > MAX_QUERY_ROWS:
+                # a prefill bucket: K and V of the slot's rows, the
+                # prompt's own and a warm prefix's alike, built from the
+                # stored latent (what every later step will read)
+                k_nope, v = materialise(stored[..., :r])
+                o = _masked_attention(
+                    queries, with_shared(k_nope, stored[:, :, None, r:]), v,
+                    pos)
+            else:
+                with jax.named_scope("mla.absorb"):
+                    w = nn.meta.unbox(kv_b.variables["params"]["kernel"])
+                    w = w.astype(cfg.dtype).reshape(r, H, dn + dv)
+                    q_lat = jnp.einsum(
+                        "blhd,rhd->blhr", queries[..., :dn], w[..., :dn],
+                        preferred_element_type=jnp.float32)
+                    q_abs = jnp.concatenate(
+                        [q_lat.astype(cfg.dtype), queries[..., dn:]], axis=-1)
+                    scale = (dn + dr) ** -0.5
+                    if cfg.attention == "full" or cfg.mesh is not None:
+                        o_lat = mla_decode_attention_reference(
+                            q_abs, stored, pos, r, scale)
+                    else:
+                        o_lat = mla_decode_attention(
+                            q_abs, stored, pos, r, scale)
+                    o = jnp.einsum(
+                        "blhr,rhd->blhd", o_lat.astype(cfg.dtype),
+                        w[..., dn:], preferred_element_type=jnp.float32)
+            # overflow is loud and contained to its slot, as in `Attention`
+            poison = jnp.logical_or(
+                (pos >= cfg.max_len)[:, :, None, None],
+                cache_ovf.value[:, None, None, None])
+            o = jnp.where(poison, jnp.nan, o)
+            return out(o.astype(cfg.dtype).reshape(B, L, H * dv))
+
+
 class MLP(nn.Module):
     cfg: TransformerConfig
 
@@ -546,6 +779,36 @@ class Block(nn.Module):
         return logical_constraint(x, ("batch", "seq", "act_embed"), cfg.mesh)
 
 
+class ShortcutMoEBlock(nn.Module):
+    """`cfg.block == "shortcut_moe"`: two latent-attention and two dense FFN
+    sublayers, and an expert branch that leaves after the first attention
+    and rejoins after the second FFN (so a deployment can overlap its
+    exchange with the dense work between):
+
+        x1 = x + A0(N(x))      h = N(x1)      s = MoE(h)
+        x2 = x1 + F0(h)        x3 = x2 + A1(N(x2))
+        x4 = x3 + F1(N(x3)) + s
+    """
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, train: bool = False, live=None):
+        from ..parallel.moe import MoE
+
+        cfg = self.cfg
+        ln = partial(_norm, cfg)
+        drop = nn.Dropout(cfg.dropout, deterministic=not train)
+        x = x + drop(MLA(cfg, name="attn_0")(ln(name="ln_attn_0")(x), live))
+        h = ln(name="ln_ffn_0")(x)
+        shortcut = MoE(cfg, name="moe")(h, live)
+        x = x + drop(MLP(cfg, name="mlp_0")(h))
+        x = x + drop(MLA(cfg, name="attn_1")(ln(name="ln_attn_1")(x), live))
+        x = x + drop(MLP(cfg, name="mlp_1")(ln(name="ln_ffn_1")(x))) \
+            + drop(shortcut)
+        return logical_constraint(x, ("batch", "seq", "act_embed"), cfg.mesh)
+
+
 class _Head(nn.Module):
     """lm_head projection with a use-site-gathered kernel.
 
@@ -567,7 +830,8 @@ class _Head(nn.Module):
         w = self.param(
             "kernel",
             nn.with_logical_partitioning(
-                nn.initializers.normal(stddev=0.02), ("embed", "vocab")
+                nn.initializers.normal(stddev=cfg.head_init_std),
+                ("embed", "vocab")
             ),
             (cfg.d_model, cfg.vocab_size),
             jnp.float32,
@@ -644,17 +908,21 @@ class TransformerLM(nn.Module):
         # recomputes only the elementwise tail — the tuner's middle
         # ground).  Stable block_{i} names keep the param tree identical
         # across the flags.
+        shortcut = cfg.block == "shortcut_moe"
+        block_cls = ShortcutMoEBlock if shortcut else Block
         if cfg.remat:
             remat_kw = {}
             if cfg.remat_policy == "dots":
                 remat_kw["policy"] = jax.checkpoint_policies.dots_saveable
-            block_cls = nn.remat(Block, static_argnums=(2,), **remat_kw)
-        else:
-            block_cls = Block
+            block_cls = nn.remat(block_cls, static_argnums=(2,), **remat_kw)
         for i in range(cfg.n_layers):
-            use_moe = cfg.n_experts > 0 and (i % cfg.moe_every == cfg.moe_every - 1)
-            x = block_cls(cfg, use_moe=use_moe, name=f"block_{i}")(
-                x, train, live)
+            if shortcut:  # every block carries the expert branch
+                block = block_cls(cfg, name=f"block_{i}")
+            else:
+                use_moe = cfg.n_experts > 0 and (
+                    i % cfg.moe_every == cfg.moe_every - 1)
+                block = block_cls(cfg, use_moe=use_moe, name=f"block_{i}")
+            x = block(x, train, live)
         x = _norm(cfg, "ln_f")(x)
         if cfg.head == "hidden":
             # deferred head: the streaming loss (lm_loss_chunked) consumes

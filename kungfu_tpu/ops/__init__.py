@@ -37,7 +37,9 @@ from .fused_matmul import (
 
 # Decode-step attention over the slot cache (ops/decode_attn.py): the
 # length-aware kernel on TPU, the dense einsum everywhere else.
-from .decode_attn import decode_attention, decode_attention_reference
+from .decode_attn import (decode_attention, decode_attention_reference,
+                          mla_decode_attention,
+                          mla_decode_attention_reference)
 
 __all__ = [
     "all_reduce", "psum_all_reduce", "rs_ag_all_reduce", "ring_all_reduce",

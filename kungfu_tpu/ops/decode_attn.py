@@ -33,6 +33,20 @@ zero there, multiply the V block the same way.  That spends Hkv times the
 multiplications the scores need, on a step whose matmul unit is idle
 (16 query rows), and never moves a cache block out of the layout it is
 stored in.
+
+Latent attention (MLA, models/transformer.py `MLA`) keeps ONE row a token:
+`cache` [B, max_len, rank + rope] holds the normed latent c and, after it,
+the rotary key part all heads share.  In the absorbed form a query head is
+q [rank + rope] = [q_nope W_uk^T | q_rope], its score against a row is one
+dot product over the whole row, and its value is the row's first `rank`
+numbers: `mla_decode_attention(q [B, L, H, rank + rope], cache, q_pos,
+rank, scale)` -> float32 [B, L, H, rank], every head against the same
+rows, so a written row is read ONCE for scores and values alike and no
+per-head K or V exists.  `mla_decode_attention_reference` is the dense
+definition and the off-TPU path; the Mosaic kernel is `kft_mla_decode_attn`,
+the same grid, prefetch and online softmax over [block, rank + rope]
+blocks.  `kernel_block` answers for a three-dimensional leaf as it does
+for a four-dimensional one.
 """
 from __future__ import annotations
 
@@ -47,6 +61,10 @@ from .. import compat
 
 #: the kernel's name in a device trace (benchmark/layer_metrics/decode_attn_*)
 KERNEL_NAME = "kft_decode_attn"
+
+#: the latent-attention kernel's name in a device trace
+#: (benchmark/layer_metrics/mla_decode_attn_*)
+MLA_KERNEL_NAME = "kft_mla_decode_attn"
 
 #: query rows a slot the kernel takes: a decode step's 1, a verify's k.
 #: The smallest prefill bucket is 16 (serving/engine.py `default_buckets`)
@@ -101,12 +119,20 @@ def kernel_block(query_rows: int, cache_shape, cache_dtype,
     dtype = jnp.dtype(cache_dtype)
     if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
         return None  # an int8 cache is read through its scales
-    _, max_len, kv_heads, head_dim = cache_shape
-    # a block is read as [block x Hkv, D] with no relayout only when the
-    # KV heads fill whole sublane tiles (8 rows of 32 bits) and D whole lanes
-    if (query_rows > MAX_QUERY_ROWS or head_dim % 128
-            or kv_heads % (32 // dtype.itemsize)):
-        return None
+    if len(cache_shape) == 3:
+        # a latent leaf [B, max_len, rank + rope]: a block is the matrix
+        # [block, rank + rope] as it lies, whatever its width
+        (_, max_len, head_dim), kv_heads = cache_shape, 1
+        if query_rows > MAX_QUERY_ROWS:
+            return None
+    else:
+        _, max_len, kv_heads, head_dim = cache_shape
+        # a block is read as [block x Hkv, D] with no relayout only when the
+        # KV heads fill whole sublane tiles (8 rows of 32 bits) and D whole
+        # lanes
+        if (query_rows > MAX_QUERY_ROWS or head_dim % 128
+                or kv_heads % (32 // dtype.itemsize)):
+            return None
     block = 1 << ((_BLOCK_BYTES // (kv_heads * head_dim * dtype.itemsize))
                   .bit_length() - 1)
     while block > 8 and (block > max_len or max_len % block):
@@ -248,3 +274,151 @@ def decode_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
         return decode_attention_reference(q, cache_k, cache_v, q_pos, window)
     return _attn_pallas(q, cache_k, cache_v, q_pos, window, block,
                         compat.pallas_mode(interpret) == "interpret")
+
+
+# -- latent attention: one shared row a token ------------------------------------------
+
+
+def mla_decode_attention_reference(q, cache, q_pos, rank: int, scale: float):
+    """The dense absorbed form: q [B, L, H, W] against every row of
+    cache [B, max_len, W] under the causal mask, values the rows' first
+    `rank` numbers.  Operands in the cache dtype, scores, softmax and
+    accumulation in float32, probabilities cast to the cache dtype before
+    the product over the values (as `decode_attention_reference`)."""
+    B, L, H, W = q.shape
+    max_len = cache.shape[1]
+    # the per-head reference's einsums with one KV head and H grouped
+    # queries: the same contraction, in the form every backend multiplies
+    rows = cache[:, :, None, :]                     # [B, max_len, 1, W]
+    s = jnp.einsum("blkgd,bmkd->bkglm",
+                   q.astype(cache.dtype).reshape(B, L, 1, H, W), rows,
+                   preferred_element_type=jnp.float32) * scale
+    valid = jnp.arange(max_len)[None, None, :] <= q_pos[:, :, None]
+    s = jnp.where(valid[:, None, None], s, _MASKED)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bkglm,bmkd->blkgd", p.astype(cache.dtype),
+                      rows[..., :rank], preferred_element_type=jnp.float32
+                      ).reshape(B, L, H, rank)
+
+
+def _mla_attn_pallas(q, cache, q_pos, rank: int, scale: float, block: int,
+                     interpret: bool):
+    """The kernel reads the cache feature-major, [B, W, max_len]: that is
+    how the chip lays a [B, max_len, W] array out when W fills no whole
+    lane tile (576 = 4.5 x 128: the compiler makes max_len the minor
+    dimension rather than pad every row to 640), so the `swapaxes` below
+    is a change of name and not of place, and a block [W, block] arrives
+    with its positions on the lanes: the scores are a plain product
+    q [R, W] x block, the values the same block contracted over its
+    lanes.  Handed to the kernel position-major, the whole leaf was copied
+    ahead of every call (150 MB a sublayer at the cell's shape)."""
+    B, L, H, W = q.shape
+    max_len = cache.shape[1]
+    assert cache.shape[2] == W and max_len % block == 0, (cache.shape, W, block)
+    R = L * H
+    q_pos = q_pos.astype(jnp.int32)
+    first, last = live_blocks(jnp, q_pos.min(axis=1), q_pos.max(axis=1),
+                              block, max_len)
+
+    def kernel(first, last, pos, q_ref, c_ref, o_ref, m_ref, l_ref, acc_ref):
+        b, j = pl.program_id(0), pl.program_id(1)
+
+        @pl.when(j == 0)
+        def _():
+            m_ref[...] = jnp.full_like(m_ref, _MASKED)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        blk = first[b] + j
+        lo = hi = pos[b, 0]  # the slot's lowest and highest query position
+        for l in range(1, L):
+            lo, hi = jnp.minimum(lo, pos[b, l]), jnp.maximum(hi, pos[b, l])
+        live = blk <= last[b]
+        # an inner block holds only rows every query of the slot attends
+        inner = (blk + 1) * block - 1 <= lo
+
+        def attend(edge: bool):
+            cols = c_ref[...]                       # [W, block]: a row a lane
+            if edge:
+                # row r of the scores is query (l, h) = (r // H, r % H)
+                row = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+                lane = jax.lax.broadcasted_iota(jnp.int32, (R, block), 1)
+                at = jnp.full((R, 1), pos[b, 0], jnp.int32)
+                for l in range(1, L):
+                    at = jnp.where(row >= l * H, pos[b, l], at)
+                valid = lane <= at - blk * block
+                # whatever lies beyond the cursor (the last request's rows,
+                # a prefill's padding, NaN) reads as 0: a row is key and
+                # value at once, and 0 x NaN is NaN
+                c = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+                cols = jnp.where(c <= hi - blk * block, cols,
+                                 jnp.zeros_like(cols))
+            s = jnp.dot(q_ref[...], cols,
+                        preferred_element_type=jnp.float32) * scale
+            if edge:
+                s = jnp.where(valid, s, _MASKED)
+            m_prev = m_ref[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            if edge:
+                p = jnp.where(valid, p, 0.0)
+            l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+            # the product over the whole row: its first `rank` numbers are
+            # the values, the rest is sliced off outside (the matmul unit
+            # is idle at 16 query rows; the block is never cut)
+            acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+                p.astype(cols.dtype), cols, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[...] = m_new
+
+        pl.when(jnp.logical_and(live, inner))(lambda: attend(False))
+        pl.when(jnp.logical_and(live, jnp.logical_not(inner)))(
+            lambda: attend(True))
+
+        @pl.when(j == pl.num_programs(1) - 1)
+        def _():
+            norm = l_ref[...]
+            o_ref[...] = acc_ref[...] / jnp.where(norm == 0.0, 1.0, norm)
+
+    def col_index(b, j, first, last, pos):
+        return b, 0, jnp.minimum(first[b] + j, last[b])
+
+    def q_index(b, j, first, last, pos):
+        return b, 0, 0
+
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((B, R, W), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, max_len // block),
+            in_specs=[
+                pl.BlockSpec((None, R, W), q_index),
+                pl.BlockSpec((None, W, block), col_index),
+            ],
+            out_specs=pl.BlockSpec((None, R, W), q_index),
+            scratch_shapes=[pltpu.VMEM((R, 1), jnp.float32),
+                            pltpu.VMEM((R, 1), jnp.float32),
+                            pltpu.VMEM((R, W), jnp.float32)],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=compat.vmem_budget_bytes()),
+        interpret=interpret,
+        name=MLA_KERNEL_NAME,
+    )(first, last, q_pos, q.astype(cache.dtype).reshape(B, R, W),
+      jnp.swapaxes(cache, 1, 2))
+    return out.reshape(B, L, H, W)[..., :rank]
+
+
+def mla_decode_attention(q: jax.Array, cache: jax.Array, q_pos: jax.Array,
+                         rank: int, scale: float, interpret=None) -> jax.Array:
+    """q [B, L, H, rank + rope] against the latent slot cache
+    [B, max_len, rank + rope] -> float32 [B, L, H, rank]: the kernel where
+    `kernel_block` says it takes the call, the reference einsum elsewhere."""
+    block = kernel_block(q.shape[1], cache.shape, cache.dtype, interpret)
+    if block is None:
+        return mla_decode_attention_reference(q, cache, q_pos, rank, scale)
+    return _mla_attn_pallas(q, cache, q_pos, rank, scale, block,
+                            compat.pallas_mode(interpret) == "interpret")

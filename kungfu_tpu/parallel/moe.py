@@ -33,12 +33,33 @@ P_e the mean router probability: k when balanced), `moe_router_z`,
 mean(logsumexp(router logits)^2), and `moe_experts` [B, L, k], the chosen
 experts (what the routing-flip measurement compares with the reference's).
 
+A share of the experts (`cfg.experts_held` of `cfg.n_experts` from
+`cfg.expert_offset`: one rank of an expert-parallel deployment), identity
+experts and a biased choice, each selected by its `TransformerConfig`
+field and absent from the program without it:
+
+    p      = softmax(u W_r)          over n_experts + n_zero_experts
+    top-k  = the k largest p + b     (`router_bias`; b starts at 0)
+    g_e    = routed_scaling_factor * p_e
+    MoE(u) = sum over chosen HELD e of g_e * expert_e(u)
+             + (sum over chosen e >= n_experts of g_e) * u
+
+The layer routes over the whole width.  An assignment to a routed expert
+that is not held here is another rank's: it takes the id of nobody, as a
+free row's does, so it owns no grouped-matmul row and reads no weight, and
+its part of the result is left out, here as in the deployment before the
+combine.  An identity expert's part is computed here in full (it needs no
+exchange anywhere) and owns no grouped-matmul row either.  The weights
+are [held, ...]; local id = e - expert_offset.
+
 Counted on the device in decode mode, in the "moe_stats" collection
 (declared only there, updated only when the caller makes it mutable: the
 serving engine's slot-cache programs): `assignments` [experts], rows routed
 to each expert; `experts_hit`, distinct experts that owned a row, summed
 over calls; `calls`.  Live rows only: a free slot's row is routed to
-nobody and counts nowhere.
+nobody and counts nowhere.  A layer with a share or with identity experts
+also counts `zero_assignments` and `absent_assignments`, so that held +
+zero + absent = k x live tokens.
 """
 from __future__ import annotations
 
@@ -54,10 +75,16 @@ from ..ops.gmm import grouped_matmul
 STATS = "moe_stats"
 
 
-def route(probs: jax.Array, k: int, renormalise: bool):
+def route(probs: jax.Array, k: int, renormalise: bool, bias=None):
     """(gates [T, k] float32, experts [T, k] int32) from probs [T, E].
-    `lax.top_k` is by value, and puts the lower index first on a tie."""
-    gates, experts = jax.lax.top_k(probs, k)
+    `lax.top_k` is by value, and puts the lower index first on a tie.
+    With `bias` [E] the choice is by probs + bias and the gates are the
+    chosen experts' probs themselves."""
+    if bias is None:
+        gates, experts = jax.lax.top_k(probs, k)
+    else:
+        _, experts = jax.lax.top_k(probs + bias, k)
+        gates = jnp.take_along_axis(probs, experts, axis=-1)
     if renormalise:
         gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
     return gates, experts.astype(jnp.int32)
@@ -70,7 +97,12 @@ class MoE(nn.Module):
     def __call__(self, x, live=None):
         cfg = self.cfg
         B, L, Dm = x.shape
-        E, k, width = cfg.n_experts, cfg.experts_per_token, cfg.d_ff
+        k, width = cfg.experts_per_token, cfg.expert_width
+        # E experts' weights are here; the router is `wide`: every routed
+        # expert, held or not, then the identity experts
+        E, routed = cfg.local_experts, cfg.n_experts
+        wide = routed + cfg.n_zero_experts
+        partial_share = E != routed or cfg.n_zero_experts > 0
         T = B * L
         init = nn.initializers.normal(stddev=0.02)
 
@@ -80,7 +112,11 @@ class MoE(nn.Module):
 
         router = self.param(
             "router", nn.with_logical_partitioning(init, ("embed", "expert")),
-            (Dm, E), jnp.float32)
+            (Dm, wide), jnp.float32)
+        bias = self.param(
+            "router_bias", nn.with_logical_partitioning(
+                nn.initializers.zeros, ("expert",)), (wide,), jnp.float32
+        ) if cfg.router_bias else None
         w_gate = expert_param("w_gate", (E, Dm, width), ("expert", "embed", "mlp"))
         w_up = expert_param("w_up", (E, Dm, width), ("expert", "embed", "mlp"))
         w_down = expert_param("w_down", (E, width, Dm), ("expert", "mlp", "embed"))
@@ -93,14 +129,30 @@ class MoE(nn.Module):
                 # different expert, a discrete change of the output
                 logits = jnp.dot(flat.astype(jnp.float32), router,
                                  precision=jax.lax.Precision.HIGHEST)
-                probs = jax.nn.softmax(logits, axis=-1)  # [T, E]
-                gates, experts = route(probs, k, cfg.norm_topk_prob)
+                probs = jax.nn.softmax(logits, axis=-1)  # [T, wide]
+                gates, experts = route(probs, k, cfg.norm_topk_prob, bias)
+                if cfg.routed_scaling_factor != 1.0:
+                    gates = gates * cfg.routed_scaling_factor
+                chosen = experts
                 if live is not None:
                     # a token of a row that is not live is nobody's: expert
                     # id E sorts last and is not counted, gate weight 0
                     mine = jnp.repeat(live, L)[:, None]               # [T, 1]
                     gates = jnp.where(mine, gates, 0.0)
                     experts = jnp.where(mine, experts, E)
+                if partial_share:
+                    # what is left of the choice here: the held experts by
+                    # their local id; an identity expert's weight on the
+                    # token itself; another rank's expert is nobody's
+                    local = chosen - cfg.expert_offset
+                    held = jnp.logical_and(local >= 0, local < E)
+                    zero = chosen >= routed
+                    if live is not None:
+                        held = jnp.logical_and(held, mine)
+                        zero = jnp.logical_and(zero, mine)
+                    zero_gate = jnp.sum(jnp.where(zero, gates, 0.0), axis=-1)
+                    gates = jnp.where(held, gates, 0.0)
+                    experts = jnp.where(held, local, E)
                 # the T*k assignments sorted by expert (stable: by token
                 # within an expert); row r of the grouped matmuls is token
                 # order[r] // k
@@ -109,7 +161,7 @@ class MoE(nn.Module):
             with jax.named_scope("moe.experts"):
                 gmm = partial(grouped_matmul, group_sizes=counts,
                               out_dtype=jnp.float32,
-                              leftover=live is not None)
+                              leftover=live is not None or partial_share)
                 rows = flat.astype(cfg.dtype)[order // k]             # [T*k, Dm]
                 gate = gmm(rows, w_gate)
                 up = gmm(rows, w_up)
@@ -117,24 +169,44 @@ class MoE(nn.Module):
                 y = gmm(h, w_down)                                    # [T*k, Dm]
                 unsort = jnp.argsort(order)
                 y = y[unsort].reshape(T, k, Dm)
-                out = jnp.einsum("tkd,tk->td", y, gates).astype(cfg.dtype)
+                out = jnp.einsum("tkd,tk->td", y, gates)
+                if not cfg.n_zero_experts:
+                    out = out.astype(cfg.dtype)
+            if cfg.n_zero_experts:
+                with jax.named_scope("moe.zero"):
+                    out = (out + zero_gate[:, None]
+                           * flat.astype(jnp.float32)).astype(cfg.dtype)
 
-        frac_tokens = counts.astype(jnp.float32) / T
-        self.sow("intermediates", "moe_aux_loss",
-                 E * jnp.sum(frac_tokens * jnp.mean(probs, axis=0)))
+        if not partial_share:
+            # the load-balancing loss is over all the experts: a share of
+            # them has no such loss of its own (and is not trained here)
+            frac_tokens = counts.astype(jnp.float32) / T
+            self.sow("intermediates", "moe_aux_loss",
+                     E * jnp.sum(frac_tokens * jnp.mean(probs, axis=0)))
         self.sow("intermediates", "moe_router_z",
                  jnp.mean(jax.scipy.special.logsumexp(logits, axis=-1) ** 2))
-        self.sow("intermediates", "moe_experts", experts.reshape(B, L, k))
+        self.sow("intermediates", "moe_experts",
+                 (chosen if partial_share else experts).reshape(B, L, k))
         if cfg.decode and (self.is_initializing()
                            or self.is_mutable_collection(STATS)):
-            zero = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
-            assignments = self.variable(STATS, "assignments", zero, E)
-            hit = self.variable(STATS, "experts_hit", zero)
-            calls = self.variable(STATS, "calls", zero)
+            zeros = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+            assignments = self.variable(STATS, "assignments", zeros, E)
+            hit = self.variable(STATS, "experts_hit", zeros)
+            calls = self.variable(STATS, "calls", zeros)
+            if partial_share:
+                to_zero = self.variable(STATS, "zero_assignments", zeros)
+                to_absent = self.variable(STATS, "absent_assignments", zeros)
             if not self.is_initializing():
                 assignments.value = assignments.value + counts
                 hit.value = hit.value + jnp.sum(counts > 0, dtype=jnp.int32)
                 calls.value = calls.value + 1
+                if partial_share:
+                    n_zero = jnp.sum(zero, dtype=jnp.int32)
+                    n_live = k * (T if live is None
+                                  else L * jnp.sum(live, dtype=jnp.int32))
+                    to_zero.value = to_zero.value + n_zero
+                    to_absent.value = to_absent.value + (
+                        n_live - n_zero - jnp.sum(counts, dtype=jnp.int32))
         return out.reshape(B, L, Dm)
 
 
@@ -148,11 +220,17 @@ def stats_totals(stats) -> dict:
                     for name, block in (stats or {}).items())
     if not layers:
         return None
-    return {
+    out = {
         "assignments": np.stack([np.asarray(m["assignments"]) for _, m in layers]),
         "experts_hit": int(sum(int(m["experts_hit"]) for _, m in layers)),
         "layer_calls": int(sum(int(m["calls"]) for _, m in layers)),
     }
+    # a layer that holds a share of its experts, or has identity experts
+    for kind in ("zero", "absent"):
+        if all(f"{kind}_assignments" in m for _, m in layers):
+            out[f"{kind}_assignments"] = int(sum(
+                int(m[f"{kind}_assignments"]) for _, m in layers))
+    return out
 
 
 def stats_health(stats):
@@ -160,10 +238,14 @@ def stats_health(stats):
     t = stats_totals(stats)
     if t is None:
         return None
-    return {"assignments_total": int(t["assignments"].sum()),
-            "assignments_by_expert_max": int(t["assignments"].sum(0).max()),
-            "experts_hit_total": t["experts_hit"],
-            "decode_layer_calls_total": t["layer_calls"]}
+    out = {"assignments_total": int(t["assignments"].sum()),
+           "assignments_by_expert_max": int(t["assignments"].sum(0).max()),
+           "experts_hit_total": t["experts_hit"],
+           "decode_layer_calls_total": t["layer_calls"]}
+    for kind in ("zero", "absent"):
+        if f"{kind}_assignments" in t:
+            out[f"{kind}_assignments_total"] = t[f"{kind}_assignments"]
+    return out
 
 
 def stats_families(stats) -> dict:
@@ -172,7 +254,7 @@ def stats_families(stats) -> dict:
     t = stats_totals(stats)
     if t is None:
         return {}
-    return {
+    out = {
         "kft_moe_assignments_total": {
             f'layer="{layer}",expert="{e}"': int(n)
             for layer, row in enumerate(t["assignments"])
@@ -180,3 +262,8 @@ def stats_families(stats) -> dict:
         "kft_moe_experts_hit_total": {"": t["experts_hit"]},
         "kft_moe_decode_layer_calls_total": {"": t["layer_calls"]},
     }
+    for kind in ("zero", "absent"):
+        if f"{kind}_assignments" in t:
+            out[f"kft_moe_{kind}_assignments_total"] = {
+                "": t[f"{kind}_assignments"]}
+    return out

@@ -230,10 +230,13 @@ class ServingEngine:
         self.spec = spec
         # rows of one KV block of the attention in `_decode` (one query row
         # a slot) and `_verify` (k): asked as the model asks when it is
-        # traced.  One block of max_len rows a slot is the dense einsum
+        # traced, of a leaf the attention reads by position (a layer's K,
+        # or the one latent leaf of a latent-attention sublayer).  One
+        # block of max_len rows a slot is the dense einsum
         leaf = next(x for path, x in
                     jax.tree_util.tree_leaves_with_path(self.cache)
-                    if getattr(path[-1], "key", None) == "cached_k")
+                    if getattr(path[-1], "key", None)
+                    in ("cached_k", "cached_latent"))
         self._attn_block = {
             rows: (None if self.dcfg.attention == "full"
                    else kernel_block(rows, leaf.shape, leaf.dtype))

@@ -27,7 +27,9 @@ between the device cache layout and plain numpy: the radix prefix cache
 (serving/prefix.py) stores matched prefixes as row blocks, and the
 disaggregation ship path (serving/disagg.py, ops/kv_ship.py) moves the same
 blocks between prefill and decode ranks.  Position-indexed leaves are every
-cache leaf except the `idx`/`overflowed` cursor state.
+cache leaf except the `idx`/`overflowed` cursor state, whatever lies behind
+the position axis: a layer's `[.., Hkv, D]` K and V (and int8 scales), or
+the one `[.., rank + rope]` latent row of a latent-attention sublayer.
 """
 from __future__ import annotations
 

@@ -20,7 +20,8 @@ import flax.linen as nn
 from jax.sharding import Mesh
 
 from kungfu_tpu.models.transformer import TransformerConfig, TransformerLM
-from kungfu_tpu.ops import decode_attention, decode_attention_reference
+from kungfu_tpu.ops import (decode_attention, decode_attention_reference,
+                            mla_decode_attention_reference)
 from kungfu_tpu.ops import decode_attn as da
 
 MAX_LEN, BLOCK, D = 64, 16, 16
@@ -327,3 +328,134 @@ def test_decode_step_logits_equal_the_einsums(monkeypatch):
         return np.stack(out)
 
     np.testing.assert_allclose(run("interpret"), run("off"), rtol=2e-4, atol=2e-4)
+
+
+# -- latent attention: one shared row a token ------------------------------------------
+
+RANK, ROPE = 32, 8
+
+
+def _latent_case(cursors, L, H, dtype, seed=0):
+    """(q, cache, positions, the cache with NaN beyond each slot's last
+    position) for q [B, L, H, RANK + ROPE] against [B, MAX_LEN, RANK + ROPE]."""
+    B, W = len(cursors), RANK + ROPE
+    kq, kc = jax.random.split(jax.random.PRNGKey(seed))
+    q = jax.random.normal(kq, (B, L, H, W), jnp.float32).astype(dtype)
+    cache = jax.random.normal(kc, (B, MAX_LEN, W), jnp.float32).astype(dtype)
+    idx0 = jnp.minimum(jnp.asarray(cursors, jnp.int32), MAX_LEN - L)
+    pos = idx0[:, None] + jnp.arange(L)[None, :]
+    dead = (jnp.arange(MAX_LEN)[None, :] > pos[:, -1:])[:, :, None]
+    return q, cache, pos, jnp.where(dead, jnp.nan, cache)
+
+
+@functools.lru_cache(maxsize=None)
+def _latent_interpreted():
+    return jax.jit(lambda q, c, pos: da._mla_attn_pallas(
+        q, c, pos, RANK, 0.25, BLOCK, True))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("L", [1, 4])
+@pytest.mark.parametrize("cursors", list(CURSORS), ids=list(CURSORS))
+def test_latent_kernel_matches_the_einsum_over_ragged_cursors(cursors, L, dtype):
+    """Every head against the same rows, each row key and value at once;
+    NaN in every row beyond a cursor must not reach the result."""
+    q, cache, pos, cache_nan = _latent_case(CURSORS[cursors], L, 4, dtype)
+    want = mla_decode_attention_reference(q, cache, pos, RANK, 0.25)
+    got = _latent_interpreted()(q, cache_nan, pos)
+    assert got.shape == (8, L, 4, RANK) and got.dtype == jnp.float32
+    assert np.isfinite(np.asarray(got)).all()
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2  # as the per-head kernel's
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=tol, atol=tol)
+
+
+def test_selection_for_a_latent_leaf():
+    pick = lambda rows=1, shape=(32, 4096, 576), dtype=jnp.bfloat16, mode=True: (  # noqa: E731
+        da.kernel_block(rows, shape, dtype, interpret=mode))
+    assert pick() == 512                          # 512 x 576 bf16: under 1 MiB
+    assert pick(rows=da.MAX_QUERY_ROWS) == 512
+    assert pick(rows=16) is None                  # a prefill bucket materialises
+    assert pick(dtype=jnp.float32) == 256
+    assert pick(mode=None) is None
+    assert pick(shape=(4, 64, 40)) == 64
+    assert pick(shape=(4, 100, 40)) is None
+
+
+@pytest.mark.parametrize("L", [1, 4], ids=["decode", "verify"])
+def test_latent_kernel_lowers_for_tpu_at_the_cells_shape(L):
+    """[32, L, 16, 576] against the [32, 4096, 576] leaf of
+    `serve-longcat-reason-r80`, through the Pallas -> Mosaic lowering."""
+    q = jax.ShapeDtypeStruct((32, L, 16, 576), jnp.bfloat16)
+    leaf = jax.ShapeDtypeStruct((32, 4096, 576), jnp.bfloat16)
+    pos = jax.ShapeDtypeStruct((32, L), jnp.int32)
+
+    def f(q, c, p):
+        return da.mla_decode_attention(q, c, p, 512, 192 ** -0.5,
+                                       interpret=False)
+
+    exp = jax.export.export(jax.jit(f), platforms=["tpu"])(q, leaf, pos)
+    assert da.MLA_KERNEL_NAME in exp.mlir_module()
+
+
+def test_the_latent_decode_program_reads_the_donated_cache_where_it_lies(
+        v5e_chip, monkeypatch):
+    """`ServingEngine._decode` of the latent-attention block at the cell's
+    attention widths (one layer, 32 slots of 4,096 rows), compiled for the
+    chip: the cache is one [32, 4096, 576] bf16 leaf a sublayer, one kernel
+    call a sublayer, NO per-head K or V of cached rows anywhere in the
+    program, no copy, transpose or convert of a whole leaf ahead of the
+    kernel (the chip lays the leaf out feature-major and the kernel takes it
+    so: `ops/decode_attn.py`), and the donated cache aliases the output."""
+    import re
+
+    from kungfu_tpu import compat
+    from kungfu_tpu.ops.gmm import KERNEL_NAME as GMM
+    from kungfu_tpu.serving import ServingEngine
+
+    monkeypatch.setattr(compat, "pallas_mode", lambda interpret=None: "compiled")
+    cfg = TransformerConfig(
+        vocab_size=512, d_model=6144, n_layers=1, n_heads=16, d_ff=256,
+        max_len=4096, rope=True, rope_theta=1e7, attention="full",
+        dtype=jnp.bfloat16, ffn="swiglu", norm="rms", block="shortcut_moe",
+        kv_lora_rank=512, q_lora_rank=1536, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, mla_scale_q_lora=True,
+        mla_scale_kv_lora=True, d_ff_expert=256, n_experts=512,
+        n_zero_experts=256, experts_per_token=12, moe_every=1,
+        routed_scaling_factor=6.0, router_bias=True, experts_held=8)
+    described = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip), tree)
+    params = described(nn.meta.unbox(jax.eval_shape(
+        TransformerLM(cfg).init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 1), jnp.int32))["params"]))
+    eng = ServingEngine(cfg, params, slots=32)
+    leaves = {path[-1].key: leaf for path, leaf in
+              jax.tree_util.tree_leaves_with_path(eng.cache)}
+    assert set(leaves) == {"cached_latent", "idx", "overflowed"}
+    assert leaves["cached_latent"].shape == (32, 4096, 576)
+    assert leaves["cached_latent"].dtype == jnp.bfloat16
+    assert eng._attn_block == {1: 512}
+    compiled = eng._decode.lower(
+        described(eng.params), described(eng.cache),
+        described(eng._dev_counters),
+        jax.ShapeDtypeStruct((32, 1), jnp.int32, sharding=v5e_chip)).compile()
+    text = compiled.as_text()
+    calls = lambda kernel: re.findall(  # noqa: E731
+        rf"= \S+ custom-call\([^\n]*{kernel}", text)
+    assert len(calls(da.MLA_KERNEL_NAME)) == 2 * cfg.n_layers
+    assert len(calls("kft_decode_attn")) == 0
+    assert len(calls(GMM)) == 3 * cfg.n_layers
+    assert "[32,4096,16," not in text          # no K or V of a cached row
+    moved = []
+    for comp in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text):
+        if "fused_computation" in comp.split("\n", 1)[0]:
+            continue  # inside a fusion nothing is written to HBM
+        moved += re.findall(
+            r"= \S*\[32,(?:4096,576|576,4096)\]\S* (?:copy|transpose|convert)\(",
+            comp)
+    assert moved == []
+    cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(eng.cache))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // 4

@@ -1,0 +1,239 @@
+"""The dense and the sparse-expert shapes lower to the programs they lowered
+to before the latent-attention block existed (ISSUE 35).
+
+ISSUE 35 adds a second attention formulation, a second block and an expert
+layer that holds a share of its experts, all selected by fields of
+`TransformerConfig` whose defaults select what was there.  The four
+configurations the benchmark already measures must not move, so their
+programs are held here letter for letter: the slot-cache programs of
+`ServingEngine` (`_decode`, `_verify_accept`, a `_prefill` bucket) and a
+training step, for a dense model (OLMo's shape: MHA, SwiGLU, RMSNorm, tied
+head) and a sparse-expert one (OLMoE's: experts in every block, QK-norm),
+through `ragged_dot` / the einsum and through the Pallas bodies.  GOLDEN
+holds the SHA-256 of the StableHLO text commit e225a5d (PR 31, the parent
+of ISSUE 35) lowered to, made by running this file as a script in a
+checkout of it
+(`JAX_PLATFORMS=cpu PYTHONPATH=. python tests/unit/test_parent_programs.py`).
+
+`slots.py` and `prefix.py` are held by what they did on the host for a
+`[B, max_len, Hkv, D]` cache: the rows they extract, the warm cache they
+build from them and the block the engine counts fetched rows by.
+
+A later PR that changes one of these programs on purpose runs the script
+on its own tree, replaces the digest and says so.
+"""
+import dataclasses
+import hashlib
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+import flax.linen as nn
+
+GOLDEN = {
+    "dense.decode.interpret":
+        "b3816e740c9d3eef28e9b0915581bb5d62e2d4b143bd5ab9cd75d223f1e94466",
+    "dense.decode.off":
+        "3e484bd818f75629d9be1054e575f7a84ad65f154f2a285c2c0c6e2c3f6f7fdf",
+    "dense.prefill.interpret":
+        "ac98b27d28e046ac3a85a85ccb2c18dda5b94d29169252024ce36dc774f92a3f",
+    "dense.prefill.off":
+        "ac98b27d28e046ac3a85a85ccb2c18dda5b94d29169252024ce36dc774f92a3f",
+    "dense.train_step.off":
+        "e1bf78d520ffde169777bb0f2d5d35a364d77ff15c831504558858f11f774798",
+    "dense.verify.interpret":
+        "110f8b6aa8e62808b59c238c29d00f7d9885132372ccd0f351a19efe84221b3d",
+    "dense.verify.off":
+        "2d36db5cf2e7c6654b699ea8546ee6651cbba2a1ad544c3d3eeca625cc04b2af",
+    "experts.decode.interpret":
+        "933377307ba6d7afd5c33e2a2c2a7fedcf12665f82059c2868ee888819ae3ae5",
+    "experts.decode.off":
+        "5d933318640cc913adcab255a81344dc8a0b63f53709f36a59d9ad80b5b68687",
+    "experts.prefill.interpret":
+        "99751054e051e79fd0ca329c7b946dbf125b223b427b73dea283beb9032e3721",
+    "experts.prefill.off":
+        "157a0807fbb6d52abdade88ed399a39dc51519d2a368a4a2dd655837847756e3",
+    "experts.train_step.off":
+        "6dc0c6c1db163676f6b77ff85271948f55fec02d7f5edfba18c605f64d2c7c15",
+    "experts.verify.interpret":
+        "8d68ce8fadda4b4eab0cbe0773a61bbbd34e5109076d2fe28bed0fa516e29934",
+    "experts.verify.off":
+        "ab53825ec2690caf3f5b06600da6829f5adbf1a796ac00e80de608b98447dec0",
+}
+
+SLOTS, K = 4, 3
+
+
+def _config(shape: str):
+    from kungfu_tpu.models.transformer import TransformerConfig
+
+    # head_dim 128 over 8 float32 KV heads: the shape the decode attention
+    # kernel takes, so the interpreted programs hold its body
+    base = dict(vocab_size=64, d_model=1024, n_layers=2, n_heads=8, d_ff=64,
+                max_len=64, rope=True, ffn="swiglu", norm="rms",
+                dtype=jnp.float32)
+    if shape == "dense":
+        return TransformerConfig(tie_embeddings=True, **base)
+    return TransformerConfig(qk_norm=True, n_experts=4, experts_per_token=2,
+                             moe_every=1, embed_init_std=1.0, **base)
+
+
+def _params(cfg):
+    from kungfu_tpu.models.transformer import TransformerLM
+
+    return nn.meta.unbox(jax.eval_shape(
+        TransformerLM(cfg).init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 4), jnp.int32))["params"])
+
+
+def _engine(shape: str):
+    from kungfu_tpu.serving import ServingEngine
+
+    cfg = _config(shape)
+    zeros = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), _params(cfg))
+    return ServingEngine(cfg, zeros, slots=SLOTS, prefill_buckets=(16,))
+
+
+def _i32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+
+def _decode(shape):
+    eng = _engine(shape)
+    return eng._decode.lower(eng.params, eng.cache, eng._dev_counters,
+                             _i32(SLOTS, 1))
+
+
+def _verify(shape):
+    eng = _engine(shape)
+    return eng._verify.lower(eng.params, eng.cache, eng._dev_counters,
+                             _i32(SLOTS, K), _i32(SLOTS, K - 1))
+
+
+def _prefill(shape):
+    eng = _engine(shape)
+    return eng._prefill.lower(eng.params, eng._small_cache0, _i32(1, 16), 5, 5)
+
+
+def _train_step(shape):
+    from kungfu_tpu.models.transformer import (
+        TransformerLM, lm_loss, lm_loss_with_aux)
+
+    cfg = dataclasses.replace(_config(shape), remat=True)
+    model = TransformerLM(cfg)
+    if cfg.n_experts:
+        loss = lambda p, t: lm_loss_with_aux(model, p, t)  # noqa: E731
+    else:
+        loss = lambda p, t: lm_loss(model.apply({"params": p}, t), t)  # noqa: E731
+    return jax.jit(jax.value_and_grad(loss)).lower(_params(cfg), _i32(2, 16))
+
+
+PROGRAMS = {
+    f"{shape}.{name}.{mode}": (mode, lower, shape)
+    for shape in ("dense", "experts")
+    for name, lower in (("decode", _decode), ("verify", _verify),
+                        ("prefill", _prefill), ("train_step", _train_step))
+    # the Pallas bodies are what a TPU runs: the decode attention kernel,
+    # the grouped matmul.  The training step has no kernel of its own here
+    for mode in (("off",) if name == "train_step" else ("off", "interpret"))
+}
+
+
+def _digest(lower, shape) -> str:
+    return hashlib.sha256(lower(shape).as_text().encode()).hexdigest()
+
+
+@pytest.mark.parametrize("name", sorted(PROGRAMS))
+def test_the_program_is_the_parents(name, monkeypatch):
+    mode, lower, shape = PROGRAMS[name]
+    monkeypatch.setenv("KFT_PALLAS", mode)
+    assert _digest(lower, shape) == GOLDEN[name], (
+        f"{name} no longer lowers to what commit e225a5d lowered to: if "
+        "that is meant, run this file as a script and replace GOLDEN")
+
+
+# -- the host work of slots.py and prefix.py -------------------------------------------
+
+
+def _small_with_rows(eng, n):
+    """A batch-1 cache whose position-indexed leaves hold distinct numbers."""
+    def fill(path, leaf):
+        if getattr(path[-1], "key", None) in ("idx", "overflowed"):
+            return leaf
+        return jnp.arange(leaf.size, dtype=jnp.float32).reshape(
+            leaf.shape).astype(leaf.dtype)
+
+    return jax.tree_util.tree_map_with_path(fill, eng._small_cache0)
+
+
+@pytest.mark.parametrize("shape", ["dense", "experts"])
+def test_the_row_helpers_do_the_host_work_they_did(shape, monkeypatch):
+    """`extract_rows` gives one `[n, Hkv, D]` block a K or V leaf under the
+    flattened path, `warm_small_cache` puts them back under a cursor at n,
+    a prefix-cache lease returns the same rows, and the engine counts the
+    rows a step fetches by the dense kernel's block (or the whole cache off
+    TPU)."""
+    from kungfu_tpu.serving.prefix import PrefixCache
+    from kungfu_tpu.serving.slots import extract_rows, warm_small_cache
+
+    monkeypatch.setenv("KFT_PALLAS", "off")
+    eng = _engine(shape)
+    cfg = eng.dcfg
+    assert eng._attn_block == {1: cfg.max_len}
+    n = 7
+    small = _small_with_rows(eng, n)
+    rows = extract_rows(small, n)
+    head_dim = cfg.d_model // cfg.n_heads
+    assert sorted(rows) == sorted(
+        (f"['block_{i}']", "['attn']", f"['cached_{kv}']")
+        for i in range(cfg.n_layers) for kv in "kv")
+    for key, block in rows.items():
+        assert block.shape == (n, cfg.n_heads, head_dim)
+        assert block.dtype == np.float32 and block.flags["C_CONTIGUOUS"]
+    warm = warm_small_cache(eng._small_cache0, rows, n)
+    assert jax.tree.structure(warm) == jax.tree.structure(small)
+    for (path, got), want in zip(
+            jax.tree_util.tree_leaves_with_path(warm), jax.tree.leaves(small)):
+        name = path[-1].key
+        if name == "idx":
+            assert np.asarray(got).tolist() == [n]
+        elif name == "overflowed":
+            assert np.asarray(got).tolist() == [False]
+        else:
+            assert got.shape == want.shape and got.dtype == want.dtype
+            np.testing.assert_array_equal(np.asarray(got)[0, :n],
+                                          np.asarray(want)[0, :n])
+            assert not np.asarray(got)[0, n:].any()
+    cache = PrefixCache(budget_bytes=1 << 20)
+    tokens = tuple(range(1, n + 1))
+    cache.insert(tokens, rows)
+    hit, lease = cache.match(tokens + (9,))
+    assert hit == n
+    for key, block in lease.rows().items():
+        np.testing.assert_array_equal(block, rows[key])
+    lease.release()
+    assert cache.total_bytes == sum(b.nbytes for b in rows.values())
+
+
+def test_the_engine_counts_fetched_rows_by_the_dense_kernels_block(monkeypatch):
+    from kungfu_tpu.models.transformer import TransformerConfig, TransformerLM
+    from kungfu_tpu.serving import ServingEngine
+
+    monkeypatch.setenv("KFT_PALLAS", "interpret")
+    cfg = TransformerConfig(vocab_size=32, d_model=1024, n_layers=1, n_heads=8,
+                            d_ff=32, max_len=512, rope=True, dtype=jnp.float32)
+    params = jax.tree.map(
+        lambda s: jnp.zeros(s.shape, s.dtype),
+        nn.meta.unbox(jax.eval_shape(
+            TransformerLM(cfg).init, jax.random.PRNGKey(0),
+            jnp.zeros((1, 4), jnp.int32))["params"]))
+    assert ServingEngine(cfg, params, slots=2)._attn_block == {1: 256}
+
+
+if __name__ == "__main__":
+    for name, (mode, lower, shape) in sorted(PROGRAMS.items()):
+        os.environ["KFT_PALLAS"] = mode
+        print(f'    "{name}":\n        "{_digest(lower, shape)}",')
