@@ -13,6 +13,14 @@ Top-level API mirrors the reference's `kungfu.python` surface
 `cluster_size`, `local_rank`, `run_barrier`, ... — see kungfu_tpu/api.py.
 """
 
+import time as _time
+
+#: the first statement the program runs in any process, and (at the bottom)
+#: the last of this import: `boot:interpreter` ends and `boot:imports`
+#: starts here (utils/trace.py `package_import_mono`, monitor/boot.py)
+_IMPORT_T0 = _time.monotonic()
+_IMPORT_T1 = None
+
 __version__ = "0.1.0"
 
 from .api import (  # noqa: F401
@@ -41,6 +49,8 @@ from .api import (  # noqa: F401
     get_variable,
     set_variable,
 )
+
+_IMPORT_T1 = _time.monotonic()
 
 
 def __getattr__(name):

@@ -34,8 +34,8 @@ INTERNAL_ENV: Dict[str, str] = {
     "KFT_SELF_HOST": "launcher->worker: this process's host id",
     "KFT_PARENT_ID": "launcher->worker: parent launcher id for orphan "
                      "detection",
-    "KFT_PROC_START": "launcher->worker: spawn timestamp for incarnation "
-                      "bookkeeping",
+    "KFT_PROC_START": "launcher->worker: wall-clock stamp of this spawn; "
+                      "the worker's boot:interpreter phase starts there",
     "KFT_INIT_CLUSTER": "launcher->worker: serialized initial cluster "
                         "document",
     "KFT_INIT_VERSION": "launcher->worker: initial cluster doc version",

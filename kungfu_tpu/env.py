@@ -14,7 +14,11 @@ srcs/go/kungfu/env/config.go:24-56), renamed KFT_*:
   KFT_CONFIG_URLS          comma-separated replica URLs of a replicated
                            config ensemble (wins over KFT_CONFIG_SERVER;
                            single-URL form is identical to it)
-  KFT_JOB_START / KFT_PROC_START  timestamps for event tracing
+  KFT_JOB_START            the launcher's real process start (wall clock):
+                           both launchers stamp it always; the job clock
+  KFT_PROC_START           wall-clock stamp of this worker's spawn
+                           (run/launcher.py ProcRunner.start): where its
+                           boot:interpreter phase starts
 
 Tuning tier (KFT_CONFIG_*, reference srcs/go/kungfu/config/config.go:24-67):
   KFT_CONFIG_LOG_LEVEL, KFT_CONFIG_ENABLE_STALL_DETECTION,
@@ -91,14 +95,28 @@ COMPILE_CACHE_DIR = os.path.join(
 )
 
 
+def starts_dir() -> str:
+    """Where a process leaves its start record when KFT_TRACE_DUMP_DIR
+    names no other place (monitor/boot.py): `starts/` beside the compiled
+    programs.  Takes the constant, not JAX's setting, so that a launcher
+    can ask without touching JAX."""
+    return os.path.join(
+        os.environ.get("JAX_COMPILATION_CACHE_DIR", "") or COMPILE_CACHE_DIR,
+        "starts")
+
+
 def enable_compile_cache() -> str:
     """Place JAX's persistent compilation cache; returns the directory.
 
     With JAX_COMPILATION_CACHE_DIR set, JAX reads it itself and nothing is
     touched here; otherwise the cache goes to `<checkout>/.jax_cache`.
     Called from process entry points only, never at import, so importing
-    the package (pytest) fills no cache.
+    the package (pytest) fills no cache.  A process that calls this is a
+    program's entry point: it may write its start record.
     """
+    from .utils.trace import arm_start_record
+
+    arm_start_record(starts_dir())
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
     if placed:
         return placed

@@ -161,6 +161,13 @@ METRIC_HELP: Dict[str, str] = {
     "kft_serve_decode_rows_total":
         "Slot-steps of the decode and verify steps: live (the slot held a "
         "request) and free (its row did no work).",
+    "kft_boot_seconds":
+        "Seconds of each boot phase of this process so far, on the job "
+        "clock (spans of category boot, docs/observability.md Boot).",
+    "kft_program_setup_seconds":
+        "Seconds this process spent building programs, by stage: trace and "
+        "lower (each instant of a thread once), load (persistent-cache "
+        "retrieval), compile (real compilations).",
     "kungfu_fleet_ranks_scraped": "1 if the rank answered the fleet scrape.",
     "kungfu_fleet_scrape_errors_total": "Failed fleet scrape fan-out fetches.",
 }

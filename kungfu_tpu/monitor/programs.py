@@ -7,11 +7,25 @@ as mysterious latency (PR 14 found one by accident), the serving engine
 the tuner's HBM footprint model was never checked against what the device
 actually allocates.  This module is that plane:
 
-  CompileWatch      a `jax.monitoring` duration listener on the backend
-                    compile event feeding `compiles_total` and the
-                    `compile_ms` histogram.  Where jax.monitoring is absent
-                    the `track()` wrapper falls back to wall-clocking the
-                    first call per signature — the tracing-callback path.
+  CompileWatch      `jax.monitoring` listeners on JAX's own compile events.
+                    The backend-compile duration feeds `compiles_total` and
+                    the `compile_ms` histogram; in JAX 0.9.0 that event
+                    wraps `compile_or_get_cached`, so it fires on a
+                    persistent-cache HIT too (after `cache_hits` and
+                    `cache_retrieval_time_sec`): `compiles` counts hits,
+                    `compile_ms` on a warm start is reading and
+                    deserialising executables, and `cache_misses` is the
+                    count of real compilations.  The ledger beside them
+                    (`compile_ledger`) keeps, for each program under JAX's
+                    `fun_name`, its trace / lower / load / compile seconds
+                    from the trace and lowering time spans and the two
+                    cache events, and totals in which a nested trace counts
+                    once.  The ledger listens from `listen()` on (an entry
+                    point's first act), the three older counters from
+                    `maybe_install()`, where they always started.
+                    Where jax.monitoring is absent the `track()`
+                    wrapper falls back to wall-clocking the first call per
+                    signature — the tracing-callback path.
   ProgramRegistry   per-process registry of tracked programs: fn name ->
                     {shape/dtype digest -> compile ms, call count}.  Every
                     NEW digest journals `program_compiled`; a sustained
@@ -60,7 +74,15 @@ from collections import deque
 from typing import Any, Callable, Dict, List, Optional
 
 from ..utils import get_logger
-from ..utils.trace import job_now, trace_scope
+from ..plan import mesh as plan_mesh
+from ..utils.trace import (
+    BOOT_CAT,
+    Span,
+    backend_phase,
+    boot_spans,
+    job_now,
+    trace_scope,
+)
 from .journal import journal_event
 
 log = get_logger("kungfu.programs")
@@ -76,12 +98,32 @@ DEFAULT_STORM_WINDOW_S = 30.0
 #: steady state is 0 new digests per window.
 DEFAULT_STORM_MIN = 4
 
-#: the jax-internal duration event backend_compile wraps every XLA
-#: compilation in (jax/_src/dispatch.py BACKEND_COMPILE_EVENT)
+#: the jax-internal duration event around `compiler.compile_or_get_cached`
+#: (jax/_src/interpreters/pxla.py, dispatch.BACKEND_COMPILE_EVENT): one for
+#: every program jit builds, compiled or served by the persistent cache
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-#: recorded when the persistent compilation cache serves an executable —
-#: no backend compile happens then, so the two events never double-count
+#: recorded when the persistent compilation cache serves an executable; the
+#: backend-compile event of the same program still follows (it wraps the
+#: lookup), so `compiles` counts this program too and its `compile_ms` is
+#: the retrieval, not a compilation
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+#: seconds `_cache_read` took on that hit: file read, decompression and
+#: deserialisation onto the device; fired inside the backend-compile event
+CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: Python tracing of a jitted function to a jaxpr (`fun_name`: the bare
+#: function name) and the jaxpr's lowering to an MLIR module (`fun_name`:
+#: `jit(name)`, what the device trace's `XLA Modules` line carries).  Both
+#: also arrive as time spans; a jit called while another is traced is
+#: traced inside its caller's span
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+
+#: rows `compile_ledger` reports by name (the rest fold into `other`), and
+#: names it keeps at all (a process has as many as its code has jits)
+LEDGER_ROWS = 32
+LEDGER_NAMES_MAX = 512
+#: intervals a thread's union may hold while a caller's trace is open
+UNION_INTERVALS_MAX = 1 << 17
 
 
 def programs_enabled() -> bool:
@@ -124,31 +166,144 @@ def _counters():
 _watch_lock = threading.Lock()
 _watch: Dict[str, Any] = {
     "installed": False,   # maybe_install ran (idempotence latch)
-    "active": False,      # the jax.monitoring listener is live
+    "active": False,      # the listener is live and the three below count
+    "listening": None,    # listen() ran: the ledger takes events in
     "compile_ms": 0.0,    # cumulative backend-compile ms this process
     "compiles": 0,
     "cache_hits": 0,      # executables served by the persistent cache
+    "cache_misses": 0,    # backend events with no hit before them: real compiles
+    "cache_load_ms": 0.0,  # cache retrieval on those hits
+    "trace_ms": 0.0,      # union of the trace spans, a thread at a time
+    "lower_ms": 0.0,      # union of the lowering spans, less traces inside
+    "events": 0,          # trace and lowering spans the ledger took in
 }
+# the ledger, under _watch_lock: program name -> row; and for each thread
+# that traces, the disjoint intervals its spans cover so far ([traces],
+# [traces and lowerings]), from which the totals grow
+_ledger: Dict[str, Dict[str, Any]] = {}
+_aliases: Dict[Any, Dict[str, Any]] = {}
+_unions: Dict[int, tuple] = {}
+_hit_tls = threading.local()  # .load_s: this thread's hit awaiting its backend event
+
+
+def _program_name(fun_name: Any) -> str:
+    """The ledger's key: `jit(step)` as the lowering and backend events
+    spell it; a trace event's bare `step` is brought to that form."""
+    name = str(fun_name or "?")
+    return name if "(" in name else f"jit({name})"
+
+
+def _row(fun_name: Any, secs: float) -> Dict[str, Any]:
+    """The row of the program an event of `secs` seconds names (caller
+    holds _watch_lock); past LEDGER_NAMES_MAX names the rest share one."""
+    row = _aliases.get(fun_name)  # by the spelling JAX sent: no string work
+    if row is None:
+        name = _program_name(fun_name)
+        if name not in _ledger and len(_ledger) >= LEDGER_NAMES_MAX:
+            name = "(more)"
+        row = _ledger.get(name)
+        if row is None:
+            row = _ledger[name] = {
+                "trace_s": 0.0, "lower_s": 0.0, "load_s": 0.0,
+                "compile_s": 0.0, "hit": 0, "miss": 0,
+                "t_first": round(job_now() - secs, 4)}
+        if len(_aliases) < 2 * LEDGER_NAMES_MAX:
+            _aliases[fun_name] = row
+    return row
+
+
+def _union_add(iv: List[tuple], s: float, e: float) -> float:
+    """Add [s, e] to `iv`, disjoint (start, end, seconds covered) in order;
+    returns by how much their union grew.  Spans arrive as they END, a
+    nested one before its caller, so only the tail can be covered or
+    touched.  A caller's trace holds every jit it calls (each `jnp`
+    function is one: tens of thousands in a model's step), and all of them
+    wait here until the caller's own span takes them up."""
+    covered = 0.0
+    while iv and iv[-1][0] >= s:
+        _, b, m = iv.pop()
+        covered += m
+        e = max(e, b)
+    if iv and iv[-1][1] > s:
+        a, b, m = iv.pop()
+        covered += m
+        s, e = a, max(e, b)
+    iv.append((s, e, e - s))
+    if len(iv) > UNION_INTERVALS_MAX:
+        # never seen: the older half as one stretch with its seconds (a
+        # span that later starts inside it would count its gaps as new)
+        head = iv[:len(iv) // 2]
+        iv[:len(head)] = [(head[0][0], head[-1][1], sum(m for _, _, m in head))]
+    return (e - s) - covered
+
+
+def _on_time_span(event: str, start_time: float, end_time: float,
+                  **kw: Any) -> None:
+    """jax.monitoring time-span listener: tracing and lowering, the two
+    stages of a first call that are Python.  Per program the seconds JAX
+    reports (a caller's include its callees'); in the totals each instant
+    of a thread counts once."""
+    if event == TRACE_EVENT:
+        key = "trace_s"
+    elif event == LOWER_EVENT:
+        key = "lower_s"
+    else:
+        return
+    start, end = float(start_time), float(end_time)
+    tid = threading.get_ident()
+    with _watch_lock:
+        _watch["events"] += 1
+        _row(kw.get("fun_name"), end - start)[key] += end - start
+        pair = _unions.get(tid)
+        if pair is None:
+            if len(_unions) >= 64:
+                _unions.clear()  # threads long gone; totals are kept
+            pair = _unions[tid] = ([], [])
+        grew = _union_add(pair[1], start, end)
+        if key == "trace_s":
+            traced = _union_add(pair[0], start, end)
+            _watch["trace_ms"] += traced * 1e3
+            grew -= traced
+        _watch["lower_ms"] += grew * 1e3
 
 
 def _on_duration_event(event: str, duration_secs: float, **kw: Any) -> None:
-    """jax.monitoring duration listener: fires for EVERY backend compile in
-    the process, tracked or not — the honest `compiles_total`.  The event
-    carries no fn identity; per-program attribution is track()'s job."""
+    """jax.monitoring duration listener: fires for EVERY program jit builds
+    in the process, tracked or not, compiled or loaded from the persistent
+    cache — `compiles_total`.  On a hit the retrieval's own duration comes
+    first, on the same thread; it decides which column of the program's
+    ledger row the backend event lands in."""
+    if event == CACHE_LOAD_EVENT:
+        _hit_tls.load_s = float(duration_secs)
+        return
     if event != BACKEND_COMPILE_EVENT:
         return
-    ms = float(duration_secs) * 1000.0
+    secs = float(duration_secs)
+    ms = secs * 1000.0
+    load_s = getattr(_hit_tls, "load_s", None)
+    _hit_tls.load_s = None
     with _watch_lock:
-        _watch["compile_ms"] += ms
-        _watch["compiles"] += 1
-    c = _counters()
+        counted = _watch["active"]
+        if counted:
+            _watch["compile_ms"] += ms
+            _watch["compiles"] += 1
+        row = _row(kw.get("fun_name"), secs)
+        if load_s is None:
+            _watch["cache_misses"] += 1
+            row["compile_s"] += secs
+            row["miss"] += 1
+        else:
+            _watch["cache_load_ms"] += load_s * 1e3
+            row["load_s"] += load_s
+            row["hit"] += 1
+    c = _counters() if counted else None
     if c is not None:
         c.inc_event("compiles_total")
         c.observe_hist("compile_ms", ms)
 
 
 def _on_event(event: str, **kw: Any) -> None:
-    if event != CACHE_HIT_EVENT:
+    if event != CACHE_HIT_EVENT or not _watch["active"]:
         return
     with _watch_lock:
         _watch["cache_hits"] += 1
@@ -157,11 +312,49 @@ def _on_event(event: str, **kw: Any) -> None:
         c.inc_event("compile_cache_hits")
 
 
-def compile_watch_state() -> Dict[str, Any]:
-    """Snapshot of the global watch: {installed, active, compile_ms,
-    compiles, cache_hits}."""
+_STAGES = ("trace_s", "lower_s", "load_s", "compile_s")
+
+
+def compile_ledger() -> Dict[str, Any]:
+    """{"programs": the LEDGER_ROWS largest by seconds, each {program,
+    trace_s, lower_s, load_s, compile_s, hit, miss, t_first}, "other": the
+    rest as one row with `programs`, how many}.  A program's seconds are
+    what JAX reports under its name (a caller's trace holds its callees');
+    the watch's totals are the ones that count each instant once."""
     with _watch_lock:
-        return dict(_watch)
+        rows = [dict(r, program=name) for name, r in _ledger.items()]
+    rows.sort(key=lambda r: -sum(r[k] for k in _STAGES))
+    rest = rows[LEDGER_ROWS:]
+    other = {k: sum(r[k] for r in rest) for k in _STAGES + ("hit", "miss")}
+    other["programs"] = len(rest)
+    for r in rows[:LEDGER_ROWS] + [other]:
+        for k in _STAGES:
+            r[k] = round(r[k], 6)
+    return {"programs": rows[:LEDGER_ROWS], "other": other}
+
+
+def boot_phases() -> List[Span]:
+    """The process's boot spans, `make_mesh`'s first question about devices
+    among them (`plan/mesh.py` stamps it, being below the recorder)."""
+    asked = plan_mesh.first_asked
+    if asked is not None:
+        backend_phase(asked[0], asked[1], platform=asked[2], devices=asked[3])
+    return boot_spans()
+
+
+def compile_watch_state() -> Dict[str, Any]:
+    """Snapshot of the global watch ({installed, active, listening,
+    compile_ms, compiles, cache_hits, cache_misses, cache_load_ms,
+    trace_ms, lower_ms, events}), the ledger's rows (`programs`, `other`)
+    and the process's boot phases (`boot`: name, start on the job clock,
+    seconds, args)."""
+    with _watch_lock:
+        out = dict(_watch)
+    out.update(compile_ledger())
+    out["boot"] = [
+        {"name": s.name, "t": round(s.t_start, 4), "s": round(s.dur, 4),
+         "args": s.args or {}} for s in boot_phases()]
+    return out
 
 
 def _compile_ms_anchor() -> float:
@@ -169,12 +362,42 @@ def _compile_ms_anchor() -> float:
         return float(_watch["compile_ms"])
 
 
+def listen() -> bool:
+    """Register the jax.monitoring listeners (idempotent): from here on the
+    ledger takes in every program's trace, lowering, load and compile.  An
+    entry point calls this first thing, so a boot is whole in the ledger;
+    `compile_ms`, `compiles` and `cache_hits` start counting at
+    `maybe_install`, where they always did (a serving worker's are the
+    programs of its requests, not of its boot).  False where KFT_PROGRAMS=0
+    or jax.monitoring is absent."""
+    if not programs_enabled():
+        return False
+    with _watch_lock:
+        if _watch["listening"] is not None:
+            return bool(_watch["listening"])
+        _watch["listening"] = False
+    try:
+        from jax import monitoring as jmon
+
+        jmon.register_event_duration_secs_listener(_on_duration_event)
+        jmon.register_event_listener(_on_event)
+        jmon.register_event_time_span_listener(_on_time_span)
+    except Exception as e:  # noqa: BLE001 - fallback path takes over
+        log.debug("jax.monitoring unavailable (%s): track() will wall-clock "
+                  "first calls instead", e)
+        return False
+    with _watch_lock:
+        _watch["listening"] = True
+    return True
+
+
 def maybe_install() -> bool:
-    """Arm the observatory (idempotent): register the jax.monitoring compile
-    listener and the live-array census tick.  Returns True when the
-    listener is live; False means track() wall-clocks compiles instead
-    (old jax, or jax.monitoring absent).  Called from
-    monitor.server.maybe_start_monitor and from the first track()."""
+    """Arm the observatory (idempotent): the jax.monitoring listeners
+    (`listen`), the watch's compile counters and the live-array census
+    tick.  Returns True when the listener is live; False means track()
+    wall-clocks compiles instead (old jax, or jax.monitoring absent).
+    Called from monitor.server.maybe_start_monitor and from the first
+    track()."""
     if not programs_enabled():
         return False
     with _watch_lock:
@@ -187,17 +410,15 @@ def maybe_install() -> bool:
         register_tick_callback(_census_tick)
     except Exception as e:  # noqa: BLE001 - census is best-effort
         log.debug("census tick not registered: %s", e)
-    try:
-        from jax import monitoring as jmon
-
-        jmon.register_event_duration_secs_listener(_on_duration_event)
-        jmon.register_event_listener(_on_event)
-    except Exception as e:  # noqa: BLE001 - fallback path takes over
-        log.debug("jax.monitoring unavailable (%s): track() will wall-clock "
-                  "first calls instead", e)
+    if not listen():
         return False
     with _watch_lock:
         _watch["active"] = True
+    c = _counters()
+    if c is not None:
+        from . import boot
+
+        c.add_source(boot.families)  # /metrics, and a capture's counters.json
     return True
 
 
@@ -497,7 +718,12 @@ class _Tracked:
         listener = bool(_watch["active"])
         anchor = _compile_ms_anchor() if listener else 0.0
         t0 = time.monotonic()
-        out = fn(*args, **kwargs)
+        # trace + lower + load-or-compile of this signature, until the call
+        # returns (its first execution is dispatched, not awaited): a boot
+        # phase, and the only span a later call never opens
+        with trace_scope("boot:first_call", cat=BOOT_CAT,
+                         args={"program": name}):
+            out = fn(*args, **kwargs)
         wall_ms = (time.monotonic() - t0) * 1000.0
         delta = (_compile_ms_anchor() - anchor) if listener else 0.0
         # the listener's delta is the real compile time; when it saw
@@ -505,6 +731,9 @@ class _Tracked:
         # the first-call wall time is the honest upper bound
         reg.note_compiled(name, digest, delta if delta > 0.0 else wall_ms,
                           count_global=not listener)
+        from . import boot
+
+        boot.refresh()  # a first call after boot complete: the record again
         return out
 
     def __getattr__(self, attr: str) -> Any:
@@ -705,6 +934,9 @@ def _reset_for_tests() -> None:
     global _registry
     _registry = ProgramRegistry()
     with _watch_lock:
-        _watch["compile_ms"] = 0.0
-        _watch["compiles"] = 0
-        _watch["cache_hits"] = 0
+        for k, v in _watch.items():
+            if isinstance(v, (int, float)) and not isinstance(v, bool):
+                _watch[k] = type(v)()
+        _ledger.clear()
+        _aliases.clear()
+        _unions.clear()

@@ -23,6 +23,7 @@ from . import env as kfenv
 from .plan import Cluster, PeerID, PeerList, Strategy, make_mesh, make_hierarchical_mesh
 from .session import Session
 from .utils import get_logger, stall_detector
+from .utils.trace import backend_devices
 
 log = get_logger("kungfu.peer")
 
@@ -168,7 +169,7 @@ class Peer:
         # hierarchical (ici x dcn) mesh whenever there are multiple hosts AND
         # multiple devices per host — the device count is what matters (one
         # process per host owning several chips is the standard TPU shape)
-        devices_per_host = max(1, len(jax.devices()) // self.host_count)
+        devices_per_host = max(1, len(backend_devices()) // self.host_count)
         if self.host_count > 1 and devices_per_host > 1:
             mesh = make_hierarchical_mesh(self.host_count)
         else:

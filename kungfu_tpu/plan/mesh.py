@@ -14,6 +14,7 @@ collectives over ICI/DCN.  This module owns:
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
@@ -26,6 +27,22 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 AXIS_ORDER = ("dp", "fsdp", "pp", "sp", "ep", "tp")
 
 DATA_AXES = ("dp", "fsdp")  # gradient reduction axes
+
+#: `make_mesh`'s first question about devices in this process: (monotonic
+#: start, end, platform, device count).  The TPU runtime comes up at the
+#: first such question anyone asks; this package sits below the span
+#: recorder, so monitor/programs.py reads the stamp from here and keeps it
+#: as the `boot:backend` phase where nobody above asked before
+first_asked: Optional[Tuple[float, float, str, int]] = None
+
+
+def _all_devices() -> Sequence[jax.Device]:
+    global first_asked
+    t0 = time.monotonic()
+    devs = jax.devices()
+    if first_asked is None:
+        first_asked = (t0, time.monotonic(), devs[0].platform, len(devs))
+    return devs
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,7 +87,7 @@ def make_mesh(
     """
     if spec is None:
         spec = MeshSpec.make(**(sizes or {"dp": -1}))
-    devs = list(devices if devices is not None else jax.devices())
+    devs = list(devices if devices is not None else _all_devices())
     sizes_r = spec.resolve(len(devs))
     names = tuple(sizes_r)
     shape = tuple(sizes_r[a] for a in names)

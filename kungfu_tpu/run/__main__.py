@@ -49,6 +49,15 @@ def main(argv=None):
         from ..serving.__main__ import main as serve_main
 
         sys.exit(serve_main(argv[1:]))
+    from ..env import starts_dir
+    from ..monitor import boot
+    from ..utils import trace
+
+    # the job clock starts with this process, -telemetry or not, so that a
+    # worker's record reads job start -> spawn -> its own entry by itself
+    trace.stamp_job_start()
+    boot.enter("launcher", starts_dir())
+    t_config = time.monotonic()
     ap = argparse.ArgumentParser(
         "kungfu-tpu-run", description="launch distributed kungfu_tpu workers"
     )
@@ -165,7 +174,6 @@ def main(argv=None):
                 args.logdir or tempfile.mkdtemp(prefix="kft-telemetry-")
             )
         os.environ.setdefault("KFT_TRACE_DUMP_DIR", os.environ["KFT_JOURNAL_DIR"])
-        os.environ.setdefault("KFT_JOB_START", repr(time.time()))
         from ..monitor.journal import set_journal_context
 
         set_journal_context(rank="launcher", identity="launcher")
@@ -201,6 +209,8 @@ def main(argv=None):
         heal=args.heal,
         heartbeat_dir=heartbeat_dir,
     )
+    # arguments, the cluster document, the config server if any
+    trace.record_span("boot:launcher.config", t_config, cat=trace.BOOT_CAT)
 
     from .launcher import install_signal_trap
 

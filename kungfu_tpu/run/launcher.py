@@ -243,15 +243,25 @@ class ProcRunner:
         self._pump: Optional[threading.Thread] = None
 
     def start(self) -> None:
+        from ..env import PROC_START
+        from ..monitor import boot
+        from ..utils.trace import BOOT_CAT, trace_scope
+
         stdout = subprocess.PIPE
-        self.popen = subprocess.Popen(
-            self.proc.args,
-            env=self.proc.env,
-            stdout=stdout,
-            stderr=subprocess.STDOUT,
-            text=True,
-            bufsize=1,
-        )
+        with trace_scope(boot.boot_name("spawn"), cat=BOOT_CAT,
+                         args={"worker": self.proc.name}):
+            # the spawn, on the wall clock the child shares: where its
+            # `boot:interpreter` starts (a respawn gets its own)
+            self.proc.env[PROC_START] = repr(time.time())
+            self.popen = subprocess.Popen(
+                self.proc.args,
+                env=self.proc.env,
+                stdout=stdout,
+                stderr=subprocess.STDOUT,
+                text=True,
+                bufsize=1,
+            )
+        boot.launcher_spawned()
         logfile = None
         if self.logdir:
             os.makedirs(self.logdir, exist_ok=True)

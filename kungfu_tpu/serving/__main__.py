@@ -47,7 +47,6 @@ def _arm_telemetry(logdir: str) -> None:
             logdir or tempfile.mkdtemp(prefix="kft-serve-telemetry-")
         )
     os.environ.setdefault("KFT_TRACE_DUMP_DIR", os.environ["KFT_JOURNAL_DIR"])
-    os.environ.setdefault("KFT_JOB_START", repr(time.time()))
 
 
 class ServeSupervisor:
@@ -196,6 +195,14 @@ class ServeSupervisor:
 
 
 def main(argv=None) -> int:
+    from ..env import starts_dir
+    from ..monitor import boot
+    from ..utils.trace import BOOT_CAT, stamp_job_start, trace_scope
+
+    # the job clock starts with this process, --telemetry or not: every
+    # worker it spawns, a respawn too, reads its boot from it
+    stamp_job_start()
+    boot.enter("supervisor", starts_dir())
     ap = argparse.ArgumentParser(prog="kungfu_tpu.serving",
                                  description="elastic inference serving fleet")
     ap.add_argument("-np", type=int, default=2, help="initial worker count")
@@ -265,41 +272,43 @@ def main(argv=None) -> int:
 
     cs: Optional[ConfigServer] = None
     ensemble = None
-    if args.config_server:
-        client = ConfigClient(args.config_server)
-    elif args.config_replicas > 1:
-        from ..elastic.ensemble import ConfigEnsemble
+    with trace_scope(boot.boot_name("config_server"), cat=BOOT_CAT):
+        if args.config_server:
+            client = ConfigClient(args.config_server)
+        elif args.config_replicas > 1:
+            from ..elastic.ensemble import ConfigEnsemble
 
-        ensemble = ConfigEnsemble(replicas=args.config_replicas,
-                                  init=cluster).start()
-        client = ensemble.client()
-    else:
-        cs = ConfigServer(host="127.0.0.1", port=args.config_port,
-                          init=cluster).start()
-        client = ConfigClient(cs.url)
+            ensemble = ConfigEnsemble(replicas=args.config_replicas,
+                                      init=cluster).start()
+            client = ensemble.client()
+        else:
+            cs = ConfigServer(host="127.0.0.1", port=args.config_port,
+                              init=cluster).start()
+            client = ConfigClient(cs.url)
     print(f"CONFIG_URL: {client.urls_spec}", flush=True)
 
     from ..monitor.counters import counters_if_enabled
-    from .router import Autoscaler, Router
 
     counters = counters_if_enabled()
-    from .tenancy import TenantRegistry
+    with trace_scope(boot.boot_name("router"), cat=BOOT_CAT):
+        from .router import Autoscaler, Router
+        from .tenancy import TenantRegistry
 
-    # tenancy is opt-in: no KFT_TENANTS_FILE (and no KV document) means
-    # None, and the router keeps the v1 single-tenant FIFO path; workers
-    # pick the same file up from their inherited environment
-    tenants = TenantRegistry.from_env(client=client)
-    if tenants is not None:
-        print(f"TENANTS: {sorted(tenants.tenants())}", flush=True)
-    # tenanted fleets need dispatch concurrency past the fleet's slot
-    # budget: preemption evidence only exists when ENGINE queues back up,
-    # and the default dispatcher pool (sized for one worker) would cap
-    # in-flight work below total slots and starve them of it
-    dispatchers = 2 * args.slots * max(1, args.max_size) if tenants else 0
-    router = Router(
-        slots_per_worker=args.slots, queue_capacity=args.queue_capacity,
-        counters=counters, tenants=tenants,
-    ).start(port=args.port, dispatchers=dispatchers)
+        # tenancy is opt-in: no KFT_TENANTS_FILE (and no KV document) means
+        # None, and the router keeps the v1 single-tenant FIFO path; workers
+        # pick the same file up from their inherited environment
+        tenants = TenantRegistry.from_env(client=client)
+        if tenants is not None:
+            print(f"TENANTS: {sorted(tenants.tenants())}", flush=True)
+        # tenanted fleets need dispatch concurrency past the fleet's slot
+        # budget: preemption evidence only exists when ENGINE queues back
+        # up, and the default dispatcher pool (sized for one worker) would
+        # cap in-flight work below total slots and starve them of it
+        dispatchers = 2 * args.slots * max(1, args.max_size) if tenants else 0
+        router = Router(
+            slots_per_worker=args.slots, queue_capacity=args.queue_capacity,
+            counters=counters, tenants=tenants,
+        ).start(port=args.port, dispatchers=dispatchers)
     print(f"SERVE_URL: http://127.0.0.1:{router.port}", flush=True)
 
     fleet = None

@@ -92,7 +92,13 @@ from ..models.transformer import (
 from ..monitor.journal import journal_event
 from ..ops.decode_attn import kernel_block, live_blocks
 from ..utils import get_logger
-from ..utils.trace import TraceContext, child_span, trace_context, trace_scope
+from ..utils.trace import (
+    BOOT_CAT,
+    TraceContext,
+    child_span,
+    trace_context,
+    trace_scope,
+)
 from .queue import AdmissionQueue
 from .request import Request, Result
 from .slots import (
@@ -141,6 +147,7 @@ class _Pending:
 
 
 class ServingEngine:
+    @trace_scope("boot:engine", cat=BOOT_CAT)  # the slot cache and the programs
     def __init__(
         self,
         cfg: TransformerConfig,

@@ -163,14 +163,24 @@ class ServingWorker:
 
         from ..models.transformer import resident_params
 
+        import jax
+
         cfg = build_config(args.preset, args.model_json)
         t0 = time.monotonic()
-        params, rung = self._resolve_weights(cfg)
+        # boot phases end when the device has done their work, not when the
+        # host has dispatched it: each then holds what it names
+        ladder: Dict[str, Any] = {}
+        with T.trace_scope("boot:weights", cat=T.BOOT_CAT, args=ladder):
+            params, rung = self._resolve_weights(cfg)
+            ladder["rung"] = rung
+            jax.block_until_ready(params)
         # the rungs give the checkpoint form; the resident form is what
         # this process computes with, the engine and a self-draft alike.
         # Nothing else reads the tree a rung made, so each float32 leaf goes
         # as its narrower copy arrives and the chip never holds both
-        params = resident_params(cfg, params, donate=True)
+        with T.trace_scope("boot:resident", cat=T.BOOT_CAT):
+            params = jax.block_until_ready(
+                resident_params(cfg, params, donate=True))
         restore_s = time.monotonic() - t0
         self.weight_rung = rung
         if self.incarnation > 0:
@@ -610,6 +620,9 @@ class ServingWorker:
               f"rung={self.weight_rung} platform={dev.platform} "
               f"device_kind={dev.device_kind!r} devices={jax.device_count()}"
               + (f" tier={self.tier}" if self.tier else ""), flush=True)
+        from ..monitor import boot
+
+        boot.complete()
         try:
             httpd.serve_forever()
         except KeyboardInterrupt:
@@ -624,6 +637,12 @@ class ServingWorker:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    from ..env import starts_dir
+    from ..monitor import boot
+
+    # `boot:interpreter` and `boot:imports` (the package, jax and this
+    # module's own imports) end here, by hand: no span could open earlier
+    boot.enter("serve-worker", starts_dir())
     ap = argparse.ArgumentParser(prog="kungfu_tpu.serving.worker")
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, required=True)
@@ -661,6 +680,10 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     apply_platform_override()
     enable_compile_cache()
+    # the TPU runtime comes up at the first question about devices: asked
+    # here, so that it has a name (`boot:backend`) and not a share of the
+    # weights
+    T.backend_devices()
     return ServingWorker(args).serve()
 
 

@@ -25,6 +25,7 @@ attention="ring", mesh=...) runs its own shard_map island inside the jit.
 """
 from __future__ import annotations
 
+import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import numpy as np
@@ -37,7 +38,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .parallel.sharding import param_shardings, rules_for_mesh
 from .plan import make_mesh
 from .train import TrainState, _put_local_shard
-from .utils.trace import trace_scope
+from .monitor import boot, programs
+from .utils.trace import BOOT_CAT, record_span, trace_scope
 
 
 class MeshTrainer:
@@ -107,13 +109,17 @@ class MeshTrainer:
         self._donate = donate
         self._shardings = None
         self._step_fn = None
+        self._booted = False
+        programs.listen()  # the compile ledger of this trainer's start
 
     # -- init -------------------------------------------------------------------------
 
+    @trace_scope("train:init", cat=BOOT_CAT)
     def init(self, rng, sample_batch) -> TrainState:
         """Initialize params under the logical rules and place them sharded.
 
-        `sample_batch` is a (host) global batch used only for shapes.
+        `sample_batch` is a (host) global batch used only for shapes.  A
+        boot phase (`train:init`), which ends when the state is placed.
         """
         self._base_rng = jax.random.fold_in(rng, 0x5eed)  # loss-rng stream
         self._multi = {}  # compiled multi-step fns capture the base rng
@@ -148,6 +154,8 @@ class MeshTrainer:
             lambda x: x.sharding, (placed, opt_state)
         )
         self._step_fn = self._build_step()
+        self._booted = False  # the next train_step is this state's first
+        jax.block_until_ready((placed, opt_state))
         return TrainState(params=placed, opt_state=opt_state, step=0)
 
     def _step_body(self, params, opt_state, batch, rng):
@@ -212,12 +220,20 @@ class MeshTrainer:
             raise RuntimeError("call init() before train_step()")
         # the host's part of a step: the rng fold and the jitted call until
         # it returns (the device runs on after it)
+        t0 = time.monotonic()
         with trace_scope("train:step", cat="train",
                          args={"step": state.step}), self.mesh:
             params, opt_state, metrics = self._step_fn(
                 state.params, state.opt_state, batch,
                 self._step_rng(state.step),
             )
+        if not self._booted:
+            # the first step of a state traces, lowers and loads or compiles
+            # the step program: the trainer's first call, and boot complete
+            self._booted = True
+            record_span("boot:first_call", t0, cat=BOOT_CAT,
+                        args={"program": "train:step"})
+            boot.complete()
         return TrainState(params, opt_state, state.step + 1), metrics
 
     def lower_step(self, state: TrainState, batch: Any):
@@ -226,7 +242,7 @@ class MeshTrainer:
         compiler, `.compile().as_text()` the one it produced."""
         if self._step_fn is None:
             raise RuntimeError("call init() before lower_step()")
-        with self.mesh:
+        with trace_scope("train:lower", cat=BOOT_CAT), self.mesh:
             return self._step_fn.lower(
                 state.params, state.opt_state, batch,
                 self._step_rng(state.step),

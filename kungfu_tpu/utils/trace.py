@@ -26,6 +26,15 @@ timeline beside the device's operations whenever a capture is running, and
 costs a few microseconds when none is.  With KFT_CONFIG_ENABLE_TRACE set it
 also records a `Span` in the ring buffer.  `record_span` / `child_span` are
 timed by hand, after the fact, so they reach the ring only.
+
+Boot phases (docs/observability.md "Boot"): a span of category `boot` is
+kept whether or not KFT_CONFIG_ENABLE_TRACE is set, in a short bounded list
+of its own (`boot_spans()`, at most BOOT_CAPACITY a process), so every
+start can say where its seconds went; the ring and its gate are untouched.
+The job clock a boot is read on is anchored on the launcher's
+`KFT_JOB_START`, which both launchers stamp always (`stamp_job_start`: the
+launcher's real start, the kernel's), and each spawn stamps
+`KFT_PROC_START`.  monitor/boot.py assembles the start record from them.
 `profile_to(dir)` wraps a block in a full `jax.profiler.trace` capture.
 
 Distributed trace context (docs/observability.md "Request tracing"): a
@@ -50,7 +59,7 @@ import sys
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, Iterator, List, Optional, Sequence, Union
+from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple, Union
 
 from .log import get_logger
 
@@ -66,7 +75,9 @@ DEFAULT_FLUSH_S = 10.0
 # wall/monotonic anchor pair, stamped once at import (reference
 # _utils.py:33-50: the launcher stamps KFT_JOB_START; each worker stamps its
 # own proc start).  Durations use the monotonic clock ONLY; the wall stamp
-# is anchor metadata for cross-host alignment.
+# is anchor metadata for cross-host alignment.  Despite the name this is the
+# time THIS MODULE was imported, which is when the package's imports reach
+# it, not the start of the process: `process_start_mono()` is that.
 _PROC_START_MONO = time.monotonic()
 _PROC_START_WALL = time.time()
 
@@ -88,6 +99,44 @@ _JOB_START_MONO = _PROC_START_MONO - (_PROC_START_WALL - _job_start_wall())
 def job_now(mono: Optional[float] = None) -> float:
     """Seconds since job start, on the monotonic clock."""
     return (time.monotonic() if mono is None else mono) - _JOB_START_MONO
+
+
+def wall_to_mono(wall: float) -> float:
+    """A wall-clock stamp another process took (`KFT_PROC_START`) on this
+    process's monotonic clock, through the import-time anchor pair."""
+    return _PROC_START_MONO - (_PROC_START_WALL - wall)
+
+
+_process_start_mono: Optional[float] = None
+
+
+def process_start_mono() -> float:
+    """This process's real start (the kernel's: `/proc/self/stat`) on its
+    monotonic clock, read once; where the kernel does not say, the import
+    of this module, which is the earliest stamp the process itself holds."""
+    global _process_start_mono
+    if _process_start_mono is None:
+        try:
+            with open("/proc/self/stat") as f:
+                after_comm = f.read().rsplit(")", 1)[1].split()
+            started = int(after_comm[19]) / os.sysconf("SC_CLK_TCK")  # field 22
+            age = time.clock_gettime(time.CLOCK_BOOTTIME) - started
+            _process_start_mono = min(time.monotonic() - age, _PROC_START_MONO)
+        except (OSError, ValueError, IndexError, AttributeError):
+            _process_start_mono = _PROC_START_MONO
+    return _process_start_mono
+
+
+def stamp_job_start() -> None:
+    """A launcher's first act: `KFT_JOB_START` is this process's real start
+    unless an outer launcher stamped it already (every child inherits it),
+    and this process's own job clock is re-anchored on it, so the
+    launcher's seconds before its first worker are on the job clock too."""
+    global _JOB_START_MONO
+    mono = process_start_mono()
+    os.environ.setdefault(
+        "KFT_JOB_START", repr(_PROC_START_WALL - (_PROC_START_MONO - mono)))
+    _JOB_START_MONO = wall_to_mono(_job_start_wall())
 
 
 def enabled() -> bool:
@@ -309,6 +358,99 @@ def export_chrome_trace(
     }
 
 
+# -- boot phases -----------------------------------------------------------------------
+
+#: the category whose spans are kept with tracing off (module docstring)
+BOOT_CAT = "boot"
+#: a start has a dozen phases and one first call for each tracked program;
+#: what a long-lived process adds later (respawns, new signatures) stops here
+BOOT_CAPACITY = 64
+
+_boot_lock = threading.Lock()
+_boot_spans: List[Span] = []
+_start_record_dir = ""
+
+
+def _keep(span: Span, ring: bool) -> None:
+    """A finished span to where it is kept: the boot list for a boot phase
+    (tracing on or off), the ring when tracing is on."""
+    if span.cat == BOOT_CAT:
+        with _boot_lock:
+            if len(_boot_spans) < BOOT_CAPACITY:
+                _boot_spans.append(span)
+    if ring:
+        global_trace_buffer().add(span)
+
+
+def boot_spans() -> List[Span]:
+    """The process's boot phases so far, in the order they closed."""
+    with _boot_lock:
+        return list(_boot_spans)
+
+
+def package_import_mono() -> Tuple[float, float]:
+    """(start, end) of `import kungfu_tpu` on the monotonic clock, as the
+    package's `__init__` stamped them: the first and last statement of the
+    program a process runs before its entry point's own."""
+    pkg = sys.modules.get(__name__.split(".")[0])
+    t0 = getattr(pkg, "_IMPORT_T0", _PROC_START_MONO)
+    return t0, getattr(pkg, "_IMPORT_T1", None) or max(t0, _PROC_START_MONO)
+
+
+_backend_seen = False
+
+
+def backend_phase(t0_mono: float, t1_mono: float, **args: Any) -> None:
+    """Keep `boot:backend`, the TPU runtime coming up at the process's first
+    question about devices, once: whoever asked later found them there."""
+    global _backend_seen
+    if not _backend_seen:
+        _backend_seen = True
+        record_span("boot:backend", t0_mono, t1_mono, cat=BOOT_CAT, args=args)
+
+
+def backend_devices():
+    """`jax.devices()`, as the `boot:backend` phase where this is the first
+    time the process asks (`plan.make_mesh`, below this module, stamps its
+    own question for monitor/programs.py `boot_phases` to hand over)."""
+    import jax
+
+    if _backend_seen:
+        return jax.devices()
+    t0 = time.monotonic()
+    with _annotation("boot:backend", None):
+        devs = jax.devices()
+    backend_phase(t0, time.monotonic(), platform=devs[0].platform,
+                  devices=len(devs))
+    return devs
+
+
+def _reset_boot_for_tests() -> None:
+    """A process with no boot so far: no phase kept, no backend phase
+    taken, no record armed."""
+    global _backend_seen, _start_record_dir
+    with _boot_lock:
+        _boot_spans.clear()
+    _backend_seen, _start_record_dir = False, ""
+
+
+def arm_start_record(directory: str) -> None:
+    """Let this process write its start record (monitor/boot.py) under
+    `directory`: called by the program's entry points and by
+    `env.enable_compile_cache()`, never at import, so importing the
+    package or running a unit test writes nothing."""
+    global _start_record_dir
+    _start_record_dir = directory
+
+
+def start_record_dir() -> str:
+    """Where this process's start record goes ("" = it writes none):
+    KFT_TRACE_DUMP_DIR when set, else what `arm_start_record` was given."""
+    if not _start_record_dir:
+        return ""
+    return os.environ.get(DUMP_DIR_ENV) or _start_record_dir
+
+
 # -- global per-process buffer ---------------------------------------------------------
 
 _global_buffer: Optional[TraceBuffer] = None
@@ -402,19 +544,21 @@ def global_trace_buffer() -> TraceBuffer:
 def record_span(name: str, t0_mono: float, t1_mono: Optional[float] = None,
                 cat: str = "", args: Optional[Dict[str, Any]] = None) -> None:
     """Record a span from explicit monotonic stamps (for phases timed by
-    hand, e.g. the heal decomposition).  No-op when tracing is off.  Under
-    an active TraceContext the span joins that trace as a child."""
-    if not enabled():
+    hand, e.g. the heal decomposition).  No-op when tracing is off, unless
+    the span is a boot phase (`cat="boot"`: kept in the boot list always).
+    Under an active TraceContext the span joins that trace as a child."""
+    on = enabled()
+    if not on and cat != BOOT_CAT:
         return
     t1 = time.monotonic() if t1_mono is None else t1_mono
     ctx = current_context()
-    global_trace_buffer().add(Span(
+    _keep(Span(
         name=name, t_start=job_now(t0_mono), dur=max(0.0, t1 - t0_mono),
         cat=cat, tid=threading.get_ident() & 0x7FFFFFFF, args=args,
         trace_id=ctx.trace_id if ctx else "",
         span_id=new_span_id() if ctx else "",
         parent_id=ctx.span_id if ctx else "",
-    ))
+    ), on)
 
 
 def child_span(name: str, t0_mono: float, t1_mono: Optional[float] = None,
@@ -487,9 +631,11 @@ def trace_scope(name: str, cat: str = "",
     single trace.  `args` is held by reference and serialized at scrape
     time, so a scope body may fill in outcome fields (e.g. per-round
     acceptance) before it closes; the annotation takes its scalars as they
-    are when the scope opens."""
+    are when the scope opens.  A boot phase (`cat="boot"`) is also kept in
+    the boot list, tracing on or off."""
     with _annotation(name, args):
-        if not enabled():
+        on = enabled()
+        if not on and cat != BOOT_CAT:
             yield
             return
         parent = current_context()
@@ -501,13 +647,13 @@ def trace_scope(name: str, cat: str = "",
                 yield
         finally:
             t1 = time.monotonic()
-            global_trace_buffer().add(Span(
+            _keep(Span(
                 name=name, t_start=job_now(t0), dur=t1 - t0, cat=cat,
                 tid=threading.get_ident() & 0x7FFFFFFF, args=args,
                 trace_id=parent.trace_id if parent else "",
                 span_id=sid,
                 parent_id=parent.span_id if parent else "",
-            ))
+            ), on)
 
 
 @contextlib.contextmanager

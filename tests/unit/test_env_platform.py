@@ -15,8 +15,13 @@ _REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__
 @pytest.fixture
 def config_updates(monkeypatch):
     """Record jax.config.update calls instead of applying them."""
+    from kungfu_tpu.utils import trace
+
     calls = []
     monkeypatch.setattr(jax.config, "update", lambda k, v: calls.append((k, v)))
+    # enable_compile_cache() also lets its process write a start record:
+    # this one is pytest's, not an entry point, so the permission goes again
+    monkeypatch.setattr(trace, "_start_record_dir", "")
     return calls
 
 
