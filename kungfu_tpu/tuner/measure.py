@@ -106,8 +106,10 @@ def default_flash_arms(heads_dims: Tuple[Tuple[int, int], ...] = ((16, 64), (8, 
         for bq, bk in ((128, 128), (256, 256), (512, 512), (256, 512),
                        (512, 1024)):
             yield ("ours", heads, dim, bq, bk)
-    # the blocked-XLA backward (auto choice below seq 4096) reads block_k
-    # as its scan granularity — sweep it too
+    # the blocked-XLA backward (never the auto choice where Pallas runs
+    # since PR 39: the kernels lead at every swept length, docs/KERNELS.md
+    # "Backward choice"; kept as the A/B arm) reads block_k as its scan
+    # granularity — sweep it too
     for heads, dim in heads_dims:
         for bq, bk in ((128, 128), (128, 512)):
             yield ("ours_xla_bwd", heads, dim, bq, bk)

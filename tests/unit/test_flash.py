@@ -180,59 +180,8 @@ def test_bwd_bad_argument_raises():
         flash_attention(q, k, v, causal=True, interpret=True, backward="nope")
 
 
-@pytest.mark.parametrize(
-    "l,hkv,window,auto_seq,expect",
-    [
-        (96, 2, None, 4096, "xla"),      # short seq, MHA: one-pass XLA wins
-        (96, 2, 32, 4096, "pallas"),     # sliding window: kernel skips blocks
-        (96, 1, None, 4096, "pallas"),   # GQA: kernel avoids head repeats
-        (96, 2, None, 64, "pallas"),     # seq >= KFT_FLASH_BWD_AUTO_SEQ
-    ],
-)
-def test_bwd_auto_selection(monkeypatch, l, hkv, window, auto_seq, expect):
-    """The shape-based auto heuristic picks the measured-faster backward.
-
-    The on-TPU branch is unreachable on CPU (`_use_interpret` preempts it),
-    so simulate it: pretend the backend is TPU and stub both backward
-    implementations with recorders returning shape-correct zeros."""
-    import kungfu_tpu.ops.flash as F
-
-    calls = []
-
-    def fake_pallas(q, k, v, o, lse, g, *a, **kw):
-        calls.append("pallas")
-        return jnp.zeros_like(q), jnp.zeros_like(k), jnp.zeros_like(v)
-
-    def fake_blocked(q, k, v, o, lse, g, *a, **kw):
-        calls.append("xla")
-        return jnp.zeros_like(q), jnp.zeros_like(k), jnp.zeros_like(v)
-
-    monkeypatch.setattr(F, "_use_interpret", lambda: False)
-    monkeypatch.setattr(F, "_bwd_pallas", fake_pallas)
-    monkeypatch.setattr(F, "_bwd_blocked", fake_blocked)
-    monkeypatch.delenv("KFT_FLASH_BWD", raising=False)
-    monkeypatch.setenv("KFT_FLASH_BWD_AUTO_SEQ", str(auto_seq))
-
-    h = 2
-    q, _, _ = _rand(1, l, h, 16, seed=5)
-    _, k, v = _rand(1, l, hkv, 16, seed=6)
-
-    def loss(q, k, v):
-        # interpret must stay None: forcing it would preempt the auto branch.
-        # The fwd kernel would then hit Mosaic on CPU — stub it too.
-        return jnp.sum(flash_attention(q, k, v, causal=True,
-                                       window=window) ** 2)
-
-    ref_fwd = F._fwd_reference
-
-    def fake_fwd(q, k, v, scale, causal, block_q, block_k, interpret, h_,
-                 hkv_, window_):
-        return ref_fwd(q, F._expand_kv(k, h_, hkv_),
-                       F._expand_kv(v, h_, hkv_), scale, causal, window_)
-
-    monkeypatch.setattr(F, "_flash_fwd", fake_fwd)
-    jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    assert calls and all(c == expect for c in calls), (calls, expect)
+# the backward arm's rule (mode, explicit argument, KFT_FLASH_BWD) is
+# tested in tier-1: tests/unit/test_flash_cached.py::test_bwd_auto_selection
 
 
 def test_bwd_env_garbage_falls_through(monkeypatch):

@@ -454,12 +454,17 @@ def test_flash_kernels_carry_stable_names():
     from kungfu_tpu.ops.flash import flash_attention
 
     q = jnp.zeros((1, 256, 2, 128), jnp.bfloat16)
+    kv = jnp.zeros((1, 256, 1, 128), jnp.bfloat16)
 
     def loss(q, k, v):
         return flash_attention(q, k, v, causal=True, interpret=False,
                                backward="pallas").astype(jnp.float32).sum()
 
-    text = jax.export.export(jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
-                             platforms=["tpu"])(q, q, q).mlir_module()
-    for name in ("kft_flash_fwd", "kft_flash_bwd_dq", "kft_flash_bwd_dkdv"):
-        assert name in text, name
+    # MHA: the one-pass backward; GQA: the dq + dk/dv pair
+    for k, names in ((q, ("kft_flash_fwd", "kft_flash_bwd")),
+                     (kv, ("kft_flash_fwd", "kft_flash_bwd_dq",
+                           "kft_flash_bwd_dkdv"))):
+        text = jax.export.export(jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
+                                 platforms=["tpu"])(q, k, k).mlir_module()
+        for name in names:
+            assert f'kernel_name = "{name}"' in text, name
