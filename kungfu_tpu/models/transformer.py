@@ -185,6 +185,29 @@ class TransformerConfig:
     # of the result is left out here (parallel/moe.py)
     experts_held: int = 0
     expert_offset: int = 0
+    # a norm of the config's flavor AFTER each sublayer of the standard
+    # block, before its residual add (the published `sandwich_norm`):
+    # a = x + N2(A(N1(x))), y = a + N4(F(N3(a))); scales `ln1_post`, `ln2_post`
+    sandwich_norm: bool = False
+    # leading dense layers: the first `first_dense_layers` blocks keep the
+    # dense FFN (width d_ff) and the `moe_every` pattern starts after them
+    # (the published `first_k_dense_replace`)
+    first_dense_layers: int = 0
+    # shared experts: one dense SwiGLU/GELU FFN of width n_shared_experts x
+    # expert_width every token passes through beside its routed experts,
+    # added to their sum unweighted (parallel/moe.py); computed here in
+    # full whatever share of the routed experts is held
+    n_shared_experts: int = 0
+    # what the router's outputs are scored by: "softmax" over the whole
+    # router, or "sigmoid" of each output on its own (then `norm_topk_prob`
+    # is what makes the chosen weights sum to 1)
+    router_scores: str = "softmax"  # "softmax" | "sigmoid"
+    # multi-token-prediction modules (`MTPModule`: 0 or 1).  The module's
+    # parameters are `params["mtp_0"]` of the one tree (made by a training-
+    # mode init, from keys of their own: the main model's weights are the
+    # same with or without it); no program of the main model runs it.  The
+    # serving engine's target-resident drafter does (serving/spec.py)
+    mtp_layers: int = 0
     # mesh is needed for attention="ring"/"ulysses" (shard_map region)
     mesh: Optional[Mesh] = None
     sp_axis: str = "sp"
@@ -247,10 +270,10 @@ class TransformerConfig:
         assert self.block in ("standard", "shortcut_moe"), self.block
         if self.block == "shortcut_moe":
             assert self.n_experts > 0, "the shortcut branch is an expert layer"
-        # the one model that has either has both: no block of another pairing
-        # is built or tested
-        assert (self.block == "shortcut_moe") == bool(self.kv_lora_rank), (
-            "latent attention comes with the shortcut block, and only with it")
+            assert self.kv_lora_rank and not self.sandwich_norm \
+                and not self.first_dense_layers, (
+                    "the shortcut block: latent attention, pre-norms only, "
+                    "every layer alike")
         if self.kv_lora_rank:
             assert (self.q_lora_rank > 0 and self.qk_nope_head_dim > 0
                     and self.v_head_dim > 0), "latent attention needs its sizes"
@@ -266,9 +289,18 @@ class TransformerConfig:
             assert 0 < self.experts_held and \
                 self.expert_offset + self.experts_held <= self.n_experts, (
                     "the experts held lie among the routed experts")
+        assert self.router_scores in ("softmax", "sigmoid"), self.router_scores
         if self.n_zero_experts or self.router_bias or self.experts_held \
-                or self.routed_scaling_factor != 1.0:
+                or self.routed_scaling_factor != 1.0 or self.n_shared_experts \
+                or self.router_scores != "softmax":
             assert self.n_experts > 0, "router options need experts"
+        assert 0 <= self.first_dense_layers <= self.n_layers
+        assert self.mtp_layers in (0, 1), "one prediction module at most"
+        if self.mtp_layers:
+            assert not self.tie_embeddings and self.block == "standard" \
+                and self.mesh is None, (
+                    "the prediction module: an untied head, the standard "
+                    "block, one device")
 
     @property
     def kv_heads(self) -> int:
@@ -282,6 +314,13 @@ class TransformerConfig:
     def local_experts(self) -> int:
         """Routed experts whose weights this process holds."""
         return self.experts_held or self.n_experts
+
+    def layer_has_experts(self, i: int) -> bool:
+        """Whether standard block `i` is an expert layer: none of the
+        leading dense layers, then every `moe_every`-th."""
+        j = i - self.first_dense_layers
+        return (self.n_experts > 0 and j >= 0
+                and j % self.moe_every == self.moe_every - 1)
 
 
 def _attention_kind(cfg: TransformerConfig) -> str:
@@ -761,6 +800,11 @@ def _norm(cfg, name: str):
 
 
 class Block(nn.Module):
+    """`cfg.block == "standard"`: attention (`Attention`, or `MLA` when
+    `cfg.kv_lora_rank`), then the dense FFN or the expert layer, each on
+    the normed stream and added back to it; under `cfg.sandwich_norm` each
+    sublayer's output is normed once more before the add."""
+
     cfg: TransformerConfig
     use_moe: bool = False
 
@@ -769,13 +813,23 @@ class Block(nn.Module):
         cfg = self.cfg
         ln = partial(_norm, cfg)
         drop = nn.Dropout(cfg.dropout, deterministic=not train)
-        x = x + drop(Attention(cfg, name="attn")(ln(name="ln1")(x), live))
+
+        def post(name, y):
+            if not cfg.sandwich_norm:
+                return y
+            return ln(name=name)(y).astype(cfg.dtype)
+
+        attn = (MLA if cfg.kv_lora_rank else Attention)(cfg, name="attn")
+        x = x + drop(post("ln1_post", attn(ln(name="ln1")(x), live)))
         if self.use_moe:
             from ..parallel.moe import MoE
 
-            x = x + drop(MoE(cfg, name="moe")(ln(name="ln2")(x), live))
+            shared = MLP if cfg.n_shared_experts else None
+            y = MoE(cfg, shared_ffn=shared, name="moe")(
+                ln(name="ln2")(x), live)
         else:
-            x = x + drop(MLP(cfg, name="mlp")(ln(name="ln2")(x)))
+            y = MLP(cfg, name="mlp")(ln(name="ln2")(x))
+        x = x + drop(post("ln2_post", y))
         return logical_constraint(x, ("batch", "seq", "act_embed"), cfg.mesh)
 
 
@@ -843,12 +897,52 @@ class _Head(nn.Module):
         return jnp.einsum("bld,dv->blv", x.astype(jnp.float32), w)
 
 
+class MTPModule(nn.Module):
+    """One multi-token-prediction module (`cfg.mtp_layers`), DeepSeek-V3's
+    published form: for position i, with the target's final-normed hidden
+    h_i and the NEXT token t_{i+1},
+
+        z = [N_e(Emb(t_{i+1})) ; N_h(h_i)] W_eh        (2 d_model -> d_model)
+        logits for t_{i+2} = Head(N_f(Block(z)))
+
+    `Block` is one standard block of the config (an expert layer when the
+    model has experts) with its own weights and, in decode mode, its own
+    cache; N_e, N_h, N_f are the module's own norms.  The embedding table
+    and the head are the TARGET's, handed in as arrays (`embedding`
+    [vocab, d_model] as stored, `head` [d_model, vocab] float32), so the
+    module's parameters are `params["mtp_0"]` alone: `enorm`, `hnorm`,
+    `eh_proj`, `block`, `ln_f`.  `hidden` [B, L, d_model], `next_tokens`
+    [B, L]; `live` as `TransformerLM` takes it."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, hidden, next_tokens, embedding, head, live=None):
+        cfg = self.cfg
+        with jax.named_scope("mtp"):
+            e = embedding[next_tokens].astype(cfg.dtype)
+            z = jnp.concatenate(
+                [_norm(cfg, "enorm")(e), _norm(cfg, "hnorm")(hidden)],
+                axis=-1).astype(cfg.dtype)
+            x = _dense(cfg.d_model, "eh_proj", (None, "embed"), cfg.dtype)(z)
+            x = Block(cfg, use_moe=cfg.n_experts > 0, name="block")(
+                x, False, live)
+            x = _norm(cfg, "ln_f")(x)
+            return jnp.einsum("bld,dv->blv", x.astype(jnp.float32),
+                              head.astype(jnp.float32))
+
+
 class TransformerLM(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, tokens, train: bool = False, live=None):
-        """`live` [B] bool, for the slot-cache programs of the serving
+    def __call__(self, tokens, train: bool = False, live=None,
+                 return_hidden: bool = False):
+        """`return_hidden`: (logits, the final-normed hidden states
+        [B, L, d_model] float32 the head read) instead of the logits: what
+        a prediction module (`MTPModule`) continues from.
+
+        `live` [B] bool, for the slot-cache programs of the serving
         engine: a row that is not live holds no request, and does no work
         that another row or a later call could see: in decode mode its
         cache cursor and overflow flag stay where they are (`Attention`),
@@ -919,11 +1013,11 @@ class TransformerLM(nn.Module):
             if shortcut:  # every block carries the expert branch
                 block = block_cls(cfg, name=f"block_{i}")
             else:
-                use_moe = cfg.n_experts > 0 and (
-                    i % cfg.moe_every == cfg.moe_every - 1)
-                block = block_cls(cfg, use_moe=use_moe, name=f"block_{i}")
+                block = block_cls(cfg, use_moe=cfg.layer_has_experts(i),
+                                  name=f"block_{i}")
             x = block(x, train, live)
         x = _norm(cfg, "ln_f")(x)
+        hidden = x
         if cfg.head == "hidden":
             # deferred head: the streaming loss (lm_loss_chunked) consumes
             # hidden states + the head kernel directly.  Touch the head at
@@ -948,16 +1042,28 @@ class TransformerLM(nn.Module):
                 "bld,vd->blv", x.astype(jnp.float32), e.astype(jnp.float32)
             )
         else:
-            logits = _Head(cfg, name="lm_head")(x)
+            head = _Head(cfg, name="lm_head")
+            logits = head(x)
+            if cfg.mtp_layers and self.is_initializing() and not cfg.decode:
+                # the module's parameters belong to the one tree; a
+                # decode-mode init (the engine's cache shapes) leaves it
+                # out, so its cache is its drafter's and not the model's.
+                # Shapes alone matter here: the rows are not moved one to
+                # the left (an init over one token would have none left)
+                MTPModule(cfg, name="mtp_0")(
+                    hidden, tokens,
+                    nn.meta.unbox(emb.variables["params"]["embedding"]),
+                    nn.meta.unbox(head.variables["params"]["kernel"]))
         # batch-sharded logits ("act_vocab" keeps tp vocab-parallelism,
         # resolves to None under fsdp): without this the partitioner may
         # shard the head matmul over the kernel's fsdp storage dims,
         # resharding the whole activation (involuntary full remat).
         # Plain "vocab" would be wrong here — under fsdp rules it outranks
         # "batch" for the fsdp axis and would shard logits feature-wise.
-        return logical_constraint(
+        logits = logical_constraint(
             logits, ("batch", "seq", "act_vocab"), cfg.mesh
         )
+        return (logits, hidden) if return_hidden else logits
 
 
 @functools.lru_cache(maxsize=8)

@@ -58,9 +58,16 @@ def _tiles(m: int, k: int, n: int) -> tuple:
     visit's wasted rows (a tile shared by several groups is multiplied once
     for each) and the weight re-reads (once a visit) balance."""
     tm = min(256, _round_up(m, 16))
-    tk = k if k <= 1024 else 1024
-    tn = n if n <= 1024 else 1024
-    return tm, tk, tn
+    return tm, _tile(k), _tile(n)
+
+
+def _tile(d: int) -> int:
+    """A dimension of the rhs whole when it is at most 1024, else the
+    largest multiple of 128 (whole lanes) up to 1024 that divides it: 1024
+    for 2048 and 6144, 768 for 7680 = 60 x 128."""
+    if d <= 1024:
+        return d
+    return max((t for t in range(128, 1025, 128) if d % t == 0), default=1024)
 
 
 def visit_metadata(group_sizes: jax.Array, m: int, tm: int):

@@ -19,7 +19,8 @@ is not live are routed to nobody: their k assignments take the expert id
 `n_experts`, which the stable sort puts after every real one and the count
 drops, so the grouped matmuls get group sizes that sum to k x live tokens
 (ops/gmm.py: the rows left over come back as zeros) and read only the
-experts live rows hit.  Such a row's output is zero, and finite whatever
+experts live rows hit.  Such a row's output is zero (beside a shared
+expert's term, which is per row and nobody else's), and finite whatever
 its input.
 
 Experts carry the logical axes ("expert", "embed", "mlp"), so an `ep` mesh
@@ -52,6 +53,22 @@ combine.  An identity expert's part is computed here in full (it needs no
 exchange anywhere) and owns no grouped-matmul row either.  The weights
 are [held, ...]; local id = e - expert_offset.
 
+Sigmoid scores and a shared expert (`cfg.router_scores == "sigmoid"`,
+`cfg.n_shared_experts`; DeepSeek-V3's published layer, absent from the
+program without their fields):
+
+    s      = sigmoid(u W_r)          float32, each output on its own
+    top-k  = the k largest s
+    g_e    = routed_scaling_factor * s_e / (sum of the chosen s + 1e-20)
+             (`norm_topk_prob`: renormalised first, then scaled)
+    MoE(u) = FFN_shared(u) + sum over chosen HELD e of g_e * expert_e(u)
+
+The shared expert is a dense FFN of width n_shared_experts x the experts'
+width (`shared`: `nn.Dense` kernels like the dense `MLP`'s) that every
+token passes through, unweighted.  It needs no exchange, so every rank of
+a deployment computes it in full and it is no part of the share: the
+parts all the shares give add up to the uncut layer with it counted once.
+
 Counted on the device in decode mode, in the "moe_stats" collection
 (declared only there, updated only when the caller makes it mutable: the
 serving engine's slot-cache programs): `assignments` [experts], rows routed
@@ -63,6 +80,7 @@ zero + absent = k x live tokens.
 """
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Any
 
@@ -75,23 +93,31 @@ from ..ops.gmm import grouped_matmul
 STATS = "moe_stats"
 
 
-def route(probs: jax.Array, k: int, renormalise: bool, bias=None):
+def route(probs: jax.Array, k: int, renormalise: bool, bias=None,
+          eps: float = 0.0):
     """(gates [T, k] float32, experts [T, k] int32) from probs [T, E].
     `lax.top_k` is by value, and puts the lower index first on a tie.
     With `bias` [E] the choice is by probs + bias and the gates are the
-    chosen experts' probs themselves."""
+    chosen experts' probs themselves.  `eps` is added to the sum the
+    chosen weights are renormalised by (sigmoid scores, whose sum no
+    softmax bounds away from 0: the published 1e-20)."""
     if bias is None:
         gates, experts = jax.lax.top_k(probs, k)
     else:
         _, experts = jax.lax.top_k(probs + bias, k)
         gates = jnp.take_along_axis(probs, experts, axis=-1)
     if renormalise:
-        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        total = jnp.sum(gates, axis=-1, keepdims=True)
+        gates = gates / (total + eps if eps else total)
     return gates, experts.astype(jnp.int32)
 
 
 class MoE(nn.Module):
     cfg: Any  # TransformerConfig
+    #: the dense FFN module a shared expert is built from
+    #: (models/transformer.py `MLP`), handed in by the block that builds the
+    #: layer: this package imports nothing of `models`
+    shared_ffn: Any = None
 
     @nn.compact
     def __call__(self, x, live=None):
@@ -129,8 +155,11 @@ class MoE(nn.Module):
                 # different expert, a discrete change of the output
                 logits = jnp.dot(flat.astype(jnp.float32), router,
                                  precision=jax.lax.Precision.HIGHEST)
-                probs = jax.nn.softmax(logits, axis=-1)  # [T, wide]
-                gates, experts = route(probs, k, cfg.norm_topk_prob, bias)
+                sigmoid = cfg.router_scores == "sigmoid"
+                probs = (jax.nn.sigmoid(logits) if sigmoid
+                         else jax.nn.softmax(logits, axis=-1))  # [T, wide]
+                gates, experts = route(probs, k, cfg.norm_topk_prob, bias,
+                                       eps=1e-20 if sigmoid else 0.0)
                 if cfg.routed_scaling_factor != 1.0:
                     gates = gates * cfg.routed_scaling_factor
                 chosen = experts
@@ -176,6 +205,14 @@ class MoE(nn.Module):
                 with jax.named_scope("moe.zero"):
                     out = (out + zero_gate[:, None]
                            * flat.astype(jnp.float32)).astype(cfg.dtype)
+            if cfg.n_shared_experts:
+                assert self.shared_ffn is not None, (
+                    "a shared expert needs the block's dense FFN module")
+                with jax.named_scope("moe.shared"):
+                    wide_cfg = dataclasses.replace(
+                        cfg, d_ff=cfg.n_shared_experts * width)
+                    out = out + self.shared_ffn(wide_cfg, name="shared")(
+                        x).reshape(T, Dm)
 
         if not partial_share:
             # the load-balancing loss is over all the experts: a share of

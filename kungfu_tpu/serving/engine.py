@@ -43,7 +43,10 @@ greedy output (docs/serving.md):
   * speculative decoding (`spec=` — serving/spec.py): a draft model
     proposes, the target verifies k tokens in ONE [slots, k] forward — the
     single new compiled decode signature — and per-slot accept cursors roll
-    back through `slots.set_cursors`
+    back through `slots.set_cursors`.  A drafter that is part of the target
+    (`spec.reads_hidden`: the model's own prediction module) is handed the
+    target's final hidden states: the prefill and verify programs built
+    for it return them beside what they always return, on the device
   * disaggregation (serving/disagg.py): `prefill_only` runs the prefill
     half with no slot at all (the prefill-tier surface), and
     `submit_prefilled` admits shipped KV rows straight into a slot with no
@@ -253,6 +256,18 @@ class ServingEngine:
         self.params_version = 0
 
         model = self.model
+        # a target-resident drafter continues from the target's hidden
+        # states: the prefill and verify programs then return them too.
+        # Without one they are the programs they always were
+        want_hidden = self._want_hidden = bool(
+            getattr(spec, "reads_hidden", False))
+        self._prefill_hidden = None  # the last cold prefill's, for `_admit_to`
+
+        def _run(variables, tokens, **kw):
+            """(logits, hidden or None, the collections the call updated)."""
+            out, st = model.apply(variables, tokens,
+                                  return_hidden=want_hidden, **kw)
+            return (*out, st) if want_hidden else (out, None, st)
 
         def _fix_cursor(cache, true_len):
             def fix(path, leaf):
@@ -279,7 +294,7 @@ class ServingEngine:
             # the zeroed template on a cold start, or a warm cache whose
             # cursor sits at the prefix-cache hit length — the forward reads
             # positions from the cursor, so ONE program serves both.
-            logits, st = model.apply(
+            logits, hidden, st = _run(
                 {"params": params, "cache": cache_small}, tokens,
                 mutable=["cache"]
             )
@@ -287,25 +302,28 @@ class ServingEngine:
                 logits, n_new - 1, axis=1, keepdims=False
             )[0].astype(jnp.float32)  # [V]
             first = jnp.argmax(last).astype(jnp.int32)
-            return first, last, _fix_cursor(st["cache"], total_len)
+            out = first, last, _fix_cursor(st["cache"], total_len)
+            return (*out, hidden) if want_hidden else out
 
         def _apply_slots(params, cache, counters, toks):
             """The model over the slot cache: (logits, cache, counters,
-            live).  A slot whose first token is FREE holds no request: the
-            model is told (`live`), and looks up token 0 for it."""
+            live, hidden).  A slot whose first token is FREE holds no
+            request: the model is told (`live`), and looks up token 0 for
+            it."""
             live = toks[:, 0] >= 0
-            logits, st = model.apply(
+            logits, hidden, st = _run(
                 {"params": params, "cache": cache, **counters},
                 jnp.maximum(toks, 0), live=live,
                 mutable=["cache", *counters]
             )
-            return logits, st["cache"], {c: st[c] for c in counters}, live
+            return (logits, st["cache"], {c: st[c] for c in counters}, live,
+                    hidden)
 
         @partial(jax.jit, donate_argnums=(1, 2))
         def _decode(params, cache, counters, toks):
             # toks [slots, 1] — THE fixed decode signature; a free slot's
             # row holds FREE, does no work and its output is never read
-            logits, cache, counters, _ = _apply_slots(
+            logits, cache, counters, _, _ = _apply_slots(
                 params, cache, counters, toks)
             last = logits[:, -1].astype(jnp.float32)  # [slots, V]
             greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
@@ -319,7 +337,7 @@ class ServingEngine:
             # per-slot cursor rollback fold into the same program: one
             # dispatch, one host sync per speculative round.
             k = toks.shape[1]
-            logits, cache, counters, live = _apply_slots(
+            logits, cache, counters, live, hidden = _apply_slots(
                 params, cache, counters, toks)
             g = jnp.argmax(
                 logits.astype(jnp.float32), axis=-1
@@ -337,7 +355,10 @@ class ServingEngine:
                 return leaf
 
             cache2 = jax.tree_util.tree_map_with_path(roll, cache)
-            return g, n_acc, cache2, counters
+            out = g, n_acc, cache2, counters
+            # [slots, k, d_model]: the drafter picks the committed
+            # positions' by n_acc, on the device
+            return (*out, hidden) if want_hidden else out
 
         # the program observatory holds the engine to its own compile
         # promises: one prefill program per bucket, ONE decode signature,
@@ -544,7 +565,10 @@ class ServingEngine:
             self.cache = write_slot(self.cache, small, slot)
         self._cursor[slot] = total
         if self.spec is not None:
-            self.spec.prefill_slot(slot, toks)
+            # the prompt's hidden states, if a cold prefill just made them
+            # and the drafter reads them (None otherwise: spec.py)
+            self.spec.prefill_slot(slot, toks, self._prefill_hidden)
+            self._prefill_hidden = None
         req.ttft_s = time.monotonic() - req.submitted_t
         req.decode_t0 = time.monotonic()
         self._observe("ttft_ms", req.ttft_s * 1e3)
@@ -573,10 +597,12 @@ class ServingEngine:
                     # hits of a hot prefix skip the host assembly entirely
                     small_in = self.prefix.warm_small(self._small_cache0,
                                                       lease)
-                greedy, last_logits, small = self._prefill(
+                greedy, last_logits, small, *hidden = self._prefill(
                     self.params, small_in, jnp.asarray(padded),
                     len(suffix), total,
                 )
+                # of the whole prompt only when nothing of it was cached
+                self._prefill_hidden = hidden[0] if hidden and not hit else None
             if self.prefix is not None:
                 # lazy rows: the device->host copy only happens when the
                 # insert actually creates a node (cache-hot admissions skip)
@@ -743,11 +769,14 @@ class ServingEngine:
             t0 = time.monotonic()
             with trace_scope("serve:verify.dispatch", cat="serving"), \
                     self._dev_lock:
-                g_dev, n_acc_dev, self.cache, self._dev_counters = self._verify(
-                    self.params, self.cache, self._dev_counters,
-                    jnp.asarray(ver.astype(np.int32)),
+                ver_dev = jnp.asarray(ver.astype(np.int32))
+                (g_dev, n_acc_dev, self.cache, self._dev_counters,
+                 *hidden) = self._verify(
+                    self.params, self.cache, self._dev_counters, ver_dev,
                     jnp.asarray(proposals.astype(np.int32)),
                 )
+                if hidden:
+                    self.spec.after_verify(ver_dev, n_acc_dev, hidden[0])
             with trace_scope("serve:verify.fetch", cat="serving"):
                 g = np.asarray(g_dev)
                 n_acc = np.asarray(n_acc_dev)
@@ -862,6 +891,8 @@ class ServingEngine:
         produced by the old weights — the stream finishes consistently and
         fresh admissions use the new weights end to end)."""
         self._install(params)
+        if self._want_hidden:  # the drafter computes with the same tree
+            self.spec.set_params(self.params)
         self.params_version += 1
         if self.prefix is not None:
             self.prefix.invalidate(reason="weight_reload")

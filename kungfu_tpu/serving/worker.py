@@ -37,7 +37,8 @@ arms the radix prefix KV cache (auto = the KFT_PREFIX_CACHE_MB budget,
 prefill + monolithic tiers only); `--spec-draft PRESET --spec-k K` arms
 speculative decoding with a draft model from the zoo presets ("same" =
 self-draft with the target's own params — the mechanics A/B used by the
-bench; decode + monolithic tiers only).
+bench; "mtp" = the target's own multi-token-prediction module, `mtp_layers`
+1 in the model JSON, verify width 2; decode + monolithic tiers only).
 
 Weight resolution at boot climbs a serving flavor of the recovery ladder
 (docs/serving.md): buddy (live peer fetch over HTTP, rejoins only) ->
@@ -206,7 +207,15 @@ class ServingWorker:
                 prefix = prefix_cache_if_enabled(counters=self.counters)
         spec = None
         draft_name = getattr(args, "spec_draft", "") or ""
-        if draft_name and self.tier != "prefill":
+        if draft_name == "mtp" and self.tier != "prefill":
+            # the target's own prediction module (`mtp_layers` 1 in the
+            # model JSON): part of `params`, verify width 2 whatever
+            # --spec-k says
+            from .spec import MTPDrafter
+
+            spec = MTPDrafter(cfg, params, slots=args.slots,
+                              counters=self.counters)
+        elif draft_name and self.tier != "prefill":
             from .spec import SpecDecoder, build_draft
 
             if draft_name == "same":
@@ -239,8 +248,7 @@ class ServingWorker:
             self.counters.add_source(lambda: {
                 family: {f'kind="{kind}"': n for kind, n in rows().items()}
                 for family, rows in (
-                    ("kft_serve_decode_attn_rows_total",
-                     self.engine.decode_attn_rows),
+                    ("kft_serve_decode_attn_rows_total", self._attn_rows),
                     ("kft_serve_decode_rows_total",
                      self.engine.decode_rows))})
         self.decode_pool = None
@@ -270,6 +278,14 @@ class ServingWorker:
                     origin_rank=self.rank, cluster_version=0,
                 ).tobytes()
             return self._weights_blob
+
+    def _attn_rows(self) -> Dict[str, int]:
+        """The engine's five kinds of cache rows and, beside them, those a
+        drafter counts for its own cache (spec.py `attn_rows`)."""
+        rows = self.engine.decode_attn_rows()
+        if self.engine.spec is not None:
+            rows.update(self.engine.spec.attn_rows())
+        return rows
 
     def _moe_stats(self, refresh: bool = True):
         """The experts' counts (parallel/moe.py's `stats_*` turn them into
@@ -661,9 +677,11 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "KFT_PREFIX_CACHE_MB budget decides; decode-tier "
                          "workers never prefill, so never cache)")
     ap.add_argument("--spec-draft", default="",
-                    help="speculative decoding draft: a PRESETS name, or "
+                    help="speculative decoding draft: a PRESETS name, "
                          "'same' for self-draft (the target's own params — "
-                         "the mechanics A/B); empty disables speculation")
+                         "the mechanics A/B), or 'mtp' for the target's own "
+                         "prediction module (mtp_layers 1; width 2); empty "
+                         "disables speculation")
     ap.add_argument("--spec-k", type=int, default=4,
                     help="verify width: the [slots, k] target step commits "
                          "up to k tokens per round")
