@@ -161,6 +161,10 @@ METRIC_HELP: Dict[str, str] = {
     "kft_serve_decode_rows_total":
         "Slot-steps of the decode and verify steps: live (the slot held a "
         "request) and free (its row did no work).",
+    "kft_serve_decode_steps_total":
+        "Decode steps by what was in flight at their dispatch: ahead (the "
+        "step before unread) and synced (nothing); wasted_rows the rows "
+        "computed for a request that had ended.",
     "kft_boot_seconds":
         "Seconds of each boot phase of this process so far, on the job "
         "clock (spans of category boot, docs/observability.md Boot).",

@@ -28,6 +28,16 @@ fixed-shape slot batch:
     expert is read for it.  The row still fills its place in the fixed
     shape and its outputs are ignored.  `decode_rows` says how many
     slot-steps were of each kind
+  * one step ahead: a greedy decode-only iteration dispatches step N+1
+    before it reads step N's tokens.  A slot whose next input is step N's
+    own output holds CARRY (-2) in the upload, and the step program takes
+    that row's token from step N's [slots] tokens on the device, so the
+    chip works on N+1 while the host reads N, pushes its tokens and
+    finishes requests.  Whatever needs the host to have seen the last
+    token first reads the step in flight (`_drain`): an admission, a
+    preemption, a speculative round, a sampling request, `set_params`,
+    `in_flight`, `prefill_only`.  `decode_steps` says how often it ran
+    ahead
   * completion: a slot frees on max_new_tokens or eos; its row is reused by
     the next admission (slots.reset_slot puts the free row's cursor at 0,
     where it stays)
@@ -119,6 +129,9 @@ log = get_logger("kungfu.serving")
 #: the token id a free slot holds in the upload of a slot-cache step: no
 #: token in this row.  The step programs read liveness from it
 FREE = -1
+#: the token id of a slot whose next input is the output of the decode step
+#: in flight: the decode program takes that row from the step's own tokens
+CARRY = -2
 
 
 def default_buckets(max_len: int, lo: int = 16) -> Tuple[int, ...]:
@@ -130,6 +143,17 @@ def default_buckets(max_len: int, lo: int = 16) -> Tuple[int, ...]:
         b *= 2
     out.append(max_len)
     return tuple(out)
+
+
+@dataclasses.dataclass
+class _Step:
+    """A decode step on the device whose tokens the host has not read."""
+
+    greedy: Any                       # [slots] int32, on the device
+    logits: Any                       # [slots, vocab] float32, on the device
+    rows: List[Tuple[int, Request]]   # the (slot, request) it was sent for
+    sampling: bool                    # a row of it draws from the logits
+    t0: float                         # monotonic, just before its dispatch
 
 
 class _Pending:
@@ -191,6 +215,8 @@ class ServingEngine:
             ("cache", "written", "written_free", "fetched", "fetched_free"), 0)
         # slot-steps of the same steps by what the slot held (`decode_rows`)
         self._decode_rows = {"live": 0, "free": 0}
+        # decode steps by what was in flight at their dispatch (`decode_steps`)
+        self._decode_steps = {"ahead": 0, "synced": 0, "wasted_rows": 0}
         self.counters = counters
         self.buckets = tuple(sorted(prefill_buckets or default_buckets(cfg.max_len)))
         assert self.buckets[-1] <= cfg.max_len
@@ -225,10 +251,22 @@ class ServingEngine:
         self._install(params)
 
         # host-side per-slot decode state (fixed [slots] arrays)
-        # FREE from a slot's release to its next admission, a token (>= 0)
-        # in between: what the step programs and the mirror below both go by
+        # FREE from a slot's release to its next admission; in between a
+        # token (>= 0) the host knows, or CARRY while the slot's next input
+        # is the output of the step in flight: what the step programs and
+        # the mirror below both go by
         self._next_tok = np.full(slots, FREE, np.int32)
-        self._cursor = np.zeros(slots, np.int64)  # mirror of cache idx
+        # mirror of cache idx: as the device will hold it once every step
+        # dispatched so far has run
+        self._cursor = np.zeros(slots, np.int64)
+        self._flight: Optional[_Step] = None  # dispatched, tokens not read
+        self._read_t = 0.0                    # monotonic of the last read
+        # results of a read made outside step() (set_params, in_flight,
+        # prefill_only), handed out by the next step()
+        self._read_early: List[Result] = []
+        # what `_decode` takes for the step before when none is in flight:
+        # no row of such an upload holds CARRY
+        self._no_prev = jnp.zeros(slots, jnp.int32)
         self._rng = np.random.default_rng(0)
         self._pending: Dict[str, _Pending] = {}
         self._completed_lock = threading.Lock()
@@ -320,9 +358,15 @@ class ServingEngine:
                     hidden)
 
         @partial(jax.jit, donate_argnums=(1, 2))
-        def _decode(params, cache, counters, toks):
+        def _decode(params, cache, counters, toks, prev=None):
             # toks [slots, 1] — THE fixed decode signature; a free slot's
-            # row holds FREE, does no work and its output is never read
+            # row holds FREE, does no work and its output is never read.
+            # prev [slots]: the tokens of the step before, which a row that
+            # holds CARRY takes its token from.  The engine always gives
+            # it (one signature); a caller with no step before leaves it
+            # out and gets the program without the select
+            if prev is not None:
+                toks = jnp.where(toks == CARRY, prev[:, None], toks)
             logits, cache, counters, _, _ = _apply_slots(
                 params, cache, counters, toks)
             last = logits[:, -1].astype(jnp.float32)  # [slots, V]
@@ -418,17 +462,22 @@ class ServingEngine:
     def step(self) -> List[Result]:
         """One continuous-batching iteration: reject expired, admit+prefill
         into free slots, one decode step for the batch.  Returns the
-        requests completed during this iteration."""
-        done: List[Result] = []
+        requests completed during this iteration (and by a read made
+        outside step() since the last one)."""
+        done, self._read_early = self._read_early, []
         for req in self.queue.drain_expired():
             done.append(self._finish(req, status="expired"))
         if self.tenants is not None:
-            self._maybe_preempt()
+            done.extend(self._maybe_preempt())
         if self.slot_mgr.active_count or self.queue.depth():
             # the span marks an iteration with work in it; one that finds
             # nothing to do is the worker loop's `serve:idle`
             with trace_scope("serve:step", cat="serving"):
                 done.extend(self._admit_and_decode())
+        if not self.slot_mgr.active_count:
+            # nothing left that a step in flight could be for: it holds
+            # only rows of requests that ended while it was dispatched
+            done.extend(self._drain())
         for req in self.queue.drain_expired():
             done.append(self._finish(req, status="expired"))
         self._gauge()
@@ -436,6 +485,10 @@ class ServingEngine:
 
     def _admit_and_decode(self) -> List[Result]:
         done: List[Result] = []
+        if self.slot_mgr.free_count and self.queue.depth():
+            # an admission writes a slot of the cache and pushes a token
+            # from the host: the host has to have seen the last step
+            done.extend(self._drain())
         while self.slot_mgr.free_count:
             req = self.queue.pop()
             if req is None:
@@ -460,7 +513,7 @@ class ServingEngine:
 
     # -- internals -----------------------------------------------------------------
 
-    def _maybe_preempt(self) -> None:
+    def _maybe_preempt(self) -> List[Result]:
         """Priority preemption: when every slot is busy and the queue's next
         request outranks the lowest-priority in-flight request, evict that
         slot.  Eviction is cheap by construction — the victim's generated
@@ -468,12 +521,15 @@ class ServingEngine:
         the resumed stream is byte-identical) and its KV rows enter the
         radix prefix cache, making the eventual re-prefill a warm hit.  At
         most ONE preemption per request (the `_preempted` flag), so a
-        starved class degrades to at-least-half progress, never livelock."""
+        starved class degrades to at-least-half progress, never livelock.
+        An eviction folds the victim's tokens and reads its cache rows, so
+        a step in flight is read first and the choice made again on what
+        that left: -> the requests the read completed."""
         if self.slot_mgr.free_count or not self.queue.depth():
-            return
+            return []
         head_prio = self.queue.head_priority()
         if head_prio is None:
-            return
+            return []
         victim_slot, victim, victim_prio = None, None, None
         for slot, req in self.slot_mgr.active().items():
             folded = len(req.prefill_tokens) + len(req.generated)
@@ -487,8 +543,11 @@ class ServingEngine:
                 victim_slot, victim, victim_prio = slot, req, p
         if (victim is None or head_prio <= victim_prio
                 or getattr(victim, "_preempted", False)):
-            return
+            return []
+        if self._flight is not None:
+            return self._drain() + self._maybe_preempt()
         self._preempt(victim_slot, victim, head_prio)
+        return []
 
     def _preempt(self, slot: int, req: Request, head_prio: int) -> None:
         cursor = int(self._cursor[slot])
@@ -632,6 +691,8 @@ class ServingEngine:
             )
         if len(req.prefill_tokens) > self.buckets[-1]:
             raise ValueError("prompt longer than the largest prefill bucket")
+        # as before an admission's prefill (a prefill tier decodes nothing)
+        self._read_early.extend(self._drain())
         toks = req.prefill_tokens
         with trace_context(self._req_ctx(req)):
             first, small, total, hit = self._run_prefill(toks, req.temperature)
@@ -663,10 +724,31 @@ class ServingEngine:
         self._push_token(slot, req, first)
 
     def _decode_step(self) -> List[Result]:
+        """One iteration of the decode loop: -> the requests one step's
+        tokens completed.  With step N in flight it dispatches step N+1
+        (N's tokens go into it on the device) and then reads N; with
+        nothing in flight it dispatches N first.  A step with a sampling
+        row is read before anything follows it: its tokens are drawn on
+        the host."""
         if self._spec_step_ok():
-            return self._spec_decode_step()
-        with trace_scope("serve:decode.upload", cat="serving"):
-            toks = jnp.asarray(self._next_tok[:, None])
+            # a round reads the host's tokens.  (No plain step is in flight
+            # here as things are: one leaves every slot it advanced stale
+            # for the drafter until an admission, which drains)
+            return self._drain() + self._spec_decode_step()
+        return self._read_step(run_ahead=True)
+
+    def _drain(self) -> List[Result]:
+        """Read the step in flight, if there is one, and dispatch nothing:
+        -> the requests that completed.  After it the host has seen every
+        token the device made, and the device's cursors are the host's."""
+        if self._flight is None:
+            return []
+        return self._read_step(run_ahead=False)
+
+    def _read_step(self, run_ahead: bool) -> List[Result]:
+        """Read one decode step, the one in flight or else one dispatched
+        here, under one `serve:decode` span; with `run_ahead` the next is
+        dispatched before the read.  -> the requests completed."""
         active = sorted(self.slot_mgr.active().items())
         targs: Dict[str, Any] = {"active": len(active)}
         ids = [r.trace_id for _, r in active if r.trace_id]
@@ -676,39 +758,90 @@ class ServingEngine:
             # belonging to one tree; the assembler counts it as a decode
             # round for each listed trace
             targs["trace_ids"] = ids
-        # the host reads [slots] token ids; the [slots, vocab] logits come
-        # back only in a step where a request samples from them
-        sampling = any(r.temperature > 0.0 for _, r in active)
         with trace_scope("serve:decode", cat="serving", args=targs,
                          track=bool(ids)):
-            t0 = time.monotonic()
-            with trace_scope("serve:decode.dispatch", cat="serving"), \
-                    self._dev_lock:
-                greedy, logits, self.cache, self._dev_counters = self._decode(
-                    self.params, self.cache, self._dev_counters, toks)
-            with trace_scope("serve:decode.fetch", cat="serving"):
-                greedy = np.asarray(greedy)
-                if sampling:
-                    logits = np.asarray(logits)
-            dt = time.monotonic() - t0
-        self._observe("tok_latency_ms", dt * 1e3)
-        if sampling:
+            step = self._flight or self._dispatch_decode(active, None)
+            self._flight = (self._dispatch_decode(active, step)
+                            if run_ahead and not step.sampling else None)
+            fetched = self._fetch(step)
+        return self._push(step, *fetched)
+
+    def _dispatch_decode(self, active: List[Tuple[int, Request]],
+                         prev: Optional[_Step]) -> Optional[_Step]:
+        """Send one decode step for the `active` (slot, request) pairs to
+        the device and move the host's mirrors to where it will leave the
+        device: -> the step, unread.  With `prev` in flight a row it
+        advanced holds CARRY, or FREE when the token in flight is the last
+        its request asked for (the row does no work and the slot stays
+        allocated until `prev` is read); when that leaves no live row
+        nothing is dispatched: -> None."""
+        # a copy: the upload may still be reading it when `_next_tok` moves
+        toks = self._next_tok.copy()
+        if prev is not None:
+            toks[[s for s, r in prev.rows
+                  if len(r.generated) + 1 >= r.remaining_new_tokens]] = FREE
+        live = toks != FREE
+        if not live.any():
+            return None
+        rows = [(s, r) for s, r in active if live[s]]
+        with trace_scope("serve:decode.upload", cat="serving"):
+            toks_dev = jnp.asarray(toks[:, None])
+        t0 = time.monotonic()
+        with trace_scope("serve:decode.dispatch", cat="serving"), \
+                self._dev_lock:
+            greedy, logits, self.cache, self._dev_counters = self._decode(
+                self.params, self.cache, self._dev_counters, toks_dev,
+                self._no_prev if prev is None else prev.greedy)
+        # every live row consumes one token, whose successor is this
+        # step's own output until it is read; a free row's cursor stays
+        self._next_tok[live] = CARRY
+        before = self._cursor
+        self._cursor = before + live
+        self._count_step(before, 1, live)
+        self._count_steps("synced" if prev is None else "ahead")
+        for _, r in rows:
+            r.decode_rounds += 1
+        if self.spec is not None:
+            # the target advanced without the draft: those slots' draft
+            # caches are behind until their next admission
+            self.spec.on_plain_step([s for s, _ in rows])
+        # the host reads [slots] token ids; the [slots, vocab] logits come
+        # back only from a step where a request samples from them
+        return _Step(greedy, logits, rows,
+                     any(r.temperature > 0.0 for _, r in rows), t0)
+
+    def _fetch(self, step: _Step) -> Tuple[np.ndarray, Optional[np.ndarray]]:
+        """Wait for a dispatched step and copy out its [slots] tokens, and
+        its logits if a row of it samples: -> (tokens, logits or None)."""
+        logits = None
+        with trace_scope("serve:decode.fetch", cat="serving"):
+            greedy = np.asarray(step.greedy)
+            if step.sampling:
+                logits = np.asarray(step.logits)
+        # what the step added to the gap between tokens: it could not
+        # start before its dispatch, nor be read before the step before
+        now = time.monotonic()
+        self._observe("tok_latency_ms",
+                      (now - max(step.t0, self._read_t)) * 1e3)
+        self._read_t = now
+        if step.sampling:
             self.decode_logit_fetches += 1
             self._count("decode_logit_fetches")
+        return greedy, logits
+
+    def _push(self, step: _Step, greedy: np.ndarray,
+              logits: Optional[np.ndarray]) -> List[Result]:
+        """Push a fetched step's tokens, each to the request its row was
+        dispatched for if that request still holds the slot (one that
+        ended on an `eos` the step could not know of does not: the row was
+        computed for nothing, and is dropped): -> the requests completed."""
         done: List[Result] = []
+        wasted = 0
         with trace_scope("serve:decode.sample", cat="serving"):
-            # every live row consumed one token; a free row's cursor stays
-            live = self._next_tok >= 0
-            before = self._cursor
-            self._cursor = before + live
-            self._count_step(before, 1, live)
-            for _, r in active:
-                r.decode_rounds += 1
-            if self.spec is not None:
-                # the target advanced without the draft: those slots' draft
-                # caches are behind until their next admission
-                self.spec.on_plain_step([s for s, _ in active])
-            for slot, req in active:
+            for slot, req in step.rows:
+                if self.slot_mgr.request_at(slot) is not req:
+                    wasted += 1
+                    continue
                 if req.temperature <= 0.0:
                     nxt = int(greedy[slot])
                 else:
@@ -717,7 +850,21 @@ class ServingEngine:
                                             from_decode=True)
                 if finished is not None:
                     done.append(finished)
+            if self._flight is not None:
+                # the step dispatched behind this one took these tokens on
+                # the device: its rows' next input is its own output
+                for slot, req in self._flight.rows:
+                    if self.slot_mgr.request_at(slot) is req:
+                        self._next_tok[slot] = CARRY
+        if wasted:
+            self._count_steps("wasted_rows", wasted)
         return done
+
+    def _count_steps(self, kind: str, n: int = 1) -> None:
+        # rebound whole, as `_count_step` does: a reader on another thread
+        # sees the counts of one moment
+        self._decode_steps = {**self._decode_steps,
+                              kind: self._decode_steps[kind] + n}
 
     def _spec_step_ok(self) -> bool:
         """Speculate this iteration?  Needs: a decoder, at least one active
@@ -889,7 +1036,10 @@ class ServingEngine:
         function of the params, so every cached row is invalidated; the
         per-slot KV of in-flight requests stays (their earlier tokens were
         produced by the old weights — the stream finishes consistently and
-        fresh admissions use the new weights end to end)."""
+        fresh admissions use the new weights end to end).  A step in
+        flight is read first: it ran on the weights it was dispatched
+        with, and nothing is dispatched across the change."""
+        self._read_early.extend(self._drain())
         self._install(params)
         if self._want_hidden:  # the drafter computes with the same tree
             self.spec.set_params(self.params)
@@ -916,7 +1066,9 @@ class ServingEngine:
 
     def in_flight(self) -> List[dict]:
         """Queued + slotted requests with their progress — the warm-resume
-        snapshot a worker ships to its buddy (worker.py)."""
+        snapshot a worker ships to its buddy (worker.py).  Progress is what
+        the host has read, so a step in flight is read first."""
+        self._read_early.extend(self._drain())
         out = []
         for req in self.slot_mgr.active().values():
             d = req.to_json()
@@ -929,7 +1081,8 @@ class ServingEngine:
         """Add one slot-cache step to `decode_attn_rows` and `decode_rows`:
         `before` the cursors it started from (`self._cursor` those it ended
         at), each slot bringing `query_rows` query rows, `live` [slots]
-        bool the slots that held a request."""
+        bool the rows that did work; the others ("free") did none: their
+        slot held no request, or one whose last token was in flight."""
         max_len = self.dcfg.max_len
         block = self._attn_block[query_rows]
         first, last = live_blocks(np, before, before + query_rows - 1, block,
@@ -952,22 +1105,37 @@ class ServingEngine:
         """Cache rows of the decode-step attention, summed over the decode
         and verify steps so far, a layer: `cache` the rows a step spans
         (slots x max_len), `written` the rows the cursors stand at after
-        it and `written_free` those of them under free slots' cursors: 0,
-        because a free slot's cursor stays at 0 (so `written` is what the
-        attention NEEDS to read), `fetched` the rows the program reads
-        (each slot's live blocks, ops/decode_attn.py: the whole cache when
-        the program was built with the dense einsum, so `fetched == cache`
-        says the kernel is not what runs), `fetched_free` those of them
-        read for free slots: one block (the verify step's k rows: the
-        blocks they span) a free slot a step, read and not used."""
+        it and `written_free` those of them under the cursors of rows that
+        did no work (so `written - written_free` is what the attention
+        NEEDS to read): 0 for a slot that holds no request, whose cursor
+        stays at 0, and the held cursor of a slot whose request's last
+        token was in flight when the step was dispatched; `fetched` the
+        rows the program reads (each slot's live blocks,
+        ops/decode_attn.py: the whole cache when the program was built
+        with the dense einsum, so `fetched == cache` says the kernel is
+        not what runs), `fetched_free` those of them read for rows that
+        did no work: one block (the verify step's k rows: the blocks they
+        span) for an empty slot, the blocks up to its cursor for a slot
+        whose request was ending, read and not used."""
         return dict(self._attn_rows)
 
     def decode_rows(self) -> Dict[str, int]:
         """Slot-steps of the decode and verify steps so far: `live` those
-        whose slot held a request, `free` those whose slot did not (its
-        row did no work: cursor held, one cache block read, no expert).
-        `live + free` = slots x steps."""
+        that computed a token for a request, `free` those that did no work
+        (cursor held, no expert): the slot held no request (one cache
+        block read), or a request whose last token was still in flight
+        from the step before (`decode_steps`).  `live + free` = slots x
+        steps."""
         return dict(self._decode_rows)
+
+    def decode_steps(self) -> Dict[str, int]:
+        """Decode steps so far by what was in flight when each was
+        dispatched: `ahead` with the step before it unread (its tokens
+        went in on the device), `synced` with nothing in flight (the host
+        had read every token); `wasted_rows` the rows of `ahead` steps
+        computed for a request that had ended on an `eos` in the step
+        before, read and dropped."""
+        return dict(self._decode_steps)
 
     def device_counters(self, refresh: bool = True) -> Dict[str, Any]:
         """What the model counted on the device, copied to the host:
@@ -1000,6 +1168,7 @@ class ServingEngine:
             "param_bytes": dict(self.param_bytes),
             "decode_attn_rows": self.decode_attn_rows(),
             "decode_rows": self.decode_rows(),
+            "decode_steps": self.decode_steps(),
         }
         if self.prefix is not None:
             out["prefix"] = self.prefix.stats()

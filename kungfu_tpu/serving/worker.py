@@ -250,7 +250,9 @@ class ServingWorker:
                 for family, rows in (
                     ("kft_serve_decode_attn_rows_total", self._attn_rows),
                     ("kft_serve_decode_rows_total",
-                     self.engine.decode_rows))})
+                     self.engine.decode_rows),
+                    ("kft_serve_decode_steps_total",
+                     self.engine.decode_steps))})
         self.decode_pool = None
         if self.tier == "prefill" and args.config_server:
             from ..elastic.config_client import ConfigClient

@@ -154,15 +154,19 @@ def v5e_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.mark.parametrize("call", ["four_arguments", "the_engines"])
 @pytest.mark.parametrize("experts", [0, 8], ids=["dense", "experts"])
 def test_the_engines_decode_program_reads_the_donated_cache_where_it_lies(
-        v5e_chip, monkeypatch, experts):
+        v5e_chip, monkeypatch, experts, call):
     """`ServingEngine._decode` at the cells' widths (two layers), compiled
     for the chip: one kernel call a layer, no copy, transpose or convert of
     a whole cache leaf ahead of it (a layout change of the operand would
     move 64 MiB a leaf a step), and the donated cache still aliases the
     program's output.  The mask that tells it which slots are free rides in
-    the token upload: the one array a call brings from the host."""
+    the token upload: the one array a call brings from the host.  The
+    engine's own call brings one more from the device, the [slots] tokens
+    of the step before (a slot that holds CARRY takes its token from them),
+    and is the same program otherwise."""
     import re
 
     from kungfu_tpu import compat
@@ -183,13 +187,15 @@ def test_the_engines_decode_program_reads_the_donated_cache_where_it_lies(
         TransformerLM(cfg).init, jax.random.PRNGKey(0),
         jnp.zeros((1, 1), jnp.int32))["params"]))
     eng = ServingEngine(cfg, params, slots=8)
+    brought = [jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=v5e_chip)]
+    if call == "the_engines":
+        brought.append(described(eng._no_prev))
     lowered = eng._decode.lower(
         described(eng.params), described(eng.cache),
-        described(eng._dev_counters),
-        jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=v5e_chip))
+        described(eng._dev_counters), *brought)
     resident = (eng.params, eng.cache, eng._dev_counters)
     assert len(jax.tree.leaves(lowered.args_info)) == len(
-        jax.tree.leaves(resident)) + 1
+        jax.tree.leaves(resident)) + len(brought)
     compiled = lowered.compile()
     text = compiled.as_text()
     calls = lambda kernel: re.findall(  # noqa: E731

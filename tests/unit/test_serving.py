@@ -38,6 +38,7 @@ from kungfu_tpu.serving import (
     SpecDecoder,
     default_buckets,
 )
+from kungfu_tpu.serving.engine import CARRY, FREE
 from kungfu_tpu.serving.slots import set_cursors, write_slot
 
 pytestmark = pytest.mark.serving
@@ -245,6 +246,7 @@ class TestEngine:
                            prefill_buckets=(8,)) if draft else None
         eng = ServingEngine(cfg, params, slots=2, prefill_buckets=(8,),
                             spec=spec)
+        ending = _ending_rows(eng)
 
         def serve(*requests):
             pend = [eng.submit(Request(prompt=p, max_new_tokens=new))
@@ -264,7 +266,12 @@ class TestEngine:
         serve(((11, 12, 13, 14), 44))          # ... twice; slot 1 looks on
         if not draft:
             assert eng.decode_rows()["free"] - before == 2 * 43 > cfg.max_len
-        assert eng.decode_attn_rows()["written_free"] == 0
+            # (4, 5) met its budget while (1, 2, 3) decoded on: its row of
+            # the step dispatched meanwhile did no work, cursor held at 3
+            assert ending == [3]
+        rows = eng.decode_attn_rows()
+        assert (rows["written_free"], rows["fetched_free"]) == \
+            _rows_of_idle_slots(eng, ending, cfg.max_len)
 
     def test_warm_resume_matches_uninterrupted(self, model_and_params):
         """prior_tokens (the re-queue warm path) must continue the stream
@@ -538,16 +545,50 @@ def _slot_leaves(eng, name):
 
 
 def _assert_free_slots_stand_still(eng):
-    """Between two steps: the device's cursors are the host's mirror, and a
-    slot that holds no request has token FREE, cursor 0 and a clear flag."""
+    """Between two steps: the device's cursors (once the step in flight, if
+    any, has run) are the host's mirror; a slot that holds a request has a
+    token or CARRY, and CARRY only for a row of the step in flight; a slot
+    that holds none has token FREE, cursor 0 and a clear flag."""
     busy = np.zeros(eng.n_slots, bool)
     busy[list(eng.slot_mgr.active())] = True
-    np.testing.assert_array_equal(eng._next_tok >= 0, busy)
+    np.testing.assert_array_equal(eng._next_tok != FREE, busy)
+    carried = np.zeros(eng.n_slots, bool)
+    if eng._flight is not None:
+        carried[[s for s, r in eng._flight.rows
+                 if eng.slot_mgr.request_at(s) is r]] = True
+    np.testing.assert_array_equal(eng._next_tok == CARRY, carried)
+    assert (eng._next_tok[busy & ~carried] >= 0).all()
     for idx in _slot_leaves(eng, "idx"):
         np.testing.assert_array_equal(idx, eng._cursor)
     assert not eng._cursor[~busy].any()
     for flag in _slot_leaves(eng, "overflowed"):
         assert not flag[~busy].any()
+
+
+def _ending_rows(eng):
+    """Watch what the engine uploads to its decode program: -> a list that
+    grows by the cursor of every row a call holds FREE under a slot that
+    holds a request: one whose last token was in flight from the step
+    before, which does no work and keeps its cursor until that is read."""
+    seen, program = [], eng._decode
+
+    def call(params, cache, counters, toks, prev):
+        idle = np.asarray(toks)[:, 0] == FREE
+        seen.extend(int(eng._cursor[s]) for s in eng.slot_mgr.active()
+                    if idle[s])
+        return program(params, cache, counters, toks, prev)
+
+    eng._decode = call
+    return seen
+
+
+def _rows_of_idle_slots(eng, ending, block):
+    """(`written_free`, `fetched_free`) as `decode_rows` and the uploads
+    say they must be: an empty slot has nothing written and reads one
+    block, a slot whose request was ending the blocks up to its cursor."""
+    empty = eng.decode_rows()["free"] - len(ending)
+    return sum(ending), empty * block + sum(
+        (c // block + 1) * block for c in ending)
 
 
 def _staggered_run_with_a_preemption(monkeypatch, pallas, draft=False):
@@ -557,7 +598,8 @@ def _staggered_run_with_a_preemption(monkeypatch, pallas, draft=False):
     both sides of the first block's end, a slot left free while the other
     decodes on; with `draft`, speculative rounds (the model its own draft).
     Free slots are looked at after every step.
-    -> (requests, tokens of each, the engine, its counters)"""
+    -> (requests, tokens of each, the engine, its counters); the engine
+    carries `ending`, the `_ending_rows` of the run"""
     from kungfu_tpu.monitor.counters import Counters
     from kungfu_tpu.serving.tenancy import TenantRegistry, TenantSpec
 
@@ -572,6 +614,7 @@ def _staggered_run_with_a_preemption(monkeypatch, pallas, draft=False):
     eng = ServingEngine(cfg, params, slots=2, prefill_buckets=(8, 16, 512),
                         prefix_cache=PrefixCache(1 << 24), tenants=reg,
                         spec=spec, counters=counters)
+    eng.ending = _ending_rows(eng)
     rs = np.random.RandomState(3)
     prompt = lambda n: tuple(int(t) for t in rs.randint(1, 64, (n,)))  # noqa: E731
     reqs = []
@@ -626,11 +669,13 @@ def test_engine_tokens_and_row_counts_with_the_kernel_and_with_the_einsum(
     _, want, einsum, _ = _staggered_run_with_a_preemption(monkeypatch, "off")
     assert got == want
     k, e = kernel.stats()["decode_attn_rows"], einsum.decode_attn_rows()
-    for rows in (k, e):
+    for rows, eng, block in ((k, kernel, 256), (e, einsum, 512)):
         assert 0 < rows["written"] <= rows["fetched"] <= rows["cache"]
         assert 0 < rows["fetched_free"] <= rows["fetched"]
         # a free slot's cursor stays at 0: no row stands written under one
-        assert rows["written_free"] == 0
+        # but a slot's whose request was ending
+        assert (rows["written_free"], rows["fetched_free"]) == \
+            _rows_of_idle_slots(eng, eng.ending, block)
     assert e["fetched"] == e["cache"]          # the einsum reads every row
     assert k["fetched"] < k["cache"]           # the kernel the live blocks
     # the same steps on both sides: the cursors do not depend on the path
@@ -662,16 +707,293 @@ def test_a_free_slot_does_no_work_and_busy_slots_serve_generates_tokens(
     assert kinds["live"] + kinds["free"] == 2 * steps
     assert 0 < kinds["free"] < kinds["live"]
     assert rows["cache"] == steps * 2 * 512
-    assert rows["written_free"] == 0
     block = 256 if pallas == "interpret" else 512  # the einsum: the whole slot
-    assert rows["fetched_free"] == kinds["free"] * block
+    assert (rows["written_free"], rows["fetched_free"]) == \
+        _rows_of_idle_slots(eng, eng.ending, block)
+    ran = eng.decode_steps()
+    assert ran["wasted_rows"] == 0 and eng._flight is None
     if draft:
         assert eng.spec.rounds > 0
+        assert ran["ahead"] + ran["synced"] < steps  # the rest were rounds
     else:
+        # the six admissions (some in one iteration) and the preemption
+        # read the step in flight first: the next was dispatched with
+        # nothing in flight; between them the loop ran one step ahead
+        assert ran["ahead"] + ran["synced"] == steps
+        assert ran["ahead"] > 6 >= ran["synced"] > 0
         # one token a live slot-step; each of the six admissions (five
         # requests, the evicted one twice) brought one from its prefill
         assert kinds["live"] == sum(
             len(t) - len(r.prompt) for t, r in zip(got, reqs)) - 5 - 1
+
+
+# -- the decode loop one step ahead ------------------------------------------------------
+
+
+def _alone(cfg, params, prompt, new):
+    """A request's tokens from an engine that serves nothing else."""
+    eng = ServingEngine(cfg, params, slots=1, prefill_buckets=(8, 16))
+    pd = eng.submit(Request(prompt=prompt, max_new_tokens=new))
+    eng.run_until_idle()
+    return tuple(pd.result.tokens)
+
+
+class TestOneStepAhead:
+    """`ServingEngine._decode_step` dispatches step N+1 before it reads
+    step N's tokens wherever the host need not have seen them first, and
+    reads the step in flight before anything that does need them."""
+
+    @pytest.mark.parametrize("drive", ["step", "run_until_idle"])
+    def test_the_benchmarks_warm_up(self, model_and_params, drive):
+        """What `benchmark/lib/serve_driver.py` sends before a window: a
+        request of two new tokens alone in the engine for each prefill
+        bucket (its second token is its last: the one decode step it joins
+        leaves no row live, so nothing may be dispatched behind it), then
+        one fixed request twice, which must answer with the same tokens.
+        Nothing is left in flight."""
+        cfg, _, params = model_and_params
+        eng = ServingEngine(cfg, params, slots=2, prefill_buckets=(8, 16))
+
+        def serve(prompt, new):
+            pd = eng.submit(Request(prompt=prompt, max_new_tokens=new))
+            if drive == "run_until_idle":
+                eng.run_until_idle(timeout_s=60)
+            else:
+                for _ in range(new + 2):
+                    eng.step()
+                    _assert_free_slots_stand_still(eng)
+            assert pd.result is not None and pd.result.status == "ok"
+            assert eng._flight is None and not eng.slot_mgr.active_count
+            return tuple(pd.result.tokens)
+
+        rs = np.random.RandomState(9)
+        for n in (5, 13):
+            prompt = tuple(int(t) for t in rs.randint(1, 64, (n,)))
+            assert serve(prompt, 2) == _alone(cfg, params, prompt, 2)
+        assert eng.decode_steps() == {"ahead": 0, "synced": 2,
+                                      "wasted_rows": 0}
+        fixed = tuple(int(t) for t in rs.randint(1, 64, (5,)))
+        a, b = serve(fixed, 8), serve(fixed, 8)
+        assert a == b and len(a) == 5 + 8
+        np.testing.assert_array_equal(a, np.asarray(generate(
+            cfg, params, jnp.asarray(fixed)[None], 8))[0])
+        # 7 decode steps a request: the first with nothing in flight, the
+        # others behind it; none behind the one that brings the last token
+        assert eng.decode_steps() == {"ahead": 12, "synced": 4,
+                                      "wasted_rows": 0}
+        assert eng._decode._cache_size() == 1
+
+    @pytest.mark.parametrize("script", ["one_request", "a_second_joins"])
+    def test_counts_of_steps_ahead_and_synced(self, model_and_params, script):
+        """A scripted run, step for step: which steps are dispatched with
+        the one before unread, and what each `step()` hands back."""
+        cfg, _, params = model_and_params
+        eng = ServingEngine(cfg, params, slots=2, prefill_buckets=(8,))
+        new = 6 if script == "one_request" else 8
+        first = eng.submit(Request(prompt=(1, 2, 3), max_new_tokens=new))
+        seen = []
+
+        def step(times=1):
+            for _ in range(times):
+                eng.step()
+                _assert_free_slots_stand_still(eng)
+                ran = eng.decode_steps()
+                seen.append((ran["synced"], ran["ahead"],
+                             len(first.request.generated)))
+
+        if script == "one_request":
+            step(5)
+            # the admission's first token, then one token a step(): step 1
+            # and step 2 are dispatched in the first, the sixth token's
+            # step is the last and nothing follows it
+            assert seen == [(1, 1, 2), (1, 2, 3), (1, 3, 4), (1, 4, 5),
+                            (1, 4, 6)]
+        else:
+            step(2)
+            second = eng.submit(Request(prompt=(4, 5), max_new_tokens=3))
+            step(4)
+            # the admission reads the step in flight (the first request's
+            # fourth token comes out with it), then both decode: synced,
+            # and ahead again.  With the second request's last token in
+            # flight its row rides FREE in the step behind, once, while the
+            # first goes on alone to its own last token
+            assert seen == [(1, 1, 2), (1, 2, 3), (2, 3, 5), (2, 4, 6),
+                            (2, 5, 7), (2, 5, 8)]
+            assert len(second.request.generated) == 3
+            # seven steps of two slots: the empty slot of four of them and
+            # the ending row of one did no work
+            assert eng.decode_rows() == {"live": 7 + 2, "free": 4 + 1}
+        assert first.result.status == "ok" and eng._flight is None
+        assert eng.decode_steps()["wasted_rows"] == 0
+        assert eng.stats()["decode_steps"] == eng.decode_steps()
+        want = np.asarray(generate(cfg, params, jnp.asarray((1, 2, 3))[None],
+                                   new))[0]
+        np.testing.assert_array_equal(np.asarray(first.result.tokens), want)
+
+    @pytest.mark.parametrize("beside", ["alone", "beside_another"])
+    def test_an_eos_the_step_in_flight_could_not_know_of(
+            self, model_and_params, beside):
+        """A request ends on its `eos` in step N with step N+1 dispatched:
+        N+1 computed its row once more, for nothing.  The token is never
+        read into any request, the row is counted, the slot is reset behind
+        N+1 and the next admission into it decodes as if alone."""
+        cfg, _, params = model_and_params
+        other, later = (9, 2, 6, 5), (7, 7, 1)
+        # a stream whose third or later new token is one it has not made
+        # before: as `eos` it stops the request there, mid-stream
+        prompt, full, cut = next(
+            (p, full, cut) for p in ((a, b, c) for a in range(1, 9)
+                                     for b in (11, 29) for c in (4, 50))
+            for full in [_alone(cfg, params, p, 12)]
+            for cut in range(len(p) + 2, len(p) + 9)
+            if full[cut] not in full[len(p):cut])
+        eos = full[cut]
+        eng = ServingEngine(cfg, params, slots=2, prefill_buckets=(8,))
+        ended = eng.submit(Request(prompt=prompt, max_new_tokens=12,
+                                   eos_id=int(eos)))
+        pend = [eng.submit(Request(prompt=other, max_new_tokens=10))] \
+            if beside == "beside_another" else []
+        while ended.result is None:
+            eng.step()
+            _assert_free_slots_stand_still(eng)
+        assert tuple(ended.result.tokens) == full[:cut + 1]
+        assert eng._next_tok[0] == FREE and eng._cursor[0] == 0
+        if beside == "alone":
+            # the step() that read the eos found nothing else to wait for
+            assert eng._flight is None
+            assert eng.decode_steps()["wasted_rows"] == 1
+        else:
+            assert [s for s, _ in eng._flight.rows] == [0, 1]
+        pend.append(eng.submit(Request(prompt=later, max_new_tokens=9)))
+        while eng.queue.depth() or eng.slot_mgr.active_count:
+            eng.step()
+            _assert_free_slots_stand_still(eng)
+        assert eng.decode_steps()["wasted_rows"] == 1 and eng._flight is None
+        for pd in pend:
+            assert tuple(pd.result.tokens) == _alone(
+                cfg, params, pd.request.prompt, pd.request.max_new_tokens)
+        assert eng.total_tokens == sum(
+            len(pd.request.generated) for pd in [ended] + pend)
+
+    @pytest.mark.parametrize("sampler", ["leaves_first", "joins_later"])
+    def test_no_step_runs_ahead_while_a_request_samples(
+            self, model_and_params, sampler):
+        """A sampling request's token is drawn on the host from fetched
+        logits, so its steps stay synchronous: the same draws from the
+        same generator as the host path, and `ahead` stands still while it
+        is active."""
+        cfg, _, params = model_and_params
+        reqs = [((3, 1, 4, 1, 5), 4, 0.8), ((9, 2, 6), 12, 0.0)]
+        want, _ = _host_path_tokens(cfg, params, reqs, (8,))
+        eng = ServingEngine(cfg, params, slots=2, prefill_buckets=(8,))
+        submit = lambda r: eng.submit(Request(  # noqa: E731
+            prompt=r[0], max_new_tokens=r[1], temperature=r[2]))
+        if sampler == "leaves_first":
+            hot, cold = submit(reqs[0]), submit(reqs[1])
+        else:
+            cold = submit(reqs[1])
+            for _ in range(3):
+                eng.step()
+            assert eng.decode_steps()["ahead"] == 3 and eng._flight is not None
+            hot = submit(reqs[0])
+        ahead = eng.decode_steps()["ahead"]
+        while hot.result is None:
+            eng.step()
+            _assert_free_slots_stand_still(eng)
+            assert eng._flight is None
+        assert eng.decode_steps()["ahead"] == ahead
+        assert eng.decode_logit_fetches == 3
+        eng.run_until_idle()
+        assert eng.decode_steps()["ahead"] > ahead
+        assert list(cold.result.tokens) == list(reqs[1][0]) + want[1]
+        if sampler == "leaves_first":
+            assert list(hot.result.tokens) == list(reqs[0][0]) + want[0]
+
+    @pytest.mark.parametrize("what", ["set_params", "in_flight",
+                                      "prefill_only", "preemption"])
+    def test_what_needs_the_last_token_reads_the_step_in_flight_first(
+            self, model_and_params, what):
+        cfg, _, params = model_and_params
+        tenants = None
+        if what == "preemption":
+            from kungfu_tpu.serving.tenancy import TenantRegistry, TenantSpec
+
+            tenants = TenantRegistry(specs={
+                "bulk": TenantSpec(name="bulk", priority=0),
+                "gold": TenantSpec(name="gold", priority=2)})
+        eng = ServingEngine(cfg, params, slots=1, prefill_buckets=(8, 16),
+                            prefix_cache=PrefixCache(1 << 20), tenants=tenants)
+        prompt = (5, 9, 2, 7)
+        pd = eng.submit(Request(prompt=prompt, max_new_tokens=5,
+                                tenant="bulk"))
+        for _ in range(3):
+            eng.step()
+        # four tokens read, the fifth and last in flight
+        assert len(pd.request.generated) == 4 and eng._flight is not None
+        others = []
+        if what == "set_params":
+            eng.set_params(params)
+        elif what == "in_flight":
+            assert eng.in_flight() == []  # it had finished, had one looked
+        elif what == "prefill_only":
+            first, rows, total, _ = eng.prefill_only(
+                Request(prompt=(8, 8, 3), max_new_tokens=4))
+            assert total == 3 and first == _alone(
+                cfg, params, (8, 8, 3), 1)[-1]
+        else:
+            others.append(eng.submit(Request(prompt=(6, 1), max_new_tokens=3,
+                                             tenant="gold")))
+        if what != "preemption":
+            # read where it was asked for, handed out by the next step()
+            assert eng._flight is None and pd.result.status == "ok"
+        done = eng.step()
+        assert [r.req_id for r in done][:1] == [pd.request.req_id]
+        # the last token was in flight, so the request ended: no eviction
+        assert eng.preemptions == 0
+        eng.run_until_idle()
+        for p in [pd] + others:
+            assert tuple(p.result.tokens) == _alone(
+                cfg, params, p.request.prompt, p.request.max_new_tokens)
+
+    def test_the_engine_has_one_decode_program_and_callers_keep_theirs(
+            self, model_and_params):
+        """The engine's calls all take the [slots] tokens of the step
+        before (zeros when none is in flight): one signature, one
+        executable.  A caller that gives four arguments lowers the program
+        without the select (tests/unit/test_parent_programs.py holds its
+        text), and a row that holds CARRY takes the token `prev` has."""
+        cfg, _, params = model_and_params
+        eng = ServingEngine(cfg, params, slots=3, prefill_buckets=(8,))
+        for n in (3, 5, 2, 4):
+            eng.submit(Request(prompt=tuple(range(1, n + 1)),
+                               max_new_tokens=n + 2))
+        eng.run_until_idle()
+        assert eng.decode_steps()["ahead"] > eng.decode_steps()["synced"] > 0
+        assert eng._decode._cache_size() == 1
+        toks = jax.ShapeDtypeStruct((3, 1), jnp.int32)
+        four = eng._decode.lower(eng.params, eng.cache, {}, toks).as_text()
+        five = eng._decode.lower(eng.params, eng.cache, {}, toks,
+                                 eng._no_prev).as_text()
+        arguments = lambda text: next(  # noqa: E731
+            ln for ln in text.splitlines() if "@main(" in ln).count("%arg")
+        assert arguments(five) == arguments(four) + 1
+        carry = "dense<-2> : tensor<i32>"  # compared with in one of them
+        assert carry in five and carry not in four
+        fresh = lambda: ServingEngine(  # noqa: E731
+            cfg, params, slots=3, prefill_buckets=(8,))
+        a = fresh()
+        known, _, _, _ = a._decode(a.params, a.cache, {},
+                                   jnp.asarray([[5], [FREE], [40]], jnp.int32))
+        b = fresh()
+        carried, _, cache, _ = b._decode(
+            b.params, b.cache, {},
+            jnp.asarray([[CARRY], [FREE], [40]], jnp.int32),
+            jnp.asarray([5, 17, 23], jnp.int32))
+        np.testing.assert_array_equal(np.asarray(known), np.asarray(carried))
+        idx = [np.asarray(leaf) for path, leaf
+               in jax.tree_util.tree_leaves_with_path(cache)
+               if getattr(path[-1], "key", None) == "idx"]
+        assert all((i == [1, 0, 1]).all() for i in idx)
 
 
 # -- radix prefix cache ----------------------------------------------------------------
@@ -1748,6 +2070,31 @@ def test_worker_reports_decode_attn_rows(monkeypatch):
     assert f'kft_serve_decode_rows_total{{kind="live"}} {steps}' in text
     assert families["kft_serve_decode_rows_total"] == {
         'kind="live"': steps, 'kind="free"': 0}
+
+
+def test_worker_reports_decode_steps(monkeypatch):
+    """`kft_serve_decode_steps_total{kind=...}` on /metrics (a counter) is
+    the engine's `decode_steps`, through the one source a profile capture
+    reads at both ends; `stats()` (so /healthz) holds the same."""
+    worker = _worker(monkeypatch, "", slots=2)
+    eng = worker.engine
+    assert eng.decode_steps() == {"ahead": 0, "synced": 0, "wasted_rows": 0}
+    eng.submit(Request(prompt=(1, 2, 3), max_new_tokens=9))
+    eng.run_until_idle()
+    ran = eng.decode_steps()
+    assert ran == {"ahead": 7, "synced": 1, "wasted_rows": 0}
+    # the worker's weights and the step's own tokens are one kind of array
+    # to `jit`: the zeros of the first call added no second executable
+    assert eng._decode._cache_size() == 1
+    text = worker.counters.prometheus_text()
+    assert "# TYPE kft_serve_decode_steps_total counter" in text
+    assert "# HELP kft_serve_decode_steps_total " in text
+    for kind, n in ran.items():
+        assert f'kft_serve_decode_steps_total{{kind="{kind}"}} {n}' in text
+    assert worker.counters.source_families()[
+        "kft_serve_decode_steps_total"] == {
+            f'kind="{kind}"': n for kind, n in ran.items()}
+    assert eng.stats()["decode_steps"] == ran
 
 
 # -- program observatory regression ----------------------------------------------------
