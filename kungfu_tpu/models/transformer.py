@@ -208,6 +208,38 @@ class TransformerConfig:
     # same with or without it); no program of the main model runs it.  The
     # serving engine's target-resident drafter does (serving/spec.py)
     mtp_layers: int = 0
+    # state-space mixers (`Mamba`, Mamba-1), chosen by `mamba_d_state` > 0:
+    # standard block `i` then mixes tokens by attention where
+    # i % attn_layer_period == attn_layer_offset and by the state-space
+    # mixer everywhere else (`layer_is_ssm`; the published keys of the same
+    # names).  The mixer's inner width is mamba_expand x d_model, its state
+    # [mamba_d_state, inner] a row, its convolution `mamba_d_conv` taps,
+    # its step size through a `mamba_dt_rank` bottleneck.  In decode mode
+    # its cache is a float32 state and the convolution's last inputs, no
+    # rows and no cursor (serving/slots.py STATE_LEAVES)
+    mamba_d_state: int = 0
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
+    mamba_d_conv: int = 4
+    mamba_conv_bias: bool = True
+    attn_layer_period: int = 0
+    attn_layer_offset: int = 0
+    # with `rope` off: the learned `pos_embed` table (True, what was there)
+    # or no positional signal of any kind (False: the order of the tokens
+    # reaches an attention layer through the recurrent layers before it)
+    pos_table: bool = True
+    # what lies BETWEEN the matmuls in float32 where it is cfg.dtype by
+    # default: the residual stream (the Mamba reference code's
+    # `residual_in_fp32`), the output of every projection that goes on as
+    # an activation (the matmul's float32 accumulator as it is: the dense
+    # FFN's, the attention's output projection, the state-space mixer's)
+    # and the products and gates made of them.  Matmul operands stay
+    # cfg.dtype (every `nn.Dense` casts its input and its kernel), so the
+    # MXU's work and the weights' bytes are the same; a decode step's
+    # activations are a few hundred KB.  At 28 layers the roundings between
+    # the matmuls are four fifths of a served logit's distance from the
+    # float32 reference, the bf16 weights the rest (PERF.md section 6, PR 42)
+    fp32_activations: bool = False
     # mesh is needed for attention="ring"/"ulysses" (shard_map region)
     mesh: Optional[Mesh] = None
     sp_axis: str = "sp"
@@ -237,7 +269,8 @@ class TransformerConfig:
     def __post_init__(self):
         assert self.d_model % self.n_heads == 0
         if self.decode:
-            assert self.rope, "decode mode requires rope positions"
+            assert self.rope or not self.pos_table, (
+                "decode mode: rope positions, or none (no learned table)")
         if self.n_kv_heads:
             assert self.n_heads % self.n_kv_heads == 0, (
                 "query heads must be a multiple of kv heads"
@@ -296,6 +329,16 @@ class TransformerConfig:
             assert self.n_experts > 0, "router options need experts"
         assert 0 <= self.first_dense_layers <= self.n_layers
         assert self.mtp_layers in (0, 1), "one prediction module at most"
+        if self.mamba_d_state:
+            assert self.block == "standard" and self.mamba_dt_rank > 0 \
+                and self.mamba_d_conv > 1 and self.mesh is None, (
+                    "state-space mixers: the standard block, one device")
+            assert 0 <= self.attn_layer_offset < self.attn_layer_period \
+                <= self.n_layers, (
+                    "a model with state-space mixers keeps an attention "
+                    "layer a period (the engine counts cache rows by it)")
+        else:
+            assert not self.attn_layer_period and not self.attn_layer_offset
         if self.mtp_layers:
             assert not self.tie_embeddings and self.block == "standard" \
                 and self.mesh is None, (
@@ -321,6 +364,13 @@ class TransformerConfig:
         j = i - self.first_dense_layers
         return (self.n_experts > 0 and j >= 0
                 and j % self.moe_every == self.moe_every - 1)
+
+
+    def layer_is_ssm(self, i: int) -> bool:
+        """Whether standard block `i` mixes tokens by the state-space
+        mixer (`Mamba`) and not by attention."""
+        return (self.mamba_d_state > 0
+                and i % self.attn_layer_period != self.attn_layer_offset)
 
 
 def _attention_kind(cfg: TransformerConfig) -> str:
@@ -356,12 +406,19 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def _dense(features, name, kernel_axes, dtype, use_bias: bool = False):
+def _dense(features, name, kernel_axes, dtype, use_bias: bool = False,
+           wide: bool = False):
+    """`wide`: the output is the matmul's float32 accumulator, not rounded
+    to `dtype` (`TransformerConfig.fp32_activations`); operands as ever."""
+    out = {"dot_general": partial(jax.lax.dot_general,
+                                  preferred_element_type=jnp.float32)} \
+        if wide else {}
     return nn.Dense(
         features,
         use_bias=use_bias,
         dtype=dtype,
         name=name,
+        **out,
         kernel_init=nn.with_logical_partitioning(
             nn.initializers.normal(stddev=0.02), kernel_axes
         ),
@@ -432,8 +489,9 @@ class Attention(nn.Module):
             )
             idx0 = cache_idx.value                      # [B]
             pos = idx0[:, None] + jnp.arange(L)[None, :]  # [B, L]
-            q = apply_rope(q, pos, cfg.rope_theta)
-            k = apply_rope(k, pos, cfg.rope_theta)
+            if cfg.rope:
+                q = apply_rope(q, pos, cfg.rope_theta)
+                k = apply_rope(k, pos, cfg.rope_theta)
 
             def quantize(x):
                 """[B, L, Hkv, D] -> (int8 values, f32 scales [B, L, Hkv])."""
@@ -517,7 +575,8 @@ class Attention(nn.Module):
             )
             o = jnp.where(poison, jnp.nan, o)
             o = o.astype(cfg.dtype).reshape(B, L, cfg.d_model)
-            return _dense(cfg.d_model, "out", ("heads", "embed"), cfg.dtype)(o)
+            return _dense(cfg.d_model, "out", ("heads", "embed"), cfg.dtype,
+                          wide=cfg.fp32_activations)(o)
 
         if cfg.rope:
             # global positions: L here is the full (logical) sequence even
@@ -621,7 +680,8 @@ class Attention(nn.Module):
                                window=cfg.window or None)
 
         o = o.reshape(B, L, cfg.d_model)
-        return _dense(cfg.d_model, "out", ("heads", "embed"), cfg.dtype)(o)
+        return _dense(cfg.d_model, "out", ("heads", "embed"), cfg.dtype,
+                      wide=cfg.fp32_activations)(o)
 
 
 def _masked_attention(q, k, v, q_pos):
@@ -769,20 +829,161 @@ class MLA(nn.Module):
             return out(o.astype(cfg.dtype).reshape(B, L, H * dv))
 
 
+def _mamba_dt_bias_init(key, shape, dtype=jnp.float32):
+    """The Mamba reference initialisation of the step size's bias: the
+    inverse softplus of a log-uniform draw in [1e-3, 1e-1], so that a
+    seeded model's states forget over 10 to 1,000 tokens as a trained
+    one's do (a normal draw makes softplus(...) about 0.7 everywhere and
+    the state forgets within two tokens)."""
+    lo, hi = jnp.log(1e-3), jnp.log(1e-1)
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32) * (hi - lo) + lo)
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+class Mamba(nn.Module):
+    """The state-space mixer of a layer `cfg.layer_is_ssm` names (Mamba-1
+    with Jamba's three inner norms), u the normed input, d_inner =
+    `mamba_expand` x d_model, N = `mamba_d_state`, K = `mamba_d_conv`:
+
+        [x, z]     = u W_in
+        x_t        = silu(sum_{j<K} w_conv[j] * x_{t-K+1+j} + b_conv)
+        [dt, B, C] = x_t W_x          dt, B, C = N_dt(dt), N_B(B), N_C(C)
+        Delta_t    = softplus(dt W_dt + b_dt)            A = -exp(A_log)
+        h_t        = exp(Delta_t A) * h_{t-1} + (Delta_t * x_t) B_t
+        y_t        = h_t C_t + D * x_t
+        out        = (y_t * silu(z_t)) W_out
+
+    W_in, W_x, W_dt, W_out are `nn.Dense` without bias (in the resident
+    tree in cfg.dtype, as every `nn.Dense`); `conv_w` [K, d_inner],
+    `conv_b`, `dt_bias`, `A_log` [N, d_inner], `D` and the norms' scales
+    are float32 leaves.  `A_log` and the state are kept TRANSPOSED against
+    the published [d_inner, N] (a checkpoint loader turns them once): the
+    channels lie on the lanes, as ops/selective_scan.py wants them.  The
+    convolution, Delta, exp(Delta A), h and y are float32; x_t goes to W_x
+    in cfg.dtype.
+
+    The decode-mode cache is two leaves with NO position axis and no
+    cursor: `ssm_state` [B, N, d_inner] float32, h after the row's last
+    real token, and `conv_state` [B, K - 1, d_inner] in cfg.dtype, the
+    K - 1 inputs before its next one (zeros before the sequence starts).
+    A call of L tokens is exactly L chained one-token calls.  `live` as
+    `Attention` takes it: a row that is not live keeps both leaves.
+    `n_new` [B] int: the real tokens of each row of a right-padded call (a
+    prefill bucket); positions at or beyond it leave both leaves
+    untouched, so what a prefill hands on is the state after token
+    n_new - 1 whatever the bucket, prompts shorter than the window
+    included.  Attention needs no such count (padding is causally
+    invisible to it); a recurrence runs through what it is given.
+
+    Training mode (no cache) starts every row from zeros and takes the
+    `lax.scan` form, which has a gradient."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u, live=None, n_new=None):
+        from ..ops.selective_scan import (
+            selective_scan, selective_scan_reference)
+
+        cfg = self.cfg
+        B, L, _ = u.shape
+        di, N = cfg.mamba_expand * cfg.d_model, cfg.mamba_d_state
+        R, K = cfg.mamba_dt_rank, cfg.mamba_d_conv
+        f32 = jnp.float32
+
+        def leaf(name, init, shape, axes):
+            return self.param(
+                name, nn.with_logical_partitioning(init, axes), shape, f32)
+
+        taps = nn.initializers.uniform(2 * K ** -0.5)
+
+        def tap_init(key, shape, dtype=f32):   # U(-1/sqrt(K), 1/sqrt(K))
+            return taps(key, shape, dtype) - K ** -0.5
+
+        with jax.named_scope("ssm"):
+            wide = cfg.fp32_activations
+            act = f32 if wide else cfg.dtype   # what goes on between matmuls
+            xz = _dense(2 * di, "in_proj", ("embed", "mlp"), cfg.dtype,
+                        wide=wide)(u)
+            x, z = xz[..., :di], xz[..., di:]
+            conv_w = leaf("conv_w", tap_init, (K, di), (None, "mlp"))
+            a = -jnp.exp(leaf(
+                "A_log",
+                lambda key, shape, dtype=f32: jnp.broadcast_to(jnp.log(
+                    jnp.arange(1, N + 1, dtype=dtype))[:, None], shape),
+                (N, di), (None, "mlp")))
+            skip = leaf("D", nn.initializers.ones, (di,), ("mlp",))
+            dt_bias = leaf("dt_bias", _mamba_dt_bias_init, (di,), ("mlp",))
+
+            n_valid = jnp.full((B,), L, jnp.int32)
+            if n_new is not None:
+                n_valid = jnp.broadcast_to(n_new.astype(jnp.int32), (B,))
+            if live is not None:
+                n_valid = jnp.where(live, n_valid, 0)
+            if cfg.decode:
+                conv_state = self.variable(
+                    "cache", "conv_state", jnp.zeros, (B, K - 1, di), cfg.dtype)
+                ssm_state = self.variable(
+                    "cache", "ssm_state", jnp.zeros, (B, N, di), f32)
+                window, h0 = conv_state.value, ssm_state.value
+            else:
+                window = jnp.zeros((B, K - 1, di), x.dtype)
+                h0 = jnp.zeros((B, N, di), f32)
+
+            with jax.named_scope("ssm.conv"):
+                # xs[t + j] is the input K - 1 - j tokens before token t
+                xs = jnp.concatenate([window.astype(x.dtype), x], axis=1)
+                acc = sum(xs[:, j:j + L].astype(f32) * conv_w[j]
+                          for j in range(K))
+                if cfg.mamba_conv_bias:
+                    acc = acc + leaf("conv_b", tap_init, (di,), ("mlp",))
+                x = nn.silu(acc).astype(act)
+                # the K - 1 inputs before each row's next real token: rows
+                # n_valid .. n_valid + K - 2 of xs (all of the old window
+                # for a row with no real token)
+                window = jax.vmap(
+                    lambda m, i: jax.lax.dynamic_slice_in_dim(m, i, K - 1, 0)
+                )(xs, n_valid)
+
+            dbc = _dense(R + 2 * N, "x_proj", ("mlp", None), cfg.dtype,
+                         wide=wide)(x)
+            dt = _norm(cfg, "dt_norm")(dbc[..., :R]).astype(act)
+            b = _norm(cfg, "b_norm")(dbc[..., R:R + N])            # float32
+            c = _norm(cfg, "c_norm")(dbc[..., R + N:])
+            delta = jax.nn.softplus(
+                _dense(di, "dt_proj", (None, "mlp"), cfg.dtype,
+                       wide=wide)(dt).astype(f32)
+                + dt_bias)
+            with jax.named_scope("ssm.scan"):
+                scan = selective_scan if cfg.decode else selective_scan_reference
+                y, h = scan(x, delta, a, b, c, h0, n_valid)
+            if cfg.decode and not self.is_initializing():
+                # init() traces the module once to create the cache: it
+                # writes no state
+                ssm_state.value = h
+                conv_state.value = window.astype(cfg.dtype)
+            y = (y + skip * x.astype(f32)) * nn.silu(z.astype(f32))
+            return _dense(cfg.d_model, "out_proj", ("mlp", "embed"),
+                          cfg.dtype, wide=wide)(y.astype(act))
+
+
 class MLP(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        h = _dense(cfg.d_ff, "in", ("embed", "mlp"), cfg.dtype)(x)
+        wide = cfg.fp32_activations
+        h = _dense(cfg.d_ff, "in", ("embed", "mlp"), cfg.dtype, wide=wide)(x)
         if cfg.ffn == "swiglu":
-            gate = _dense(cfg.d_ff, "gate", ("embed", "mlp"), cfg.dtype)(x)
+            gate = _dense(cfg.d_ff, "gate", ("embed", "mlp"), cfg.dtype,
+                          wide=wide)(x)
             h = nn.silu(gate) * h
         else:
             h = nn.gelu(h)
         h = logical_constraint(h, ("batch", "seq", "mlp"), cfg.mesh)
-        return _dense(cfg.d_model, "out", ("mlp", "embed"), cfg.dtype)(h)
+        return _dense(cfg.d_model, "out", ("mlp", "embed"), cfg.dtype,
+                      wide=wide)(h)
 
 
 def _norm(cfg, name: str):
@@ -807,9 +1008,10 @@ class Block(nn.Module):
 
     cfg: TransformerConfig
     use_moe: bool = False
+    ssm: bool = False
 
     @nn.compact
-    def __call__(self, x, train: bool = False, live=None):
+    def __call__(self, x, train: bool = False, live=None, n_new=None):
         cfg = self.cfg
         ln = partial(_norm, cfg)
         drop = nn.Dropout(cfg.dropout, deterministic=not train)
@@ -819,8 +1021,12 @@ class Block(nn.Module):
                 return y
             return ln(name=name)(y).astype(cfg.dtype)
 
-        attn = (MLA if cfg.kv_lora_rank else Attention)(cfg, name="attn")
-        x = x + drop(post("ln1_post", attn(ln(name="ln1")(x), live)))
+        if self.ssm:
+            mixed = Mamba(cfg, name="mamba")(ln(name="ln1")(x), live, n_new)
+        else:
+            attn = (MLA if cfg.kv_lora_rank else Attention)(cfg, name="attn")
+            mixed = attn(ln(name="ln1")(x), live)
+        x = x + drop(post("ln1_post", mixed))
         if self.use_moe:
             from ..parallel.moe import MoE
 
@@ -937,10 +1143,14 @@ class TransformerLM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, live=None,
-                 return_hidden: bool = False):
+                 return_hidden: bool = False, n_new=None):
         """`return_hidden`: (logits, the final-normed hidden states
         [B, L, d_model] float32 the head read) instead of the logits: what
         a prediction module (`MTPModule`) continues from.
+
+        `n_new` [B] int, for a right-padded call on a model with
+        state-space mixers (`Mamba`): how many of each row's tokens are
+        real.  Only those layers are told; None is every token real.
 
         `live` [B] bool, for the slot-cache programs of the serving
         engine: a row that is not live holds no request, and does no work
@@ -971,10 +1181,11 @@ class TransformerLM(nn.Module):
         # table's embed dim may be fsdp-sharded (ZeRO-3), and without the
         # constraint the gather output inherits that feature-dim sharding
         x = logical_constraint(
-            emb(tokens).astype(cfg.dtype), ("batch", "seq", "act_embed"),
-            cfg.mesh,
+            emb(tokens).astype(jnp.float32 if cfg.fp32_activations
+                               else cfg.dtype),
+            ("batch", "seq", "act_embed"), cfg.mesh,
         )
-        if not cfg.rope:  # rope applies per-layer in Attention instead
+        if not cfg.rope and cfg.pos_table:  # rope: per layer, in Attention
             pos = self.param(
                 "pos_embed",
                 nn.with_logical_partitioning(nn.initializers.normal(stddev=0.02), ("seq", "embed")),
@@ -1011,11 +1222,11 @@ class TransformerLM(nn.Module):
             block_cls = nn.remat(block_cls, static_argnums=(2,), **remat_kw)
         for i in range(cfg.n_layers):
             if shortcut:  # every block carries the expert branch
-                block = block_cls(cfg, name=f"block_{i}")
+                x = block_cls(cfg, name=f"block_{i}")(x, train, live)
             else:
                 block = block_cls(cfg, use_moe=cfg.layer_has_experts(i),
-                                  name=f"block_{i}")
-            x = block(x, train, live)
+                                  ssm=cfg.layer_is_ssm(i), name=f"block_{i}")
+                x = block(x, train, live, n_new)
         x = _norm(cfg, "ln_f")(x)
         hidden = x
         if cfg.head == "hidden":
@@ -1170,9 +1381,9 @@ def generate(
     """
     assert prompt.ndim == 2
     b, prompt_len = prompt.shape
-    assert cfg.rope, (
-        "generate() requires a rope-trained model: a learned pos_embed "
-        "table has no decode-cursor equivalent here"
+    assert cfg.rope or not cfg.pos_table, (
+        "generate() requires a rope-trained (or position-free) model: a "
+        "learned pos_embed table has no decode-cursor equivalent here"
     )
     assert prompt_len + max_new_tokens <= cfg.max_len, (
         f"{prompt_len}+{max_new_tokens} exceeds max_len={cfg.max_len}"

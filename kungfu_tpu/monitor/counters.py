@@ -165,6 +165,13 @@ METRIC_HELP: Dict[str, str] = {
         "Decode steps by what was in flight at their dispatch: ahead (the "
         "step before unread) and synced (nothing); wasted_rows the rows "
         "computed for a request that had ended.",
+    "kft_serve_cache_bytes":
+        "Bytes of the serving engine's slot cache by kind: rows (leaves "
+        "with a position axis, cursors beside them) and state (what a "
+        "recurrent layer keeps a slot).",
+    "kft_serve_scan_tokens_total":
+        "Tokens the recurrent layers' scan walked, a layer: prefill (real "
+        "tokens, not bucket padding) and decode (live slot-steps).",
     "kft_boot_seconds":
         "Seconds of each boot phase of this process so far, on the job "
         "clock (spans of category boot, docs/observability.md Boot).",

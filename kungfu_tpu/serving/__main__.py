@@ -259,6 +259,18 @@ def main(argv=None) -> int:
             ap.error(f"-np {args.np} one-chip workers need more than "
                      f"--chips-per-host {args.chips_per_host}")
         args.max_size = min(args.max_size, args.chips_per_host)
+    if args.model_json and json.loads(args.model_json).get("mamba_d_state"):
+        # the worker refuses these too (worker.py), but a worker that dies
+        # at boot is respawned: say it once, here, before any is spawned
+        asked = [flag for flag, on in (
+            ("--prefix-cache on", args.prefix_cache == "on"),
+            ("--spec-draft", bool(args.spec_draft)),
+            ("--prefill-ranks", args.prefill_ranks > 0)) if on]
+        if asked:
+            ap.error(f"{', '.join(asked)}: not with a model that keeps "
+                     "recurrent state (mamba_d_state > 0): prefix reuse, "
+                     "speculation and shipped prefills cut or roll back a "
+                     "cache by position, and a state has none (ROADMAP R6)")
     if args.telemetry:
         _arm_telemetry(args.logdir)
         from ..monitor.journal import set_journal_context

@@ -73,6 +73,19 @@ given (models/transformer.py `resident_params`): each leaf in the dtype
 the programs read it in, converted once at `__init__` / `set_params`, so no
 step converts a whole weight again.  `param_bytes` says what that is.
 
+A model with recurrent layers (state-space mixers, `cfg.mamba_d_state`)
+keeps, for a slot, a state beside the attention layers' rows: leaves of the
+one slot cache with no position axis (serving/slots.py `STATE_LEAVES`).  The
+loop above is the same: an admission's `write_slot` replaces the slot's
+state with the prefill's, a free slot's row is not live and keeps its state,
+and the prefill program tells the model how many of the bucket's tokens are
+real (`n_new`), because a recurrence would run through the padding that
+attention cannot see.  What slices a cache by position or rolls a cursor
+back is refused when the engine is made (`prefix_cache`, `spec`) or called
+(`submit_prefilled`, `prefill_only`), with the reason; a preempted request
+resumes through a cold prefill of its folded tokens.  `cache_bytes` says
+what the cache holds by kind, `scan_tokens` how many tokens the scan walked.
+
 The per-slot cache cursors this relies on live in models/transformer.py
 (decode mode).  The int8 KV-cache storage dtype comes straight from the
 model config (`kv_cache_dtype="int8"`): the serving cache stores quantized
@@ -116,8 +129,10 @@ from .queue import AdmissionQueue
 from .request import Request, Result
 from .slots import (
     SlotManager,
+    cache_bytes,
     extract_rows,
     extract_slot_rows,
+    has_state,
     reset_slot,
     warm_small_cache,
     write_slot,
@@ -189,7 +204,8 @@ class ServingEngine:
         spec=None,
         tenants: Optional[TenantRegistry] = None,
     ):
-        assert cfg.rope, "serving decode requires a rope config (cache cursors)"
+        assert cfg.rope or not cfg.pos_table, (
+            "serving decode: rope positions from the cache cursors, or none")
         # decode overrides mirror generate(): full attention on the cache, a
         # dense head, GSPMD (not shard_map) sharding under `mesh`: there the
         # cache read stays the plain einsum GSPMD can split ("full"); on
@@ -240,6 +256,19 @@ class ServingEngine:
         self._dev_counters_host = jax.device_get(state)
         self._dev_lock = threading.Lock()
         self._small_cache0 = zeros(1)[1]["cache"]
+        # bytes of the slot cache by kind (rows | state), from its shapes
+        self.cache_bytes = cache_bytes(self.cache)
+        # recurrent layers keep a state a slot: no position to cut it at
+        self._stateful = has_state(self.cache)
+        if self._stateful and (prefix_cache is not None or spec is not None):
+            raise ValueError(
+                "a model with recurrent state serves with no prefix cache "
+                "and no speculation: a state is the summary of every token "
+                "so far, so a prefix hit would need a snapshot of it at the "
+                "hit length and a rejected draft a way to roll it back "
+                "(serving/slots.py STATE_LEAVES)")
+        # tokens the recurrent layers' scan walked, a layer (`scan_tokens`)
+        self._scan_tokens = {"prefill": 0, "decode": 0}
         self._param_shardings = None
         if mesh is not None:
             from ..parallel.sharding import decode_cache_shardings, param_shardings
@@ -299,6 +328,7 @@ class ServingEngine:
         # Without one they are the programs they always were
         want_hidden = self._want_hidden = bool(
             getattr(spec, "reads_hidden", False))
+        stateful = self._stateful
         self._prefill_hidden = None  # the last cold prefill's, for `_admit_to`
 
         def _run(variables, tokens, **kw):
@@ -332,9 +362,12 @@ class ServingEngine:
             # the zeroed template on a cold start, or a warm cache whose
             # cursor sits at the prefix-cache hit length — the forward reads
             # positions from the cursor, so ONE program serves both.
+            # a recurrence runs through what it is given: a model with
+            # state is told how many of the bucket's tokens are real
+            real = {"n_new": jnp.reshape(n_new, (1,))} if stateful else {}
             logits, hidden, st = _run(
                 {"params": params, "cache": cache_small}, tokens,
-                mutable=["cache"]
+                mutable=["cache"], **real
             )
             last = jax.lax.dynamic_index_in_dim(
                 logits, n_new - 1, axis=1, keepdims=False
@@ -446,6 +479,7 @@ class ServingEngine:
         frees (the decode-tier half of disaggregation).  Re-ships of an
         already-known request (a prefill rank died mid-wait and the retry
         re-shipped) return the existing handle — the double-serve guard."""
+        self._rows_only("shipped KV")
         with self._completed_lock:
             existing = self._pending.get(req.req_id)
         if existing is not None:
@@ -576,6 +610,12 @@ class ServingEngine:
                       trace_id=req.trace_id)
         self.queue.requeue(req, count=False)
 
+    def _rows_only(self, what: str) -> None:
+        if self._stateful:
+            raise ValueError(
+                f"{what} moves cache rows between ranks; this model keeps "
+                "recurrent state beside its rows, which has no rows to move")
+
     def _bucket_for(self, n: int) -> int:
         for b in self.buckets:
             if n <= b:
@@ -676,6 +716,8 @@ class ServingEngine:
                     first = self._sample(np.asarray(last_logits), temperature)
             dt = time.monotonic() - t0
         self.total_prefill_tokens += len(suffix)
+        if self._stateful:
+            self._count_scan("prefill", len(suffix))
         self._observe("prefill_ms", dt * 1e3)
         return first, small, total, hit
 
@@ -684,6 +726,7 @@ class ServingEngine:
         with NO slot and return what the decode tier needs — the first
         token, the KV rows, and the cursor.  Raises ValueError exactly as
         submit() would on a request that can never fit."""
+        self._rows_only("a prefill tier")
         need = len(req.prefill_tokens) + req.remaining_new_tokens
         if need > self.dcfg.max_len:
             raise ValueError(
@@ -1100,6 +1143,19 @@ class ServingEngine:
         self._decode_rows = {
             "live": self._decode_rows["live"] + n_live,
             "free": self._decode_rows["free"] + self.n_slots - n_live}
+        if self._stateful:
+            self._count_scan("decode", n_live * query_rows)
+
+    def _count_scan(self, kind: str, tokens: int) -> None:
+        self._scan_tokens = {**self._scan_tokens,
+                             kind: self._scan_tokens[kind] + tokens}
+
+    def scan_tokens(self) -> Dict[str, int]:
+        """Tokens the recurrent layers' scan walked so far, a layer:
+        `prefill` the real tokens of the prefills (not the padding of their
+        buckets), `decode` the live slot-steps of the decode steps.  Zeros
+        for a model without such layers."""
+        return dict(self._scan_tokens)
 
     def decode_attn_rows(self) -> Dict[str, int]:
         """Cache rows of the decode-step attention, summed over the decode
@@ -1169,7 +1225,10 @@ class ServingEngine:
             "decode_attn_rows": self.decode_attn_rows(),
             "decode_rows": self.decode_rows(),
             "decode_steps": self.decode_steps(),
+            "cache_bytes": dict(self.cache_bytes),
         }
+        if self._stateful:
+            out["scan_tokens"] = self.scan_tokens()
         if self.prefix is not None:
             out["prefix"] = self.prefix.stats()
         if self.spec is not None:

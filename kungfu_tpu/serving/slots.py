@@ -30,6 +30,19 @@ blocks between prefill and decode ranks.  Position-indexed leaves are every
 cache leaf except the `idx`/`overflowed` cursor state, whatever lies behind
 the position axis: a layer's `[.., Hkv, D]` K and V (and int8 scales), or
 the one `[.., rank + rope]` latent row of a latent-attention sublayer.
+
+STATE leaves (`STATE_LEAVES`) are the third kind: what a recurrent layer
+keeps for a slot (models/transformer.py `Mamba`: `ssm_state`
+[slots, N, d_inner] and `conv_state` [slots, K - 1, d_inner]), with a slot
+axis and NO position axis.  `write_slot` replaces a slot whole, state
+included, so an admission replaces the last tenant's state; `reset_slot`
+leaves it where it is, as it leaves rows (a free slot's row is not live and
+the model keeps its state untouched).  A state is the summary of every
+token so far and cannot be cut at a position: the helpers that slice rows
+by position or move cursors alone (`extract_rows`, `extract_slot_rows`,
+`warm_small_cache`, `set_cursors`) raise on a tree that holds one, and the
+engine refuses what is built on them (prefix reuse, speculation, shipped
+KV) when it is made.
 """
 from __future__ import annotations
 
@@ -44,10 +57,36 @@ import numpy as np
 from .request import Request
 
 CURSOR_LEAVES = ("idx", "overflowed")
+#: leaves a recurrent layer keeps a slot: no position axis
+STATE_LEAVES = ("ssm_state", "conv_state")
 
 
 def _leaf_name(path) -> Optional[str]:
     return getattr(path[-1], "key", None)
+
+
+def has_state(cache) -> bool:
+    """Whether the cache tree holds a recurrent layer's state."""
+    return any(_leaf_name(path) in STATE_LEAVES
+               for path, _ in jax.tree_util.tree_flatten_with_path(cache)[0])
+
+
+def cache_bytes(cache) -> Dict[str, int]:
+    """Bytes of the cache tree by kind: position-indexed `rows` (cursors
+    beside them) and recurrent `state`, from the leaves' shapes."""
+    out = {"rows": 0, "state": 0}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
+        kind = "state" if _leaf_name(path) in STATE_LEAVES else "rows"
+        out[kind] += leaf.size * leaf.dtype.itemsize
+    return out
+
+
+def _rows_only(cache, what: str) -> None:
+    if has_state(cache):
+        raise ValueError(
+            f"{what}: the cache holds recurrent state (no position axis); a "
+            "state is the summary of every token so far and cannot be cut "
+            "at a position or rolled back by a cursor")
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -78,14 +117,19 @@ def reset_slot(big, slot):
     return jax.tree_util.tree_map_with_path(fix, big)
 
 
-@partial(jax.jit, donate_argnums=(0,))
 def set_cursors(big, cursors):
     """Write every slot's cursor from `cursors` [slots] int32 — the per-slot
     speculative rollback.  K/V rows and overflow flags are untouched: rows
     above a cursor are never attended (reset_slot's contract), and the
     engine only speculates on slots with `cursor + k <= max_len`, so a
-    rollback can never need to clear an overflow."""
+    rollback can never need to clear an overflow.  Raises on a tree with
+    state leaves: a cursor cannot roll a recurrence back."""
+    _rows_only(big, "set_cursors")
+    return _set_cursors(big, cursors)
 
+
+@partial(jax.jit, donate_argnums=(0,))
+def _set_cursors(big, cursors):
     def fix(path, leaf):
         if _leaf_name(path) == "idx":
             return cursors.astype(leaf.dtype)
@@ -102,6 +146,7 @@ def extract_rows(small, n: int) -> Dict[tuple, np.ndarray]:
     device_get and the row slice happens on the HOST: an eager device
     slice (`leaf[0, :n]`) would compile one slice program per distinct
     prefix length — a compile storm on mixed traffic."""
+    _rows_only(small, "extract_rows")
     out = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(small)[0]:
         if _leaf_name(path) in CURSOR_LEAVES:
@@ -117,6 +162,7 @@ def extract_slot_rows(big, slot: int, n: int) -> Dict[tuple, np.ndarray]:
     these to the radix prefix cache so the evicted request's re-prefill is
     a warm hit.  Same discipline as extract_rows — batched device_get,
     HOST-side slicing — so no per-(slot, length) slice programs compile."""
+    _rows_only(big, "extract_slot_rows")
     out = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(big)[0]:
         if _leaf_name(path) in CURSOR_LEAVES:
@@ -133,6 +179,7 @@ def warm_small_cache(template, rows: Dict[tuple, np.ndarray], n: int):
     at all).  `template` is the engine's zeroed [1, max_len, ...] tree;
     output shapes/dtypes match it exactly, so the jitted prefill/graft
     programs never retrace."""
+    _rows_only(template, "warm_small_cache")
 
     def fill(path, leaf):
         name = _leaf_name(path)

@@ -167,6 +167,22 @@ class ServingWorker:
         import jax
 
         cfg = build_config(args.preset, args.model_json)
+        # a model with recurrent layers keeps a state a slot, which no
+        # position cuts: nothing built on rows alone is armed for it, and
+        # asking for it ends the boot here, before any weight is made
+        # (serving/slots.py STATE_LEAVES; the engine refuses them too)
+        stateful = cfg.mamba_d_state > 0
+        asked = [flag for flag, on in (
+            ("--prefix-cache on", args.prefix_cache == "on"),
+            ("--spec-draft", bool(getattr(args, "spec_draft", ""))),
+            ("--tier", bool(self.tier))) if on]
+        if stateful and asked:
+            raise SystemExit(
+                f"{', '.join(asked)}: not with a model that keeps recurrent "
+                "state (mamba_d_state > 0): a prefix hit needs a snapshot of "
+                "the state at the hit length, a rejected draft a way to roll "
+                "it back, a shipped prefill rows to ship; none exists yet "
+                "(ROADMAP R6)")
         t0 = time.monotonic()
         # boot phases end when the device has done their work, not when the
         # host has dispatched it: each then holds what it names
@@ -198,7 +214,8 @@ class ServingWorker:
         from .engine import ServingEngine
 
         prefix = None
-        if self.tier != "decode" and getattr(args, "prefix_cache", "auto") != "off":
+        if not stateful and self.tier != "decode" \
+                and getattr(args, "prefix_cache", "auto") != "off":
             from .prefix import PrefixCache, prefix_cache_if_enabled
 
             if args.prefix_cache == "on":
@@ -252,7 +269,11 @@ class ServingWorker:
                     ("kft_serve_decode_rows_total",
                      self.engine.decode_rows),
                     ("kft_serve_decode_steps_total",
-                     self.engine.decode_steps))})
+                     self.engine.decode_steps),
+                    ("kft_serve_cache_bytes",
+                     lambda: self.engine.cache_bytes),
+                    ("kft_serve_scan_tokens_total",
+                     self.engine.scan_tokens))})
         self.decode_pool = None
         if self.tier == "prefill" and args.config_server:
             from ..elastic.config_client import ConfigClient

@@ -21,6 +21,14 @@ build from them and the block the engine counts fetched rows by.
 
 A later PR that changes one of these programs on purpose runs the script
 on its own tree, replaces the digest and says so.
+
+ISSUE 42 adds the "hybrid" shape (state-space mixers beside position-free
+multi-query attention, state leaves in the slot cache) so that the next PR
+is held to its programs too: `_decode`, a `_prefill` bucket (which hands
+the model its count of real tokens) and a training step, through the
+`lax.scan` and through the selective-scan kernel's body.  Their digests are
+of commit-of-PR-42's own lowering; a model with recurrent state serves
+without speculation, so it has no verify program.
 """
 import dataclasses
 import hashlib
@@ -62,6 +70,16 @@ GOLDEN = {
         "8d68ce8fadda4b4eab0cbe0773a61bbbd34e5109076d2fe28bed0fa516e29934",
     "experts.verify.off":
         "ab53825ec2690caf3f5b06600da6829f5adbf1a796ac00e80de608b98447dec0",
+    "hybrid.decode.interpret":
+        "a3417fc3d0b4b5954012d8d590074c708bac7c711fa5076e18e2a014259cdadc",
+    "hybrid.decode.off":
+        "9c0923c46d73ba8235351354dfb04defb5f42cedf46f1918bd80c52e6a1145fd",
+    "hybrid.prefill.interpret":
+        "d0364d07de543738e2c241dcc14556be0cf1dd6776a9213e2c60a104ae055afc",
+    "hybrid.prefill.off":
+        "1d43e3150e905a80d92f68849e0e3889f1157b4a7d02a490415bda6cce07732d",
+    "hybrid.train_step.off":
+        "a25b9f333846a5147707ded4446f86ccb38868497edd35f7b302ffbb16f5d75f",
 }
 
 SLOTS, K = 4, 3
@@ -77,6 +95,13 @@ def _config(shape: str):
                 dtype=jnp.float32)
     if shape == "dense":
         return TransformerConfig(tie_embeddings=True, **base)
+    if shape == "hybrid":
+        # layer 1 of 2 attention (one KV head: the dense einsum), layer 0 a
+        # state-space mixer of inner width 2048: four sub-tiles of the kernel
+        return TransformerConfig(**dict(
+            base, rope=False, pos_table=False, tie_embeddings=True,
+            n_kv_heads=1, mamba_d_state=16, mamba_dt_rank=64,
+            attn_layer_period=2, attn_layer_offset=1))
     return TransformerConfig(qk_norm=True, n_experts=4, experts_per_token=2,
                              moe_every=1, embed_init_std=1.0, **base)
 
@@ -133,9 +158,10 @@ def _train_step(shape):
 
 PROGRAMS = {
     f"{shape}.{name}.{mode}": (mode, lower, shape)
-    for shape in ("dense", "experts")
+    for shape in ("dense", "experts", "hybrid")
     for name, lower in (("decode", _decode), ("verify", _verify),
                         ("prefill", _prefill), ("train_step", _train_step))
+    if (shape, name) != ("hybrid", "verify")
     # the Pallas bodies are what a TPU runs: the decode attention kernel,
     # the grouped matmul.  The training step has no kernel of its own here
     for mode in (("off",) if name == "train_step" else ("off", "interpret"))
