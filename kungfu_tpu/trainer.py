@@ -114,49 +114,73 @@ class MeshTrainer:
 
     # -- init -------------------------------------------------------------------------
 
-    @trace_scope("train:init", cat=BOOT_CAT)
     def init(self, rng, sample_batch) -> TrainState:
         """Initialize params under the logical rules and place them sharded.
 
         `sample_batch` is a (host) global batch used only for shapes.  A
         boot phase (`train:init`), which ends when the state is placed.
+
+        The optimizer state is created where it will live: a sub-tree of it
+        that mirrors the parameters (the same tree and shapes: Adam's mu
+        and nu, a momentum trace, an EMA) takes the parameters' shardings
+        leaf for leaf, every other leaf (a step count, a schedule's scalar,
+        a factored statistic) is replicated on the mesh.  Sharding
+        propagation cannot do this: `tx.init` is `zeros_like` and fresh
+        scalars, which depend on no VALUE of the parameters, so nothing
+        propagates and every leaf would come back replicated, each chip
+        holding and updating whole moments of tensors it owns a share of.
+        The span's `args` say what was placed (`opt_state_sharded_leaves`,
+        `opt_state_replicated_leaves`, `opt_state_bytes_a_chip`).
         """
-        self._base_rng = jax.random.fold_in(rng, 0x5eed)  # loss-rng stream
-        self._multi = {}  # compiled multi-step fns capture the base rng
-        with nn.logical_axis_rules(self.rules):
-            boxed = self.model.init(rng, *_as_args(sample_batch))["params"]
-        self._shardings = param_shardings(self.mesh, boxed, self.rules)
-        params = nn.meta.unbox(boxed)
-        with self.mesh:
-            placed = jax.jit(lambda p: p, out_shardings=self._shardings)(params)
-            # let propagation shard the optimizer state like the params
-            opt_state = jax.jit(self.tx.init)(placed)
-            # leaves tx.init created fresh (step counters, scalar
-            # schedules) come back default-placed on ONE device, not the
-            # mesh: pin them replicated on the mesh.  Every other leaf is
-            # put where it already is, which COMMITS it: jit keys its cache
-            # on committedness, and the step returns committed arrays, so
-            # an uncommitted initial state compiled the step a second time.
-            mesh_devs = set(self.mesh.devices.flat)
-            replicated = NamedSharding(self.mesh, P())
-
-            def on_mesh(x):
-                if set(x.sharding.device_set) != mesh_devs:
-                    return jax.device_put(x, replicated)
-                return jax.device_put(x, x.sharding)
-
-            opt_state = jax.tree.map(on_mesh, opt_state)
-        # the step hands its state back under exactly these shardings.  Left
-        # to the compiler, equal shardings come back spelled differently
-        # (P() vs P(None, None)), which misses jit's cache: the second step
-        # compiled the whole program again
-        self._state_shardings = jax.tree.map(
-            lambda x: x.sharding, (placed, opt_state)
-        )
-        self._step_fn = self._build_step()
-        self._booted = False  # the next train_step is this state's first
-        jax.block_until_ready((placed, opt_state))
+        span_args: Dict[str, Any] = {}  # filled before the span closes
+        with trace_scope("train:init", cat=BOOT_CAT, args=span_args):
+            self._base_rng = jax.random.fold_in(rng, 0x5eed)  # loss-rng stream
+            self._multi = {}  # compiled multi-step fns capture the base rng
+            with nn.logical_axis_rules(self.rules):
+                boxed = self.model.init(rng, *_as_args(sample_batch))["params"]
+            self._shardings = param_shardings(self.mesh, boxed, self.rules)
+            params = nn.meta.unbox(boxed)
+            with self.mesh:
+                placed = jax.jit(
+                    lambda p: p, out_shardings=self._shardings)(params)
+                # every leaf under an explicit sharding comes back COMMITTED
+                # on the mesh: jit keys its cache on committedness, and the
+                # step returns committed arrays, so an uncommitted initial
+                # state compiled the step a second time
+                opt_state = jax.jit(
+                    self.tx.init,
+                    out_shardings=self._opt_state_shardings(
+                        jax.eval_shape(self.tx.init, placed), placed),
+                )(placed)
+            # the step hands its state back under exactly these shardings.
+            # Left to the compiler, equal shardings come back spelled
+            # differently (P() vs P(None, None)), which misses jit's cache:
+            # the second step compiled the whole program again
+            self._state_shardings = jax.tree.map(
+                lambda x: x.sharding, (placed, opt_state)
+            )
+            self._step_fn = self._build_step()
+            self._booted = False  # the next train_step is this state's first
+            jax.block_until_ready((placed, opt_state))
+            span_args.update(_opt_state_placement(opt_state))
         return TrainState(params=placed, opt_state=opt_state, step=0)
+
+    def _opt_state_shardings(self, abstract, params):
+        """Where each leaf of `tx.init`'s result (`abstract`: its shapes)
+        lives: under `self._shardings` for every sub-tree that mirrors
+        `params`, replicated on the mesh for every other leaf."""
+        treedef = jax.tree.structure(params)
+        shapes = [x.shape for x in jax.tree.leaves(params)]
+
+        def mirrors(node):
+            return (jax.tree.structure(node) == treedef
+                    and [x.shape for x in jax.tree.leaves(node)] == shapes)
+
+        replicated = NamedSharding(self.mesh, P())
+        return jax.tree.map(
+            lambda node: self._shardings if mirrors(node) else replicated,
+            abstract, is_leaf=mirrors,
+        )
 
     def _step_body(self, params, opt_state, batch, rng):
         """One step under the logical rules: shared by the single-step jit
@@ -306,3 +330,17 @@ class MeshTrainer:
 
 def _as_args(batch):
     return batch if isinstance(batch, tuple) else (batch,)
+
+
+def _opt_state_placement(opt_state) -> Dict[str, int]:
+    """What `init` placed, for the `train:init` span: the optimizer state's
+    array leaves by whether a device holds a share or the whole, and the
+    bytes one device holds of them (its addressable shards)."""
+    leaves = jax.tree.leaves(opt_state)
+    sharded = sum(not x.sharding.is_fully_replicated for x in leaves)
+    return {
+        "opt_state_sharded_leaves": sharded,
+        "opt_state_replicated_leaves": len(leaves) - sharded,
+        "opt_state_bytes_a_chip": sum(
+            x.addressable_shards[0].data.nbytes for x in leaves),
+    }

@@ -279,6 +279,40 @@ def test_the_first_step_of_a_state_is_the_trainers_first_call():
     assert len(T.global_trace_buffer()) == 0  # train:step itself: the ring's gate
 
 
+@pytest.mark.parametrize("axis, sharded", [("fsdp", 6), ("dp", 0)])
+def test_train_init_says_where_it_placed_the_optimizer_state(axis, sharded):
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+
+    from kungfu_tpu.trainer import MeshTrainer
+
+    class Net(nn.Module):  # two kernels with a logical "embed" dimension
+        @nn.compact
+        def __call__(self, x):
+            for i, axes in enumerate((("embed", "mlp"), ("mlp", "embed"))):
+                x = nn.Dense(8, kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.lecun_normal(), axes), name=f"d{i}")(x)
+            return x
+
+    mesh = P.plan_mesh.make_mesh(devices=jax.devices()[:4], **{axis: 4})
+    tr = MeshTrainer(Net(), lambda m, p, b: jnp.mean(m.apply({"params": p}, b) ** 2),
+                     optax.chain(optax.adamw(1e-3), optax.ema(0.9)), mesh=mesh)
+    tr.init(jax.random.PRNGKey(0), np.ones((8, 8), np.float32))
+    (phase,) = [p for p in boot.record()["phases"] if p["name"] == "train:init"]
+    # bytes: adamw's mu and nu and the ema of two [8, 8] float32 kernels,
+    # of which a chip holds a quarter under fsdp; of two biases, with the
+    # two step counts, which it holds whole
+    kernels, rest = 3 * 2 * 8 * 8 * 4, 3 * 2 * 8 * 4 + 2 * 4
+    assert phase["args"] == {
+        "opt_state_sharded_leaves": sharded,
+        "opt_state_replicated_leaves": 14 - sharded,
+        "opt_state_bytes_a_chip": (kernels // 4 if sharded else kernels) + rest,
+    }
+
+
 # -- the job clock ---------------------------------------------------------------------
 
 
