@@ -430,6 +430,54 @@ def _dense(features, name, kernel_axes, dtype, use_bias: bool = False,
     )
 
 
+def _scatter_rows(cache, rows, start):
+    """ONE scatter of B * L points, each a whole [...] tail of the leaf,
+    batched over the slot axis (so GSPMD splits it over a sharded slot axis
+    as it split the vmapped write).  Sorted, unique and in bounds by
+    construction."""
+    pos = start[:, None] + jnp.arange(rows.shape[1], dtype=start.dtype)
+    dims = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=tuple(range(2, cache.ndim)),
+        inserted_window_dims=(1,), scatter_dims_to_operand_dims=(1,),
+        operand_batching_dims=(0,), scatter_indices_batching_dims=(0,))
+    return jax.lax.scatter(
+        cache, pos[..., None], rows, dims, indices_are_sorted=True,
+        unique_indices=True, mode="promise_in_bounds")
+
+
+def _write_each_slot(cache, rows, start):
+    """One `dynamic_update_slice` a slot, written out over the static slot
+    count.  The starts are unsigned so that none of them brings the
+    negative-index select JAX puts before a signed one."""
+    start = start.astype(jnp.uint32)
+    origin = (jnp.uint32(0),) * (cache.ndim - 2)
+    for b in range(rows.shape[0]):
+        cache = jax.lax.dynamic_update_slice(
+            cache, rows[b:b + 1], (jnp.uint32(b), start[b]) + origin)
+    return cache
+
+
+def _store_rows(cache: jax.Array, rows: jax.Array, idx0: jax.Array) -> jax.Array:
+    """`cache` [B, max_len, ...] with `rows` [B, L, ...] written at
+    cache[b, idx0[b] : idx0[b] + L] for every slot b, in place on a donated
+    cache; a start past max_len - L is clamped to it.  One algorithm, spelt
+    by the leaf's shape so that the TPU compiler makes no loop over the
+    slots of it (a vmapped `dynamic_update_slice` becomes a scatter with an
+    L-row window, which it expands into a `while` of one turn a slot):
+
+    - a leaf whose rows are planes ([B, max_len, Hkv, D]): one scatter of
+      points, which compiles to one in-place fusion a leaf;
+    - a leaf whose rows are vectors ([B, max_len, W]: the latent rows, the
+      int8 cache's scales): for a scatter there the compiler copies the
+      whole leaf to another layout and back (150 MB a latent leaf), so the
+      slots' writes are written out, one a slot, each in place.
+    """
+    start = jnp.clip(idx0, 0, cache.shape[1] - rows.shape[1])
+    if cache.ndim >= 4 and rows.shape[0] > 1:
+        return _scatter_rows(cache, rows, start)
+    return _write_each_slot(cache, rows, start)
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
 
@@ -480,10 +528,10 @@ class Attention(nn.Module):
                 "cache", "idx", lambda: jnp.zeros((B,), jnp.int32)
             )
             # sticky PER-SLOT overflow flags: once a row's write ran past
-            # max_len the clamped dynamic_update_slice has clobbered that
-            # row's older slots, so EVERY later output of that row is
-            # suspect, not just out-of-range positions.  Cleared per slot
-            # when the serving engine re-prefills it.
+            # max_len, `_store_rows` pulled its start back to max_len - L
+            # and clobbered that row's older slots, so EVERY later output
+            # of that row is suspect, not just out-of-range positions.
+            # Cleared per slot when the serving engine re-prefills it.
             cache_ovf = self.variable(
                 "cache", "overflowed", lambda: jnp.zeros((B,), jnp.bool_)
             )
@@ -504,18 +552,15 @@ class Attention(nn.Module):
 
             def store(cache_var, scale_var, x):
                 """Write x at each slot's own cursor (quantizing + scale
-                write if int8).  vmapped over the batch dim: rows land at
-                per-slot positions, the continuous-batching write shape."""
+                write if int8): rows land at per-slot positions, the
+                continuous-batching write shape, one scatter a K/V leaf
+                and no loop over the slots (`_store_rows`)."""
                 if quant:
                     x, sc = quantize(x)
-                    scale_var.value = jax.vmap(
-                        lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (i, 0))
-                    )(scale_var.value, sc, idx0)
+                    scale_var.value = _store_rows(scale_var.value, sc, idx0)
                 else:
                     x = x.astype(cache_var.value.dtype)
-                cache_var.value = jax.vmap(
-                    lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (i, 0, 0))
-                )(cache_var.value, x, idx0)
+                cache_var.value = _store_rows(cache_var.value, x, idx0)
 
             def load(cache_var, scale_var):
                 """The int8 cache in the model dtype: the dequant (exact
@@ -786,9 +831,8 @@ class MLA(nn.Module):
                 materialise(c)
             else:
                 rows = jnp.concatenate([c, k_rot[:, :, 0]], axis=-1)
-                cache.value = jax.vmap(
-                    lambda m, u, i: jax.lax.dynamic_update_slice(m, u, (i, 0))
-                )(cache.value, rows.astype(cache.value.dtype), idx0)
+                cache.value = _store_rows(
+                    cache.value, rows.astype(cache.value.dtype), idx0)
                 step = L if live is None else jnp.where(live, L, 0)
                 cache_idx.value = idx0 + step
                 cache_ovf.value = jnp.logical_or(

@@ -9,6 +9,7 @@ kernel and einsum from what a call shows of itself.
 """
 import dataclasses
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -154,21 +155,54 @@ def v5e_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _scatter_loops(text):
+    """The `while` loops the TPU compiler expands a windowed scatter into,
+    one turn a slot: what a vmapped `dynamic_update_slice` of a step's new
+    cache rows becomes, two a layer."""
+    return [name for name in re.findall(
+        r' while\([^\n]*op_name="([^"]*)"', text) if name.endswith("/scatter")]
+
+
+def _whole_leaves_moved(text, leaf):
+    """Copies, transposes and converts of a whole cache leaf (`leaf`: a
+    regular expression of its dimensions) outside fusions: inside a fusion
+    nothing is written to HBM."""
+    moved = []
+    for comp in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text):
+        if "fused_computation" in comp.split("\n", 1)[0]:
+            continue
+        moved += re.findall(
+            rf"= \S*\[{leaf}\]\S* (?:copy|transpose|convert)\(", comp)
+    return moved
+
+
+# (fields over the dense block, slots, the leaf's dimensions, kernel calls a layer)
+STEP_MODELS = {
+    "dense": ({}, 8, "8,2048,16,128", 1),
+    "experts": (dict(n_experts=8, experts_per_token=2, moe_every=1), 8,
+                "8,2048,16,128", 1),
+    # jamba2-3b-serve's attention layers: one KV head at 64 slots of 4,096
+    # rows, read by the dense einsum (no kernel for one KV head)
+    "one_kv_head": (dict(d_model=2560, n_heads=20, n_kv_heads=1, max_len=4096,
+                         rope=False, pos_table=False, norm="rms"), 64,
+                    "64,4096,1,128", 0),
+}
+
+
 @pytest.mark.parametrize("call", ["four_arguments", "the_engines"])
-@pytest.mark.parametrize("experts", [0, 8], ids=["dense", "experts"])
+@pytest.mark.parametrize("model", list(STEP_MODELS))
 def test_the_engines_decode_program_reads_the_donated_cache_where_it_lies(
-        v5e_chip, monkeypatch, experts, call):
+        v5e_chip, monkeypatch, model, call):
     """`ServingEngine._decode` at the cells' widths (two layers), compiled
     for the chip: one kernel call a layer, no copy, transpose or convert of
     a whole cache leaf ahead of it (a layout change of the operand would
-    move 64 MiB a leaf a step), and the donated cache still aliases the
-    program's output.  The mask that tells it which slots are free rides in
-    the token upload: the one array a call brings from the host.  The
-    engine's own call brings one more from the device, the [slots] tokens
-    of the step before (a slot that holds CARRY takes its token from them),
-    and is the same program otherwise."""
-    import re
-
+    move 64 MiB a leaf a step), the step's new rows written with no loop
+    over the slots (one scatter fusion a leaf), and the donated cache still
+    aliases the program's output.  The mask that tells it which slots are
+    free rides in the token upload: the one array a call brings from the
+    host.  The engine's own call brings one more from the device, the
+    [slots] tokens of the step before (a slot that holds CARRY takes its
+    token from them), and is the same program otherwise."""
     from kungfu_tpu import compat
     from kungfu_tpu.ops.gmm import KERNEL_NAME as GMM
     from kungfu_tpu.serving import ServingEngine
@@ -176,18 +210,18 @@ def test_the_engines_decode_program_reads_the_donated_cache_where_it_lies(
     # the program asks jax.default_backend(), the CPU here: the test (not
     # the program) steers it onto the path the chip takes
     monkeypatch.setattr(compat, "pallas_mode", lambda interpret=None: "compiled")
-    cfg = TransformerConfig(vocab_size=512, d_model=2048, n_layers=2,
-                            n_heads=16, d_ff=256, max_len=2048, rope=True,
-                            attention="full", dtype=jnp.bfloat16,
-                            n_experts=experts, experts_per_token=2, moe_every=1,
-                            ffn="swiglu")
+    fields, slots, leaf, kernel_calls = STEP_MODELS[model]
+    cfg = TransformerConfig(**{**dict(
+        vocab_size=512, d_model=2048, n_layers=2, n_heads=16, d_ff=256,
+        max_len=2048, rope=True, attention="full", dtype=jnp.bfloat16,
+        ffn="swiglu"), **fields})
     described = lambda tree: jax.tree.map(  # noqa: E731
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip), tree)
     params = described(nn.meta.unbox(jax.eval_shape(
         TransformerLM(cfg).init, jax.random.PRNGKey(0),
         jnp.zeros((1, 1), jnp.int32))["params"]))
-    eng = ServingEngine(cfg, params, slots=8)
-    brought = [jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=v5e_chip)]
+    eng = ServingEngine(cfg, params, slots=slots)
+    brought = [jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=v5e_chip)]
     if call == "the_engines":
         brought.append(described(eng._no_prev))
     lowered = eng._decode.lower(
@@ -200,16 +234,11 @@ def test_the_engines_decode_program_reads_the_donated_cache_where_it_lies(
     text = compiled.as_text()
     calls = lambda kernel: re.findall(  # noqa: E731
         rf"= \S+ custom-call\([^\n]*{kernel}", text)
-    assert len(calls("kft_decode_attn")) == cfg.n_layers
+    assert len(calls("kft_decode_attn")) == kernel_calls * cfg.n_layers
     # gate, up and down of every expert layer: one grouped matmul each
-    assert len(calls(GMM)) == (3 * cfg.n_layers if experts else 0)
-    moved = []
-    for comp in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text):
-        if "fused_computation" in comp.split("\n", 1)[0]:
-            continue  # inside a fusion nothing is written to HBM
-        moved += re.findall(
-            r"= \S*\[8,2048,16,128\]\S* (?:copy|transpose|convert)\(", comp)
-    assert moved == []
+    assert len(calls(GMM)) == (3 * cfg.n_layers if cfg.n_experts else 0)
+    assert _scatter_loops(text) == []
+    assert _whole_leaves_moved(text, leaf) == []
     cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(eng.cache))
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= cache_bytes
@@ -414,8 +443,6 @@ def test_the_latent_decode_program_reads_the_donated_cache_where_it_lies(
     program, no copy, transpose or convert of a whole leaf ahead of the
     kernel (the chip lays the leaf out feature-major and the kernel takes it
     so: `ops/decode_attn.py`), and the donated cache aliases the output."""
-    import re
-
     from kungfu_tpu import compat
     from kungfu_tpu.ops.gmm import KERNEL_NAME as GMM
     from kungfu_tpu.serving import ServingEngine
@@ -453,14 +480,10 @@ def test_the_latent_decode_program_reads_the_donated_cache_where_it_lies(
     assert len(calls("kft_decode_attn")) == 0
     assert len(calls(GMM)) == 3 * cfg.n_layers
     assert "[32,4096,16," not in text          # no K or V of a cached row
-    moved = []
-    for comp in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text):
-        if "fused_computation" in comp.split("\n", 1)[0]:
-            continue  # inside a fusion nothing is written to HBM
-        moved += re.findall(
-            r"= \S*\[32,(?:4096,576|576,4096)\]\S* (?:copy|transpose|convert)\(",
-            comp)
-    assert moved == []
+    # the step's 32 new rows a sublayer: no loop over the slots, and not
+    # the scatter that would copy the leaf to a row-major layout and back
+    assert _scatter_loops(text) == []
+    assert _whole_leaves_moved(text, "32,(?:4096,576|576,4096)") == []
     cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(eng.cache))
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= cache_bytes
