@@ -350,16 +350,13 @@ def _lm_config(n_layers: int, mesh=None, **kw):
 
 def child_a() -> dict:
     """Flash kernels, compiled by Mosaic, against the plain-XLA reference."""
-    import dataclasses
-
     import numpy as np
     import jax
     import jax.numpy as jnp
 
     from kungfu_tpu import native
-    from kungfu_tpu.ops.flash import flash_attention
+    from kungfu_tpu.ops.flash import flash_attention, flash_blocks
     from kungfu_tpu.parallel.ring_attention import full_attention
-    from kungfu_tpu.tuner import resolve_flash_blocks
 
     device = _device()
     print(f"native: {'built' if native.available() else 'numpy'}", flush=True)
@@ -367,8 +364,8 @@ def child_a() -> dict:
     setup_s, step_ms = 0.0, []
     for H in (WIDTH["n_heads"], WIDTH["n_heads"] // 2):  # head_dim 64, 128
         D = WIDTH["d_model"] // H
-        cfg = dataclasses.replace(_lm_config(1), n_heads=H)
-        bq, bk = resolve_flash_blocks(cfg, batch=B, seq_len=L)
+        bq, bk = flash_blocks(None, None, head_dim=D, seq_len=L,
+                              dtype_bytes=2)  # the table, as a model's None
         ks = jax.random.split(jax.random.PRNGKey(H), 4)
         q, k, v, w = [jax.random.normal(kk, (B, L, H, D), jnp.bfloat16)
                       for kk in ks]
@@ -397,8 +394,9 @@ def child_a() -> dict:
             grad = jax.jit(jax.grad(loss_of(attn, w), argnums=(0, 1, 2)))
             text = grad.lower(q, k, v).as_text()
             n_calls = text.count("tpu_custom_call")
-            # forward kernel always; dq and dk/dv kernels when forced
-            want = 3 if backward == "pallas" else 1
+            # forward kernel always; forced, the backward too (MHA at this
+            # length: the one-pass kernel, so one more call, not two)
+            want = 2 if backward == "pallas" else 1
             assert n_calls >= want, (
                 f"D{D} {arm}: {n_calls} tpu_custom_call in the lowered "
                 f"grad, expected at least {want}")

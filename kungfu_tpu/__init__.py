@@ -55,10 +55,6 @@ _IMPORT_T1 = _time.monotonic()
 
 def __getattr__(name):
     # lazy heavyweight exports (importing them pulls in jax at module scope)
-    if name == "FSDPTrainer":
-        from .fsdp import FSDPTrainer
-
-        return FSDPTrainer
     if name == "DataParallelTrainer":
         from .train import DataParallelTrainer
 

@@ -17,10 +17,10 @@ Three surfaces:
               traces fn (no devices, no compile) and returns structured
               `Finding`s with jaxpr provenance.
   hooks       `Session(..., analyze=True)`, `synchronous_sgd(...,
-              analyze=True)`, `pair_averaging(..., analyze=True)`,
-              `FSDPTrainer(..., analyze=True)` — or `KUNGFU_ANALYZE=1` —
-              run the checker at trace time and raise `AnalysisError` on
-              error-severity findings before dispatch.
+              analyze=True)`, `pair_averaging(..., analyze=True)` — or
+              `KUNGFU_ANALYZE=1` — run the checker at trace time and
+              raise `AnalysisError` on error-severity findings before
+              dispatch.
   CLI         `python -m kungfu_tpu.analysis` lints the built-in program
               corpus (optimizers, examples, benchmark programs, every
               registered strategy implementation); `--module pkg.mod`

@@ -2,7 +2,7 @@
 
 Each Program lazily builds one representative collective program — the
 shipped optimizers in the same harnesses the trainers run them in, the
-Session collectives for every registered Strategy, the FSDP/pipeline
+Session collectives for every registered Strategy, the fsdp/pipeline
 parallel schedules, and the example/benchmark train steps — plus the
 check() arguments (mesh, compression) it is deployed with.  Tests assert
 the whole corpus is error-free; the CLI re-checks it on demand, which is
@@ -279,38 +279,32 @@ def _b_session_group():
     return build
 
 
-def _b_fsdp(hybrid: bool, compression=None):
+def _b_fsdp(hybrid: bool):
+    """MeshTrainer's step over an fsdp mesh: the trainer the benchmark's
+    four-chip cell runs (GSPMD: the collectives are the compiler's, the
+    lint sees the program handed to it)."""
     def build():
+        import jax
         import numpy as np
         import optax
 
-        from ..fsdp import FSDPTrainer
         from ..models.transformer import TransformerConfig, TransformerLM, lm_loss
+        from ..trainer import MeshTrainer
 
         mesh = _mesh({"dp": 2, "fsdp": 4} if hybrid else {"fsdp": 8})
         cfg = TransformerConfig(
             vocab_size=256, d_model=64, n_layers=2, n_heads=4, d_ff=128,
-            max_len=32,
+            max_len=32, mesh=mesh,
         )
-        model = TransformerLM(cfg)
-
-        def loss_fn(params, tokens):
-            return lm_loss(model.apply({"params": params}, tokens), tokens)
-
-        trainer = FSDPTrainer(loss_fn, optax.adam(1e-3), mesh=mesh,
-                              compression=compression)
-        import jax
-        import jax.numpy as jnp
-
-        params = model.init(jax.random.PRNGKey(0),
-                            jnp.zeros((1, 32), jnp.int32))["params"]
-        state = trainer.init(params)
-        world = trainer.world
-        batch = _sds((world * 2, 32), "int32")
-        args = (_abstract(state.params), _abstract(state.opt_state), batch)
-        comp_kw = {"dp": trainer.compression} if (hybrid and compression) else None
-        return trainer._compiled_step, args, {"mesh": mesh,
-                                              "compression": comp_kw}
+        trainer = MeshTrainer(
+            TransformerLM(cfg),
+            lambda m, p, t: lm_loss(m.apply({"params": p}, t), t),
+            optax.adam(1e-3), mesh=mesh)
+        state = trainer.init(jax.random.PRNGKey(0),
+                             np.zeros((16, 32), np.int32))
+        args = (_abstract(state.params), _abstract(state.opt_state),
+                _sds((16, 32), "int32"), _abstract(trainer._step_rng(0)))
+        return trainer._step_fn, args, {"mesh": mesh}
 
     return build
 
@@ -550,20 +544,20 @@ def builtin_programs() -> List[Program]:
                 _b_session("PALLAS_FUSED_MATMUL", {"dp": 8}, 1),
                 "fused computation-collective strategy (its allreduce is "
                 "the pallas ring pair; the matmul fusion itself lives in "
-                "ops/fused_matmul + fsdp.py's gather/scatter paths)"),
+                "ops/fused_matmul)"),
         # parallel schedules
         Program("pipeline-gpipe", ("parallel",), _b_pipeline(1),
                 "GPipe schedule over the pp ring"),
         Program("pipeline-circular", ("parallel",), _b_pipeline(2),
                 "circular (interleaved) pipeline, 2 rounds"),
         Program("fsdp-plain", ("parallel",), _b_fsdp(False),
-                "ZeRO-3 step, pure fsdp axis"),
+                "MeshTrainer adam step, pure fsdp axis"),
         # examples + benchmark programs
         Program("example-mnist-slp", ("example",), _b_mnist_slp(),
                 "examples/mnist_slp.py train step"),
         Program("example-fsdp-transformer", ("example", "bench"),
-                _b_fsdp(True, compression="int8"),
-                "examples/fsdp_transformer.py hybrid step, int8 dp leg "
+                _b_fsdp(True),
+                "examples/fsdp_transformer.py hybrid dp x fsdp step "
                 "(the largest corpus program)"),
         Program("bench-compression-int8", ("bench", "compression"),
                 _b_bench_compression("int8"),

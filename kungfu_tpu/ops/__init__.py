@@ -25,12 +25,10 @@ from .pallas_collectives import (
     ring_reduce_scatter as pallas_ring_reduce_scatter,
 )
 
-# Fused computation-collective matmuls (ops/fused_matmul.py): the FSDP
-# unshard/epilogue and ring attention's KV hop on the DMA data plane.
+# Fused computation-collective matmuls (ops/fused_matmul.py): a sharded
+# matmul's unshard/epilogue and ring attention's KV hop on the DMA data plane.
 from .fused_matmul import (
     all_gather_matmul,
-    dma_all_gather,
-    dma_reduce_scatter,
     matmul_reduce_scatter,
     ring_shift,
 )
@@ -48,6 +46,6 @@ __all__ = [
     "pallas_ring_all_reduce", "fused_ring_all_reduce",
     "pallas_ring_reduce_scatter", "pallas_ring_all_gather",
     "all_gather_matmul", "matmul_reduce_scatter",
-    "dma_all_gather", "dma_reduce_scatter", "ring_shift",
+    "ring_shift",
     "decode_attention", "decode_attention_reference",
 ]

@@ -34,8 +34,8 @@ def resolve_ce_block(block: Optional[int], n_tokens: Optional[int] = None,
     """The vocab chunk size the streaming head actually runs with.
 
     An explicit int always wins; None asks, in order: the KFT_CE_BLOCK
-    env knob, then the tuner's footprint default (streams ~64 MiB logit
-    blocks, clamped to [512, 8192] — kungfu_tpu/tuner/footprint.py).
+    env knob, then `default_ce_block` (streams ~64 MiB logit blocks,
+    clamped to [512, 8192]).
     Malformed env values fall through rather than wedge a trace.
     """
     if block:
@@ -46,9 +46,24 @@ def resolve_ce_block(block: Optional[int], n_tokens: Optional[int] = None,
             return int(env)
         except ValueError:
             pass
-    from ..tuner.footprint import default_ce_block
-
     return default_ce_block(n_tokens, vocab)
+
+
+def default_ce_block(n_tokens: Optional[int] = None,
+                     vocab: Optional[int] = None) -> int:
+    """Shape-conditional chunked-CE block default: stream ~64 MiB logit
+    blocks (f32), clamped to [512, 8192] powers of two.  With no token
+    count known, 2048 (the historical default)."""
+    if not n_tokens or n_tokens <= 0:
+        return 2048
+    target = (64 << 20) // (4 * n_tokens)
+    block = 512
+    while block * 2 <= target and block < 8192:
+        block *= 2
+    if vocab:
+        while block > vocab and block > 512:
+            block //= 2
+    return block
 
 
 def _pad_w(w: jax.Array, block: int):
@@ -65,7 +80,7 @@ def chunked_lm_head_ll(h, w, targets, block: Optional[int] = None):
 
     h: [N, D] (any float dtype; matmul runs in f32 like the dense head),
     w: [D, V], targets: [N] int32.  `block=None` resolves the vocab chunk
-    through `resolve_ce_block` (env, then the tuner's footprint default).
+    through `resolve_ce_block` (env, then the shape default).
     Returns (ll [N] f32, log_z [N] f32) — log-probability of the target
     and the log-normalizer (for PaLM z-loss), matching the dense
     `_token_ll` contract.

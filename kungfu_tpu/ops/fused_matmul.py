@@ -1,11 +1,11 @@
 """Fused computation-collective matmuls — public wrappers over ring_kernels.
 
-The FSDP step used to pay its collectives as separate XLA ops that
-serialize against the matmuls producing/consuming them: the forward
-unshard (`lax.all_gather` then `jnp.dot`), the backward epilogue
-(`jnp.dot` then `lax.psum_scatter`), and ring attention's per-hop
-`lax.ppermute` KV rotation.  This module exposes the fused alternatives
-(arXiv 2305.06942 on the ops/ring_kernels.py DMA machinery):
+A sharded step pays its collectives as separate XLA ops that serialize
+against the matmuls producing/consuming them: the forward unshard
+(`lax.all_gather` then `jnp.dot`), the backward epilogue (`jnp.dot` then
+`lax.psum_scatter`), and ring attention's per-hop `lax.ppermute` KV
+rotation.  This module exposes the fused alternatives (arXiv 2305.06942
+on the ops/ring_kernels.py DMA machinery):
 
   all_gather_matmul
       y = x @ concat_rows(all_gather(w_shard)) with the weight shards
@@ -18,11 +18,6 @@ unshard (`lax.all_gather` then `jnp.dot`), the backward epilogue
       computed directly into the outbound ring slot.  Layout-matched to
       `jnp.dot(..., f32)` + `lax.psum_scatter(..., scatter_dimension=0,
       tiled=True)`.
-  dma_all_gather / dma_reduce_scatter
-      the tiled gather/scatter pair as differentiable (custom-VJP)
-      Pallas ring collectives — each one's transpose is the other, so
-      an FSDP step whose unshard rides the DMA all-gather gets its
-      gradient reduce-scatter on the DMA plane for free (fsdp.py).
   ring_shift
       single-hop ring rotation (`ppermute (i -> i+shift)`) as one
       remote DMA — what ring attention's blockwise KV rotation rides
@@ -105,7 +100,7 @@ def all_gather_matmul(
     can't run here — semantics preserved, only the schedule changes.
 
     block_m/block_n: MXU tile split of each per-hop dot (0 = whole
-    block); owned by the compute tuner against the shared VMEM budget.
+    block), against the shared VMEM budget.
     """
     n = C._axis_size(axis_name)
     mode = PC.pallas_mode(interpret)
@@ -216,70 +211,6 @@ def matmul_reduce_scatter(
     return out[:mc, :nn].astype(x.dtype)
 
 
-# --- differentiable DMA gather/scatter (the FSDP unshard path) -------------------------
-
-
-def _ag_tiled(x, axis_name, interpret):
-    """Tiled DMA all-gather: (d0, ...) per rank -> (n*d0, ...), the
-    `lax.all_gather(tiled=True)` layout; lax fallback lives inside
-    ring_all_gather."""
-    n = C._axis_size(axis_name)
-    out = PC.ring_all_gather(x, axis_name, interpret)
-    return out.reshape((n * x.shape[0],) + tuple(x.shape[1:]))
-
-
-def _rs_tiled(x, axis_name, interpret):
-    """Tiled DMA reduce-scatter: (n*d0, ...) -> this rank's summed
-    (d0, ...) rows, the `lax.psum_scatter(tiled=True)` ownership."""
-    n = C._axis_size(axis_name)
-    d0 = x.shape[0] // n
-    stacked = x.reshape((n, d0) + tuple(x.shape[1:]))
-    return PC.ring_reduce_scatter(stacked, axis_name, interpret)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
-def dma_all_gather(x: jax.Array, axis_name: str,
-                   interpret: Optional[bool] = None) -> jax.Array:
-    """`lax.all_gather(x, axis, tiled=True)` on the Pallas DMA ring,
-    differentiable: the VJP is `dma_reduce_scatter` (the transpose of a
-    tiled gather is the tiled summed scatter), so FSDP's forward
-    unshard AND its backward gradient reduce-scatter both ride the DMA
-    data plane from one call site (fsdp.py).  x must have ndim >= 1;
-    falls back to the lax lowering whenever the kernels can't run."""
-    return _ag_tiled(x, axis_name, interpret)
-
-
-def _dma_ag_fwd(x, axis_name, interpret):
-    return _ag_tiled(x, axis_name, interpret), None
-
-
-def _dma_ag_bwd(axis_name, interpret, _res, g):
-    return (_rs_tiled(g, axis_name, interpret),)
-
-
-dma_all_gather.defvjp(_dma_ag_fwd, _dma_ag_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
-def dma_reduce_scatter(x: jax.Array, axis_name: str,
-                       interpret: Optional[bool] = None) -> jax.Array:
-    """`lax.psum_scatter(x, axis, scatter_dimension=0, tiled=True)` on
-    the Pallas DMA ring, differentiable (VJP = `dma_all_gather`).
-    x.shape[0] must be divisible by the axis size."""
-    return _rs_tiled(x, axis_name, interpret)
-
-
-def _dma_rs_fwd(x, axis_name, interpret):
-    return _rs_tiled(x, axis_name, interpret), None
-
-
-def _dma_rs_bwd(axis_name, interpret, _res, g):
-    return (_ag_tiled(g, axis_name, interpret),)
-
-
-dma_reduce_scatter.defvjp(_dma_rs_fwd, _dma_rs_bwd)
-
-
 # --- single-hop ring rotation (ring attention's KV hop) --------------------------------
 
 
@@ -342,9 +273,9 @@ def _smoke(np_ranks: int) -> int:
     the pallas gate off every fused entry point must produce the exact
     lax result through the clean fallback; (2) under KFT_PALLAS=interpret
     the real kernel bodies must be bit-identical on integer-valued
-    payloads (all-gather-matmul, matmul-reduce-scatter, the dma
-    gather/scatter pair, and the ring-shift hop); (3) gradients flow
-    through the custom-VJP wrappers and match the XLA transposes."""
+    payloads (all-gather-matmul, matmul-reduce-scatter and the
+    ring-shift hop); (3) gradients flow through the custom-VJP wrapper
+    and match the XLA transpose."""
     import numpy as np
 
     from jax import shard_map
@@ -392,26 +323,20 @@ def _smoke(np_ranks: int) -> int:
         assert np.array_equal(got2.reshape(want2.shape), want2), \
             "interpret matmul_reduce_scatter != unfused reference"
 
-        # dma gather/scatter + ring shift parity vs the lax lowerings
+        # ring shift parity vs the lax lowering
         v = rng.randint(-8, 8, size=(n, 48)).astype(np.float32)
-        ag = shmap(lambda vv: dma_all_gather(vv[0], "dp"), spec, spec)
-        want3 = np.tile(v.reshape(-1), (n, 1))  # every rank: the full gather
-        assert np.array_equal(
-            np.asarray(ag(v)).reshape(n, -1), want3), \
-            "dma_all_gather wrong"
         sh = shmap(lambda vv: ring_shift(vv[0], "dp"), spec, spec)
         got4 = np.asarray(sh(v)).reshape(n, -1)
         assert np.array_equal(got4, np.roll(v, 1, axis=0)), "ring_shift wrong"
         print(f"RESULT: fused-matmul smoke interpret kernels ok (np={n})")
 
-        # gradients flow through the custom VJPs
+        # gradients flow through the custom VJP (the cotangent rotates back)
         def loss(vv):
-            return jnp.sum(dma_all_gather(vv[0], "dp") ** 2)
+            return jnp.sum(ring_shift(vv[0], "dp") ** 2)
 
         g = shmap(jax.grad(loss), spec, spec)(jnp.asarray(v))
-        want_g = 2.0 * n * v
-        assert np.allclose(np.asarray(g).reshape(n, -1), want_g), \
-            "dma_all_gather VJP wrong"
+        assert np.allclose(np.asarray(g).reshape(n, -1), 2.0 * v), \
+            "ring_shift VJP wrong"
         print("RESULT: fused-matmul smoke custom-VJP gradients ok")
     finally:
         os.environ.pop("KFT_PALLAS", None)

@@ -324,8 +324,7 @@ def scratch_bytes(n: int, chunk_elems: int,
 def _mxu_dot(a, b, block_m: int = 0, block_n: int = 0):
     """fp32-accumulated a @ b, optionally split into (block_m, block_n)
     MXU tiles (static Python loops — straight-line Mosaic).  0 = whole
-    operand in one pass.  The tile shapes are the tuner-owned knob
-    (tuner/space.py fused_block_m/n) sharing the same VMEM budget as the
+    operand in one pass.  The tiles share the VMEM budget with the
     flash tiles and ring comm slots."""
     m, _ = a.shape
     nn = b.shape[1]

@@ -92,18 +92,26 @@ def _mean_reducer(axis_name: AxisName, impl: str):
     raise ValueError(f"unknown reduce impl {impl!r}")
 
 
+def default_bucket_bytes(total_grad_bytes: int) -> Optional[int]:
+    """The `bucket_bytes="auto"` resolution: small gradient trees keep
+    XLA's single fused collective (bucketing them only adds launch
+    overhead); past ~2 buckets' worth the 4 MiB bucket layout wins by
+    overlapping with backprop (docs/pallas.md)."""
+    bucket = 4 << 20
+    if total_grad_bytes <= 2 * bucket:
+        return None
+    return bucket
+
+
 def _resolve_bucket_bytes(bucket_bytes, leaves) -> int:
     """The bucket size a sync layout actually runs with (0 = unbucketed).
 
-    "auto" asks the compute tuner's footprint table
-    (tuner.footprint.default_bucket_bytes): small gradient trees keep
+    "auto" is `default_bucket_bytes`: small gradient trees keep
     XLA's single fused collective, larger ones get the 4 MiB overlap
     layout.  Resolved at trace time from the real leaves, so the same
     transform does the right thing for every model it's reused on.
     """
     if bucket_bytes == "auto":
-        from ..tuner.footprint import default_bucket_bytes
-
         total = sum(int(g.size) * jnp.dtype(g.dtype).itemsize
                     for g in leaves)
         return default_bucket_bytes(total) or 0

@@ -75,10 +75,18 @@ def pipeline_spmd(
         stage_fn = jax.checkpoint(stage_fn)
 
     # zeros_like inherits xs's vma (it may vary over dp when a data axis
-    # rides along); pcast adds the pp axis the carries rotate over
-    h0 = lax.pcast(jnp.zeros_like(xs[0]), axis_name, to="varying")
-    out0 = lax.pcast(jnp.zeros_like(xs), axis_name, to="varying")
-    store0 = lax.pcast(jnp.zeros_like(xs), axis_name, to="varying")
+    # rides along); pcast adds the pp axis the carries rotate over.  Under
+    # shard_map(check_vma=False) nothing is typed as varying (not even
+    # axis_index) and pcast's transpose, a psum of the cotangent, refuses
+    # the untyped value: there the cast is left out
+    typed = axis_name in jax.typeof(stage).vma
+
+    def vary(x):
+        return lax.pcast(x, axis_name, to="varying") if typed else x
+
+    h0 = vary(jnp.zeros_like(xs[0]))
+    out0 = vary(jnp.zeros_like(xs))
+    store0 = vary(jnp.zeros_like(xs))
 
     def tick(carry, t):
         h, store, out = carry
@@ -92,7 +100,7 @@ def pipeline_spmd(
         # device 0 input: fresh microbatch t while t < M, else the parked
         # activation whose next round starts now (slot t % M)
         fresh = lax.dynamic_index_in_dim(xs, jnp.minimum(t, M - 1), 0, keepdims=False)
-        fresh = lax.pcast(fresh, axis_name, to="varying")
+        fresh = vary(fresh)
         if R > 1:
             recirc = lax.dynamic_index_in_dim(store, t % M, 0, keepdims=False)
             feed = jnp.where(t < M, fresh, recirc)

@@ -49,8 +49,7 @@ def rules_for_mesh(mesh: Mesh, rules=DEFAULT_RULES) -> Tuple[Tuple[str, Optional
     parallelism inside MeshTrainer: parameter *embed* dims shard over
     fsdp (XLA inserts the per-layer all-gathers — ZeRO-3 semantics by
     sharding propagation) and the batch shards over BOTH dp and fsdp
-    (fsdp groups are data-parallel).  This is the rules-table composition
-    path; chunk-flattened FSDPTrainer remains the alternative layout.
+    (fsdp groups are data-parallel).
     """
     names = set(mesh.axis_names)
     fsdp_defaults = rules is DEFAULT_RULES and "fsdp" in names

@@ -236,7 +236,7 @@ class Session:
                 return C.ring_all_reduce(y, axes[0], op)
             if impl in PALLAS_IMPLS:
                 # PALLAS_FUSED_MATMUL's allreduce is the pallas ring pair
-                # (its matmul fusion lives in fsdp.py / ops.fused_matmul)
+                # (its matmul fusion lives in ops.fused_matmul)
                 from .ops import pallas_collectives as PC
 
                 return PC.ring_all_reduce(y, axes[0], op)

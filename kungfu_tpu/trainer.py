@@ -55,9 +55,8 @@ class MeshTrainer:
       tx: optax transform (plain optimizers; see module docstring).
       mesh: the device mesh (dp/sp/tp/ep/fsdp axes).  An `fsdp` axis
         activates GSPMD fully-sharded parameters via the default rules
-        (embed dims shard over fsdp, batch over dp AND fsdp) — the
-        rules-table composition path; chunk-flattened FSDPTrainer remains
-        the alternative layout.
+        (embed dims shard over fsdp, batch over dp AND fsdp), and
+        composes with tp/sp/ep axes through the same rules table.
       rules: logical->mesh axis rules; default derives from the mesh.
       batch_axes: mesh axes the batch dim shards over (default: the axes
         the rules map "batch" to — dp, plus fsdp when present).

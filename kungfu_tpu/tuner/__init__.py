@@ -5,20 +5,20 @@ Per (model shape × backend × batch), searches step-graph configurations
 chunk, donation and gradient-sync buckets — prunes with a VMEM/HBM
 footprint model, runs a measured runoff (the hand-tuned default always a
 control), and persists winners in a JSON prior cache keyed
-(shape digest | backend | jax version).  `resolve_flash_blocks` is the
-read path `TransformerConfig(flash_block_q=None)` consults.  See
+(shape digest | backend | jax version).  The shape defaults a model runs
+with untuned live beside their kernels (`default_flash_blocks` in
+ops/flash.py, `default_ce_block` in ops/chunked_ce.py,
+`default_bucket_bytes` in optimizers/sync.py) and are re-exported here;
+a winner reaches a model through `ComputeTuner.apply`.  See
 docs/tuning.md.
 """
+from ..ops.chunked_ce import default_ce_block
+from ..ops.flash import default_flash_blocks
+from ..optimizers.sync import default_bucket_bytes
 from .cache import PriorCache, backend_name, default_cache_path, jax_version
-from .core import (
-    ComputeTuner,
-    default_flash_blocks,
-    resolve_flash_blocks,
-)
+from .core import ComputeTuner, resolve_flash_blocks
 from .footprint import (
     check_fit,
-    default_bucket_bytes,
-    default_ce_block,
     flash_vmem_bytes,
     predict_step_ms,
     step_hbm_bytes,

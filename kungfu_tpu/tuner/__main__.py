@@ -140,8 +140,7 @@ def _smoke(args) -> int:
     import jax
     import jax.numpy as jnp
 
-    bq, bk = resolve_flash_blocks(base, batch=shape.batch_per_chip,
-                                  seq_len=shape.seq_len)
+    bq, bk = resolve_flash_blocks(base, seq_len=shape.seq_len)
     explicit = dataclasses.replace(base, flash_block_q=bq, flash_block_k=bk)
     toks = jnp.asarray(np.random.RandomState(0).randint(
         0, shape.vocab_size, size=(shape.batch_per_chip, shape.seq_len)),

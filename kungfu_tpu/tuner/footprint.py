@@ -27,6 +27,7 @@ import os
 from typing import Dict, Optional, Tuple
 
 from ..compat import VMEM_ENV, vmem_budget_bytes
+from ..ops import flash
 from .space import ShapeKey, StepConfig
 
 #: HBM budget (GiB) for the footprint gate
@@ -73,21 +74,10 @@ def _dtype_bytes(dtype: str) -> int:
 
 
 def flash_vmem_bytes(cfg: StepConfig, shape: ShapeKey) -> int:
-    """Resident VMEM of one flash fwd grid step under this tiling.
-
-    The kernel streams K/V block-by-block *from VMEM* — the BlockSpec
-    brings the full padded [L, D] K and V rows in (ops/flash.py), so the
-    sequence term dominates at long L; the per-tile term is the score /
-    probability block plus fp32 accumulators.
-    """
-    d = cfg.head_dim
-    db = _dtype_bytes(shape.dtype)
-    l_pad = math.ceil(shape.seq_len / cfg.block_k) * cfg.block_k
-    resident = 2 * l_pad * d * db          # full K and V rows
-    resident += 2 * cfg.block_q * d * db   # q tile + output tile
-    resident += cfg.block_q * cfg.block_k * 4 * 2  # scores + probabilities f32
-    resident += cfg.block_q * (d + 2) * 4  # fp32 accumulator + m/l stats
-    return resident
+    """Resident VMEM of one flash fwd grid step under this tiling
+    (ops/flash.py keeps the model beside the kernels' BlockSpecs)."""
+    return flash.flash_vmem_bytes(cfg.block_q, cfg.block_k, cfg.head_dim,
+                                  shape.seq_len, _dtype_bytes(shape.dtype))
 
 
 def step_hbm_bytes(cfg: StepConfig, shape: ShapeKey) -> Dict[str, int]:
@@ -124,27 +114,6 @@ def step_hbm_bytes(cfg: StepConfig, shape: ShapeKey) -> Dict[str, int]:
             "bucket": bucket, "total": total}
 
 
-def fused_matmul_vmem_bytes(cfg: StepConfig, shape: ShapeKey,
-                            world: int = 4) -> int:
-    """Resident VMEM of one fused all-gather-matmul call under this
-    config: the rotating weight-shard comm slots (together one full
-    weight matrix — the widest per-layer matmul, d_model × max(d_ff,
-    4·d_model)) plus the per-hop MXU operand/accumulator tiles.  Shares
-    KFT_PALLAS_VMEM_MIB with the flash tiles and ring comm slots — a
-    tiling that blows the budget is rejected before it can wedge a chip
-    (the fused_matmul wrapper applies the same per-call gate at trace
-    time; this gate keeps such configs out of the runoff entirely)."""
-    if not cfg.fused_matmul:
-        return 0
-    db = _dtype_bytes(shape.dtype)
-    widest = max(shape.d_ff, 4 * shape.d_model)
-    comm = shape.d_model * widest * db  # n slots × (d_model/n × widest)
-    bm = cfg.fused_block_m or 128
-    bn = cfg.fused_block_n or 128
-    tiles = bm * bn * 4 + bm * shape.d_model * db + shape.d_model * bn * db
-    return comm + tiles
-
-
 def check_fit(cfg: StepConfig, shape: ShapeKey) -> Optional[str]:
     """None when the config fits both budgets, else the rejection reason
     (the footprint gate's single entry point — rejected configs journal
@@ -153,12 +122,6 @@ def check_fit(cfg: StepConfig, shape: ShapeKey) -> Optional[str]:
     if vmem > vmem_budget_bytes():
         return (f"flash tile {cfg.block_q}x{cfg.block_k} needs "
                 f"{vmem >> 20} MiB VMEM > {VMEM_ENV}="
-                f"{vmem_budget_bytes() >> 20} MiB")
-    fused_vmem = fused_matmul_vmem_bytes(cfg, shape)
-    if fused_vmem > vmem_budget_bytes():
-        return (f"fused matmul tiles {cfg.fused_block_m}x"
-                f"{cfg.fused_block_n} + weight comm slots need "
-                f"{fused_vmem >> 20} MiB VMEM > {VMEM_ENV}="
                 f"{vmem_budget_bytes() >> 20} MiB")
     hbm = step_hbm_bytes(cfg, shape)
     if hbm["total"] > hbm_budget_bytes():
@@ -218,31 +181,3 @@ def _device_peaks() -> Tuple[float, float]:
         kind = ""
     flops, hbm = peak_specs(kind)
     return (flops or 1e12, hbm or 50e9)
-
-
-def default_bucket_bytes(total_grad_bytes: int) -> Optional[int]:
-    """The `bucket_bytes="auto"` resolution (optimizers/sync.py, fsdp.py):
-    small gradient trees keep XLA's single fused collective (bucketing
-    them only adds launch overhead); past ~2 buckets' worth the 4 MiB
-    bucket layout wins by overlapping with backprop (docs/pallas.md)."""
-    bucket = 4 << 20
-    if total_grad_bytes <= 2 * bucket:
-        return None
-    return bucket
-
-
-def default_ce_block(n_tokens: Optional[int] = None,
-                     vocab: Optional[int] = None) -> int:
-    """Shape-conditional chunked-CE block default: stream ~64 MiB logit
-    blocks (f32), clamped to [512, 8192] powers of two.  With no token
-    count known, 2048 (the historical default)."""
-    if not n_tokens or n_tokens <= 0:
-        return 2048
-    target = (64 << 20) // (4 * n_tokens)
-    block = 512
-    while block * 2 <= target and block < 8192:
-        block *= 2
-    if vocab:
-        while block > vocab and block > 512:
-            block //= 2
-    return block

@@ -56,22 +56,6 @@ DEFAULT_CE_CHUNKS: Tuple[int, ...] = (0, 2048, 8192)
 #: PR-9 gradient-sync bucket sizes (0 = single fused tree)
 DEFAULT_BUCKET_BYTES: Tuple[int, ...] = (0, 4 << 20)
 
-#: MXU tile splits for the fused computation-collective matmul kernels
-#: (ops/fused_matmul.py block_m × block_n); (0, 0) = whole-block dot.
-#: Checked against the same KFT_PALLAS_VMEM_MIB budget as the flash
-#: tiles and ring comm slots (footprint.fused_matmul_vmem_bytes).
-FUSED_MATMUL_BLOCKS: Tuple[Tuple[int, int], ...] = (
-    (0, 0), (128, 128), (256, 256), (128, 512), (256, 512),
-)
-
-#: fused-matmul arms the default enumeration sweeps: off (the unfused
-#: XLA gather/scatter — always the runoff control) and on with the
-#: whole-block dot; the explicit tile splits in FUSED_MATMUL_BLOCKS are
-#: for targeted sweeps so the default space stays tractable
-DEFAULT_FUSED_ARMS: Tuple[Tuple[bool, int, int], ...] = (
-    (False, 0, 0), (True, 0, 0),
-)
-
 
 @dataclasses.dataclass(frozen=True)
 class ShapeKey:
@@ -157,24 +141,13 @@ class StepConfig:
     ce_chunk: int = 0            # 0 = dense logits
     donate: bool = True
     bucket_bytes: int = 0        # 0 = single fused gradient tree
-    # fused computation-collective kernels (ops/fused_matmul.py): route
-    # the FSDP gather/scatter through the DMA data plane, with the
-    # per-hop MXU dot split into (fused_block_m, fused_block_n) tiles
-    # (0 = whole block).  The tiles share KFT_PALLAS_VMEM_MIB with the
-    # flash tiles and ring comm slots.
-    fused_matmul: bool = False
-    fused_block_m: int = 0
-    fused_block_n: int = 0
 
     def describe(self) -> str:
         remat = self.remat_policy if self.remat else "off"
         ce = str(self.ce_chunk) if self.ce_chunk else "dense"
-        fused = (f"|fused:{self.fused_block_m or 'x'}x"
-                 f"{self.fused_block_n or 'x'}" if self.fused_matmul else "")
         return (f"flash{self.block_q}x{self.block_k}/{self.backward}"
                 f"|h{self.head_dim}|remat:{remat}|ce:{ce}"
-                f"|donate:{int(self.donate)}|bucket:{self.bucket_bytes}"
-                f"{fused}")
+                f"|donate:{int(self.donate)}|bucket:{self.bucket_bytes}")
 
     def n_heads_for(self, shape: ShapeKey) -> int:
         return shape.d_model // self.head_dim
@@ -216,7 +189,6 @@ def enumerate_configs(
     backwards: Sequence[str] = ("pallas", "xla"),
     remat_arms: Sequence[Tuple[bool, str]] = REMAT_ARMS,
     donations: Sequence[bool] = (True, False),
-    fused_arms: Sequence[Tuple[bool, int, int]] = DEFAULT_FUSED_ARMS,
 ) -> List[StepConfig]:
     """The full candidate set for one shape.
 
@@ -240,21 +212,14 @@ def enumerate_configs(
                             continue  # dense head in disguise
                         for bb in bucket_bytes:
                             for donate in donations:
-                                for fused, fbm, fbn in fused_arms:
-                                    cfg = StepConfig(
-                                        block_q=cbq, block_k=cbk,
-                                        backward=bwd,
-                                        head_dim=hd, remat=remat,
-                                        remat_policy=(policy if remat
-                                                      else "none"),
-                                        ce_chunk=int(ce),
-                                        donate=bool(donate),
-                                        bucket_bytes=int(bb),
-                                        fused_matmul=bool(fused),
-                                        fused_block_m=int(fbm) if fused else 0,
-                                        fused_block_n=int(fbn) if fused else 0,
-                                    )
-                                    if cfg not in seen:
-                                        seen.add(cfg)
-                                        out.append(cfg)
+                                cfg = StepConfig(
+                                    block_q=cbq, block_k=cbk, backward=bwd,
+                                    head_dim=hd, remat=remat,
+                                    remat_policy=policy if remat else "none",
+                                    ce_chunk=int(ce), donate=bool(donate),
+                                    bucket_bytes=int(bb),
+                                )
+                                if cfg not in seen:
+                                    seen.add(cfg)
+                                    out.append(cfg)
     return out

@@ -275,32 +275,18 @@ class TestTraceTimeHooks:
         monkeypatch.delenv("KUNGFU_ANALYZE")
         assert not Session(_mesh_dp())._analyze
 
-    def test_fsdp_analyze_clean_step(self):
-        from kungfu_tpu.fsdp import FSDPTrainer
+    @pytest.mark.parametrize("name", ["fsdp-plain",
+                                      "example-fsdp-transformer"])
+    def test_mesh_trainer_fsdp_step_passes_the_trace_time_hook(self, name):
+        """The step MeshTrainer builds over an fsdp (and dp x fsdp) mesh,
+        through the hook a trainer calls before its first dispatch:
+        `check_and_raise` raises on an error finding."""
+        from kungfu_tpu.analysis.programs import get_program
 
-        mesh = Mesh(np.array(jax.devices()[:8]), ("fsdp",))
-
-        def loss_fn(params, batch):
-            import jax.numpy as jnp
-
-            return jnp.mean((batch @ params["w"]) ** 2)
-
-        trainer = FSDPTrainer(loss_fn, optax.sgd(0.1), mesh=mesh,
-                              analyze=True)
-        state = trainer.init({"w": np.ones((16, 8), np.float32)})
-        batch = trainer.shard_batch(np.ones((16, 16), np.float32))
-        state2, metrics = trainer.train_step(state, batch)
-        assert trainer._linted
-        assert np.isfinite(float(np.asarray(metrics["loss"])))
-
-    def test_fsdp_rejects_typo_compression_key(self):
-        from kungfu_tpu.fsdp import FSDPTrainer
-
-        mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4),
-                    ("dp", "fsdp"))
-        with pytest.raises(ValueError, match="known axis"):
-            FSDPTrainer(lambda p, b: 0.0, optax.sgd(0.1), mesh=mesh,
-                        compression={"pd": "int8"})
+        fn, args, kw = get_program(name).build()
+        assert "fsdp" in kw["mesh"].axis_names
+        analysis.check_and_raise(fn, *args, mesh=kw["mesh"],
+                                 context="MeshTrainer.train_step")
 
     def test_pipeline_ring_validated(self):
         # the ring perm is built from the live axis size, so any bijection
@@ -341,10 +327,10 @@ class TestCLI:
 
 # -- env audit: the count of KFT_* names only falls (ROADMAP D5) ------------------------
 
-#: the number PR 27 left, less KFT_FLASH_BWD_AUTO_SEQ (PR 39).  Lower it when
-#: you remove a name; raising it needs the two callers at the parent commit
-#: that need different values.
-KFT_NAMES_CEILING = 75
+#: the number PR 27 left, less the flash backward's two switches (its length
+#: threshold, PR 39; its arm, PR 45).  Lower it when you remove a name; raising
+#: it needs the two callers at the parent commit that need different values.
+KFT_NAMES_CEILING = 74
 
 
 def test_env_audit_is_clean_and_prints_a_count_under_the_ceiling(capsys):

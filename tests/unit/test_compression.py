@@ -379,34 +379,3 @@ class TestCounters:
         ratios = global_counters().compression_ratios()
         assert ratios.get("c8", 0) > 3.0  # acceptance: >= 3x fewer bytes
         assert 0 < global_counters().quant_errors()["c8"] < 0.1
-
-
-class TestFSDPCompression:
-    def test_fsdp_dp_leg_compressed_trains(self):
-        import optax
-        from jax.sharding import Mesh
-        from kungfu_tpu.fsdp import FSDPTrainer
-
-        devs = np.array(jax.devices()[:8]).reshape(2, 4)
-        mesh = Mesh(devs, ("dp", "fsdp"))
-
-        def loss_fn(params, batch):
-            x, y = batch
-            pred = x @ params["w"] + params["b"]
-            return jnp.mean((pred - y) ** 2)
-
-        trainer = FSDPTrainer(
-            loss_fn, optax.sgd(0.05), mesh=mesh, compression="int8"
-        )
-        rng = np.random.RandomState(12)
-        params = {"w": rng.randn(16, 4).astype(np.float32) * 0.1,
-                  "b": np.zeros(4, np.float32)}
-        state = trainer.init(params)
-        x = rng.randn(64, 16).astype(np.float32)
-        w_true = rng.randn(16, 4).astype(np.float32)
-        batch = trainer.shard_batch((x, x @ w_true))
-        losses = []
-        for _ in range(30):
-            state, m = trainer.train_step(state, batch)
-            losses.append(float(np.asarray(m["loss"])))
-        assert losses[-1] < losses[0] * 0.5  # learning through the int8 wire

@@ -136,18 +136,19 @@ def test_gradients_unpadded_length(causal):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
 
 
-def test_bwd_xla_pallas_agree(monkeypatch):
-    """KFT_FLASH_BWD=xla (the bench A/B switch) must give the same grads as
-    the Pallas backward."""
+def test_bwd_xla_pallas_agree():
+    """backward="xla" (the A/B arm) must give the same grads as the Pallas
+    backward."""
     q, k, v = _rand(1, 96, 2, 16, seed=13)
 
-    def loss(q, k, v):
+    def loss(q, k, v, backward=None):
         return jnp.sum(flash_attention(q, k, v, causal=True,
-                                       block_q=32, block_k=32, interpret=True) ** 2)
+                                       block_q=32, block_k=32, interpret=True,
+                                       backward=backward) ** 2)
 
     g_pallas = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    monkeypatch.setenv("KFT_FLASH_BWD", "xla")
-    g_xla = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    g_xla = jax.grad(lambda q, k, v: loss(q, k, v, "xla"),
+                     argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g_pallas, g_xla):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
@@ -155,7 +156,7 @@ def test_bwd_xla_pallas_agree(monkeypatch):
 @pytest.mark.parametrize("bwd", ["pallas", "xla"])
 def test_bwd_explicit_argument(bwd):
     """backward= forces the chosen implementation and matches the reference
-    gradients (the argument-based form of the KFT_FLASH_BWD A/B)."""
+    gradients."""
     q, k, v = _rand(1, 96, 2, 16, seed=13)
 
     def loss(q, k, v):
@@ -180,23 +181,8 @@ def test_bwd_bad_argument_raises():
         flash_attention(q, k, v, causal=True, interpret=True, backward="nope")
 
 
-# the backward arm's rule (mode, explicit argument, KFT_FLASH_BWD) is
-# tested in tier-1: tests/unit/test_flash_cached.py::test_bwd_auto_selection
-
-
-def test_bwd_env_garbage_falls_through(monkeypatch):
-    """Unrecognized KFT_FLASH_BWD values (stale exports like '0'/'true')
-    must fall through to auto selection, not crash the trace."""
-    monkeypatch.setenv("KFT_FLASH_BWD", "0")
-    q, k, v = _rand(1, 64, 1, 16, seed=7)
-
-    def loss(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=True,
-                                       block_q=32, block_k=32,
-                                       interpret=True) ** 2)
-
-    g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    assert all(np.isfinite(np.asarray(x)).all() for x in g)
+# the backward arm's rule (mode, explicit argument) is tested in tier-1:
+# tests/unit/test_flash_cached.py::test_bwd_auto_selection
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -287,21 +273,22 @@ def test_gqa_kernel_unpadded_length_and_lse():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=1e-5)
 
 
-def test_gqa_xla_bwd_matches(monkeypatch):
-    """The KFT_FLASH_BWD=xla path must reduce GQA dk/dv over the group too."""
+def test_gqa_xla_bwd_matches():
+    """The backward="xla" path must reduce GQA dk/dv over the group too."""
     b, l, h, hkv, d = 1, 64, 4, 2, 16
     ks = jax.random.split(jax.random.PRNGKey(29), 3)
     q = jax.random.normal(ks[0], (b, l, h, d))
     k = jax.random.normal(ks[1], (b, l, hkv, d))
     v = jax.random.normal(ks[2], (b, l, hkv, d))
 
-    def loss(q, k, v):
+    def loss(q, k, v, backward=None):
         return jnp.sum(flash_attention(q, k, v, causal=True,
-                                       block_q=32, block_k=32, interpret=True) ** 2)
+                                       block_q=32, block_k=32, interpret=True,
+                                       backward=backward) ** 2)
 
     g_pallas = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    monkeypatch.setenv("KFT_FLASH_BWD", "xla")
-    g_xla = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    g_xla = jax.grad(lambda q, k, v: loss(q, k, v, "xla"),
+                     argnums=(0, 1, 2))(q, k, v)
     for a, b_ in zip(g_pallas, g_xla):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=1e-5)
 

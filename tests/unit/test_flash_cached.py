@@ -153,22 +153,23 @@ def test_one_pass_backward_matches_the_pair(monkeypatch):
 
 
 @pytest.mark.parametrize(
-    "mode,l,backward,env,expect",
+    "mode,l,backward,expect",
     [
-        ("off", 96, None, None, "xla"),          # plain CPU: lowers anywhere
-        ("interpret", 96, None, None, "pallas"),  # short MHA: no threshold
-        ("compiled", 2048, None, None, "pallas"),  # the training cells' shape
-        ("compiled", 2048, "xla", None, "xla"),  # explicit argument wins
-        ("off", 96, "pallas", "xla", "pallas"),  # ... over mode and env both
-        ("compiled", 96, None, "xla", "xla"),    # the A/B switch
-        ("compiled", 96, None, "0", "pallas"),   # garbage falls through
-        ("off", 96, None, "pallas", "xla"),      # no interpreter by accident
+        ("off", 96, None, "xla"),            # plain CPU: lowers anywhere
+        ("interpret", 96, None, "pallas"),   # short MHA: no threshold
+        ("compiled", 2048, None, "pallas"),  # the training cells' shape
+        ("compiled", 2048, "xla", "xla"),    # explicit argument wins
+        ("compiled", 96, "xla", "xla"),      # the A/B arm, at any length
+        ("interpret", 96, "xla", "xla"),
+        ("off", 96, "pallas", "pallas"),     # ... over the mode too
+        ("interpret", 96, "pallas", "pallas"),
+        ("off", 96, "xla", "xla"),
     ],
 )
-def test_bwd_auto_selection(monkeypatch, mode, l, backward, env, expect):
-    """The arm is chosen by `pallas_mode` (what the code can see), the
-    explicit argument, and KFT_FLASH_BWD=xla — by no length.  Both arms
-    and the forward are stubbed: this is the rule, not the kernels."""
+def test_bwd_auto_selection(monkeypatch, mode, l, backward, expect):
+    """The arm is chosen by `pallas_mode` (what the code can see) and the
+    explicit argument — by no length and no environment variable.  Both
+    arms and the forward are stubbed: this is the rule, not the kernels."""
     calls = []
 
     def recorder(name):
@@ -185,10 +186,6 @@ def test_bwd_auto_selection(monkeypatch, mode, l, backward, env, expect):
     monkeypatch.setattr(F, "_bwd_pallas", recorder("pallas"))
     monkeypatch.setattr(F, "_bwd_blocked", recorder("xla"))
     monkeypatch.setattr(F, "_flash_fwd", fake_fwd)
-    if env is None:
-        monkeypatch.delenv("KFT_FLASH_BWD", raising=False)
-    else:
-        monkeypatch.setenv("KFT_FLASH_BWD", env)
 
     q, k, v = _rand(1, l, 2, 16, seed=5)
     _grads(lambda q, k, v: jnp.sum(

@@ -16,11 +16,10 @@ against the exact unfused lax programs the off-TPU fallback uses:
                   entry point must produce the lax lowering's result
                   exactly — routing a step through the fused ops is
                   always safe.
-  differentiation the custom-VJP pair (dma_all_gather/dma_reduce_scatter
-                  are each other's transpose; ring_shift rotates its
-                  cotangent backwards) must match the lax transposes, so
-                  FSDP training and ring attention stay correct when
-                  their collectives move to the DMA plane.
+  differentiation ring_shift's custom VJP (the cotangent rotates
+                  backwards) must match the lax transpose, so ring
+                  attention stays correct when its KV hop moves to the
+                  DMA plane.
 """
 import numpy as np
 import pytest
@@ -213,99 +212,7 @@ class TestMatmulReduceScatter:
         assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
-# -- differentiable DMA gather/scatter + ring shift -----------------------------------
-
-
-class TestDmaCollectives:
-    @pytest.mark.parametrize("n", [2, 4])
-    def test_all_gather_parity_and_grad(self, n, interpret_gate):
-        mesh = _mesh(n)
-        v = jnp.asarray(_ints((n, 48), seed=6))
-
-        got = _shmap(lambda x: FM.dma_all_gather(x[0], "dp"), mesh, P("dp"))(v)
-        want = _shmap(lambda x: lax.all_gather(x[0], "dp", tiled=True),
-                      mesh, P("dp"))(v)
-        assert np.array_equal(np.asarray(got), np.asarray(want))
-
-        c = jnp.asarray(_ints((n, n * 48), seed=7))
-
-        def g(fn):
-            return np.asarray(_shmap(
-                lambda x, cc: jax.grad(
-                    lambda xx: jnp.sum(fn(xx[0]) * cc[0]))(x),
-                mesh, (P("dp"), P("dp")))(v, c))
-
-        g_dma = g(lambda x: FM.dma_all_gather(x, "dp"))
-        g_lax = g(lambda x: lax.all_gather(x, "dp", tiled=True))
-        assert np.array_equal(g_dma, g_lax)
-
-    @pytest.mark.parametrize("n", [2, 4])
-    def test_reduce_scatter_parity_and_grad(self, n, interpret_gate):
-        mesh = _mesh(n)
-        v = jnp.asarray(_ints((n, n * 24), seed=8))
-
-        got = _shmap(lambda x: FM.dma_reduce_scatter(x[0], "dp"),
-                     mesh, P("dp"))(v)
-        want = _shmap(
-            lambda x: lax.psum_scatter(x[0], "dp", scatter_dimension=0,
-                                       tiled=True), mesh, P("dp"))(v)
-        assert np.array_equal(np.asarray(got), np.asarray(want))
-
-        c = jnp.asarray(_ints((n, 24), seed=9))
-
-        def g(fn):
-            return np.asarray(_shmap(
-                lambda x, cc: jax.grad(
-                    lambda xx: jnp.sum(fn(xx[0]) * cc[0]))(x),
-                mesh, (P("dp"), P("dp")))(v, c))
-
-        g_dma = g(lambda x: FM.dma_reduce_scatter(x, "dp"))
-        g_lax = g(lambda x: lax.psum_scatter(x, "dp", scatter_dimension=0,
-                                             tiled=True))
-        assert np.array_equal(g_dma, g_lax)
-
-    def test_fallback_bitwise_gate_off(self, monkeypatch):
-        """With the gate off the wrappers ARE the lax lowerings."""
-        monkeypatch.delenv("KFT_PALLAS", raising=False)
-        n = 2
-        mesh = _mesh(n)
-        v = jnp.asarray(_ints((n, 40), seed=10))
-        got = _shmap(lambda x: FM.dma_all_gather(x[0], "dp"), mesh, P("dp"))(v)
-        want = _shmap(lambda x: lax.all_gather(x[0], "dp", tiled=True),
-                      mesh, P("dp"))(v)
-        assert np.array_equal(np.asarray(got), np.asarray(want))
-
-    def test_multi_axis_mesh_falls_back(self, interpret_gate):
-        """A ring on one axis of a MULTI-axis manual region must take
-        the lax path: a scalar LOGICAL device_id is only well-defined
-        for a sole named axis (the Pallas DMA discharge raises
-        NotImplementedError otherwise — found driving the dp×sp×tp
-        dryrun).  Correctness, not an error, is the contract."""
-        if len(jax.devices()) < 4:
-            pytest.skip("needs a 2x2 mesh")
-        mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
-                    ("dp", "fsdp"))
-        v = jnp.asarray(_ints((2, 2, 24), seed=16))
-        got = jax.jit(shard_map(
-            lambda x: FM.dma_all_gather(x[0, 0], "fsdp")[None, None],
-            mesh=mesh, in_specs=P("dp", "fsdp"),
-            out_specs=P("dp", "fsdp"), check_vma=False))(v)
-        want = jax.jit(shard_map(
-            lambda x: lax.all_gather(x[0, 0], "fsdp", tiled=True)[None, None],
-            mesh=mesh, in_specs=P("dp", "fsdp"),
-            out_specs=P("dp", "fsdp"), check_vma=False))(v)
-        assert np.array_equal(np.asarray(got), np.asarray(want))
-        # ring_shift on the sp-like axis of a 2-axis mesh likewise
-        got2 = jax.jit(shard_map(
-            lambda x: FM.ring_shift(x[0, 0], "fsdp")[None, None],
-            mesh=mesh, in_specs=P("dp", "fsdp"),
-            out_specs=P("dp", "fsdp"), check_vma=False))(v)
-        perm = [(0, 1), (1, 0)]
-        want2 = jax.jit(shard_map(
-            lambda x: lax.ppermute(x[0, 0], "fsdp", perm)[None, None],
-            mesh=mesh, in_specs=P("dp", "fsdp"),
-            out_specs=P("dp", "fsdp"), check_vma=False))(v)
-        assert np.array_equal(np.asarray(got2), np.asarray(want2))
+# -- ring shift ------------------------------------------------------------------------
 
 
 class TestRingShift:
@@ -337,50 +244,27 @@ class TestRingShift:
         assert np.array_equal(g_dma, g_lax)
 
 
-# -- FSDP integration -----------------------------------------------------------------
-
-
-class TestFSDPIntegration:
-    def _train(self, dma, steps=3):
-        import optax
-
-        from kungfu_tpu.fsdp import FSDPTrainer
-
-        def loss_fn(params, batch):
-            return jnp.mean((batch @ params["w"] + params["b"] - 1.0) ** 2)
-
-        params = {
-            "w": _ints((16, 4), seed=0),
-            "b": np.zeros(4, np.float32),
-        }
-        batch = _ints((8, 16), seed=1)
-        tr = FSDPTrainer(loss_fn, optax.sgd(0.01), dma_collectives=dma)
-        st = tr.init(params)
-        sb = tr.shard_batch(batch)
-        for _ in range(steps):
-            st, m = tr.train_step(st, sb)
-        return tr.eval_params(st), float(np.asarray(m["loss"]))
-
-    def test_dma_unshard_matches_legacy(self, interpret_gate):
-        """The step whose unshard + gradient scatter ride the DMA
-        kernels must train identically (to float rounding — the
-        custom-VJP boundary changes XLA's fusion, not the math)."""
-        p_off, l_off = self._train(False)
-        p_dma, l_dma = self._train(True)  # selected: kernels engage
-        assert np.isfinite(l_dma)
-        np.testing.assert_allclose(l_off, l_dma, rtol=1e-5)
-        for k in p_off:
-            np.testing.assert_allclose(p_off[k], p_dma[k], rtol=1e-5,
-                                       atol=1e-6)
-
-    def test_gate_off_is_legacy_program(self, monkeypatch):
-        monkeypatch.delenv("KFT_PALLAS", raising=False)
-        p_off, l_off = self._train(False, steps=2)
-        p_auto, l_auto = self._train(None, steps=2)
-        np.testing.assert_allclose(l_off, l_auto, rtol=1e-6)
-        for k in p_off:
-            np.testing.assert_allclose(p_off[k], p_auto[k], rtol=1e-6,
-                                       atol=1e-7)
+    def test_multi_axis_mesh_falls_back(self, interpret_gate):
+        """A ring on one axis of a MULTI-axis manual region must take
+        the lax path: a scalar LOGICAL device_id is only well-defined
+        for a sole named axis (the Pallas DMA discharge raises
+        NotImplementedError otherwise — found driving the dp×sp×tp
+        dryrun).  Correctness, not an error, is the contract."""
+        if len(jax.devices()) < 4:
+            pytest.skip("needs a 2x2 mesh")
+        mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                    ("dp", "fsdp"))
+        v = jnp.asarray(_ints((2, 2, 24), seed=16))
+        got = jax.jit(shard_map(
+            lambda x: FM.ring_shift(x[0, 0], "fsdp")[None, None],
+            mesh=mesh, in_specs=P("dp", "fsdp"),
+            out_specs=P("dp", "fsdp"), check_vma=False))(v)
+        perm = [(0, 1), (1, 0)]
+        want = jax.jit(shard_map(
+            lambda x: lax.ppermute(x[0, 0], "fsdp", perm)[None, None],
+            mesh=mesh, in_specs=P("dp", "fsdp"),
+            out_specs=P("dp", "fsdp"), check_vma=False))(v)
+        assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
 # -- planner + strategy registration --------------------------------------------------
@@ -472,93 +356,3 @@ class TestPlannerFused:
         monkeypatch.setenv("KFT_PALLAS", "interpret")
         assert Session._impl_tag(
             Impl.PALLAS_FUSED_MATMUL) == "pallas_fused_matmul"
-
-
-# -- tuner ownership of the fused tiles -----------------------------------------------
-
-
-class TestTunerFused:
-    def test_config_json_roundtrip(self):
-        from kungfu_tpu.tuner.space import StepConfig
-
-        cfg = StepConfig(fused_matmul=True, fused_block_m=256,
-                         fused_block_n=512)
-        assert StepConfig.from_json(cfg.to_json()) == cfg
-        assert "fused:256x512" in cfg.describe()
-        # old cache entries (no fused keys) load with the knob off
-        d = cfg.to_json()
-        for k in ("fused_matmul", "fused_block_m", "fused_block_n"):
-            d.pop(k)
-        assert StepConfig.from_json(d).fused_matmul is False
-
-    def test_default_is_unfused_control(self):
-        from kungfu_tpu.tuner.space import ShapeKey, default_config
-
-        shape = ShapeKey(vocab_size=64, d_model=16, n_layers=1, n_heads=2,
-                         n_kv_heads=0, d_ff=32, seq_len=16, batch_per_chip=2,
-                         dtype="float32")
-        assert default_config(shape).fused_matmul is False
-
-    def test_enumeration_carries_fused_arms(self):
-        from kungfu_tpu.tuner.space import ShapeKey, enumerate_configs
-
-        shape = ShapeKey(vocab_size=64, d_model=16, n_layers=1, n_heads=2,
-                         n_kv_heads=0, d_ff=32, seq_len=16, batch_per_chip=2,
-                         dtype="float32")
-        cands = enumerate_configs(shape)
-        assert any(c.fused_matmul for c in cands)
-        assert any(not c.fused_matmul for c in cands)
-
-    def test_footprint_gate_rejects_oversized_fused_tiles(self, monkeypatch):
-        from kungfu_tpu.tuner.footprint import check_fit
-        from kungfu_tpu.tuner.space import ShapeKey, StepConfig
-
-        shape = ShapeKey(vocab_size=32000, d_model=4096, n_layers=2,
-                         n_heads=32, n_kv_heads=0, d_ff=16384, seq_len=128,
-                         batch_per_chip=1, dtype="bfloat16")
-        monkeypatch.setenv("KFT_PALLAS_VMEM_MIB", "16")
-        cfg = StepConfig(block_q=64, block_k=64, head_dim=128,
-                         fused_matmul=True, fused_block_m=512,
-                         fused_block_n=512)
-        reason = check_fit(cfg, shape)
-        assert reason is not None and "fused matmul" in reason
-        # the unfused spelling of the same config fits (or fails on a
-        # different budget), so the gate is attributable
-        cfg_off = StepConfig(block_q=64, block_k=64, head_dim=128)
-        r2 = check_fit(cfg_off, shape)
-        assert r2 is None or "fused matmul" not in r2
-
-    def test_shipped_prior_ships_fused_off(self):
-        """The remote-DMA kernels have not compiled on a chip: the prior a
-        fresh checkout installs must not route the flagship through them."""
-        from kungfu_tpu.tuner import cache as T
-
-        flagship = T.ShapeKey(vocab_size=32000, d_model=1024, n_layers=24,
-                              n_heads=16, n_kv_heads=0, d_ff=4096,
-                              seq_len=2048, batch_per_chip=4,
-                              dtype="bfloat16", causal=True)
-        c = T.PriorCache("/nonexistent/never-created.json")
-        cfg = c.get_config(flagship.digest(), "tpu", "any-version")
-        assert cfg is not None and not cfg.fused_matmul
-
-    def test_apply_reports_dma_knob(self):
-        import dataclasses
-
-        from kungfu_tpu.models.transformer import TransformerConfig
-        from kungfu_tpu.tuner.core import ComputeTuner
-        from kungfu_tpu.tuner.space import ShapeKey, StepConfig
-
-        shape = ShapeKey(vocab_size=64, d_model=16, n_layers=1, n_heads=2,
-                         n_kv_heads=0, d_ff=32, seq_len=16, batch_per_chip=2,
-                         dtype="float32")
-        tuner = ComputeTuner(shape, cache=None)
-        base = TransformerConfig(vocab_size=64, d_model=16, n_layers=1,
-                                 n_heads=2, d_ff=32, max_len=16,
-                                 dtype=np.float32)
-        cfg = StepConfig(head_dim=8, fused_matmul=True, fused_block_m=128,
-                         fused_block_n=128)
-        _, extras = tuner.apply(base, cfg)
-        assert extras["dma_collectives"] is True
-        assert extras["fused_block_m"] == 128
-        with pytest.raises(dataclasses.FrozenInstanceError):
-            cfg.fused_matmul = False  # frozen

@@ -42,7 +42,7 @@ RANK = {
     "policy": 7,
     # trainers and what measures a step
     "train": 8,
-    "checkpoint": 9, "fsdp": 9, "trainer": 9, "tuner": 9,
+    "checkpoint": 9, "trainer": 9, "tuner": 9,
     # whole-program tools and the elastic runtime
     "analysis": 10, "elastic": 10,
     "api": 11, "planner": 11, "run": 11,
@@ -60,14 +60,10 @@ KNOWN_UPWARD = {
     ("ops/ring_kernels.py", "compression"): "compression <-> ops: codec constants live above the kernels",
     ("ops/pallas_collectives.py", "session"): "the --smoke main of a kernel module builds a Session",
     ("ops/kv_ship.py", "serving"): "kv_ship is serving code filed under ops",
-    ("ops/chunked_ce.py", "tuner"): "lazy resolver: the CE block default lives in the tuner",
-    ("models/transformer.py", "tuner"): "lazy resolver: the flash-tile choice lives in the tuner",
-    ("optimizers/sync.py", "tuner"): "lazy resolver: bucket_bytes='auto' asks the tuner",
     ("monitor/__main__.py", "models"): "the compile drill builds a model inside monitor's CLI",
     ("monitor/__main__.py", "serving"): "the compile drill builds an engine inside monitor's CLI",
     ("parallel/pp_transformer.py", "models"): "parallel <-> models: PipelinedLM reuses the LM's blocks",
     ("run/__main__.py", "serving"): "run <-> serving: `kungfu-run -serve` forwards to the supervisor",
-    ("fsdp.py", "analysis"): "analyze= hook: the trainer calls the linter at trace time",
     ("optimizers/gossip.py", "analysis"): "analyze= hook",
     ("optimizers/sync.py", "analysis"): "analyze= hook",
     ("session.py", "analysis"): "analyze= hook",

@@ -291,41 +291,6 @@ class TestBucketedSync:
         buckets = _pack_buckets(leaves, 1024)
         assert buckets == [[0], [1], [2]]
 
-    @pytest.mark.parametrize("n", [2, 4])
-    def test_fsdp_bucketed_identity(self, n):
-        import optax
-
-        from kungfu_tpu.fsdp import FSDPTrainer
-
-        if len(jax.devices()) < 2 * n:
-            pytest.skip("needs dp x fsdp devices")
-        mesh = Mesh(np.array(jax.devices()[: 2 * n]).reshape(2, n),
-                    ("dp", "fsdp"))
-
-        def loss_fn(params, batch):
-            return jnp.mean((batch @ params["w"] + params["b"] - 1.0) ** 2)
-
-        params = {
-            "w": np.random.RandomState(0).randn(16, 4).astype(np.float32),
-            "b": np.zeros(4, np.float32),
-        }
-        batch = np.random.RandomState(1).randn(8, 16).astype(np.float32)
-
-        def train(bb):
-            tr = FSDPTrainer(loss_fn, optax.sgd(0.1), mesh=mesh,
-                             bucket_bytes=bb)
-            st = tr.init(params)
-            sb = tr.shard_batch(batch)
-            for _ in range(3):
-                st, m = tr.train_step(st, sb)
-            return tr.eval_params(st), float(np.asarray(m["loss"]))
-
-        p0, l0 = train(None)
-        p1, l1 = train(1 << 14)
-        assert l0 == l1
-        for k in p0:
-            assert np.array_equal(p0[k], p1[k])
-
     def test_session_group_bucketed(self):
         from kungfu_tpu.plan import make_mesh
         from kungfu_tpu.session import Session

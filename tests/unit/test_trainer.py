@@ -1,5 +1,7 @@
 """MeshTrainer (public multi-axis trainer): sharded steps must match the
-unsharded single-device computation, across dp x tp, dp x sp, and ep meshes."""
+unsharded single-device computation, across dp x tp, dp x sp, ep and fsdp
+meshes; under fsdp parameters and moments are stored one share a device and
+a checkpoint restores under another layout."""
 import numpy as np
 import pytest
 
@@ -12,10 +14,6 @@ from kungfu_tpu.models.transformer import (
 )
 from kungfu_tpu.plan import MeshSpec, make_mesh
 from kungfu_tpu.trainer import MeshTrainer
-
-# compile-heavy: excluded from the fast dev loop (pytest -m 'not slow');
-# CI runs the full suite unfiltered
-pytestmark = pytest.mark.slow
 
 
 def _loss_fn(model, params, toks):
@@ -35,13 +33,12 @@ def _tokens(batch=4):
     return np.random.RandomState(0).randint(0, 64, size=(batch, 32)).astype(np.int32)
 
 
-def _baseline(cfg_kw, tokens, steps=2):
-    """Unsharded single-device reference run."""
-    model = TransformerLM(_cfg(**cfg_kw))
+def _single_device_run(tokens, tx, steps, **cfg_kw):
+    """(losses, host params) of `steps` steps on one device, no mesh."""
     import flax.linen as nn
 
+    model = TransformerLM(_cfg(**cfg_kw))
     params = nn.meta.unbox(model.init(jax.random.PRNGKey(0), tokens)["params"])
-    tx = optax.sgd(0.05)
     opt = tx.init(params)
 
     @jax.jit
@@ -50,9 +47,30 @@ def _baseline(cfg_kw, tokens, steps=2):
         u, s = tx.update(g, s, p)
         return optax.apply_updates(p, u), s, loss
 
+    losses = []
     for _ in range(steps):
         params, opt, loss = step(params, opt)
-    return float(loss)
+        losses.append(float(loss))
+    return losses, jax.tree.map(np.asarray, params)
+
+
+def _baseline(cfg_kw, tokens, steps=2):
+    """Last loss of the unsharded single-device reference run."""
+    return _single_device_run(tokens, optax.sgd(0.05), steps, **cfg_kw)[0][-1]
+
+
+#: the two sharded layouts: every device a shard, and two replicas of four
+FSDP_MESHES = [dict(fsdp=8), dict(dp=2, fsdp=4)]
+
+
+def _mesh_id(axes):
+    return "x".join(f"{k}{v}" for k, v in axes.items())
+
+
+def _fsdp_trainer(mesh, tx, tokens):
+    model = TransformerLM(_cfg(mesh=mesh, attention="full"))
+    trainer = MeshTrainer(model, _loss_fn, tx, mesh=mesh)
+    return trainer, trainer.init(jax.random.PRNGKey(0), tokens)
 
 
 @pytest.mark.parametrize(
@@ -330,6 +348,91 @@ class TestMeshTrainerFSDP:
 
         st_d, loss_d = run(make_mesh(dp=8))
         assert abs(loss_f - loss_d) < 1e-4, (loss_f, loss_d)
+
+    # Adam divides by sqrt(v): where a gradient is small, float32's
+    # reduction order moves a step by a fraction of lr (1e-2 of it allowed)
+    @pytest.mark.parametrize("tx,atol", [(optax.sgd(0.1, momentum=0.9), 2e-5),
+                                         (optax.adam(1e-2), 1e-4)],
+                             ids=["sgd_momentum", "adam"])
+    @pytest.mark.parametrize("axes", FSDP_MESHES, ids=_mesh_id)
+    def test_steps_equal_the_single_device_steps(self, axes, tx, atol):
+        """Three steps under fsdp (and dp x fsdp) against the same three
+        on one device with no mesh: every loss, then every parameter."""
+        tokens = _tokens(8)
+        trainer, state = _fsdp_trainer(make_mesh(**axes), tx, tokens)
+        batch, losses = trainer.shard_batch(tokens), []
+        for _ in range(3):
+            state, m = trainer.train_step(state, batch)
+            losses.append(float(np.asarray(m["loss"])))
+        want_losses, want = _single_device_run(tokens, tx, steps=3,
+                                               attention="full")
+        np.testing.assert_allclose(losses, want_losses, rtol=2e-5)
+        assert losses[-1] < losses[0]
+        jax.tree.map(
+            lambda a, b: np.testing.assert_allclose(a, b, rtol=0, atol=atol),
+            trainer.eval_params(state), want)
+
+    @pytest.mark.parametrize("axes", FSDP_MESHES, ids=_mesh_id)
+    def test_parameters_and_moments_stored_one_share_a_device(self, axes):
+        """Every matrix, its momentum trace and both of Adam's moments:
+        a device holds 1/n_fsdp of each, before and after a step."""
+        tokens = _tokens(8)
+        n = axes["fsdp"]
+        tx = optax.chain(optax.trace(0.9), optax.adam(1e-2))
+        trainer, state = _fsdp_trainer(make_mesh(**axes), tx, tokens)
+
+        def check(state):
+            trace, adam = state.opt_state[0], state.opt_state[1][0]
+            for tree in (state.params, trace.trace, adam.mu, adam.nu):
+                matrices = [x for x in jax.tree.leaves(tree) if x.ndim >= 2]
+                assert len(matrices) >= 10
+                for x in matrices:
+                    assert x.addressable_shards[0].data.size * n == x.size, (
+                        x.shape, x.sharding.spec)
+            held = sum(x.addressable_shards[0].data.nbytes
+                       for x in jax.tree.leaves((state.params, state.opt_state)))
+            whole = sum(x.nbytes for x in jax.tree.leaves(
+                (state.params, state.opt_state)))
+            # the norm scales (vectors) and two counts stay whole
+            assert held < whole / n * 1.1, (held, whole)
+
+        check(state)
+        state, _ = trainer.train_step(state, trainer.shard_batch(tokens))
+        check(state)
+
+    @pytest.mark.parametrize("axes", FSDP_MESHES, ids=_mesh_id)
+    def test_checkpoint_restores_under_another_layout(self, axes, tmp_path):
+        """Two steps under one fsdp layout, saved; restored onto the OTHER
+        layout's freshly placed state: the same values under the other
+        layout's shardings, and the third step reads the same loss."""
+        from kungfu_tpu.checkpoint import CheckpointManager
+
+        (other,) = [m for m in FSDP_MESHES if m != axes]
+        tokens = _tokens(8)
+        tx = optax.adam(1e-2)
+        a, sa = _fsdp_trainer(make_mesh(**axes), tx, tokens)
+        for _ in range(2):
+            sa, _ = a.train_step(sa, a.shard_batch(tokens))
+        mgr = CheckpointManager(str(tmp_path / "ckpt"))
+        try:
+            assert mgr.save(2, {"params": sa.params, "opt": sa.opt_state})
+            mgr.wait()
+            b, sb = _fsdp_trainer(make_mesh(**other), tx, tokens)
+            got, _ = mgr.restore(like={"params": sb.params,
+                                       "opt": sb.opt_state})
+        finally:
+            mgr.close()
+        for new, placed, saved in zip(
+                jax.tree.leaves(got),
+                jax.tree.leaves({"params": sb.params, "opt": sb.opt_state}),
+                jax.tree.leaves({"params": sa.params, "opt": sa.opt_state})):
+            assert new.sharding == placed.sharding
+            np.testing.assert_array_equal(np.asarray(new), np.asarray(saved))
+        sb = type(sb)(params=got["params"], opt_state=got["opt"], step=2)
+        sa, ma = a.train_step(sa, a.shard_batch(tokens))
+        sb, mb = b.train_step(sb, b.shard_batch(tokens))
+        np.testing.assert_allclose(float(np.asarray(mb["loss"])),
+                                   float(np.asarray(ma["loss"])), rtol=2e-5)
 
 
 def test_loss_arity_detection_ignores_defaults():

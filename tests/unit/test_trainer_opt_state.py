@@ -4,8 +4,8 @@ replicated on the mesh, every leaf committed, so that under fsdp a chip
 holds and updates its share of Adam's moments and the first and second
 step of a state run one compiled program.
 
-Tiny models, so these run in tier-1 (`tests/unit/test_trainer.py` is
-marked slow as a whole).
+`tests/unit/test_trainer.py` holds the trainer's steps against one
+device's, layout by layout.
 """
 import numpy as np
 import pytest

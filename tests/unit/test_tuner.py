@@ -5,9 +5,10 @@ every tuned axis (tiles, head layout, backward arm, remat policy, CE
 chunk, donation/buckets) and stays JSON round-trippable; the footprint
 gate rejects configs that blow KFT_PALLAS_VMEM_MIB / the HBM budget; the
 prior cache round-trips, misses on any stale key component and drops
-stale entries; tile resolution (flash_block=None) prefers explicit ints,
-then the cached winner, then the shape-conditional hunt defaults, clamped
-to VMEM; the measured runoff always keeps the hand-tuned default as a
+stale entries; tile resolution (flash_block=None) is the shape table
+beside the kernels, clamped to VMEM, explicit ints win, and a cached
+winner reaches a model through `ComputeTuner.apply` alone; the measured
+runoff always keeps the hand-tuned default as a
 control (the tuned config of record never loses to it) and a cache hit
 skips measurement; tuned-vs-default numerics: the resolution path and the
 remat policies are bit-identical on the forward pass and grad-close on
@@ -25,7 +26,6 @@ import jax.numpy as jnp
 
 import kungfu_tpu.tuner as T
 from kungfu_tpu.tuner import cache as tuner_cache
-from kungfu_tpu.tuner import core as tuner_core
 from kungfu_tpu.tuner import footprint as F
 
 pytestmark = pytest.mark.tuner
@@ -171,8 +171,7 @@ class TestPriorCache:
         again = T.PriorCache(path)
         assert again.get_config(shape.digest(), "cpu", "0.4.37") == cfg
         # any stale key component misses
-        assert again.get_config(shape.digest(), "tpu", "0.4.37",
-                                shipped=False) is None
+        assert again.get_config(shape.digest(), "tpu", "0.4.37") is None
         assert again.get_config(shape.digest(), "cpu", "0.5.0") is None
         assert again.get_config(tiny(seq_len=32).digest(), "cpu",
                                 "0.4.37") is None
@@ -194,20 +193,32 @@ class TestPriorCache:
         c = T.PriorCache(path)
         assert len(c) == 0 and c.load_error
 
-    def test_shipped_r5_priors_answer_on_tpu_only(self):
-        c = T.PriorCache("/nonexistent/never-created.json")
-        d = flagship().digest()
-        tpu = c.get_config(d, "tpu", "whatever-version")
-        assert tpu is not None and (tpu.block_q, tpu.block_k) == (256, 512)
-        assert tpu.head_dim == 128 and tpu.backward == "pallas"
-        assert c.get_config(d, "cpu", "whatever-version") is None
+    def test_no_file_no_answer_on_any_backend(self):
+        """The cache holds what a runoff on this stack wrote and nothing
+        else: no record ships with the code."""
+        for path in ("", "/nonexistent/never-created.json"):
+            c = T.PriorCache(path)
+            assert len(c) == 0
+            for backend in ("tpu", "cpu"):
+                assert c.get_config(flagship().digest(), backend,
+                                    "whatever-version") is None
 
-    def test_file_entry_beats_shipped_prior(self, tmp_path):
+    def test_entries_of_an_earlier_version_still_load(self, tmp_path):
+        """A file written when StepConfig had more axes (the fused-matmul
+        keys, PR 45) loads: unknown keys are dropped, known ones kept."""
         path = str(tmp_path / "prior.json")
-        c = T.PriorCache(path)
         mine = T.StepConfig(block_q=512, block_k=512, head_dim=64)
+        c = T.PriorCache(path)
         c.put(flagship(), "tpu", "0.4.37", mine)
-        assert c.get_config(flagship().digest(), "tpu", "0.4.37") == mine
+        with open(path) as f:
+            d = json.load(f)
+        for e in d["entries"].values():
+            e["config"].update(fused_matmul=True, fused_block_m=128,
+                               fused_block_n=128)
+        with open(path, "w") as f:
+            json.dump(d, f)
+        assert T.PriorCache(path).get_config(
+            flagship().digest(), "tpu", "0.4.37") == mine
 
 
 class TestResolution:
@@ -219,52 +230,87 @@ class TestResolution:
         base.update(kw)
         return TransformerConfig(**base)
 
-    def test_explicit_ints_always_win(self):
-        cfg = self._cfg(flash_block_q=64, flash_block_k=96)
-        assert T.resolve_flash_blocks(cfg, batch=4, seq_len=2048) == (64, 96)
+    def _traced_tiles(self, cfg, monkeypatch, seq_len=2048):
+        """(block_q, block_k) `Attention` hands the flash kernel when a
+        one-layer model of `cfg`'s attention shape is traced."""
+        from kungfu_tpu.models.transformer import TransformerLM
+        from kungfu_tpu.ops import flash
 
-    def test_shape_conditional_hunt_defaults(self):
+        seen = []
+        real = flash.flash_attention
+
+        def spy(q, k, v, **kw):
+            seen.append((kw["block_q"], kw["block_k"]))
+            return real(q, k, v, **kw)
+
+        monkeypatch.setattr(flash, "flash_attention", spy)
+        small = dataclasses.replace(cfg, vocab_size=64, n_layers=1, d_ff=64,
+                                    attention="flash")
+        model = TransformerLM(small)
+        jax.eval_shape(lambda t: model.init(jax.random.PRNGKey(0), t),
+                       jax.ShapeDtypeStruct((1, seq_len), jnp.int32))
+        assert len(set(seen)) == 1, seen
+        return seen[0]
+
+    def test_explicit_ints_always_win(self, monkeypatch):
+        cfg = self._cfg(flash_block_q=64, flash_block_k=96)
+        assert T.resolve_flash_blocks(cfg, seq_len=2048) == (64, 96)
+        assert self._traced_tiles(cfg, monkeypatch) == (64, 96)
+        # one explicit int wins on its own axis, the table fills the other
+        one = self._cfg(flash_block_q=128)
+        assert T.resolve_flash_blocks(one, seq_len=2048) == (128, 1024)
+
+    def test_shape_conditional_hunt_defaults(self, monkeypatch):
         # head_dim 64 at seq 2048: the 16×64 sweep winner
         assert T.resolve_flash_blocks(
-            self._cfg(), batch=4, seq_len=2048) == (512, 1024)
+            self._cfg(), seq_len=2048) == (512, 1024)
         # head_dim 128: the MXU-native winner
         assert T.resolve_flash_blocks(
-            self._cfg(n_heads=8), batch=4, seq_len=2048) == (256, 512)
+            self._cfg(n_heads=8), seq_len=2048) == (256, 512)
+        assert self._traced_tiles(self._cfg(n_heads=8),
+                                  monkeypatch) == (256, 512)
         # short sequences stay safe
         assert T.default_flash_blocks(64, 512) == (128, 128)
         assert T.default_flash_blocks(64, 1024) == (256, 256)
 
-    def test_cached_winner_wins_over_table(self, tmp_path, monkeypatch):
+    def test_installed_winner_is_what_attention_traces(self, tmp_path,
+                                                       monkeypatch):
         path = str(tmp_path / "prior.json")
+        cfg = self._cfg()
+        shape = T.ShapeKey.of(cfg, batch_per_chip=4, seq_len=2048)
+        cache = T.PriorCache(path)
+        cache.put(shape, T.backend_name(), T.jax_version(),
+                  T.StepConfig(block_q=256, block_k=256, head_dim=64))
+        tuned, _ = T.ComputeTuner(shape, cache=cache).apply(cfg)
+        assert (tuned.flash_block_q, tuned.flash_block_k) == (256, 256)
+        assert self._traced_tiles(tuned, monkeypatch) == (256, 256)
+        # the model itself reads no file: untuned, it traces the table
+        # even where KFT_TUNER_CACHE names the winner's file
         monkeypatch.setenv(tuner_cache.CACHE_ENV, path)
-        tuner_core._reset_prior_cache_for_tests()
-        try:
-            cfg = self._cfg()
-            shape = T.ShapeKey.of(cfg, batch_per_chip=4, seq_len=2048)
-            T.PriorCache(path).put(shape, T.backend_name(), T.jax_version(),
-                                   T.StepConfig(block_q=256, block_k=256,
-                                                head_dim=64))
-            tuner_core._reset_prior_cache_for_tests()
-            assert T.resolve_flash_blocks(cfg, batch=4, seq_len=2048) == \
-                (256, 256)
-            # a prior tuned for ANOTHER layout must not leak tiles onto
-            # this config's declared head_dim
-            T.PriorCache(path).put(shape, T.backend_name(), T.jax_version(),
-                                   T.StepConfig(block_q=256, block_k=512,
-                                                head_dim=128))
-            tuner_core._reset_prior_cache_for_tests()
-            assert T.resolve_flash_blocks(cfg, batch=4, seq_len=2048) == \
-                (512, 1024)
-        finally:
-            tuner_core._reset_prior_cache_for_tests()
+        assert self._traced_tiles(cfg, monkeypatch) == (512, 1024)
+        # a winner for ANOTHER layout installs that layout with its
+        # tiles; they never land on the declared head_dim alone
+        cache.put(shape, T.backend_name(), T.jax_version(),
+                  T.StepConfig(block_q=256, block_k=512, head_dim=128))
+        tuned, _ = T.ComputeTuner(shape, cache=cache).apply(cfg)
+        assert tuned.d_model // tuned.n_heads == 128
+        assert self._traced_tiles(tuned, monkeypatch) == (256, 512)
 
     def test_vmem_clamp_degrades_instead_of_wedging(self, monkeypatch):
         monkeypatch.setenv(F.VMEM_ENV, "2")
-        bq, bk = T.resolve_flash_blocks(self._cfg(), batch=4, seq_len=2048)
+        bq, bk = T.resolve_flash_blocks(self._cfg(), seq_len=2048)
         probe = T.StepConfig(block_q=bq, block_k=bk, head_dim=64)
         assert F.flash_vmem_bytes(
             probe, flagship()) <= F.vmem_budget_bytes()
         assert (bq, bk) != (512, 1024)
+        # the same clamp halves an oversize winner on its way in
+        cfg = self._cfg()
+        tuner = T.ComputeTuner(T.ShapeKey.of(cfg, 4, seq_len=2048),
+                               cache=None)
+        tuned, _ = tuner.apply(cfg, T.StepConfig(block_q=512, block_k=1024,
+                                                 head_dim=64))
+        assert (tuned.flash_block_q, tuned.flash_block_k) == (bq, bk)
+        assert self._traced_tiles(tuned, monkeypatch) == (bq, bk)
 
 
 class TestTuneRunoff:
@@ -386,9 +432,7 @@ class TestApply:
         assert cfg.remat and cfg.remat_policy == "dots"
         assert cfg.head == "hidden"
         assert extras == {"ce_chunk": 4096, "donate": False,
-                          "bucket_bytes": 4 << 20,
-                          "dma_collectives": False,
-                          "fused_block_m": 0, "fused_block_n": 0}
+                          "bucket_bytes": 4 << 20}
 
     def test_apply_never_refactors_gqa_heads(self):
         from kungfu_tpu.models.transformer import TransformerConfig
@@ -425,7 +469,7 @@ class TestNumericalParity:
         toks = self._toks(shape)
         params = TransformerLM(base).init(jax.random.PRNGKey(0),
                                           toks)["params"]
-        bq, bk = T.resolve_flash_blocks(base, batch=2, seq_len=16)
+        bq, bk = T.resolve_flash_blocks(base, seq_len=16)
         explicit = dataclasses.replace(base, flash_block_q=bq,
                                        flash_block_k=bk)
         np.testing.assert_array_equal(
