@@ -32,12 +32,22 @@ from ..ops.decode_attn import (
     decode_attention_reference,
     mla_decode_attention,
     mla_decode_attention_reference,
+    select_blocks,
+    sparse_decode_attention,
+    sparse_prefill_attention,
 )
 from ..parallel.sharding import logical_constraint
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..compat import pallas_mode
 from ..parallel.ring_attention import full_attention, ring_attention
+
+#: the mixer kinds `TransformerConfig.mixer_types` may list.  Each keeps,
+#: a decode-mode slot, what no position cuts (a matrix state; keys
+#: compressed at a stride of their own): the serving supervisor refuses a
+#: model with ANY list what it refuses a stateful one (serving/__main__.py),
+#: so a kind that keeps none needs that rule changed with this tuple
+LISTED_MIXERS = ("lightning-attn", "minicpm4")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -227,6 +237,52 @@ class TransformerConfig:
     # or no positional signal of any kind (False: the order of the tokens
     # reaches an attention layer through the recurrent layers before it)
     pos_table: bool = True
+    # a mixer kind a layer, from a list (the published `mixer_types`), where
+    # the rules above choose between two by a period: entry i is
+    # "lightning-attn" (`LightningAttention`: linear attention over
+    # `n_heads` heads of e = d_model / n_heads, a [heads, e, e] float32
+    # matrix state a row and the row's position, for its rotation: `rope`
+    # is the published `lightning_use_rope`) or "minicpm4"
+    # (`SparseAttention`: grouped-query attention over the blocks a
+    # selector chooses, compressed keys beside the rows).  Empty = the
+    # rules above (`layer_kind` answers either way)
+    mixer_types: Tuple[str, ...] = ()
+    # whether the block-selected layers rotate q and k when `rope` is on
+    # (the published `attn_use_rope`; false: they carry no positional term)
+    attn_use_rope: bool = True
+    # the selector of the block-selected layers (the family's published
+    # `sparse_config`): rows in blocks of `sparse_block_size`; compressed
+    # keys the mean of two strides of keys (the published `kernel_size` 32 =
+    # 2 x `kernel_stride`: the selector's pooling is written for that), one
+    # every `sparse_kernel_stride` rows; a query attends `sparse_topk` blocks: the
+    # first `sparse_init_blocks`, those of the `sparse_window_size` newest
+    # rows, then by score (ops/decode_attn.py `select_blocks`)
+    sparse_block_size: int = 64
+    sparse_topk: int = 64
+    sparse_kernel_stride: int = 16
+    sparse_init_blocks: int = 1
+    sparse_window_size: int = 2048
+    # standard deviation the block-selected layers' value projection is
+    # initialised with (every other matrix: 0.02).  Such a layer has no
+    # output norm, so on seeded stand-in weights its output is an average
+    # of n random value rows and falls with sqrt(n): over the 4,096 rows a
+    # long query reads it reaches the residual stream thirty times under a
+    # lightning layer's normed output, where a comparison of logits cannot
+    # see a wrong block.  A configuration checked on seeded weights names
+    # the value that restores the layer's share at its context length
+    # (benchmark/configs/minicpm-sala-serve.json `assumed.weights`).  A
+    # checkpoint overwrites it
+    sparse_v_init_std: float = 0.02
+    # the MiniCPM family's three scale constants, each off at its default:
+    # the embedding times `scale_emb`; every sublayer's output times
+    # scale_depth / sqrt(`scale_depth_layers` or n_layers) before its
+    # residual add (a trained constant of the PUBLISHED depth, so a model
+    # cut in depth names that depth); the head reads the final norm divided
+    # by d_model / `dim_model_base`
+    scale_emb: float = 1.0
+    scale_depth: float = 0.0
+    scale_depth_layers: int = 0
+    dim_model_base: int = 0
     # what lies BETWEEN the matmuls in float32 where it is cfg.dtype by
     # default: the residual stream (the Mamba reference code's
     # `residual_in_fp32`), the output of every projection that goes on as
@@ -267,6 +323,25 @@ class TransformerConfig:
 
     def __post_init__(self):
         assert self.d_model % self.n_heads == 0
+        if self.mixer_types:
+            # a list from JSON; a tuple hashes (flax, lru_cache)
+            object.__setattr__(self, "mixer_types", tuple(self.mixer_types))
+            assert len(self.mixer_types) == self.n_layers and all(
+                kind in LISTED_MIXERS for kind in self.mixer_types), \
+                self.mixer_types
+            assert not self.mamba_d_state and self.block == "standard", (
+                "a list of mixers: the standard block, no period rule")
+            assert self.mesh is None and self.kv_cache_dtype == "model" \
+                and self.causal and not self.window, (
+                    "lightning and block-selected layers: one device, "
+                    "causal, rows in the model's dtype")
+            assert self.sparse_block_size % self.sparse_kernel_stride == 0 \
+                and self.sparse_window_size % self.sparse_block_size == 0 \
+                and self.sparse_init_blocks >= 1, (
+                    "the selector: whole strides a block, whole blocks a "
+                    "window, block 0 always read")
+            if self.decode:
+                assert self.max_len % self.sparse_block_size == 0
         if self.decode:
             assert self.rope or not self.pos_table, (
                 "decode mode: rope positions, or none (no learned table)")
@@ -365,11 +440,35 @@ class TransformerConfig:
                 and j % self.moe_every == self.moe_every - 1)
 
 
+    def layer_kind(self, i: int) -> str:
+        """What mixes the tokens in standard block `i`: "attention",
+        "ssm" (`Mamba`), "lightning-attn" or "minicpm4".  The one rule:
+        `mixer_types` where the model gives the list, else the state-space
+        mixer everywhere but one layer a period, else attention."""
+        if self.mixer_types:
+            return self.mixer_types[i]
+        if self.mamba_d_state > 0 \
+                and i % self.attn_layer_period != self.attn_layer_offset:
+            return "ssm"
+        return "attention"
+
     def layer_is_ssm(self, i: int) -> bool:
         """Whether standard block `i` mixes tokens by the state-space
         mixer (`Mamba`) and not by attention."""
-        return (self.mamba_d_state > 0
-                and i % self.attn_layer_period != self.attn_layer_offset)
+        return self.layer_kind(i) == "ssm"
+
+    def has_mixer(self, *kinds: str) -> bool:
+        """Whether any standard block's mixer is one of `kinds`."""
+        return any(self.layer_kind(i) in kinds for i in range(self.n_layers))
+
+    @property
+    def keeps_state(self) -> bool:
+        """Whether a decode-mode slot holds what no position cuts: a
+        recurrent state (`Mamba`, `LightningAttention`) or keys compressed
+        at a stride of their own (`SparseAttention`).  Then nothing that
+        slices a cache by position or rolls a cursor back is served
+        (serving/slots.py STATE_LEAVES, STRIDED_LEAVES)."""
+        return self.has_mixer("ssm", *LISTED_MIXERS)
 
 
 def _attention_kind(cfg: TransformerConfig) -> str:
@@ -406,7 +505,7 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 
 def _dense(features, name, kernel_axes, dtype, use_bias: bool = False,
-           wide: bool = False):
+           wide: bool = False, init_std: float = 0.02):
     """`wide`: the output is the matmul's float32 accumulator, not rounded
     to `dtype` (`TransformerConfig.fp32_activations`); operands as ever."""
     out = {"dot_general": partial(jax.lax.dot_general,
@@ -419,7 +518,7 @@ def _dense(features, name, kernel_axes, dtype, use_bias: bool = False,
         name=name,
         **out,
         kernel_init=nn.with_logical_partitioning(
-            nn.initializers.normal(stddev=0.02), kernel_axes
+            nn.initializers.normal(stddev=init_std), kernel_axes
         ),
         # bias shards with the projection's OUTPUT dim (kernel_axes[-1]):
         # under tp the q/k/v outputs are head-sharded, so the bias is too
@@ -454,6 +553,17 @@ def _write_each_slot(cache, rows, start):
         cache = jax.lax.dynamic_update_slice(
             cache, rows[b:b + 1], (jnp.uint32(b), start[b]) + origin)
     return cache
+
+
+def _slot_rows(cache: jax.Array, start: jax.Array, n: int) -> jax.Array:
+    """[B, n, ...]: rows start[b] .. start[b] + n - 1 of every slot b of
+    `cache` [B, max_len, ...], one `dynamic_slice` a slot, written out over
+    the static slot count as `_write_each_slot` writes: the vmapped form is
+    a gather, for which the TPU compiler copies the whole leaf into a layout
+    with the slots inside the rows (100 MB a K leaf of 16 x 12,288 x 256)."""
+    start = start.astype(jnp.uint32)
+    return jnp.stack([jax.lax.dynamic_slice_in_dim(cache[b], start[b], n, 0)
+                      for b in range(cache.shape[0])])
 
 
 def _store_rows(cache: jax.Array, rows: jax.Array, idx0: jax.Array) -> jax.Array:
@@ -956,11 +1066,7 @@ class Mamba(nn.Module):
             skip = leaf("D", nn.initializers.ones, (di,), ("mlp",))
             dt_bias = leaf("dt_bias", _mamba_dt_bias_init, (di,), ("mlp",))
 
-            n_valid = jnp.full((B,), L, jnp.int32)
-            if n_new is not None:
-                n_valid = jnp.broadcast_to(n_new.astype(jnp.int32), (B,))
-            if live is not None:
-                n_valid = jnp.where(live, n_valid, 0)
+            n_valid = _real_tokens(B, L, live, n_new)
             if cfg.decode:
                 conv_state = self.variable(
                     "cache", "conv_state", jnp.zeros, (B, K - 1, di), cfg.dtype)
@@ -1008,6 +1114,259 @@ class Mamba(nn.Module):
                           cfg.dtype, wide=wide)(y.astype(act))
 
 
+def _real_tokens(B: int, L: int, live, n_new):
+    """[B] int32: how many of each row's L tokens a recurrence walks: all
+    of them, `n_new` of a right-padded call, none of a row that is not
+    live."""
+    n_valid = jnp.full((B,), L, jnp.int32)
+    if n_new is not None:
+        n_valid = jnp.broadcast_to(n_new.astype(jnp.int32), (B,))
+    if live is not None:
+        n_valid = jnp.where(live, n_valid, 0)
+    return n_valid
+
+
+def _slot_cursors(module: nn.Module, B: int):
+    """The per-slot cursor `idx` [B] int32 and sticky flag `overflowed` [B]
+    a decode-mode mixer keeps in the cache collection (`Attention` says
+    what each means)."""
+    return (module.variable("cache", "idx", lambda: jnp.zeros((B,), jnp.int32)),
+            module.variable("cache", "overflowed",
+                            lambda: jnp.zeros((B,), jnp.bool_)))
+
+
+class LightningAttention(nn.Module):
+    """The linear-attention mixer of a "lightning-attn" layer (Lightning
+    Attention as MiniMax-Text-01 publishes it, with MiniCPM-SALA's norms and
+    gate), u the normed input, H = `n_heads`, e = d_model / H:
+
+        q, k, v = u W_q, u W_k, u W_v                (no bias, no activation)
+        q, k    = rope(N_q(q)), rope(N_k(k))         (RMSNorm over e a head,
+                                                      scale [e]; `rope`)
+        S_t     = lam_h S_{t-1} + k_t^T v_t          (S [e, e] a head, float32)
+        o_t     = q_t S_t / sqrt(e)                  lam_h = exp(-2^(-8 (h+1) / H))
+        out     = (N_o(o) * sigmoid(u W_g)) W_o      (N_o over all H x e)
+
+    Every projection is an `nn.Dense` (in the resident tree in cfg.dtype);
+    the three norms' scales are float32 leaves.  The recurrence is
+    ops/lightning_attn.py: its kernel in decode mode, the `lax.scan` (which
+    has a gradient) in training mode, where every row starts from zeros.
+
+    The decode-mode cache is `lin_state` [B, H, e, e] float32, S after the
+    row's last real token (no position axis: serving/slots.py STATE_LEAVES),
+    and the cursor `idx` and flag `overflowed` that `Attention` keeps: the
+    state needs no position, the rotation of q and k does.  A call of L
+    tokens is exactly L chained one-token calls.  `live` and `n_new` as
+    `Mamba` takes them: a row that is not live keeps its state and its
+    cursor, and positions at or beyond `n_new` never enter the state."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u, live=None, n_new=None):
+        from ..ops.lightning_attn import (
+            decay_slopes, lightning_attention, lightning_attention_reference)
+
+        cfg = self.cfg
+        B, L, _ = u.shape
+        H, e = cfg.n_heads, cfg.d_model // cfg.n_heads
+        f32 = jnp.float32
+        wide = cfg.fp32_activations
+        with jax.named_scope("lin"):
+            def project(name):
+                y = _dense(H * e, name, ("embed", "heads"), cfg.dtype)(u)
+                return y.reshape(B, L, H, e)
+
+            q = _norm(cfg, "q_norm")(project("q")).astype(cfg.dtype)
+            k = _norm(cfg, "k_norm")(project("k")).astype(cfg.dtype)
+            v = project("v")
+            gate = _dense(H * e, "gate", ("embed", "heads"), cfg.dtype,
+                          wide=wide)(u)
+            n_valid = _real_tokens(B, L, live, n_new)
+            if cfg.decode:
+                state = self.variable(
+                    "cache", "lin_state", jnp.zeros, (B, H, e, e), f32)
+                cache_idx, cache_ovf = _slot_cursors(self, B)
+                idx0, s0 = cache_idx.value, state.value
+                pos = idx0[:, None] + jnp.arange(L)[None, :]    # [B, L]
+            else:
+                pos, s0 = jnp.arange(L)[None, :], jnp.zeros((B, H, e, e), f32)
+            if cfg.rope:
+                q = apply_rope(q, pos, cfg.rope_theta)
+                k = apply_rope(k, pos, cfg.rope_theta)
+            with jax.named_scope("lin.kernel"):
+                attend = (lightning_attention if cfg.decode
+                          else lightning_attention_reference)
+                o, s = attend(q, k, v, decay_slopes(H), s0, n_valid)
+            o = _norm(cfg, "o_norm")(o.reshape(B, L, H * e))    # float32
+            o = o * jax.nn.sigmoid(gate.astype(f32))
+            if cfg.decode:
+                if not self.is_initializing():
+                    # init() traces the module once to create the cache: it
+                    # writes no state and moves no cursor
+                    state.value = s
+                    cache_idx.value = idx0 + n_valid
+                    cache_ovf.value = jnp.logical_or(
+                        cache_ovf.value, idx0 + n_valid > cfg.max_len)
+                # overflow is loud and contained to its slot, as in `Attention`
+                poison = jnp.logical_or((pos >= cfg.max_len)[:, :, None],
+                                        cache_ovf.value[:, None, None])
+                o = jnp.where(poison, jnp.nan, o)
+            return _dense(cfg.d_model, "out", ("heads", "embed"), cfg.dtype,
+                          wide=wide)(o.astype(f32 if wide else cfg.dtype))
+
+
+def _compressed_keys(rows, stride: int):
+    """[B, M / stride, W] float32: entry m the mean of rows stride x m ..
+    stride x (m + 2) - 1 of rows [B, M, W] (the last entry's second half
+    lies beyond M and is taken as zeros: no query ever sees that entry)."""
+    B, M, W = rows.shape
+    part = rows.reshape(B, M // stride, stride, W).astype(jnp.float32).sum(2)
+    return (part + jnp.pad(part[:, 1:], ((0, 0), (0, 1), (0, 0)))) / (2 * stride)
+
+
+class SparseAttention(nn.Module):
+    """The block-selected attention of a "minicpm4" layer (the InfLLM-v2
+    selection of the MiniCPM4 release), u the normed input, H = `n_heads`
+    query heads on `n_kv_heads` KV heads of D = d_model / H:
+
+        q, k, v = u W_q, u W_k, u W_v        q, k = N_q(q), N_k(k) a head
+        (rotated only if `rope` and `attn_use_rope`)
+        chosen  = ops/decode_attn.py `select_blocks`: compressed keys (the
+                  mean of 2 x `sparse_kernel_stride` keys every
+                  `sparse_kernel_stride` rows), softmax a query head, summed
+                  over a KV head's group, max-pooled onto blocks of
+                  `sparse_block_size` rows; the first block(s) and the
+                  window always, `sparse_topk` in all
+        o_h     = softmax over the rows j <= t of the chosen blocks
+                  (q_h . k_j / sqrt(D)) v_j
+        out     = (o * sigmoid(u W_g)) W_o
+
+    so a query with at most `sparse_topk` blocks at or before it is plain
+    causal attention.  Every projection is an `nn.Dense`.
+
+    The decode-mode cache: `cached_k`, `cached_v` [B, max_len, Hkv x D] in
+    cfg.dtype (a KV head a run of D lanes of a row: the kernel's block of
+    one KV head's rows is then whole tiles), `k_cmp`
+    [B, max_len / stride, Hkv x D] the compressed keys (entry m is written
+    when its last row, stride x (m + 2) - 1, lands), and the cursor
+    and flag that `Attention` keeps, under the same contract (`live`,
+    overflow poisoning, a call of L tokens = L chained calls).  A decode
+    step (one row a slot) reads the chosen blocks
+    (`sparse_decode_attention`: the kernel); a prefill bucket (more than
+    `MAX_QUERY_ROWS` rows) and training mode choose for every row in chunks
+    of query rows (`sparse_prefill_attention`).  A verify step's 2 to
+    `MAX_QUERY_ROWS` rows are refused: the engine serves this model no
+    speculation.  Training mode keeps no cache and compresses the call's
+    own keys."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u, live=None):
+        cfg = self.cfg
+        H, D, Hkv = cfg.n_heads, cfg.d_model // cfg.n_heads, cfg.kv_heads
+        B, L, _ = u.shape
+        block, stride = cfg.sparse_block_size, cfg.sparse_kernel_stride
+        choice = dict(block=block, stride=stride, topk=cfg.sparse_topk,
+                      init_blocks=cfg.sparse_init_blocks,
+                      window=cfg.sparse_window_size)
+        f32 = jnp.float32
+        wide = cfg.fp32_activations
+        with jax.named_scope("sparse"):
+            def project(name, heads, init_std=0.02):
+                y = _dense(heads * D, name, ("embed", "heads"), cfg.dtype,
+                           init_std=init_std)(u)
+                return y.reshape(B, L, heads, D)
+
+            q = _norm(cfg, "q_norm")(project("q", H)).astype(cfg.dtype)
+            k = _norm(cfg, "k_norm")(project("k", Hkv)).astype(cfg.dtype)
+            v = project("v", Hkv, cfg.sparse_v_init_std)
+            gate = _dense(H * D, "gate", ("embed", "heads"), cfg.dtype,
+                          wide=wide)(u)
+            if cfg.decode:
+                assert L == 1 or L > MAX_QUERY_ROWS, (
+                    "a block-selected layer takes a decode step of one row "
+                    "or a prefill bucket, no verify step")
+                rows = (B, cfg.max_len, Hkv * D)
+                cache_k = self.variable(
+                    "cache", "cached_k", jnp.zeros, rows, cfg.dtype)
+                cache_v = self.variable(
+                    "cache", "cached_v", jnp.zeros, rows, cfg.dtype)
+                k_cmp = self.variable(
+                    "cache", "k_cmp", jnp.zeros,
+                    (B, cfg.max_len // stride, Hkv * D), cfg.dtype)
+                cache_idx, cache_ovf = _slot_cursors(self, B)
+                idx0 = cache_idx.value
+                pos = idx0[:, None] + jnp.arange(L)[None, :]    # [B, L]
+            else:
+                pos = jnp.broadcast_to(jnp.arange(L)[None, :], (B, L))
+            if cfg.rope and cfg.attn_use_rope:
+                q = apply_rope(q, pos, cfg.rope_theta)
+                k = apply_rope(k, pos, cfg.rope_theta)
+            k, v = k.reshape(B, L, Hkv * D), v.reshape(B, L, Hkv * D)
+
+            if not cfg.decode:
+                pad = ((0, 0), (0, -L % block), (0, 0))
+                k, v = jnp.pad(k, pad), jnp.pad(v, pad)
+                o = sparse_prefill_attention(
+                    q, k, v, _compressed_keys(k, stride).astype(cfg.dtype),
+                    pos, **choice)
+            else:
+                if not self.is_initializing():
+                    # init() traces the module once to create the cache: it
+                    # writes no rows and moves no cursor
+                    cache_k.value = _store_rows(cache_k.value, k, idx0)
+                    cache_v.value = _store_rows(cache_v.value, v, idx0)
+                    step = L if live is None else jnp.where(live, L, 0)
+                    cache_idx.value = idx0 + step
+                    cache_ovf.value = jnp.logical_or(
+                        cache_ovf.value, idx0 + step > cfg.max_len)
+                    k_cmp.value = self._compress(
+                        cache_k.value, k_cmp.value, pos, live)
+                if L > 1:
+                    o = sparse_prefill_attention(
+                        q, cache_k.value, cache_v.value, k_cmp.value, pos,
+                        **choice)
+                else:
+                    with jax.named_scope("sparse.select"):
+                        ids, n = select_blocks(q, k_cmp.value, pos, **choice)
+                    with jax.named_scope("sparse.attend"):
+                        o = sparse_decode_attention(
+                            q, cache_k.value, cache_v.value, ids, n, pos, block)
+                poison = jnp.logical_or(
+                    (pos >= cfg.max_len)[:, :, None, None],
+                    cache_ovf.value[:, None, None, None])
+                o = jnp.where(poison, jnp.nan, o)
+            o = o.reshape(B, L, H * D) * jax.nn.sigmoid(gate.astype(f32))
+            return _dense(cfg.d_model, "out", ("heads", "embed"), cfg.dtype,
+                          wide=wide)(o.astype(f32 if wide else cfg.dtype))
+
+    def _compress(self, rows, held, pos, live):
+        """The compressed keys after this call's rows at `pos` [B, L] were
+        stored in `rows`.  A prefill bucket: every entry, from the rows as
+        they lie (entries over rows not yet written are rewritten when their
+        last row lands, and seen by no query before).  A decode step: the
+        one entry its row completes, if it completes one and the row is
+        live; every other entry, a free slot's all, stays."""
+        stride = self.cfg.sparse_kernel_stride
+        size = 2 * stride                   # rows under one compressed key
+        if pos.shape[1] > 1:
+            return _compressed_keys(rows, stride).astype(held.dtype)
+        t = pos[:, 0]                           # the row just written
+        done = jnp.logical_and(t >= size - 1, (t - size + 1) % stride == 0)
+        if live is not None:
+            done = jnp.logical_and(done, live)
+        m = jnp.clip((t - size + 1) // stride, 0, held.shape[1] - 1)
+        last = _slot_rows(
+            rows, jnp.clip(m * stride, 0, rows.shape[1] - size), size)
+        new = last.astype(jnp.float32).mean(axis=1, keepdims=True)
+        old = _slot_rows(held, m, 1)
+        return _store_rows(
+            held, jnp.where(done[:, None, None], new.astype(held.dtype), old),
+            m)
+
+
 class MLP(nn.Module):
     cfg: TransformerConfig
 
@@ -1042,14 +1401,16 @@ def _norm(cfg, name: str):
 
 
 class Block(nn.Module):
-    """`cfg.block == "standard"`: attention (`Attention`, or `MLA` when
-    `cfg.kv_lora_rank`), then the dense FFN or the expert layer, each on
-    the normed stream and added back to it; under `cfg.sandwich_norm` each
-    sublayer's output is normed once more before the add."""
+    """`cfg.block == "standard"`: the mixer `kind` names (attention:
+    `Attention`, or `MLA` when `cfg.kv_lora_rank`; "ssm": `Mamba`;
+    "lightning-attn": `LightningAttention`; "minicpm4": `SparseAttention`),
+    then the dense FFN or the expert layer, each on the normed stream and
+    added back to it; under `cfg.sandwich_norm` each sublayer's output is
+    normed once more before the add, under `cfg.scale_depth` scaled."""
 
     cfg: TransformerConfig
     use_moe: bool = False
-    ssm: bool = False
+    kind: str = "attention"   # `TransformerConfig.layer_kind`
 
     @nn.compact
     def __call__(self, x, train: bool = False, live=None, n_new=None):
@@ -1058,15 +1419,23 @@ class Block(nn.Module):
         drop = nn.Dropout(cfg.dropout, deterministic=not train)
 
         def post(name, y):
-            if not cfg.sandwich_norm:
-                return y
-            return ln(name=name)(y).astype(cfg.dtype)
+            if cfg.sandwich_norm:
+                y = ln(name=name)(y).astype(cfg.dtype)
+            if cfg.scale_depth:
+                y = y * jnp.asarray(cfg.scale_depth / (
+                    cfg.scale_depth_layers or cfg.n_layers) ** 0.5, y.dtype)
+            return y
 
-        if self.ssm:
-            mixed = Mamba(cfg, name="mamba")(ln(name="ln1")(x), live, n_new)
+        u = ln(name="ln1")(x)
+        if self.kind == "ssm":
+            mixed = Mamba(cfg, name="mamba")(u, live, n_new)
+        elif self.kind == "lightning-attn":
+            mixed = LightningAttention(cfg, name="lin")(u, live, n_new)
+        elif self.kind == "minicpm4":
+            mixed = SparseAttention(cfg, name="attn")(u, live)
         else:
             attn = (MLA if cfg.kv_lora_rank else Attention)(cfg, name="attn")
-            mixed = attn(ln(name="ln1")(x), live)
+            mixed = attn(u, live)
         x = x + drop(post("ln1_post", mixed))
         if self.use_moe:
             from ..parallel.moe import MoE
@@ -1221,9 +1590,11 @@ class TransformerLM(nn.Module):
         # pin the lookup output to the activation layout immediately: the
         # table's embed dim may be fsdp-sharded (ZeRO-3), and without the
         # constraint the gather output inherits that feature-dim sharding
+        rows = emb(tokens)
+        if cfg.scale_emb != 1.0:
+            rows = rows.astype(jnp.float32) * cfg.scale_emb
         x = logical_constraint(
-            emb(tokens).astype(jnp.float32 if cfg.fp32_activations
-                               else cfg.dtype),
+            rows.astype(jnp.float32 if cfg.fp32_activations else cfg.dtype),
             ("batch", "seq", "act_embed"), cfg.mesh,
         )
         if not cfg.rope and cfg.pos_table:  # rope: per layer, in Attention
@@ -1266,10 +1637,12 @@ class TransformerLM(nn.Module):
                 x = block_cls(cfg, name=f"block_{i}")(x, train, live)
             else:
                 block = block_cls(cfg, use_moe=cfg.layer_has_experts(i),
-                                  ssm=cfg.layer_is_ssm(i), name=f"block_{i}")
+                                  kind=cfg.layer_kind(i), name=f"block_{i}")
                 x = block(x, train, live, n_new)
         x = _norm(cfg, "ln_f")(x)
         hidden = x
+        if cfg.dim_model_base:
+            x = x * (cfg.dim_model_base / cfg.d_model)
         if cfg.head == "hidden":
             # deferred head: the streaming loss (lm_loss_chunked) consumes
             # hidden states + the head kernel directly.  Touch the head at

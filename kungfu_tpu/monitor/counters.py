@@ -172,6 +172,11 @@ METRIC_HELP: Dict[str, str] = {
     "kft_serve_scan_tokens_total":
         "Tokens the recurrent layers' scan walked, a layer: prefill (real "
         "tokens, not bucket padding) and decode (live slot-steps).",
+    "kft_serve_sparse_rows_total":
+        "Cache rows of the block-selected attention of the decode steps, a "
+        "layer and a KV head: written (what busy slots held), fetched (the "
+        "rows of the blocks chosen for them) and kernels (compressed keys "
+        "their selector scored).",
     "kft_boot_seconds":
         "Seconds of each boot phase of this process so far, on the job "
         "clock (spans of category boot, docs/observability.md Boot).",

@@ -47,6 +47,12 @@ definition and the off-TPU path; the Mosaic kernel is `kft_mla_decode_attn`,
 the same grid, prefetch and online softmax over [block, rank + rope]
 blocks.  `kernel_block` answers for a three-dimensional leaf as it does
 for a four-dimensional one.
+
+Attention over SELECTED blocks (models/transformer.py `SparseAttention`)
+is the third form, at the end of this file: `select_blocks` chooses, a
+query row and a KV head, which blocks of rows to read, and
+`kft_sparse_decode_attn` reads that visit list where `kft_decode_attn`
+reads one contiguous run (`live_blocks`).
 """
 from __future__ import annotations
 
@@ -422,3 +428,297 @@ def mla_decode_attention(q: jax.Array, cache: jax.Array, q_pos: jax.Array,
         return mla_decode_attention_reference(q, cache, q_pos, rank, scale)
     return _mla_attn_pallas(q, cache, q_pos, rank, scale, block,
                             compat.pallas_mode(interpret) == "interpret")
+
+
+# -- attention over selected blocks: a visit list a (slot, KV head) --------------------
+#
+# Block-selected sparse attention (models/transformer.py `SparseAttention`,
+# the `minicpm4` mixer): the position axis is cut into blocks of `block`
+# rows, and a query attends the rows at or before its own position of at
+# most `topk` blocks, chosen for each (query row, KV head) by the query's
+# scores against COMPRESSED keys (the mean of `2 x stride` keys, one every
+# `stride` rows).  The leaves are [B, max_len, Hkv x D] (K, V) and
+# [B, max_len / stride, Hkv x D] (the compressed keys): a KV head is a run
+# of D lanes of a row, so a block of one KV head's rows is a [block, D]
+# window of whole tiles whatever Hkv is (2 here: a [.., 2, D] plane has no
+# such window).
+#
+# `select_blocks` is the choice, in XLA; `sparse_decode_attention` reads the
+# chosen blocks for a decode step (the Mosaic kernel `kft_sparse_decode_attn`,
+# or `sparse_decode_attention_reference`, a gather and an einsum);
+# `sparse_prefill_attention` is the same choice for every row of a long call,
+# in chunks of query rows, as a dense product under the chosen blocks' mask.
+
+#: the block-selected kernel's name in a device trace
+#: (benchmark/layer_metrics/sparse_attn_*)
+SPARSE_KERNEL_NAME = "kft_sparse_decode_attn"
+#: chosen blocks of one grid step of that kernel: each is one [block, D]
+#: DMA a K and a V (16 KB at 64 rows of 128 bf16), so a step's fixed cost
+#: is shared by eight of them and its scores are one [G, 8 x block] matmul
+_SPARSE_BLOCKS_A_STEP = 8
+#: query rows of one chunk of `sparse_prefill_attention`: its scores are
+#: [H, 128, max_len] float32, 200 MB at 32 heads and 12,288 rows
+_SPARSE_QUERY_CHUNK = 128
+_FORCED = 1e30
+
+
+def visible_kernels(xp, q_pos, kernel_size: int, stride: int):
+    """How many compressed keys lie wholly at or before position `q_pos`
+    (key m covers rows stride x m .. stride x m + kernel_size - 1).  `xp`
+    is numpy on the host (the engine's count) and jax.numpy in the program."""
+    return xp.maximum((q_pos - kernel_size + 1) // stride + 1, 0)
+
+
+def block_scores(q, k_cmp, q_pos, *, block: int, stride: int,
+                 init_blocks: int, window: int):
+    """What a query row ranks the blocks by, a KV head: q [B, L, H, D],
+    k_cmp [B, Mk, Hkv x D] (compressed key m = the mean of rows
+    stride x m .. stride x (m + 2) - 1 of that KV head), q_pos [B, L] ->
+    float32 [B, L, Hkv, blocks]:
+
+        p^h     = softmax_m(q_h . kbar_m / sqrt(D)) over the compressed keys
+                  wholly at or before the query's position, a query head
+        P_m     = the sum of p^h_m over the query heads of the KV head
+        score_b = the largest P_m of the keys that overlap block b
+
+    with `_FORCED` for the blocks always read (the first `init_blocks` and
+    those that hold the `window` newest rows) and -1 for a block beyond
+    the query's own."""
+    B, L, H, D = q.shape
+    Mk = k_cmp.shape[1]
+    Hkv = k_cmp.shape[2] // D
+    ratio = block // stride
+    nb = Mk // ratio
+    kbar = k_cmp.reshape(B, Mk, Hkv, D)
+    s = jnp.einsum("blkgd,bmkd->blkgm",
+                   q.astype(kbar.dtype).reshape(B, L, Hkv, H // Hkv, D), kbar,
+                   preferred_element_type=jnp.float32) * (D ** -0.5)
+    seen = (jnp.arange(Mk) < visible_kernels(
+        jnp, q_pos, 2 * stride, stride)[..., None])[:, :, None, None]
+    p = jax.nn.softmax(jnp.where(seen, s, _MASKED), axis=-1) * seen
+    p = p.sum(axis=3)                                   # [B, L, Hkv, Mk]
+    # the keys that overlap block b: ratio x b - 1 (its second half lies in
+    # the block's first `stride` rows) .. ratio x b + ratio - 1
+    before = jnp.pad(p, ((0, 0),) * 3 + ((1, 0),))[..., :Mk]
+    score = jnp.maximum(p.reshape(B, L, Hkv, nb, ratio).max(-1),
+                        before.reshape(B, L, Hkv, nb, ratio)[..., 0])
+    at = (q_pos // block)[..., None, None]              # the query's block
+    b = jnp.arange(nb)
+    forced = jnp.logical_or(b < init_blocks, b > at - window // block)
+    return jnp.where(b <= at, jnp.where(forced, _FORCED, score), -1.0)
+
+
+def select_blocks(q, k_cmp, q_pos, *, topk: int, **scoring):
+    """The blocks each query row attends, a KV head (`block_scores`'
+    arguments) -> (ids [B, L, Hkv, K] int32, n [B, L, Hkv] int32), K =
+    min(topk, blocks): the first n[b, l, k] of ids[b, l, k] are the chosen
+    blocks (the forced ones first, then by falling score), the rest are not
+    to be read.  So: the forced blocks always, then by score, K in all;
+    every block at or before the query's while those are at most K."""
+    score = block_scores(q, k_cmp, q_pos, **scoring)
+    vals, ids = jax.lax.top_k(score, min(topk, score.shape[-1]))
+    return ids.astype(jnp.int32), (vals >= 0).sum(-1).astype(jnp.int32)
+
+
+def sparse_decode_attention_reference(q, cache_k, cache_v, ids, n, q_pos,
+                                      block: int):
+    """The definition: q [B, L, H, D] against the rows at or before
+    q_pos [B, L] of the blocks ids[b, l, k, :n[b, l, k]] of KV head k of
+    cache_k / cache_v [B, max_len, Hkv x D] -> float32 [B, L, H, D].  The
+    chosen blocks gathered, then `decode_attention_reference`'s arithmetic:
+    operands in the cache dtype, scores, softmax and accumulation float32."""
+    B, L, H, D = q.shape
+    max_len, Hkv = cache_k.shape[1], cache_k.shape[2] // D
+    K = ids.shape[-1]
+    pick = jnp.moveaxis(ids, 1, 2)[..., None, None]     # [B, Hkv, L, K, 1, 1]
+
+    def chosen(cache):                                  # [B, Hkv, L, K, block, D]
+        blocks = cache.reshape(B, max_len // block, block, Hkv, D)
+        return jnp.take_along_axis(
+            jnp.transpose(blocks, (0, 3, 1, 2, 4))[:, :, None], pick, axis=3)
+
+    k, v = chosen(cache_k), chosen(cache_v)
+    s = jnp.einsum("blkgd,bkljrd->bklgjr",
+                   q.astype(k.dtype).reshape(B, L, Hkv, H // Hkv, D), k,
+                   preferred_element_type=jnp.float32) * (D ** -0.5)
+    rows = pick[..., 0] * block + jnp.arange(block)     # [B, Hkv, L, K, block]
+    valid = jnp.logical_and(
+        rows <= q_pos[:, None, :, None, None],
+        (jnp.arange(K) < jnp.moveaxis(n, 1, 2)[..., None])[..., None])
+    s = jnp.where(valid[:, :, :, None], s, _MASKED)
+    p = jax.nn.softmax(s.reshape(s.shape[:4] + (-1,)), axis=-1)
+    o = jnp.einsum("bklgjr,bkljrd->blkgd",
+                   p.reshape(s.shape).astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(B, L, H, D)
+
+
+def sparse_kernel_takes(query_rows: int, head_dim: int, cache_dtype,
+                        interpret=None) -> bool:
+    """Whether `kft_sparse_decode_attn` takes this call here: one query row
+    a slot, K and V in bf16 or float32, a head of whole lane tiles."""
+    mode = compat.pallas_mode(interpret)
+    return (mode != "off" and query_rows == 1
+            and jnp.dtype(cache_dtype) in (jnp.dtype(jnp.bfloat16),
+                                           jnp.dtype(jnp.float32))
+            and (mode == "interpret" or head_dim % 128 == 0))
+
+
+def _sparse_attn_pallas(q, cache_k, cache_v, ids, n, q_pos, block: int,
+                        interpret: bool):
+    """q [B, H, D], ids [B, Hkv, K], n [B, Hkv], q_pos [B].  The grid walks
+    (slot, KV head, `_SPARSE_BLOCKS_A_STEP` of the listed blocks): the list
+    is scalar-prefetched and each of a step's blocks is an operand of its
+    own, whose index map reads its place in the list; a place past n repeats
+    block n - 1, so no new DMA is issued for it, and is not computed.  The
+    KV head's G = H / Hkv query heads are the rows of the tile: one
+    [G, blocks x block] matmul scores them all against rows each of them
+    attends (16 rows fill a bf16 tile; a tile a query head would hold one)."""
+    B, H, D = q.shape
+    Hkv, K = ids.shape[1], ids.shape[2]
+    G = H // Hkv
+    per = next(u for u in (_SPARSE_BLOCKS_A_STEP, 4, 2, 1) if K % u == 0)
+    width = per * block
+    scale = D ** -0.5
+
+    def kernel(ids_ref, n_ref, pos_ref, q_ref, *refs):
+        k_refs, v_refs = refs[:per], refs[per:2 * per]
+        o_ref, m_ref, l_ref, acc_ref = refs[2 * per:]
+        b, h, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+        @pl.when(j == 0)
+        def _():
+            m_ref[...] = jnp.full_like(m_ref, _MASKED)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        @pl.when(j * per < n_ref[b, h])
+        def _():
+            # the cache row under each lane of the scores and each sublane
+            # of V; a place past the list's end holds no row (-1 > no cursor)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+            sub = jax.lax.broadcasted_iota(jnp.int32, (width, 1), 0)
+            row_l = jnp.full((1, width), jnp.iinfo(jnp.int32).max, jnp.int32)
+            row_s = jnp.full((width, 1), jnp.iinfo(jnp.int32).max, jnp.int32)
+            for u in range(per):
+                place = j * per + u
+                first = jnp.where(place < n_ref[b, h],
+                                  ids_ref[b, h * K + place] * block,
+                                  jnp.iinfo(jnp.int32).max - width)
+                row_l = jnp.where(lane // block == u, first + lane % block, row_l)
+                row_s = jnp.where(sub // block == u, first + sub % block, row_s)
+            t = pos_ref[b]
+            valid = row_l <= t
+            k = jnp.concatenate([r[...] for r in k_refs], axis=0)
+            v = jnp.concatenate([r[...] for r in v_refs], axis=0)
+            # rows no query attends meet a probability of 0, and 0 x NaN is
+            # NaN: whatever lies beyond the cursor reads as 0
+            v = jnp.where(row_s <= t, v, jnp.zeros_like(v))
+            s = jax.lax.dot_general(
+                q_ref[...], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid, s, _MASKED)
+            m_prev = m_ref[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_ref[...] = m_new
+
+        @pl.when(j == pl.num_programs(2) - 1)
+        def _():
+            norm = l_ref[...]
+            o_ref[...] = acc_ref[...] / jnp.where(norm == 0.0, 1.0, norm)
+
+    def listed(u):
+        def index(b, h, j, ids, n, pos):
+            place = jnp.minimum(j * per + u, n[b, h] - 1)
+            return b, ids[b, h * K + place], h
+        return pl.BlockSpec((None, block, D), index)
+
+    def group(b, h, j, ids, n, pos):
+        return b, h, 0, 0
+
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, Hkv, K // per),
+            in_specs=[pl.BlockSpec((None, None, G, D), group)]
+            + [listed(u) for u in range(per)] * 2,
+            out_specs=pl.BlockSpec((None, None, G, D), group),
+            scratch_shapes=[pltpu.VMEM((G, 1), jnp.float32),
+                            pltpu.VMEM((G, 1), jnp.float32),
+                            pltpu.VMEM((G, D), jnp.float32)],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=compat.vmem_budget_bytes()),
+        interpret=interpret,
+        name=SPARSE_KERNEL_NAME,
+    )(ids.reshape(B, Hkv * K), n, q_pos.astype(jnp.int32),
+      q.astype(cache_k.dtype).reshape(B, Hkv, G, D),
+      *([cache_k] * per), *([cache_v] * per))
+    return out.reshape(B, H, D)
+
+
+def sparse_decode_attention(q, cache_k, cache_v, ids, n, q_pos, block: int,
+                            interpret=None) -> jax.Array:
+    """q [B, L, H, D] against the listed blocks of the slot cache
+    [B, max_len, Hkv x D] -> float32 [B, L, H, D]: the kernel where
+    `sparse_kernel_takes` says so, the gather and einsum elsewhere."""
+    if not sparse_kernel_takes(q.shape[1], q.shape[3], cache_k.dtype, interpret):
+        return sparse_decode_attention_reference(
+            q, cache_k, cache_v, ids, n, q_pos, block)
+    return _sparse_attn_pallas(
+        q[:, 0], cache_k, cache_v, ids[:, 0], n[:, 0], q_pos[:, 0], block,
+        compat.pallas_mode(interpret) == "interpret")[:, None]
+
+
+def sparse_prefill_attention(q, k, v, k_cmp, q_pos, *, block: int, **choice):
+    """Every row of a long call under its own choice of blocks:
+    q [B, L, H, D] at q_pos [B, L] against k, v [B, M, Hkv x D] and
+    k_cmp [B, M / stride, Hkv x D] -> float32 [B, L, H, D].  In chunks of
+    `_SPARSE_QUERY_CHUNK` query rows, one after another (`lax.map`): a chunk
+    chooses its blocks (`select_blocks`, with `choice`), spreads the choice
+    to a [chunk, M] mask a KV head and takes the dense masked product, so no
+    [L, M] tensor exists whole.  M x chunk x H scores are computed where
+    topk x block x chunk x H are needed: sound while M is a few times
+    topk x block (3 at 12,288 rows), and a kernel's work beyond that."""
+    B, L, H, D = q.shape
+    M, Hkv = k.shape[1], k.shape[2] // D
+    nb = M // block
+    chunk = min(_SPARSE_QUERY_CHUNK, L)
+    pad = -L % chunk
+    q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    q_pos = jnp.pad(q_pos, ((0, 0), (0, pad)))
+    keys, values = k.reshape(B, M, Hkv, D), v.reshape(B, M, Hkv, D)
+
+    def one(args):
+        q_c, pos_c = args                           # [B, chunk, H, D], [B, chunk]
+        with jax.named_scope("sparse.select"):
+            ids, n = select_blocks(q_c, k_cmp, pos_c, block=block, **choice)
+            listed = jnp.arange(ids.shape[-1]) < n[..., None]
+            hit = jnp.logical_and(ids[..., None] == jnp.arange(nb),
+                                  listed[..., None]).any(-2)    # [B, c, Hkv, nb]
+        with jax.named_scope("sparse.attend"):
+            valid = jnp.logical_and(
+                jnp.repeat(hit, block, axis=-1),
+                (jnp.arange(M) <= pos_c[..., None])[:, :, None])
+            s = jnp.einsum(
+                "blkgd,bmkd->bkglm",
+                q_c.astype(keys.dtype).reshape(B, chunk, Hkv, H // Hkv, D), keys,
+                preferred_element_type=jnp.float32) * (D ** -0.5)
+            valid = jnp.moveaxis(valid, 2, 1)[:, :, None]   # [B, Hkv, 1, c, M]
+            p = jax.nn.softmax(jnp.where(valid, s, _MASKED), axis=-1)
+            return jnp.einsum("bkglm,bmkd->blkgd", p.astype(values.dtype),
+                              values, preferred_element_type=jnp.float32
+                              ).reshape(B, chunk, H, D)
+
+    chunks = lambda t: jnp.moveaxis(                      # noqa: E731
+        t.reshape((B, (L + pad) // chunk, chunk) + t.shape[2:]), 1, 0)
+    o = jax.lax.map(one, (chunks(q), chunks(q_pos)))
+    return jnp.moveaxis(o, 0, 1).reshape(B, L + pad, H, D)[:, :L]

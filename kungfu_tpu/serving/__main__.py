@@ -259,8 +259,11 @@ def main(argv=None) -> int:
             ap.error(f"-np {args.np} one-chip workers need more than "
                      f"--chips-per-host {args.chips_per_host}")
         args.max_size = min(args.max_size, args.chips_per_host)
-    if args.model_json and json.loads(args.model_json).get("mamba_d_state"):
-        # the worker refuses these too (worker.py), but a worker that dies
+    model = json.loads(args.model_json) if args.model_json else {}
+    if model.get("mamba_d_state") or model.get("mixer_types"):
+        # every kind a list may name keeps state (models/transformer.py
+        # LISTED_MIXERS; this parent loads no jax, so it asks the JSON).
+        # The worker refuses these too (worker.py), but a worker that dies
         # at boot is respawned: say it once, here, before any is spawned
         asked = [flag for flag, on in (
             ("--prefix-cache on", args.prefix_cache == "on"),
@@ -268,7 +271,8 @@ def main(argv=None) -> int:
             ("--prefill-ranks", args.prefill_ranks > 0)) if on]
         if asked:
             ap.error(f"{', '.join(asked)}: not with a model that keeps "
-                     "recurrent state (mamba_d_state > 0): prefix reuse, "
+                     "recurrent state (mamba_d_state > 0, or a list of "
+                     "mixer_types): prefix reuse, "
                      "speculation and shipped prefills cut or roll back a "
                      "cache by position, and a state has none (ROADMAP R6)")
     if args.telemetry:

@@ -85,6 +85,11 @@ back is refused when the engine is made (`prefix_cache`, `spec`) or called
 (`submit_prefilled`, `prefill_only`), with the reason; a preempted request
 resumes through a cold prefill of its folded tokens.  `cache_bytes` says
 what the cache holds by kind, `scan_tokens` how many tokens the scan walked.
+Linear-attention layers ("lightning-attn" in `cfg.mixer_types`) are the
+same to the loop: a matrix state a slot, counted by `scan_tokens` too.
+Block-selected layers ("minicpm4") keep compressed keys at a stride of
+their own beside their rows (slots.py `STRIDED_LEAVES`), refused the same
+things; `sparse_rows` says what their decode steps held, fetched and scored.
 
 The per-slot cache cursors this relies on live in models/transformer.py
 (decode mode).  The int8 KV-cache storage dtype comes straight from the
@@ -116,7 +121,7 @@ from ..models.transformer import (
     resident_params,
 )
 from ..monitor.journal import journal_event
-from ..ops.decode_attn import kernel_block, live_blocks
+from ..ops.decode_attn import kernel_block, live_blocks, visible_kernels
 from ..utils import get_logger
 from ..utils.trace import (
     BOOT_CAT,
@@ -262,13 +267,17 @@ class ServingEngine:
         self._stateful = has_state(self.cache)
         if self._stateful and (prefix_cache is not None or spec is not None):
             raise ValueError(
-                "a model with recurrent state serves with no prefix cache "
-                "and no speculation: a state is the summary of every token "
-                "so far, so a prefix hit would need a snapshot of it at the "
-                "hit length and a rejected draft a way to roll it back "
-                "(serving/slots.py STATE_LEAVES)")
+                "a model with recurrent state, or keys compressed at a "
+                "stride of their own, serves with no prefix cache and no "
+                "speculation: a state is the summary of every token so far, "
+                "so a prefix hit would need a snapshot of it at the hit "
+                "length and a rejected draft a way to roll it back "
+                "(serving/slots.py STATE_LEAVES, STRIDED_LEAVES)")
         # tokens the recurrent layers' scan walked, a layer (`scan_tokens`)
         self._scan_tokens = {"prefill": 0, "decode": 0}
+        # rows of the block-selected layers' decode steps (`sparse_rows`)
+        self._sparse = self.dcfg.has_mixer("minicpm4")
+        self._sparse_rows = {"written": 0, "fetched": 0, "kernels": 0}
         self._param_shardings = None
         if mesh is not None:
             from ..parallel.sharding import decode_cache_shardings, param_shardings
@@ -314,8 +323,10 @@ class ServingEngine:
                     jax.tree_util.tree_leaves_with_path(self.cache)
                     if getattr(path[-1], "key", None)
                     in ("cached_k", "cached_latent"))
+        # (block-selected layers read their rows through a kernel of their
+        # own, counted by `sparse_rows`: `kft_decode_attn` is not what runs)
         self._attn_block = {
-            rows: (None if self.dcfg.attention == "full"
+            rows: (None if self.dcfg.attention == "full" or self._sparse
                    else kernel_block(rows, leaf.shape, leaf.dtype))
             or self.dcfg.max_len
             for rows in {1, spec.k if spec is not None else 1}}
@@ -1145,6 +1156,18 @@ class ServingEngine:
             "free": self._decode_rows["free"] + self.n_slots - n_live}
         if self._stateful:
             self._count_scan("decode", n_live * query_rows)
+        if self._sparse:
+            # a live slot's query at `before` holds before + 1 rows, reads
+            # the rows of at most topk of its blocks and scores the
+            # compressed keys that lie wholly at or before it
+            cfg, at = self.dcfg, before[live]
+            blocks = np.minimum(at // cfg.sparse_block_size + 1,
+                                cfg.sparse_topk)
+            add = ((at + 1).sum(), blocks.sum() * cfg.sparse_block_size,
+                   visible_kernels(np, at, 2 * cfg.sparse_kernel_stride,
+                                   cfg.sparse_kernel_stride).sum())
+            self._sparse_rows = {kind: n + int(a) for (kind, n), a
+                                 in zip(self._sparse_rows.items(), add)}
 
     def _count_scan(self, kind: str, tokens: int) -> None:
         self._scan_tokens = {**self._scan_tokens,
@@ -1156,6 +1179,18 @@ class ServingEngine:
         buckets), `decode` the live slot-steps of the decode steps.  Zeros
         for a model without such layers."""
         return dict(self._scan_tokens)
+
+    def sparse_rows(self) -> Dict[str, int]:
+        """Cache rows of the block-selected layers' attention, summed over
+        the decode steps so far, a layer and a KV head: `written` the rows
+        live slots held (their query's position + 1), `fetched` the rows
+        of the blocks chosen for them (every block at or before the query
+        while those are at most `sparse_topk`, that many after:
+        `fetched` = `written` rounded up to blocks says no selection),
+        `kernels` the compressed keys their selector scored.  From the
+        cursors, as `select_blocks` counts; zeros for a model without such
+        layers."""
+        return dict(self._sparse_rows)
 
     def decode_attn_rows(self) -> Dict[str, int]:
         """Cache rows of the decode-step attention, summed over the decode
@@ -1229,6 +1264,8 @@ class ServingEngine:
         }
         if self._stateful:
             out["scan_tokens"] = self.scan_tokens()
+        if self._sparse:
+            out["sparse_rows"] = self.sparse_rows()
         if self.prefix is not None:
             out["prefix"] = self.prefix.stats()
         if self.spec is not None:

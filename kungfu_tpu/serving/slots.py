@@ -33,7 +33,8 @@ the one `[.., rank + rope]` latent row of a latent-attention sublayer.
 
 STATE leaves (`STATE_LEAVES`) are the third kind: what a recurrent layer
 keeps for a slot (models/transformer.py `Mamba`: `ssm_state`
-[slots, N, d_inner] and `conv_state` [slots, K - 1, d_inner]), with a slot
+[slots, N, d_inner] and `conv_state` [slots, K - 1, d_inner];
+`LightningAttention`: `lin_state` [slots, heads, e, e]), with a slot
 axis and NO position axis.  `write_slot` replaces a slot whole, state
 included, so an admission replaces the last tenant's state; `reset_slot`
 leaves it where it is, as it leaves rows (a free slot's row is not live and
@@ -43,6 +44,16 @@ by position or move cursors alone (`extract_rows`, `extract_slot_rows`,
 `warm_small_cache`, `set_cursors`) raise on a tree that holds one, and the
 engine refuses what is built on them (prefix reuse, speculation, shipped
 KV) when it is made.
+
+STRIDED leaves (`STRIDED_LEAVES`) are indexed by position at a stride of
+their own (models/transformer.py `SparseAttention`: `k_cmp`
+[slots, max_len / stride, Hkv x D], entry m a summary of rows
+stride x m .. stride x (m + 2) - 1).  They are rows to the allocator
+(`write_slot` moves them with the slot, `reset_slot` leaves them, entries
+over rows above a cursor are never read, `cache_bytes` counts them as
+`rows`), but the row helpers slice every leaf at ONE length: they raise on
+a tree that holds one as they do for a state, and the engine refuses the
+same things, until a helper knows the stride.
 """
 from __future__ import annotations
 
@@ -58,7 +69,9 @@ from .request import Request
 
 CURSOR_LEAVES = ("idx", "overflowed")
 #: leaves a recurrent layer keeps a slot: no position axis
-STATE_LEAVES = ("ssm_state", "conv_state")
+STATE_LEAVES = ("ssm_state", "conv_state", "lin_state")
+#: leaves indexed by position at a stride of their own
+STRIDED_LEAVES = ("k_cmp",)
 
 
 def _leaf_name(path) -> Optional[str]:
@@ -66,8 +79,9 @@ def _leaf_name(path) -> Optional[str]:
 
 
 def has_state(cache) -> bool:
-    """Whether the cache tree holds a recurrent layer's state."""
-    return any(_leaf_name(path) in STATE_LEAVES
+    """Whether the cache tree holds what the row helpers cannot cut at a
+    position: a recurrent layer's state, or a leaf at a stride of its own."""
+    return any(_leaf_name(path) in STATE_LEAVES + STRIDED_LEAVES
                for path, _ in jax.tree_util.tree_flatten_with_path(cache)[0])
 
 
@@ -84,9 +98,10 @@ def cache_bytes(cache) -> Dict[str, int]:
 def _rows_only(cache, what: str) -> None:
     if has_state(cache):
         raise ValueError(
-            f"{what}: the cache holds recurrent state (no position axis); a "
-            "state is the summary of every token so far and cannot be cut "
-            "at a position or rolled back by a cursor")
+            f"{what}: the cache holds recurrent state (no position axis) or "
+            "keys compressed at a stride of their own; a state is the "
+            "summary of every token so far and cannot be cut at a position "
+            "or rolled back by a cursor, and no helper slices a strided leaf")
 
 
 @partial(jax.jit, donate_argnums=(0,))

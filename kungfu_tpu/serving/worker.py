@@ -171,7 +171,7 @@ class ServingWorker:
         # position cuts: nothing built on rows alone is armed for it, and
         # asking for it ends the boot here, before any weight is made
         # (serving/slots.py STATE_LEAVES; the engine refuses them too)
-        stateful = cfg.mamba_d_state > 0
+        stateful = cfg.keeps_state
         asked = [flag for flag, on in (
             ("--prefix-cache on", args.prefix_cache == "on"),
             ("--spec-draft", bool(getattr(args, "spec_draft", ""))),
@@ -179,7 +179,8 @@ class ServingWorker:
         if stateful and asked:
             raise SystemExit(
                 f"{', '.join(asked)}: not with a model that keeps recurrent "
-                "state (mamba_d_state > 0): a prefix hit needs a snapshot of "
+                "state (state-space, lightning or block-selected layers): a "
+                "prefix hit needs a snapshot of "
                 "the state at the hit length, a rejected draft a way to roll "
                 "it back, a shipped prefill rows to ship; none exists yet "
                 "(ROADMAP R6)")
@@ -273,7 +274,9 @@ class ServingWorker:
                     ("kft_serve_cache_bytes",
                      lambda: self.engine.cache_bytes),
                     ("kft_serve_scan_tokens_total",
-                     self.engine.scan_tokens))})
+                     self.engine.scan_tokens),
+                    ("kft_serve_sparse_rows_total",
+                     self.engine.sparse_rows))})
         self.decode_pool = None
         if self.tier == "prefill" and args.config_server:
             from ..elastic.config_client import ConfigClient

@@ -20,7 +20,8 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax.sharding import Mesh
 
-from kungfu_tpu.models.transformer import TransformerConfig, TransformerLM
+from kungfu_tpu.models.transformer import (TransformerConfig, TransformerLM,
+                                           _compressed_keys)
 from kungfu_tpu.ops import (decode_attention, decode_attention_reference,
                             mla_decode_attention_reference)
 from kungfu_tpu.ops import decode_attn as da
@@ -488,3 +489,187 @@ def test_the_latent_decode_program_reads_the_donated_cache_where_it_lies(
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= cache_bytes
     assert mem.temp_size_in_bytes < cache_bytes // 4
+
+
+# -- attention over selected blocks (`kft_sparse_decode_attn`) -------------------------
+
+S_BLOCK, S_STRIDE, S_TOPK, S_LEN = 8, 2, 6, 128
+S_CHOICE = dict(block=S_BLOCK, stride=S_STRIDE, topk=S_TOPK, init_blocks=1,
+                window=2 * S_BLOCK)
+
+
+def _sparse_case(B, L, H, Hkv, D, dtype, cursors, seed=0):
+    """(q, K, V, compressed keys, positions): rows [B, S_LEN, Hkv x D] with
+    NaN in K and V beyond each slot's last position."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = (3 * jax.random.normal(ks[0], (B, L, H, D), jnp.float32)).astype(dtype)
+    ck = jax.random.normal(ks[1], (B, S_LEN, Hkv * D), jnp.float32).astype(dtype)
+    cv = jax.random.normal(ks[2], (B, S_LEN, Hkv * D), jnp.float32).astype(dtype)
+    kc = _compressed_keys(ck, S_STRIDE).astype(dtype)
+    idx0 = jnp.minimum(jnp.asarray(cursors, jnp.int32), S_LEN - L)
+    pos = idx0[:, None] + jnp.arange(L)[None, :]
+    dead = (jnp.arange(S_LEN)[None, :] > pos[:, -1:])[:, :, None]
+    return (q, jnp.where(dead, jnp.nan, ck), jnp.where(dead, jnp.nan, cv), kc,
+            pos, ck, cv)
+
+
+def _plain(q, ck, cv, pos, Hkv, D):
+    B = q.shape[0]
+    return decode_attention_reference(
+        q, ck.reshape(B, S_LEN, Hkv, D), cv.reshape(B, S_LEN, Hkv, D), pos)
+
+
+def test_the_selector_forces_the_first_block_and_the_window_then_ranks():
+    q, _, _, kc, pos, _, _ = _sparse_case(3, 1, 8, 2, 16, jnp.float32,
+                                          [100, 37, 0])
+    ids, n = da.select_blocks(q, kc, pos, **S_CHOICE)
+    assert ids.shape == (3, 1, 2, S_TOPK) and n.shape == (3, 1, 2)
+    ids, n = np.asarray(ids), np.asarray(n)
+    # position 100 is in block 12: blocks 0, 11, 12 always, three by score
+    assert n[0].tolist() == [[S_TOPK, S_TOPK]]
+    for k in range(2):
+        assert set(ids[0, 0, k, :3]) == {0, 11, 12}
+        assert all(0 < b < 11 for b in ids[0, 0, k, 3:])
+        assert len(set(ids[0, 0, k])) == S_TOPK
+    # position 37 is in block 4: five blocks reachable, every one chosen
+    assert n[1].tolist() == [[5, 5]]
+    assert all(set(ids[1, 0, k, :5]) == set(range(5)) for k in range(2))
+    # a slot at position 0 reads block 0 alone
+    assert n[2].tolist() == [[1, 1]] and (ids[2, 0, :, 0] == 0).all()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("cursors", [[100, 37, 0, 127], [127] * 4, [0] * 4,
+                                     [47, 48, 49, 7]],
+                         ids=["mix", "last_row", "zero", "block_edges"])
+def test_sparse_kernel_matches_the_gather_over_ragged_cursors(cursors, dtype):
+    q, ck, cv, kc, pos, ck0, cv0 = _sparse_case(4, 1, 8, 2, 128, dtype, cursors)
+    ids, n = da.select_blocks(q, kc, pos, **S_CHOICE)
+    want = da.sparse_decode_attention_reference(q, ck0, cv0, ids, n, pos, S_BLOCK)
+    got = da.sparse_decode_attention(q, ck, cv, ids, n, pos, S_BLOCK,
+                                     interpret=True)
+    assert got.shape == (4, 1, 8, 128) and got.dtype == jnp.float32
+    assert np.isfinite(np.asarray(got)).all()
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=tol, atol=tol)
+
+
+def test_sparse_reference_takes_a_verify_steps_rows_each_with_its_own_list():
+    q, _, _, kc, pos, ck, cv = _sparse_case(2, 4, 8, 2, 16, jnp.float32, [90, 30])
+    ids, n = da.select_blocks(q, kc, pos, **S_CHOICE)
+    whole = da.sparse_decode_attention(q, ck, cv, ids, n, pos, S_BLOCK,
+                                       interpret=True)   # 4 rows: the gather
+    for l in range(4):
+        one = da.sparse_decode_attention_reference(
+            q[:, l:l + 1], ck, cv, ids[:, l:l + 1], n[:, l:l + 1],
+            pos[:, l:l + 1], S_BLOCK)
+        np.testing.assert_allclose(np.asarray(whole[:, l]), np.asarray(one[:, 0]),
+                                   rtol=1e-6, atol=1e-6)
+
+
+def test_at_most_topk_blocks_reachable_is_plain_attention():
+    """Rows 0 .. topk x block - 1 attend every block at or before them: the
+    prefill form there equals itself with nothing to drop bit for bit, and
+    the plain causal einsum to rounding; later rows differ from it."""
+    B, L, H, Hkv, D = 2, 112, 8, 2, 16
+    q, _, _, kc, _, ck, cv = _sparse_case(B, L, H, Hkv, D, jnp.float32, [0, 0])
+    pos = jnp.broadcast_to(jnp.arange(L)[None], (B, L))
+    got = da.sparse_prefill_attention(q, ck, cv, kc, pos, **S_CHOICE)
+    every = da.sparse_prefill_attention(
+        q, ck, cv, kc, pos, **dict(S_CHOICE, topk=S_LEN // S_BLOCK))
+    plain = _plain(q, ck, cv, pos, Hkv, D)
+    reach = S_TOPK * S_BLOCK
+    np.testing.assert_array_equal(np.asarray(got[:, :reach]),
+                                  np.asarray(every[:, :reach]))
+    np.testing.assert_allclose(np.asarray(got[:, :reach]),
+                               np.asarray(plain[:, :reach]), rtol=2e-6, atol=2e-6)
+    assert float(jnp.abs(got[:, reach:] - plain[:, reach:]).max()) > 0.1
+
+
+def test_the_prefill_form_is_the_decode_form_row_by_row():
+    B, L, H, Hkv, D = 2, 120, 8, 2, 16
+    q, _, _, kc, _, ck, cv = _sparse_case(B, L, H, Hkv, D, jnp.float32, [0, 0])
+    pos = jnp.broadcast_to(jnp.arange(L)[None], (B, L))
+    got = da.sparse_prefill_attention(q, ck, cv, kc, pos, **S_CHOICE)
+    ids, n = da.select_blocks(q, kc, pos, **S_CHOICE)
+    want = da.sparse_decode_attention_reference(q, ck, cv, ids, n, pos, S_BLOCK)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_sparse_selection_from_what_a_call_shows():
+    takes = da.sparse_kernel_takes
+    assert takes(1, 128, jnp.bfloat16, interpret=True)
+    assert takes(1, 128, jnp.float32, interpret=False)
+    assert not takes(4, 128, jnp.bfloat16, interpret=True)    # a verify step
+    assert not takes(1, 64, jnp.bfloat16, interpret=False)    # half a lane tile
+    assert takes(1, 64, jnp.bfloat16, interpret=True)
+    assert not takes(1, 128, jnp.int8, interpret=True)
+    assert not takes(1, 128, jnp.bfloat16)                    # no kernels here
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_sparse_kernel_lowers_for_tpu_at_the_cells_shape(dtype):
+    """`jax.export` runs the Pallas -> Mosaic lowering on this host: 16
+    slots, 32 query heads on 2 KV heads of 128, 64 blocks of 64 of 12,288
+    rows."""
+    S = jax.ShapeDtypeStruct
+    rows = S((16, 12288, 256), dtype)
+
+    def f(q, k, v, ids, n, pos):
+        return da.sparse_decode_attention(q, k, v, ids, n, pos, 64,
+                                          interpret=False)
+
+    exp = jax.export.export(jax.jit(f), platforms=["tpu"])(
+        S((16, 1, 32, 128), dtype), rows, rows, S((16, 1, 2, 64), jnp.int32),
+        S((16, 1, 2), jnp.int32), S((16, 1), jnp.int32))
+    assert da.SPARSE_KERNEL_NAME in exp.mlir_module()
+
+
+def test_the_sala_decode_program_reads_the_donated_cache_where_it_lies(
+        v5e_chip, monkeypatch):
+    """`ServingEngine._decode` of a block-selected layer and a lightning
+    layer at the published widths, compiled for the chip: one
+    `kft_sparse_decode_attn` and one `kft_lightning_attn`, no
+    `kft_decode_attn`, no copy, transpose or convert of a whole K, V or
+    state leaf ahead of them, and the donated cache aliases the output."""
+    from kungfu_tpu import compat
+    from kungfu_tpu.ops.lightning_attn import KERNEL_NAME as LIGHTNING
+    from kungfu_tpu.serving import ServingEngine
+
+    monkeypatch.setattr(compat, "pallas_mode", lambda interpret=None: "compiled")
+    slots = 16
+    cfg = TransformerConfig(
+        vocab_size=512, d_model=4096, n_layers=2, n_heads=32, n_kv_heads=2,
+        d_ff=256, max_len=12288, rope=True, attn_use_rope=False, norm="rms",
+        ffn="swiglu", dtype=jnp.bfloat16,
+        mixer_types=["minicpm4", "lightning-attn"], scale_emb=12.0,
+        scale_depth=1.4, scale_depth_layers=32, dim_model_base=256)
+    described = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip), tree)
+    params = described(nn.meta.unbox(jax.eval_shape(
+        TransformerLM(cfg).init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 1), jnp.int32))["params"]))
+    eng = ServingEngine(cfg, params, slots=slots)
+    compiled = eng._decode.lower(
+        described(eng.params), described(eng.cache),
+        described(eng._dev_counters),
+        jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=v5e_chip),
+        described(eng._no_prev)).compile()
+    text = compiled.as_text()
+    calls = lambda kernel: re.findall(  # noqa: E731
+        rf" custom-call\([^\n]*{kernel}", text)   # (o, state): a tuple's type
+    assert len(calls(da.SPARSE_KERNEL_NAME)) == 1
+    assert len(calls(LIGHTNING)) == 1
+    assert calls("kft_decode_attn") == []
+    assert _whole_leaves_moved(text, "16,12288,256") == []
+    assert _whole_leaves_moved(text, "16,32,128,128") == []
+    cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(eng.cache))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    # the compiler's own staging of weights (two 4096 x 4096 kernels in
+    # flight) is all: less than ONE K leaf
+    assert mem.temp_size_in_bytes < slots * 12288 * 256 * 2

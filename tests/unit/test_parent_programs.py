@@ -36,6 +36,15 @@ leaf, one `dynamic_update_slice` at batch 1) where a vmapped
 `dynamic_update_slice` wrote them.  The decode, verify and prefill digests
 are of PR 44's own lowering; the three training steps are the digests they
 were (training never enters decode mode).
+
+ISSUE 47 adds the "sala" shape (a block-selected layer beside a lightning
+linear-attention layer, from `mixer_types`, under the MiniCPM family's three
+scale constants; a matrix state and compressed keys in the slot cache) so
+that the next PR is held to its programs too: `_decode`, a `_prefill`
+bucket and a training step, through the einsum / `lax.scan` forms and
+through the two kernels' bodies.  Their digests are of PR 47's own
+lowering; like the hybrid it has no verify program.  Every digest above it
+is the one it was: the new fields default to what was there.
 """
 import dataclasses
 import hashlib
@@ -87,6 +96,16 @@ GOLDEN = {
         "1ad15325891f75f2c0c3de60a98cf17de03d3fccbe78689d2aec78b74c700d01",
     "hybrid.train_step.off":
         "a25b9f333846a5147707ded4446f86ccb38868497edd35f7b302ffbb16f5d75f",
+    "sala.decode.interpret":
+        "2500f4e269c92816d1483ec99cf2854f8d2771aca81b671df807daaccaad0352",
+    "sala.decode.off":
+        "b5d0acea40ddc98ef1491b54e1efc428d532b47e3c56b3c65daf30cc3e053be5",
+    "sala.prefill.interpret":
+        "1496aad2c5450ad2cfd1048215e4dbc08f2724b1486c4010101d311039db5cb2",
+    "sala.prefill.off":
+        "0ec3d8dad8945143c6a40b58928889468caeabd3ea31b67c72d44f08afa95d27",
+    "sala.train_step.off":
+        "cc0771dbf157ee59a31e6aa33909c3220e366631f7845c24de8087e7157a4529",
 }
 
 SLOTS, K = 4, 3
@@ -109,6 +128,16 @@ def _config(shape: str):
             base, rope=False, pos_table=False, tie_embeddings=True,
             n_kv_heads=1, mamba_d_state=16, mamba_dt_rank=64,
             attn_layer_period=2, attn_layer_offset=1))
+    if shape == "sala":
+        # layer 0 block-selected (8 query heads on 2 KV heads, blocks of 8
+        # rows, 4 of 8 read), layer 1 lightning (8 heads of 128: one block
+        # of heads of its kernel)
+        return TransformerConfig(**dict(
+            base, n_kv_heads=2, attn_use_rope=False,
+            mixer_types=("minicpm4", "lightning-attn"), sparse_block_size=8,
+            sparse_topk=4, sparse_kernel_stride=2,
+            sparse_window_size=16, scale_emb=12.0, scale_depth=1.4,
+            scale_depth_layers=32, dim_model_base=256))
     return TransformerConfig(qk_norm=True, n_experts=4, experts_per_token=2,
                              moe_every=1, embed_init_std=1.0, **base)
 
@@ -165,10 +194,10 @@ def _train_step(shape):
 
 PROGRAMS = {
     f"{shape}.{name}.{mode}": (mode, lower, shape)
-    for shape in ("dense", "experts", "hybrid")
+    for shape in ("dense", "experts", "hybrid", "sala")
     for name, lower in (("decode", _decode), ("verify", _verify),
                         ("prefill", _prefill), ("train_step", _train_step))
-    if (shape, name) != ("hybrid", "verify")
+    if (shape, name) not in (("hybrid", "verify"), ("sala", "verify"))
     # the Pallas bodies are what a TPU runs: the decode attention kernel,
     # the grouped matmul.  The training step has no kernel of its own here
     for mode in (("off",) if name == "train_step" else ("off", "interpret"))
