@@ -35,6 +35,7 @@ from ..ops.decode_attn import (
     select_blocks,
     sparse_decode_attention,
     sparse_prefill_attention,
+    sparse_prefill_attention_reference,
 )
 from ..parallel.sharding import logical_constraint
 from jax.sharding import Mesh, PartitionSpec as P
@@ -1254,11 +1255,15 @@ class SparseAttention(nn.Module):
     overflow poisoning, a call of L tokens = L chained calls).  A decode
     step (one row a slot) reads the chosen blocks
     (`sparse_decode_attention`: the kernel); a prefill bucket (more than
-    `MAX_QUERY_ROWS` rows) and training mode choose for every row in chunks
-    of query rows (`sparse_prefill_attention`).  A verify step's 2 to
-    `MAX_QUERY_ROWS` rows are refused: the engine serves this model no
-    speculation.  Training mode keeps no cache and compresses the call's
-    own keys."""
+    `MAX_QUERY_ROWS` rows) chooses for every row in chunks of query rows
+    and attends all rows in one kernel that keeps its scores in VMEM and
+    stops at a query tile's last position (`sparse_prefill_attention`:
+    `kft_sparse_prefill_attn` where Pallas runs, the XLA chunks elsewhere).
+    A verify step's 2 to `MAX_QUERY_ROWS` rows are refused: the engine
+    serves this model no speculation.  Training mode keeps no cache,
+    compresses the call's own keys and takes the XLA chunks
+    (`sparse_prefill_attention_reference`): it needs a gradient, which the
+    kernel does not give."""
 
     cfg: TransformerConfig
 
@@ -1309,7 +1314,7 @@ class SparseAttention(nn.Module):
             if not cfg.decode:
                 pad = ((0, 0), (0, -L % block), (0, 0))
                 k, v = jnp.pad(k, pad), jnp.pad(v, pad)
-                o = sparse_prefill_attention(
+                o = sparse_prefill_attention_reference(
                     q, k, v, _compressed_keys(k, stride).astype(cfg.dtype),
                     pos, **choice)
             else:

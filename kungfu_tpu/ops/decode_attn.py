@@ -52,11 +52,15 @@ Attention over SELECTED blocks (models/transformer.py `SparseAttention`)
 is the third form, at the end of this file: `select_blocks` chooses, a
 query row and a KV head, which blocks of rows to read, and
 `kft_sparse_decode_attn` reads that visit list where `kft_decode_attn`
-reads one contiguous run (`live_blocks`).
+reads one contiguous run (`live_blocks`).  A prefill bucket's rows each
+choose too, and `kft_sparse_prefill_attn` attends them all under the
+choices' bitmap, flash-style: scores in VMEM, key tiles up to a query
+tile's last position.
 """
 from __future__ import annotations
 
-from typing import Optional
+import functools
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -447,7 +451,10 @@ def mla_decode_attention(q: jax.Array, cache: jax.Array, q_pos: jax.Array,
 # chosen blocks for a decode step (the Mosaic kernel `kft_sparse_decode_attn`,
 # or `sparse_decode_attention_reference`, a gather and an einsum);
 # `sparse_prefill_attention` is the same choice for every row of a long call,
-# in chunks of query rows, as a dense product under the chosen blocks' mask.
+# made in chunks of query rows and attended in one Mosaic kernel
+# (`kft_sparse_prefill_attn`) under the choices' bitmap, or, where the kernel
+# does not run and in training mode, chunk by chunk as a dense product under
+# the chosen blocks' mask (`sparse_prefill_attention_reference`).
 
 #: the block-selected kernel's name in a device trace
 #: (benchmark/layer_metrics/sparse_attn_*)
@@ -456,9 +463,22 @@ SPARSE_KERNEL_NAME = "kft_sparse_decode_attn"
 #: DMA a K and a V (16 KB at 64 rows of 128 bf16), so a step's fixed cost
 #: is shared by eight of them and its scores are one [G, 8 x block] matmul
 _SPARSE_BLOCKS_A_STEP = 8
-#: query rows of one chunk of `sparse_prefill_attention`: its scores are
-#: [H, 128, max_len] float32, 200 MB at 32 heads and 12,288 rows
+#: query rows of one chunk of a long call's selection (`select_blocks` under
+#: `lax.map`: [H, 128, max_len / stride] float32 scores, 12.6 MB at 32 heads
+#: and 12,288 rows).  Where the XLA form attends as well, a chunk's scores
+#: over the rows are [H, 128, max_len] float32, 200 MB there, four passes
+#: through HBM; where the kernel runs they do not exist
 _SPARSE_QUERY_CHUNK = 128
+#: the prefill kernel's name in a device trace
+#: (benchmark/layer_metrics/sparse_prefill_attn_share.json); it may not
+#: contain the decode kernel's, which `sparse_attn_share` buckets by
+SPARSE_PREFILL_KERNEL_NAME = "kft_sparse_prefill_attn"
+#: (query rows, key rows) of one of its tiles.  At 32 heads on 2 KV heads a
+#: tile is 2,048 rows of scores; the key rows a step amortise its fixed work
+#: (the running max, sum and output rescaled, the mask built) against a last
+#: tile half beyond the cursor: 12,288 causal rows take 33.5, 19.3 and
+#: 12.7 ms at 256, 512 and 1,024 key rows (PERF.md section 6, PR 48)
+_SPARSE_PREFILL_TILES = (128, 1024)
 _FORCED = 1e30
 
 
@@ -678,32 +698,51 @@ def sparse_decode_attention(q, cache_k, cache_v, ids, n, q_pos, block: int,
         compat.pallas_mode(interpret) == "interpret")[:, None]
 
 
-def sparse_prefill_attention(q, k, v, k_cmp, q_pos, *, block: int, **choice):
-    """Every row of a long call under its own choice of blocks:
-    q [B, L, H, D] at q_pos [B, L] against k, v [B, M, Hkv x D] and
-    k_cmp [B, M / stride, Hkv x D] -> float32 [B, L, H, D].  In chunks of
-    `_SPARSE_QUERY_CHUNK` query rows, one after another (`lax.map`): a chunk
-    chooses its blocks (`select_blocks`, with `choice`), spreads the choice
-    to a [chunk, M] mask a KV head and takes the dense masked product, so no
-    [L, M] tensor exists whole.  M x chunk x H scores are computed where
-    topk x block x chunk x H are needed: sound while M is a few times
-    topk x block (3 at 12,288 rows), and a kernel's work beyond that."""
-    B, L, H, D = q.shape
-    M, Hkv = k.shape[1], k.shape[2] // D
-    nb = M // block
+def _chosen_bitmap(q, k_cmp, q_pos, *, block: int, stride: int, **choice):
+    """`select_blocks`' lists as a bitmap: bool [B, L, Hkv, blocks], set
+    where block b is among the first n of a (row, KV head)'s list."""
+    ids, n = select_blocks(q, k_cmp, q_pos, block=block, stride=stride, **choice)
+    listed = jnp.arange(ids.shape[-1]) < n[..., None]
+    nb = k_cmp.shape[1] * stride // block
+    return jnp.logical_and(ids[..., None] == jnp.arange(nb),
+                           listed[..., None]).any(-2)
+
+
+def _pad_to_chunks(q, q_pos):
+    """(rows of a chunk, q, q_pos): the query rows padded to whole chunks of
+    at most `_SPARSE_QUERY_CHUNK`, the padding at position 0."""
+    L = q_pos.shape[1]
     chunk = min(_SPARSE_QUERY_CHUNK, L)
     pad = -L % chunk
-    q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    q_pos = jnp.pad(q_pos, ((0, 0), (0, pad)))
+    return (chunk, jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0))),
+            jnp.pad(q_pos, ((0, 0), (0, pad))))
+
+
+def _chunked(t, chunk: int):
+    """[B, L, ...] -> [L / chunk, B, chunk, ...]: what `lax.map` walks."""
+    return jnp.moveaxis(
+        t.reshape((t.shape[0], t.shape[1] // chunk, chunk) + t.shape[2:]), 1, 0)
+
+
+def sparse_prefill_attention_reference(q, k, v, k_cmp, q_pos, *, block: int,
+                                       **choice):
+    """The XLA form of `sparse_prefill_attention`, and what training mode
+    runs (it has a gradient): in chunks of `_SPARSE_QUERY_CHUNK` query rows,
+    one after another (`lax.map`), a chunk chooses its blocks
+    (`select_blocks`, with `choice`), spreads the choice to a [chunk, M] mask
+    a KV head and takes the dense masked product, so no [L, M] tensor exists
+    whole.  M x chunk x H float32 scores pass through memory a chunk, four
+    times over, where topk x block x chunk x H are needed: what the kernel
+    below is for."""
+    B, L, H, D = q.shape
+    M, Hkv = k.shape[1], k.shape[2] // D
+    chunk, q, q_pos = _pad_to_chunks(q, q_pos)
     keys, values = k.reshape(B, M, Hkv, D), v.reshape(B, M, Hkv, D)
 
     def one(args):
         q_c, pos_c = args                           # [B, chunk, H, D], [B, chunk]
         with jax.named_scope("sparse.select"):
-            ids, n = select_blocks(q_c, k_cmp, pos_c, block=block, **choice)
-            listed = jnp.arange(ids.shape[-1]) < n[..., None]
-            hit = jnp.logical_and(ids[..., None] == jnp.arange(nb),
-                                  listed[..., None]).any(-2)    # [B, c, Hkv, nb]
+            hit = _chosen_bitmap(q_c, k_cmp, pos_c, block=block, **choice)
         with jax.named_scope("sparse.attend"):
             valid = jnp.logical_and(
                 jnp.repeat(hit, block, axis=-1),
@@ -718,7 +757,186 @@ def sparse_prefill_attention(q, k, v, k_cmp, q_pos, *, block: int, **choice):
                               values, preferred_element_type=jnp.float32
                               ).reshape(B, chunk, H, D)
 
-    chunks = lambda t: jnp.moveaxis(                      # noqa: E731
-        t.reshape((B, (L + pad) // chunk, chunk) + t.shape[2:]), 1, 0)
-    o = jax.lax.map(one, (chunks(q), chunks(q_pos)))
-    return jnp.moveaxis(o, 0, 1).reshape(B, L + pad, H, D)[:, :L]
+    o = jax.lax.map(one, (_chunked(q, chunk), _chunked(q_pos, chunk)))
+    return jnp.moveaxis(o, 0, 1).reshape(B, -1, H, D)[:, :L]
+
+
+def _prefill_bitmap(q, k_cmp, q_pos, **choice):
+    """`_chosen_bitmap` of every row of a long call, chunk by chunk."""
+    B, L = q_pos.shape
+    chunk, q, q_pos = _pad_to_chunks(q, q_pos)
+    hit = jax.lax.map(lambda a: _chosen_bitmap(a[0], k_cmp, a[1], **choice),
+                      (_chunked(q, chunk), _chunked(q_pos, chunk)))
+    return jnp.moveaxis(hit, 0, 1).reshape((B, -1) + hit.shape[3:])[:, :L]
+
+
+def sparse_prefill_tiles(query_rows: int, rows: int, head_dim: int,
+                         block: int, cache_dtype,
+                         interpret=None) -> Optional[Tuple[int, int]]:
+    """(query rows, key rows) of a tile when `kft_sparse_prefill_attn` takes
+    this call here, None when the XLA chunks do.  From what a caller can see
+    of the call: a prefill bucket's rows (more than `MAX_QUERY_ROWS`), the
+    rows of a K leaf, a head of whole lane tiles, K and V in bf16 or
+    float32, a key tile that holds whole blocks and divides the leaf."""
+    mode = compat.pallas_mode(interpret)
+    if (mode == "off" or query_rows <= MAX_QUERY_ROWS
+            or jnp.dtype(cache_dtype) not in (jnp.dtype(jnp.bfloat16),
+                                              jnp.dtype(jnp.float32))
+            or (mode != "interpret" and head_dim % 128)):
+        return None
+    tile_q, tile_k = _SPARSE_PREFILL_TILES
+    while tile_k > block and (rows % tile_k or tile_k % block):
+        tile_k //= 2
+    if rows % tile_k or tile_k % block or (mode != "interpret" and tile_k % 128):
+        return None
+    # a short bucket is one tile of its own rows, in whole sublane tiles
+    return min(tile_q, -(-query_rows // 32) * 32), tile_k
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "block", "tile_q", "tile_k", "interpret", "vmem_bytes"))
+def _sparse_prefill_pallas(q, k, v, hit, q_pos, *, block: int, tile_q: int,
+                           tile_k: int, interpret: bool, vmem_bytes: int):
+    """q [B, L, H, D] in the leaves' dtype, k, v [B, M, Hkv x D], hit
+    [B, L, Hkv, nb] bool, q_pos [B, L] -> float32 [B, L, H, D].  One cached
+    program a shape (ops/flash.py `_fwd_pallas`: a model's two block-selected
+    layers trace this body once).
+
+    The grid walks (slot, KV head, query tile, key tile); the key-tile index
+    of a step past the tile of the query tile's last position repeats that
+    tile, so no new DMA is issued for it, and its body is skipped: a causal
+    call reads and multiplies half the rows.  The G = H / Hkv query heads of
+    a KV head are rows of the tile, head-major ([G x tile_q, D]: q and the
+    output are read and written as they lie, [B, L, H x D], a head a run of
+    D lanes, like a KV head of a K row), so one mask [tile_q, tile_k] serves
+    all G.  That mask is the bitmap's row spread over `block` rows a column
+    (a [tile_q, nb] x [nb, tile_k] product of zeros and ones: the matmul unit
+    is the cheap place to repeat a lane) AND row <= q_pos.  Scores, running
+    max, sum and output are float32 in VMEM; no score reaches HBM."""
+    B, L, H, D = q.shape
+    M, Hkv = k.shape[1], k.shape[2] // D
+    G, nb = H // Hkv, M // block
+    assert M % tile_k == 0 and tile_k % block == 0, (M, tile_k, block)
+    pad, nb_pad = -L % tile_q, -nb % 128
+    Lp, nq, nk = L + pad, (L + pad) // tile_q, M // tile_k
+    scale = D ** -0.5
+    q = jnp.pad(q.reshape(B, L, H * D), ((0, 0), (0, pad), (0, 0)))
+    q_pos = jnp.pad(q_pos.astype(jnp.int32), ((0, 0), (0, pad)))
+    bitmap = jnp.pad(jnp.moveaxis(hit, 2, 1).astype(jnp.int8),
+                     ((0, 0), (0, 0), (0, pad), (0, nb_pad)))
+    # the last position a query tile can see: the walk's end, and the row
+    # past which V reads as 0
+    hi = jnp.clip(q_pos.reshape(B, nq, tile_q).max(-1), 0, M - 1)
+
+    def kernel(hi_ref, q_ref, pos_ref, hit_ref, k_ref, v_ref, o_ref,
+               q_s, m_s, l_s, acc_s):
+        b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+        last = hi_ref[b, i]
+
+        @pl.when(j == 0)
+        def _():
+            for g in range(G):
+                q_s[g * tile_q:(g + 1) * tile_q, :] = q_ref[:, g * D:(g + 1) * D]
+            m_s[...] = jnp.full_like(m_s, _MASKED)
+            l_s[...] = jnp.zeros_like(l_s)
+            acc_s[...] = jnp.zeros_like(acc_s)
+
+        @pl.when(j * tile_k <= last)
+        def _():
+            first = j * tile_k
+            col = first + jax.lax.broadcasted_iota(jnp.int32, (1, tile_k), 1)
+            sub = first + jax.lax.broadcasted_iota(jnp.int32, (tile_k, 1), 0)
+            # rows no query of the tile attends meet a probability of 0, and
+            # 0 x NaN is NaN: whatever lies beyond the tile's last position
+            # (the last request's rows, a free slot's) reads as 0
+            v_t = v_ref[...]
+            v_t = jnp.where(sub <= last, v_t, jnp.zeros_like(v_t))
+            of = jax.lax.broadcasted_iota(jnp.int32, (nb + nb_pad, tile_k), 0)
+            spread = (of == col // block).astype(jnp.bfloat16)
+            chosen = jnp.dot(hit_ref[...].astype(jnp.bfloat16), spread,
+                             preferred_element_type=jnp.float32)
+            valid = jnp.logical_and(chosen > 0.5, col <= pos_ref[...])[None]
+            s = jax.lax.dot_general(
+                q_s[...], k_ref[...], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid, s.reshape(G, tile_q, tile_k), _MASKED)
+            m_prev = m_s[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # a masked place is exactly 0: exp(-1e30 - m) is, unless every
+            # place of the row so far is masked and m itself is -1e30
+            p = jnp.exp(s - jnp.where(m_new == _MASKED, 0.0, m_new))
+            l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=2, keepdims=True)
+            pv = jnp.dot(p.astype(v_t.dtype).reshape(G * tile_q, tile_k), v_t,
+                         preferred_element_type=jnp.float32)
+            acc_s[...] = alpha * acc_s[...] + pv.reshape(G, tile_q, D)
+            m_s[...] = m_new
+
+        @pl.when(j == nk - 1)
+        def _():
+            norm = l_s[...]
+            o = acc_s[...] / jnp.where(norm == 0.0, 1.0, norm)
+            for g in range(G):
+                o_ref[:, g * D:(g + 1) * D] = o[g]
+
+    def rows_index(b, h, i, j, hi):
+        return b, i, h
+
+    def kv_index(b, h, i, j, hi):
+        return b, jnp.minimum(j, hi[b, i] // tile_k), h
+
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((B, Lp, H * D), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Hkv, nq, nk),
+            in_specs=[
+                pl.BlockSpec((None, tile_q, G * D), rows_index),
+                pl.BlockSpec((None, tile_q, 1),
+                             lambda b, h, i, j, hi: (b, i, 0)),
+                pl.BlockSpec((None, None, tile_q, nb + nb_pad),
+                             lambda b, h, i, j, hi: (b, h, i, 0)),
+                pl.BlockSpec((None, tile_k, D), kv_index),
+                pl.BlockSpec((None, tile_k, D), kv_index),
+            ],
+            out_specs=pl.BlockSpec((None, tile_q, G * D), rows_index),
+            scratch_shapes=[pltpu.VMEM((G * tile_q, D), k.dtype),
+                            pltpu.VMEM((G, tile_q, 1), jnp.float32),
+                            pltpu.VMEM((G, tile_q, 1), jnp.float32),
+                            pltpu.VMEM((G, tile_q, D), jnp.float32)],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=vmem_bytes),
+        interpret=interpret,
+        name=SPARSE_PREFILL_KERNEL_NAME,
+    )(hi, q, q_pos[..., None], bitmap, k, v)
+    return out[:, :L].reshape(B, L, H, D)
+
+
+def sparse_prefill_attention(q, k, v, k_cmp, q_pos, *, block: int,
+                             interpret=None, **choice):
+    """Every row of a long call under its own choice of blocks:
+    q [B, L, H, D] at q_pos [B, L] against k, v [B, M, Hkv x D] and
+    k_cmp [B, M / stride, Hkv x D] -> float32 [B, L, H, D].  Where
+    `sparse_prefill_tiles` says the kernel takes the call: the choice still
+    chunk by chunk in XLA (`select_blocks`, with `choice`, under
+    `sparse.select`), handed on as a bitmap, a byte a (row, KV head, block),
+    and the attention of all rows in one `kft_sparse_prefill_attn`.  Elsewhere
+    `sparse_prefill_attention_reference`.  No gradient: training mode calls
+    the reference."""
+    tiles = sparse_prefill_tiles(q.shape[1], k.shape[1], q.shape[3], block,
+                                 k.dtype, interpret)
+    if tiles is None:
+        return sparse_prefill_attention_reference(
+            q, k, v, k_cmp, q_pos, block=block, **choice)
+    with jax.named_scope("sparse.select"):
+        hit = _prefill_bitmap(q, k_cmp, q_pos, block=block, **choice)
+    with jax.named_scope("sparse.attend"):
+        return _sparse_prefill_pallas(
+            q.astype(k.dtype), k, v, hit, q_pos, block=block,
+            tile_q=tiles[0], tile_k=tiles[1],
+            interpret=compat.pallas_mode(interpret) == "interpret",
+            vmem_bytes=compat.vmem_budget_bytes())

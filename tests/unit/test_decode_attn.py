@@ -498,15 +498,18 @@ S_CHOICE = dict(block=S_BLOCK, stride=S_STRIDE, topk=S_TOPK, init_blocks=1,
                 window=2 * S_BLOCK)
 
 
-def _sparse_case(B, L, H, Hkv, D, dtype, cursors, seed=0):
+def _sparse_case(B, L, H, Hkv, D, dtype, cursors, seed=0, past_the_leaf=False):
     """(q, K, V, compressed keys, positions): rows [B, S_LEN, Hkv x D] with
-    NaN in K and V beyond each slot's last position."""
+    NaN in K and V beyond each slot's last position (a cursor is pulled
+    back so that the call ends inside the leaf unless `past_the_leaf`)."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = (3 * jax.random.normal(ks[0], (B, L, H, D), jnp.float32)).astype(dtype)
     ck = jax.random.normal(ks[1], (B, S_LEN, Hkv * D), jnp.float32).astype(dtype)
     cv = jax.random.normal(ks[2], (B, S_LEN, Hkv * D), jnp.float32).astype(dtype)
     kc = _compressed_keys(ck, S_STRIDE).astype(dtype)
-    idx0 = jnp.minimum(jnp.asarray(cursors, jnp.int32), S_LEN - L)
+    idx0 = jnp.asarray(cursors, jnp.int32)
+    if not past_the_leaf:
+        idx0 = jnp.minimum(idx0, S_LEN - L)
     pos = idx0[:, None] + jnp.arange(L)[None, :]
     dead = (jnp.arange(S_LEN)[None, :] > pos[:, -1:])[:, :, None]
     return (q, jnp.where(dead, jnp.nan, ck), jnp.where(dead, jnp.nan, cv), kc,
@@ -673,3 +676,192 @@ def test_the_sala_decode_program_reads_the_donated_cache_where_it_lies(
     # the compiler's own staging of weights (two 4096 x 4096 kernels in
     # flight) is all: less than ONE K leaf
     assert mem.temp_size_in_bytes < slots * 12288 * 256 * 2
+
+
+# -- a prefill's attention over selected blocks (`kft_sparse_prefill_attn`) ------------
+
+
+PREFILL_CASES = {
+    # B, L, H, Hkv, D, cursors
+    "cold": (2, 112, 8, 2, 16, [0, 0]),
+    "warm_cursors": (2, 40, 8, 2, 16, [60, 17]),
+    "ragged_last_tile": (1, 100, 8, 2, 16, [20]),   # 100 rows: tiles of 128
+    "several_tiles": (1, 128, 4, 2, 16, [0]),       # four query tiles of 32
+    "past_the_leaf": (2, 40, 8, 2, 16, [100, 90]),  # positions 128.. are padding
+    "one_query_head_a_kv_head": (2, 64, 2, 2, 16, [0, 30]),
+    "sixteen_query_heads_a_kv_head": (1, 64, 32, 2, 128, [50]),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(PREFILL_CASES))
+def test_sparse_prefill_kernel_matches_the_xla_chunks(case, dtype, monkeypatch):
+    """The kernel's body in the interpreter against the XLA chunks on the
+    rows as stored, with NaN in K and V beyond each slot's last position: a
+    cold call and warm cursors, a last tile of padding, rows past the leaf,
+    one and sixteen query heads a KV head, both dtypes."""
+    B, L, H, Hkv, D, cursors = PREFILL_CASES[case]
+    if case == "several_tiles":
+        monkeypatch.setattr(da, "_SPARSE_PREFILL_TILES", (32, 64))
+    q, ck, cv, kc, pos, ck0, cv0 = _sparse_case(
+        B, L, H, Hkv, D, dtype, cursors, past_the_leaf=True)
+    want = da.sparse_prefill_attention_reference(q, ck0, cv0, kc, pos, **S_CHOICE)
+    got = da.sparse_prefill_attention(q, ck, cv, kc, pos, interpret=True,
+                                      **S_CHOICE)
+    assert got.shape == (B, L, H, D) and got.dtype == jnp.float32
+    assert np.isfinite(np.asarray(got)).all()
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=tol, atol=tol)
+
+
+def test_sparse_prefill_kernel_takes_padding_rows_at_position_0():
+    """Rows a caller pads a call with (position 0, as the chunks pad their
+    own last chunk) attend row 0 alone and move no other row."""
+    B, L, H, Hkv, D = 2, 48, 8, 2, 16
+    q, ck, cv, kc, pos, ck0, cv0 = _sparse_case(B, L, H, Hkv, D, jnp.float32,
+                                                [40, 0])
+    pos = jnp.where(jnp.arange(L) < 37, pos, 0)
+    want = da.sparse_prefill_attention_reference(q, ck0, cv0, kc, pos, **S_CHOICE)
+    got = da.sparse_prefill_attention(q, ck, cv, kc, pos, interpret=True,
+                                      **S_CHOICE)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    v0 = cv0.reshape(B, S_LEN, Hkv, D)[:, 0]            # row 0, a KV head
+    np.testing.assert_allclose(
+        np.asarray(got[:, 37:]).reshape(B, L - 37, Hkv, H // Hkv, D),
+        np.broadcast_to(np.asarray(v0)[:, None, :, None],
+                        (B, L - 37, Hkv, H // Hkv, D)), rtol=1e-6, atol=1e-6)
+
+
+def test_sparse_prefill_kernel_under_topk_blocks_is_plain_attention():
+    """Rows 0 .. topk x block - 1 have at most topk blocks at or before
+    them: the kernel there is the plain causal einsum."""
+    B, L, H, Hkv, D = 2, S_TOPK * S_BLOCK, 8, 2, 16
+    q, ck, cv, kc, pos, ck0, cv0 = _sparse_case(B, L, H, Hkv, D, jnp.float32,
+                                                [0, 0])
+    got = da.sparse_prefill_attention(q, ck, cv, kc, pos, interpret=True,
+                                      **S_CHOICE)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_plain(q, ck0, cv0, pos, Hkv, D)),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_sparse_prefill_kernel_reads_the_bitmap():
+    """A block cleared in one (row, KV head)'s bitmap changes that row's
+    output for that KV head's query heads and no other number: the kernel
+    attends what the bitmap says, not every row at or before the query."""
+    B, L, H, Hkv, D = 1, 96, 8, 2, 16
+    q, _, _, kc, pos, ck, cv = _sparse_case(B, L, H, Hkv, D, jnp.float32, [0])
+    hit = da._chosen_bitmap(q, kc, pos, **S_CHOICE)     # [B, L, Hkv, nb]
+    run = functools.partial(da._sparse_prefill_pallas, q, ck, cv,
+                            block=S_BLOCK, tile_q=32, tile_k=64, interpret=True,
+                            vmem_bytes=64 << 20)
+    whole = run(hit, pos)
+    np.testing.assert_allclose(
+        np.asarray(whole), np.asarray(da.sparse_prefill_attention_reference(
+            q, ck, cv, kc, pos, **S_CHOICE)), rtol=1e-5, atol=1e-5)
+    row, head = 90, 1
+    assert bool(hit[0, row, head, 0])                   # block 0 is forced
+    less = run(hit.at[0, row, head, 0].set(False), pos)
+    moved = np.abs(np.asarray(less - whole)).max(-1)[0]         # [L, H]
+    G = H // Hkv
+    assert (moved[row, head * G:(head + 1) * G] > 1e-3).all()
+    moved[row, head * G:(head + 1) * G] = 0
+    assert not moved.any()
+
+
+def test_two_sparse_layers_of_one_shape_trace_the_prefill_kernel_once():
+    """Two layers' calls of one shape in one program (after
+    test_flash_cached.py): the kernel's body is traced once and the module
+    lowered for a TPU holds one Mosaic kernel, called twice."""
+    choice = dict(block=64, stride=16, topk=2, init_blocks=1, window=64)
+    S = jax.ShapeDtypeStruct
+    rows = S((1, 256, 256), jnp.bfloat16)
+
+    def two(q, k, v, kc, pos):
+        for _ in range(2):
+            q = da.sparse_prefill_attention(
+                q, k, v, kc, pos, interpret=False, **choice).astype(q.dtype)
+        return q
+
+    txt = jax.export.export(jax.jit(two), platforms=["tpu"])(
+        S((1, 256, 4, 128), jnp.bfloat16), rows, rows,
+        S((1, 16, 256), jnp.bfloat16), S((1, 256), jnp.int32)).mlir_module()
+    assert txt.count("stablehlo.custom_call @tpu_custom_call") == 1
+    assert re.findall(r'kernel_name = "([^"]+)"', txt) == [
+        da.SPARSE_PREFILL_KERNEL_NAME]
+
+
+def test_sparse_prefill_selection_from_what_a_call_shows():
+    tiles = da.sparse_prefill_tiles
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    assert tiles(12288, 12288, 128, 64, bf16, interpret=False) == (128, 1024)
+    assert tiles(12288, 12288, 128, 64, f32, interpret=False) == (128, 1024)
+    assert tiles(16, 12288, 128, 64, bf16, interpret=False) == (32, 1024)
+    assert tiles(100, 12288, 128, 64, bf16, interpret=False) == (128, 1024)
+    assert tiles(1, 12288, 128, 64, bf16, interpret=False) is None   # a decode step
+    assert tiles(da.MAX_QUERY_ROWS, 12288, 128, 64, bf16, interpret=False) is None
+    assert tiles(4096, 12288, 64, 64, bf16, interpret=False) is None  # half a lane tile
+    assert tiles(4096, 12288, 64, 64, bf16, interpret=True) == (128, 1024)
+    assert tiles(4096, 12288, 128, 64, jnp.int8, interpret=True) is None
+    assert tiles(64, 64, 128, 8, f32, interpret=True) == (64, 64)
+    assert tiles(64, 64, 128, 8, f32, interpret=False) is None  # no whole lane tile of keys
+    assert tiles(4096, 12288, 128, 96, bf16, interpret=True) is None  # no tile of whole blocks
+    assert tiles(4096, 12288, 128, 64, bf16) is None                  # no kernels here
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("rows", [16, 12288], ids=["bucket16", "bucket12288"])
+def test_sparse_prefill_kernel_compiles_for_the_chip_at_the_cells_shape(
+        v5e_chip, rows, dtype):
+    """One request's prefill bucket at the published widths (32 query heads
+    on 2 KV heads of 128, 192 blocks of 64 of 12,288 rows), compiled for the
+    chip: the kernel is there, and no [.., 128, 12288] float32 score chunk
+    (201 MB) is among the program's temporaries: what is, is the output's
+    and q's change of layout."""
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)  # noqa: E731
+    leaf = S((1, 12288, 256), dtype)
+    choice = dict(block=64, stride=16, topk=64, init_blocks=1, window=2048)
+
+    def f(q, k, v, kc, pos):
+        return da.sparse_prefill_attention(q, k, v, kc, pos, interpret=False,
+                                           **choice)
+
+    compiled = jax.jit(f).lower(
+        S((1, rows, 32, 128), dtype), leaf, leaf, S((1, 768, 256), dtype),
+        S((1, rows), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(
+        rf" custom-call\([^\n]*{da.SPARSE_PREFILL_KERNEL_NAME}", text)) == 1
+    assert not re.search(r"f32\[[0-9,]*128,12288\]", text)
+    out_bytes = rows * 32 * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * out_bytes + (64 << 20)
+
+
+def test_the_benchmark_buckets_the_prefill_kernel_by_its_name():
+    """`sparse_prefill_attn_share` finds the kernel by the name the program
+    gives it, and the decode kernel's metric (a substring match over the
+    whole capture) cannot take its events."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "layer_metrics",
+                           "sparse_prefill_attn_share.json")) as f:
+        reader = json.load(f)
+    assert reader["bucket"]["match"] == da.SPARSE_PREFILL_KERNEL_NAME
+    assert reader["kind"] == "trace" and reader["reduce"] == "share_of_busy"
+    with open(os.path.join(root, "benchmark", "layer_metrics",
+                           "sparse_attn_share.json")) as f:
+        assert json.load(f)["bucket"]["match"] not in da.SPARSE_PREFILL_KERNEL_NAME
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entry = [m for m in json.load(f)["per_layer"]
+                 if m["name"] == "sparse_prefill_attn_share"]
+    assert entry == [{
+        "name": "sparse_prefill_attn_share", "unit": "%", "better": "higher",
+        "source": "device_trace", "layer": "kernels", "moves": "tpot_p50_ms",
+        "workloads": ["serve-sala-docs-r80"]}]
