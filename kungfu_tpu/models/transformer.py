@@ -30,9 +30,11 @@ from ..ops.decode_attn import (
     MAX_QUERY_ROWS,
     decode_attention,
     decode_attention_reference,
+    kernel_block,
     mla_decode_attention,
     mla_decode_attention_reference,
     select_blocks,
+    slot_walk,
     sparse_decode_attention,
     sparse_prefill_attention,
     sparse_prefill_attention_reference,
@@ -588,11 +590,21 @@ def _store_rows(cache: jax.Array, rows: jax.Array, idx0: jax.Array) -> jax.Array
     return _write_each_slot(cache, rows, start)
 
 
+def _kernels_read(cfg: TransformerConfig) -> bool:
+    """Whether a decode-mode layer hands its cache read to
+    ops/decode_attn.py's choice of kernel or einsum, and not to the plain
+    einsum outright: attention="full" asks for that, a cache sharded over
+    a mesh needs it (GSPMD splits an einsum, not a Mosaic call), an int8
+    cache is read through its scales."""
+    return (cfg.attention != "full" and cfg.mesh is None
+            and cfg.kv_cache_dtype != "int8")
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, x, live=None):
+    def __call__(self, x, live=None, walk=None):
         cfg = self.cfg
         H, D = cfg.n_heads, cfg.d_model // cfg.n_heads
         Hkv = cfg.kv_heads
@@ -690,7 +702,7 @@ class Attention(nn.Module):
                 # all).  A row that is not live keeps its cursor and its
                 # flag: a free serving slot stays at 0, its dummy k/v land
                 # on row 0 of its own slot, which the next admission
-                # replaces, and the read below stays inside its first block
+                # replaces, and the kernel below reads nothing of its slot
                 step = L if live is None else jnp.where(live, L, 0)
                 cache_idx.value = idx0 + step
                 cache_ovf.value = jnp.logical_or(
@@ -710,12 +722,14 @@ class Attention(nn.Module):
                 o = decode_attention_reference(
                     q, load(cache_k, kscale), load(cache_v, vscale), pos,
                     cfg.window)
-            elif cfg.attention == "full" or cfg.mesh is not None:
+            elif not _kernels_read(cfg):
                 o = decode_attention_reference(
                     q, cache_k.value, cache_v.value, pos, cfg.window)
             else:
+                # (`walk`: the step's visit list where `TransformerLM` has
+                # built it for all layers; a free slot's rows come back 0)
                 o = decode_attention(q, cache_k.value, cache_v.value, pos,
-                                     cfg.window)
+                                     cfg.window, live=live, walk=walk)
             # a cursor past max_len clamps that row's cache write and
             # clobbers its older slots — poison the ROW with NaN so overflow
             # is LOUD instead of silently-wrong logits (generate() bounds
@@ -879,7 +893,7 @@ class MLA(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, x, live=None):
+    def __call__(self, x, live=None, walk=None):
         cfg = self.cfg
         H, r = cfg.n_heads, cfg.kv_lora_rank
         dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -964,12 +978,12 @@ class MLA(nn.Module):
                     q_abs = jnp.concatenate(
                         [q_lat.astype(cfg.dtype), queries[..., dn:]], axis=-1)
                     scale = (dn + dr) ** -0.5
-                    if cfg.attention == "full" or cfg.mesh is not None:
+                    if not _kernels_read(cfg):
                         o_lat = mla_decode_attention_reference(
                             q_abs, stored, pos, r, scale)
                     else:
                         o_lat = mla_decode_attention(
-                            q_abs, stored, pos, r, scale)
+                            q_abs, stored, pos, r, scale, live=live, walk=walk)
                     o = jnp.einsum(
                         "blhr,rhd->blhd", o_lat.astype(cfg.dtype),
                         w[..., dn:], preferred_element_type=jnp.float32)
@@ -1418,7 +1432,8 @@ class Block(nn.Module):
     kind: str = "attention"   # `TransformerConfig.layer_kind`
 
     @nn.compact
-    def __call__(self, x, train: bool = False, live=None, n_new=None):
+    def __call__(self, x, train: bool = False, live=None, n_new=None,
+                 walk=None):
         cfg = self.cfg
         ln = partial(_norm, cfg)
         drop = nn.Dropout(cfg.dropout, deterministic=not train)
@@ -1440,7 +1455,7 @@ class Block(nn.Module):
             mixed = SparseAttention(cfg, name="attn")(u, live)
         else:
             attn = (MLA if cfg.kv_lora_rank else Attention)(cfg, name="attn")
-            mixed = attn(u, live)
+            mixed = attn(u, live, walk)
         x = x + drop(post("ln1_post", mixed))
         if self.use_moe:
             from ..parallel.moe import MoE
@@ -1468,17 +1483,19 @@ class ShortcutMoEBlock(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, x, train: bool = False, live=None):
+    def __call__(self, x, train: bool = False, live=None, walk=None):
         from ..parallel.moe import MoE
 
         cfg = self.cfg
         ln = partial(_norm, cfg)
         drop = nn.Dropout(cfg.dropout, deterministic=not train)
-        x = x + drop(MLA(cfg, name="attn_0")(ln(name="ln_attn_0")(x), live))
+        x = x + drop(MLA(cfg, name="attn_0")(
+            ln(name="ln_attn_0")(x), live, walk))
         h = ln(name="ln_ffn_0")(x)
         shortcut = MoE(cfg, name="moe")(h, live)
         x = x + drop(MLP(cfg, name="mlp_0")(h))
-        x = x + drop(MLA(cfg, name="attn_1")(ln(name="ln_attn_1")(x), live))
+        x = x + drop(MLA(cfg, name="attn_1")(
+            ln(name="ln_attn_1")(x), live, walk))
         x = x + drop(MLP(cfg, name="mlp_1")(ln(name="ln_ffn_1")(x))) \
             + drop(shortcut)
         return logical_constraint(x, ("batch", "seq", "act_embed"), cfg.mesh)
@@ -1555,6 +1572,34 @@ class MTPModule(nn.Module):
 
 class TransformerLM(nn.Module):
     cfg: TransformerConfig
+
+    def _slot_walk(self, L: int, live):
+        """The visit list the attention kernels of a decode or verify step
+        walk (ops/decode_attn.py `slot_walk`), built HERE, once for every
+        layer: each attention layer keeps cursors of its own, all the same
+        numbers, so what a layer built from its own the compiler could not
+        merge with its neighbour's.  From the first attention layer's
+        cursors, as that layer will read them, and the step's `live`; None
+        where no layer's read is the kernel's, and a layer called without
+        one builds its own."""
+        cfg = self.cfg
+        if (not cfg.decode or self.is_initializing()
+                or not _kernels_read(cfg)):
+            return None
+        shortcut = cfg.block == "shortcut_moe"
+        first = next((i for i in range(cfg.n_layers)
+                      if shortcut or cfg.layer_kind(i) == "attention"), None)
+        if first is None:
+            return None
+        held = self.variables["cache"][f"block_{first}"][
+            "attn_0" if shortcut else "attn"]
+        leaf = held["cached_latent" if cfg.kv_lora_rank else "cached_k"]
+        block = kernel_block(L, leaf.shape, leaf.dtype)
+        if block is None:
+            return None
+        pos = held["idx"][:, None] + jnp.arange(L)[None, :]
+        return slot_walk(pos, live, block, cfg.max_len,
+                         0 if cfg.kv_lora_rank else cfg.window)
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, live=None,
@@ -1637,13 +1682,14 @@ class TransformerLM(nn.Module):
             if cfg.remat_policy == "dots":
                 remat_kw["policy"] = jax.checkpoint_policies.dots_saveable
             block_cls = nn.remat(block_cls, static_argnums=(2,), **remat_kw)
+        walk = self._slot_walk(L, live)
         for i in range(cfg.n_layers):
             if shortcut:  # every block carries the expert branch
-                x = block_cls(cfg, name=f"block_{i}")(x, train, live)
+                x = block_cls(cfg, name=f"block_{i}")(x, train, live, walk)
             else:
                 block = block_cls(cfg, use_moe=cfg.layer_has_experts(i),
                                   kind=cfg.layer_kind(i), name=f"block_{i}")
-                x = block(x, train, live, n_new)
+                x = block(x, train, live, n_new, walk)
         x = _norm(cfg, "ln_f")(x)
         hidden = x
         if cfg.dim_model_base:

@@ -20,13 +20,22 @@ and what runs off TPU (`compat.pallas_mode() == "off"`).
 
 On TPU the same contract is a Mosaic kernel the trace names
 `kft_decode_attn`; under KFT_PALLAS=interpret its body runs in the Pallas
-interpreter.  The grid walks (slot, KV block).  Each slot's first and last
-live block are scalar-prefetched; the block index of a grid step past the
-last live block repeats that block, so no new DMA is issued for it, and
-its body is skipped: a slot at cursor 300 of 2,048 reads two blocks of
-256 rows, not eight.  Blocks are combined by online softmax, in the
-einsum's arithmetic.  A K block [block, Hkv, D] is read as the matrix
-[block x Hkv, D] it already is in memory: one matmul gives every query
+interpreter.  The grid walks a LIST of visits, (slot, KV block) pairs
+ordered by slot, and is as long as the list: its one dimension is the
+traced count.  `visits` makes the list from each slot's first and last
+live block (`live_blocks`) and the step's `live` mask: a slot at cursor
+300 of 2,048 is two visits of 256 rows, not eight, and a slot that is not
+live (it holds no request, or one whose last token is in flight) is none,
+so a call's cost follows the rows busy slots have written and not the
+number of slots.  List, first and last are scalar-prefetched; a visit's
+index maps read its slot and block from the list, the running max, sum
+and output are reset at a slot's first visit and the output written at
+its last.  No grid step maps the output rows of a slot with no visit:
+they are set to zero outside the kernel (`_walked`).  With every slot
+live the list is every slot's run, and at max_len the whole
+(slot, block) rectangle the grid once was.  Blocks are combined by online
+softmax, in the einsum's arithmetic.  A K block [block, Hkv, D] is read as
+the matrix [block x Hkv, D] it already is in memory: one matmul gives every query
 head's score against every (row, KV head) pair, the pairs of another KV
 head are masked like the rows beyond the cursor, and the probabilities,
 zero there, multiply the V block the same way.  That spends Hkv times the
@@ -44,15 +53,16 @@ rank, scale)` -> float32 [B, L, H, rank], every head against the same
 rows, so a written row is read ONCE for scores and values alike and no
 per-head K or V exists.  `mla_decode_attention_reference` is the dense
 definition and the off-TPU path; the Mosaic kernel is `kft_mla_decode_attn`,
-the same grid, prefetch and online softmax over [block, rank + rope]
-blocks.  `kernel_block` answers for a three-dimensional leaf as it does
-for a four-dimensional one.
+the same walk (one `Walk` serves both: `slot_walk`), prefetch and online
+softmax over [block, rank + rope] blocks.  `kernel_block` answers for a
+three-dimensional leaf as it does for a four-dimensional one.
 
 Attention over SELECTED blocks (models/transformer.py `SparseAttention`)
 is the third form, at the end of this file: `select_blocks` chooses, a
 query row and a KV head, which blocks of rows to read, and
-`kft_sparse_decode_attn` reads that visit list where `kft_decode_attn`
-reads one contiguous run (`live_blocks`).  A prefill bucket's rows each
+`kft_sparse_decode_attn` reads that list of chosen blocks on a static
+grid where `kft_decode_attn` walks each live slot's one contiguous run
+(`live_blocks`).  A prefill bucket's rows each
 choose too, and `kft_sparse_prefill_attn` attends them all under the
 choices' bitmap, flash-style: scores in VMEM, key tiles up to a query
 tile's last position.
@@ -60,7 +70,7 @@ tile's last position.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -163,32 +173,89 @@ def live_blocks(xp, q_lo, q_hi, block: int, max_len: int, window: int = 0):
     return xp.minimum(first, last), last
 
 
+def visits(xp, first, last, live, length: int):
+    """The (slot, block) pairs a step's attention reads, ordered by slot:
+    block first[b] .. last[b] of every LIVE slot b (`live` [B] bool; None:
+    every slot).  A slot that is not live holds no request, or one whose
+    last token is in flight: it contributes no visit.  -> (slot [length],
+    block [length], how many of them are visits): `length` is the static
+    slots x blocks a slot; the places past the count hold (0, 0) and are
+    never walked.  `xp` as `live_blocks` takes it: the engine counts the
+    rows a step fetches from the list the program walks."""
+    n = last - first + 1
+    if live is not None:
+        n = xp.where(live, n, 0)
+    ends = xp.cumsum(n)
+    starts = ends - n
+    # place i of the list is slot b's where starts[b] <= i < ends[b]: a
+    # [length, B] mask and two sums over it, which the compiler fuses (the
+    # same list by gathers, first[slot], took 11 us at 8 slots and 39 us
+    # at 32 on the chip; this takes under 2: PERF.md section 6, PR 49)
+    at = xp.arange(length)[:, None]
+    mine = xp.logical_and(at >= starts[None, :], at < ends[None, :])
+    slot = xp.where(mine, xp.arange(n.shape[0])[None, :], 0).sum(axis=1)
+    block = xp.where(mine, first[None, :] + at - starts[None, :], 0).sum(axis=1)
+    return slot.astype(xp.int32), block.astype(xp.int32), ends[-1]
+
+
+class Walk(NamedTuple):
+    """What both kernels walk in one step: `live_blocks` of the slots'
+    query rows, `visits` of those under the step's `live` mask (kept, so
+    that a slot with no visit reads as zeros).  A function of the cursors
+    and the mask alone, so a model builds it once a step for all its
+    layers (models/transformer.py `TransformerLM`)."""
+    first: jax.Array   # [B]
+    last: jax.Array    # [B]
+    slot: jax.Array    # [B x blocks a slot]
+    block: jax.Array   # [B x blocks a slot]
+    count: jax.Array   # []
+    live: Optional[jax.Array]
+
+
+def slot_walk(q_pos, live, block: int, max_len: int, window: int = 0) -> Walk:
+    """The `Walk` of query rows at q_pos [B, L] over blocks of `block`
+    rows (`kernel_block`)."""
+    q_pos = q_pos.astype(jnp.int32)
+    first, last = live_blocks(jnp, q_pos.min(axis=1), q_pos.max(axis=1),
+                              block, max_len, window)
+    return Walk(first, last, *visits(
+        jnp, first, last, live, q_pos.shape[0] * (max_len // block)), live)
+
+
+def _walked(call, walk: Walk, q_pos, *operands):
+    """`call` (a `pl.pallas_call` over the grid `(walk.count,)`) on the
+    walk's scalars and `operands`; the rows of a slot the walk does not
+    visit, which no grid step wrote, read as zeros."""
+    out = call(walk.slot, walk.block, walk.first, walk.last,
+               q_pos.astype(jnp.int32), *operands)
+    if walk.live is None:
+        return out
+    return jnp.where(walk.live[:, None, None], out, 0.0)
+
+
 def _attn_pallas(q, cache_k, cache_v, q_pos, window: int, block: int,
-                 interpret: bool):
+                 interpret: bool, walk: Walk):
     B, L, H, D = q.shape
     max_len, Hkv = cache_k.shape[1], cache_k.shape[2]
     assert H % Hkv == 0 and max_len % block == 0, (H, Hkv, max_len, block)
     G, R, lanes = H // Hkv, L * H, block * Hkv
     scale = 1.0 / (D ** 0.5)
-    q_pos = q_pos.astype(jnp.int32)
-    first, last = live_blocks(jnp, q_pos.min(axis=1), q_pos.max(axis=1),
-                              block, max_len, window)
+    assert walk.slot.shape == (B * (max_len // block),), (walk.slot.shape, B)
 
-    def kernel(first, last, pos, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-               acc_ref):
-        b, j = pl.program_id(0), pl.program_id(1)
+    def kernel(slot, block_of, first, last, pos, q_ref, k_ref, v_ref, o_ref,
+               m_ref, l_ref, acc_ref):
+        i = pl.program_id(0)
+        b, blk = slot[i], block_of[i]
 
-        @pl.when(j == 0)
+        @pl.when(blk == first[b])
         def _():
             m_ref[...] = jnp.full_like(m_ref, _MASKED)
             l_ref[...] = jnp.zeros_like(l_ref)
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        blk = first[b] + j
         lo = hi = pos[b, 0]  # the slot's lowest and highest query position
         for l in range(1, L):
             lo, hi = jnp.minimum(lo, pos[b, l]), jnp.maximum(hi, pos[b, l])
-        live = blk <= last[b]
         # an inner block holds only rows every query of the slot attends: no
         # row of it is beyond a cursor or out of a window
         inner = (blk + 1) * block - 1 <= lo
@@ -232,27 +299,26 @@ def _attn_pallas(q, cache_k, cache_v, q_pos, window: int, block: int,
                 p.astype(v.dtype), v, preferred_element_type=jnp.float32)
             m_ref[...] = m_new
 
-        pl.when(jnp.logical_and(live, inner))(lambda: attend(False))
-        pl.when(jnp.logical_and(live, jnp.logical_not(inner)))(
-            lambda: attend(True))
+        pl.when(inner)(lambda: attend(False))
+        pl.when(jnp.logical_not(inner))(lambda: attend(True))
 
-        @pl.when(j == pl.num_programs(1) - 1)
+        @pl.when(blk == last[b])
         def _():
             norm = l_ref[...]
             o_ref[...] = acc_ref[...] / jnp.where(norm == 0.0, 1.0, norm)
 
-    def kv_index(b, j, first, last, pos):
-        return b, jnp.minimum(first[b] + j, last[b]), 0, 0
+    def kv_index(i, slot, block_of, first, last, pos):
+        return slot[i], block_of[i], 0, 0
 
-    def row_index(b, j, first, last, pos):
-        return b, 0, 0
+    def row_index(i, slot, block_of, first, last, pos):
+        return slot[i], 0, 0
 
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((B, R, D), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B, max_len // block),
+            num_scalar_prefetch=5,
+            grid=(walk.count,),
             in_specs=[
                 pl.BlockSpec((None, R, D), row_index),
                 pl.BlockSpec((None, block, Hkv, D), kv_index),
@@ -264,26 +330,33 @@ def _attn_pallas(q, cache_k, cache_v, q_pos, window: int, block: int,
                             pltpu.VMEM((R, D), jnp.float32)],
         ),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=compat.vmem_budget_bytes()),
         interpret=interpret,
         name=KERNEL_NAME,
-    )(first, last, q_pos, q.astype(cache_k.dtype).reshape(B, R, D),
-      cache_k, cache_v)
+    )
+    out = _walked(call, walk, q_pos, q.astype(cache_k.dtype).reshape(B, R, D),
+                  cache_k, cache_v)
     return out.reshape(B, L, H, D)
 
 
 def decode_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
-                     q_pos: jax.Array, window: int = 0,
-                     interpret=None) -> jax.Array:
+                     q_pos: jax.Array, window: int = 0, interpret=None,
+                     live=None, walk: Optional[Walk] = None) -> jax.Array:
     """q [B, L, H, D] against the slot cache [B, max_len, Hkv, D] -> float32
     [B, L, H, D]: the kernel where `kernel_block` says it takes the call,
-    the reference einsum elsewhere."""
+    the reference einsum elsewhere.  `live` [B] bool (None: every slot):
+    the kernel visits no block of a slot that is not live and returns
+    zeros for it; the einsum, which reads the whole cache anyway, takes no
+    notice.  `walk`: the step's `slot_walk` of these positions, this mask
+    and the kernel's block where the caller has built it already."""
     block = kernel_block(q.shape[1], cache_k.shape, cache_k.dtype, interpret)
     if block is None:
         return decode_attention_reference(q, cache_k, cache_v, q_pos, window)
+    if walk is None:
+        walk = slot_walk(q_pos, live, block, cache_k.shape[1], window)
     return _attn_pallas(q, cache_k, cache_v, q_pos, window, block,
-                        compat.pallas_mode(interpret) == "interpret")
+                        compat.pallas_mode(interpret) == "interpret", walk)
 
 
 # -- latent attention: one shared row a token ------------------------------------------
@@ -312,7 +385,7 @@ def mla_decode_attention_reference(q, cache, q_pos, rank: int, scale: float):
 
 
 def _mla_attn_pallas(q, cache, q_pos, rank: int, scale: float, block: int,
-                     interpret: bool):
+                     interpret: bool, walk: Walk):
     """The kernel reads the cache feature-major, [B, W, max_len]: that is
     how the chip lays a [B, max_len, W] array out when W fills no whole
     lane tile (576 = 4.5 x 128: the compiler makes max_len the minor
@@ -326,24 +399,22 @@ def _mla_attn_pallas(q, cache, q_pos, rank: int, scale: float, block: int,
     max_len = cache.shape[1]
     assert cache.shape[2] == W and max_len % block == 0, (cache.shape, W, block)
     R = L * H
-    q_pos = q_pos.astype(jnp.int32)
-    first, last = live_blocks(jnp, q_pos.min(axis=1), q_pos.max(axis=1),
-                              block, max_len)
+    assert walk.slot.shape == (B * (max_len // block),), (walk.slot.shape, B)
 
-    def kernel(first, last, pos, q_ref, c_ref, o_ref, m_ref, l_ref, acc_ref):
-        b, j = pl.program_id(0), pl.program_id(1)
+    def kernel(slot, block_of, first, last, pos, q_ref, c_ref, o_ref, m_ref,
+               l_ref, acc_ref):
+        i = pl.program_id(0)
+        b, blk = slot[i], block_of[i]
 
-        @pl.when(j == 0)
+        @pl.when(blk == first[b])
         def _():
             m_ref[...] = jnp.full_like(m_ref, _MASKED)
             l_ref[...] = jnp.zeros_like(l_ref)
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        blk = first[b] + j
         lo = hi = pos[b, 0]  # the slot's lowest and highest query position
         for l in range(1, L):
             lo, hi = jnp.minimum(lo, pos[b, l]), jnp.maximum(hi, pos[b, l])
-        live = blk <= last[b]
         # an inner block holds only rows every query of the slot attends
         inner = (blk + 1) * block - 1 <= lo
 
@@ -382,27 +453,26 @@ def _mla_attn_pallas(q, cache, q_pos, rank: int, scale: float, block: int,
                 preferred_element_type=jnp.float32)
             m_ref[...] = m_new
 
-        pl.when(jnp.logical_and(live, inner))(lambda: attend(False))
-        pl.when(jnp.logical_and(live, jnp.logical_not(inner)))(
-            lambda: attend(True))
+        pl.when(inner)(lambda: attend(False))
+        pl.when(jnp.logical_not(inner))(lambda: attend(True))
 
-        @pl.when(j == pl.num_programs(1) - 1)
+        @pl.when(blk == last[b])
         def _():
             norm = l_ref[...]
             o_ref[...] = acc_ref[...] / jnp.where(norm == 0.0, 1.0, norm)
 
-    def col_index(b, j, first, last, pos):
-        return b, 0, jnp.minimum(first[b] + j, last[b])
+    def col_index(i, slot, block_of, first, last, pos):
+        return slot[i], 0, block_of[i]
 
-    def q_index(b, j, first, last, pos):
-        return b, 0, 0
+    def q_index(i, slot, block_of, first, last, pos):
+        return slot[i], 0, 0
 
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((B, R, W), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B, max_len // block),
+            num_scalar_prefetch=5,
+            grid=(walk.count,),
             in_specs=[
                 pl.BlockSpec((None, R, W), q_index),
                 pl.BlockSpec((None, W, block), col_index),
@@ -413,25 +483,30 @@ def _mla_attn_pallas(q, cache, q_pos, rank: int, scale: float, block: int,
                             pltpu.VMEM((R, W), jnp.float32)],
         ),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=compat.vmem_budget_bytes()),
         interpret=interpret,
         name=MLA_KERNEL_NAME,
-    )(first, last, q_pos, q.astype(cache.dtype).reshape(B, R, W),
-      jnp.swapaxes(cache, 1, 2))
+    )
+    out = _walked(call, walk, q_pos, q.astype(cache.dtype).reshape(B, R, W),
+                  jnp.swapaxes(cache, 1, 2))
     return out.reshape(B, L, H, W)[..., :rank]
 
 
 def mla_decode_attention(q: jax.Array, cache: jax.Array, q_pos: jax.Array,
-                         rank: int, scale: float, interpret=None) -> jax.Array:
+                         rank: int, scale: float, interpret=None, live=None,
+                         walk: Optional[Walk] = None) -> jax.Array:
     """q [B, L, H, rank + rope] against the latent slot cache
     [B, max_len, rank + rope] -> float32 [B, L, H, rank]: the kernel where
-    `kernel_block` says it takes the call, the reference einsum elsewhere."""
+    `kernel_block` says it takes the call, the reference einsum elsewhere;
+    `live` and `walk` as `decode_attention` takes them."""
     block = kernel_block(q.shape[1], cache.shape, cache.dtype, interpret)
     if block is None:
         return mla_decode_attention_reference(q, cache, q_pos, rank, scale)
+    if walk is None:
+        walk = slot_walk(q_pos, live, block, cache.shape[1])
     return _mla_attn_pallas(q, cache, q_pos, rank, scale, block,
-                            compat.pallas_mode(interpret) == "interpret")
+                            compat.pallas_mode(interpret) == "interpret", walk)
 
 
 # -- attention over selected blocks: a visit list a (slot, KV head) --------------------

@@ -121,7 +121,8 @@ from ..models.transformer import (
     resident_params,
 )
 from ..monitor.journal import journal_event
-from ..ops.decode_attn import kernel_block, live_blocks, visible_kernels
+from ..ops.decode_attn import (kernel_block, live_blocks, visible_kernels,
+                               visits)
 from ..utils import get_logger
 from ..utils.trace import (
     BOOT_CAT,
@@ -317,8 +318,8 @@ class ServingEngine:
         # rows of one KV block of the attention in `_decode` (one query row
         # a slot) and `_verify` (k): asked as the model asks when it is
         # traced, of a leaf the attention reads by position (a layer's K,
-        # or the one latent leaf of a latent-attention sublayer).  One
-        # block of max_len rows a slot is the dense einsum
+        # or the one latent leaf of a latent-attention sublayer).  None is
+        # the dense einsum: max_len rows a slot, whatever the slot holds
         leaf = next(x for path, x in
                     jax.tree_util.tree_leaves_with_path(self.cache)
                     if getattr(path[-1], "key", None)
@@ -328,7 +329,6 @@ class ServingEngine:
         self._attn_block = {
             rows: (None if self.dcfg.attention == "full" or self._sparse
                    else kernel_block(rows, leaf.shape, leaf.dtype))
-            or self.dcfg.max_len
             for rows in {1, spec.k if spec is not None else 1}}
         self._grafts: Dict[str, tuple] = {}  # req_id -> (meta, rows) shipped KV
         self.params_version = 0
@@ -1139,13 +1139,21 @@ class ServingEngine:
         slot held no request, or one whose last token was in flight."""
         max_len = self.dcfg.max_len
         block = self._attn_block[query_rows]
-        first, last = live_blocks(np, before, before + query_rows - 1, block,
-                                  max_len, self.dcfg.window)
-        fetched = (last - first + 1) * block
-        written = np.minimum(self._cursor, max_len)
         free = ~live
+        if block is None:
+            # the einsum reads every row of every slot
+            fetched, fetched_free = self.n_slots * max_len, free.sum() * max_len
+        else:
+            # the list the kernels walk, as the program builds it: the
+            # blocks of live slots, so none of a row that did no work
+            first, last = live_blocks(np, before, before + query_rows - 1,
+                                      block, max_len, self.dcfg.window)
+            slot, _, n = visits(np, first, last, live,
+                                self.n_slots * (max_len // block))
+            fetched, fetched_free = n * block, free[slot[:n]].sum() * block
+        written = np.minimum(self._cursor, max_len)
         add = (self.n_slots * max_len, written.sum(), written @ free,
-               fetched.sum(), fetched @ free)
+               fetched, fetched_free)
         # rebound whole, so a reader on another thread (/metrics, a
         # profile capture) sees the totals of one step or of the next
         self._attn_rows = {kind: n + int(a) for (kind, n), a
@@ -1201,22 +1209,22 @@ class ServingEngine:
         NEEDS to read): 0 for a slot that holds no request, whose cursor
         stays at 0, and the held cursor of a slot whose request's last
         token was in flight when the step was dispatched; `fetched` the
-        rows the program reads (each slot's live blocks,
-        ops/decode_attn.py: the whole cache when the program was built
-        with the dense einsum, so `fetched == cache` says the kernel is
-        not what runs), `fetched_free` those of them read for rows that
-        did no work: one block (the verify step's k rows: the blocks they
-        span) for an empty slot, the blocks up to its cursor for a slot
-        whose request was ending, read and not used."""
+        rows the program reads (the blocks of the list its kernels walk,
+        ops/decode_attn.py `visits`: the live blocks of the slots that did
+        work; the whole cache when the program was built with the dense
+        einsum, so `fetched == cache` says the kernel is not what runs),
+        `fetched_free` those of them read for rows that did no work: 0
+        under the kernels, which visit no block of such a slot, be it
+        empty or its request ending; its whole slot under the einsum."""
         return dict(self._attn_rows)
 
     def decode_rows(self) -> Dict[str, int]:
         """Slot-steps of the decode and verify steps so far: `live` those
         that computed a token for a request, `free` those that did no work
-        (cursor held, no expert): the slot held no request (one cache
-        block read), or a request whose last token was still in flight
-        from the step before (`decode_steps`).  `live + free` = slots x
-        steps."""
+        (cursor held, no expert, no cache block read by the attention
+        kernels): the slot held no request, or a request whose last token
+        was still in flight from the step before (`decode_steps`).
+        `live + free` = slots x steps."""
         return dict(self._decode_rows)
 
     def decode_steps(self) -> Dict[str, int]:

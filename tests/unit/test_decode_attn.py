@@ -59,7 +59,8 @@ def _case(cursors, L, H, Hkv, dtype, seed=0):
 def _interpreted(window):
     """The kernel's body in the Pallas interpreter, traced once a shape."""
     return jax.jit(lambda q, k, v, pos: da._attn_pallas(
-        q, k, v, pos, window, BLOCK, True))
+        q, k, v, pos, window, BLOCK, True,
+        da.slot_walk(pos, None, BLOCK, MAX_LEN, window)))
 
 
 def _check(cursors, L, H, Hkv, dtype, window):
@@ -113,6 +114,145 @@ def test_live_blocks_are_the_same_on_the_host_and_in_the_program():
     first, last = da.live_blocks(np, lo, hi, BLOCK, MAX_LEN, 20)
     assert last.tolist() == [0, 1, 1, 2, 3, 3]     # rows beyond max_len clip
     assert first.tolist() == [0, 0, 0, 1, 2, 3]    # rows every window has left
+
+
+def _listed(first, last, live):
+    """The visit list written out: (slot, block) of every live slot's run."""
+    return [(b, j) for b in range(len(first)) if live is None or live[b]
+            for j in range(first[b], last[b] + 1)]
+
+
+@pytest.mark.parametrize("live", [
+    None, [True] * 6, [False] * 6, [False, True, False, False, True, False],
+    [True, False, True, True, False, True]],
+    ids=["none_given", "all", "no_slot", "two_of_six", "four_of_six"])
+def test_the_visit_list_is_the_same_on_the_host_and_in_the_program(live):
+    """`visits` under numpy (the engine's count of fetched rows) and under
+    jax.numpy (what the kernels walk): the blocks first..last of every live
+    slot, ordered by slot, and nothing of a slot that is not live; the
+    places past the count hold block 0 of slot 0, which exists."""
+    lo = np.array([0, 15, 16, 40, 63, 70])
+    hi = lo + np.array([0, 3, 0, 3, 0, 3])
+    mask = None if live is None else np.array(live)
+    length = len(lo) * (MAX_LEN // BLOCK)
+    for window in (0, 20):
+        first, last = da.live_blocks(np, lo, hi, BLOCK, MAX_LEN, window)
+        host = da.visits(np, first, last, mask, length)
+        prog = da.visits(jnp, jnp.asarray(first), jnp.asarray(last),
+                         None if live is None else jnp.asarray(mask), length)
+        for h, p in zip(host, prog):
+            assert np.asarray(h).tolist() == np.asarray(p).tolist()
+        slot, block, n = host
+        assert slot.dtype == block.dtype == np.int32 and slot.shape == (length,)
+        assert list(zip(slot[:n].tolist(), block[:n].tolist())) == _listed(
+            first, last, live)
+        assert (slot[n:] == 0).all() and (block[n:] == 0).all()
+        walk = da.slot_walk(jnp.stack([jnp.asarray(lo), jnp.asarray(hi)], 1),
+                            None if live is None else jnp.asarray(mask),
+                            BLOCK, MAX_LEN, window)
+        assert int(walk.count) == n
+        assert np.asarray(walk.slot).tolist() == slot.tolist()
+        assert np.asarray(walk.block).tolist() == block.tolist()
+
+
+# (cursors, query rows a slot, window, live): both kernels take every case
+# but the window's, which the latent kernel has none of
+LIVE_CASES = {
+    "no_slot_live": (CURSORS["mix"], 1, 0, [False] * 8),
+    "one_of_eight": ([0, 0, 0, 37, 0, 0, 0, 0], 1, 0,
+                     [False, False, False, True, False, False, False, False]),
+    "every_slot_at_max_len": ([MAX_LEN - 1] * 8, 1, 0, [True] * 8),
+    # the window has left block 0 of the slots at 40 and beyond
+    "window_first_block_not_0": ([40, 63, 5, 47, 63, 0, 33, 20], 1, 20,
+                                 [True, True, False, True, False, False,
+                                  True, True]),
+    # four query rows at BLOCK - 2 .. BLOCK + 1: a slot's rows in two blocks
+    "verify_rows_in_two_blocks": ([BLOCK - 2, 2 * BLOCK - 2, 0, 5, BLOCK - 2,
+                                   3 * BLOCK - 3, 0, MAX_LEN - 1], 4, 0,
+                                  [True, True, False, True, False, True,
+                                   False, True]),
+    "live_not_given": (CURSORS["mix"], 1, 0, None),
+    "live_not_given_verify": (CURSORS["mix"], 4, 0, None),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _interpreted_under_a_mask(kind, window):
+    """Either kernel's body in the interpreter on the walk of a `live`
+    mask, traced once a shape."""
+    if kind == "latent":
+        return jax.jit(lambda q, c, pos, live: da._mla_attn_pallas(
+            q, c, pos, RANK, 0.25, BLOCK, True,
+            da.slot_walk(pos, live, BLOCK, MAX_LEN)))
+    return jax.jit(lambda q, k, v, pos, live: da._attn_pallas(
+        q, k, v, pos, window, BLOCK, True,
+        da.slot_walk(pos, live, BLOCK, MAX_LEN, window)))
+
+
+@pytest.mark.parametrize("case,kind", [
+    (c, k) for c in LIVE_CASES for k in ("per_head", "latent")
+    if not (k == "latent" and LIVE_CASES[c][2])])
+def test_kernels_match_their_einsums_under_a_live_mask(case, kind):
+    """A live slot's rows are the einsum's; a slot that is not live comes
+    back as zeros whatever its slot holds: NaN stands in EVERY row of such
+    a slot (and beyond every live slot's cursor), and the kernel, which
+    visits no block of it, neither reads it nor leaves its output
+    unwritten."""
+    cursors, L, window, live = LIVE_CASES[case]
+    mask = np.ones(8, bool) if live is None else np.array(live)
+    if kind == "latent":
+        q, cache, pos, cache_nan = _latent_case(cursors, L, 4, jnp.float32)
+        want = mla_decode_attention_reference(q, cache, pos, RANK, 0.25)
+        leaves = (jnp.where(mask[:, None, None], cache_nan, jnp.nan),)
+        plain = _latent_interpreted()
+    else:
+        q, ck, cv, pos, ck_nan, cv_nan = _case(cursors, L, 8, 2, jnp.float32)
+        want = decode_attention_reference(q, ck, cv, pos, window)
+        dead = ~mask[:, None, None, None]
+        leaves = (jnp.where(dead, jnp.nan, ck_nan),
+                  jnp.where(dead, jnp.nan, cv_nan))
+        plain = _interpreted(window)
+    if live is None:  # the walk the kernel builds for itself: every slot
+        got = np.asarray(plain(q, *leaves, pos))
+    else:
+        got = np.asarray(_interpreted_under_a_mask(kind, window)(
+            q, *leaves, pos, jnp.asarray(mask)))
+    assert got.shape == want.shape and np.isfinite(got).all()
+    assert (got[~mask] == 0.0).all()
+    np.testing.assert_allclose(got[mask], np.asarray(want)[mask],
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("kind", ["per_head", "latent"])
+def test_the_public_call_hands_live_to_the_kernel(kind, monkeypatch):
+    """`decode_attention(..., live=)` and `mla_decode_attention(..., live=)`
+    build the walk themselves where the caller brings none, and take the
+    caller's where it does (the model's one list a step): the same rows
+    either way, zeros for the slot that is not live; the einsum, off TPU,
+    ignores the mask."""
+    monkeypatch.setenv("KFT_PALLAS", "interpret")
+    live = jnp.asarray([True, False] * 4)
+    if kind == "latent":
+        q, cache, pos, _ = _latent_case(CURSORS["mix"], 1, 4, jnp.float32)
+        call = lambda **kw: da.mla_decode_attention(  # noqa: E731
+            q, cache, pos, RANK, 0.25, **kw)
+        block = da.kernel_block(1, cache.shape, cache.dtype)
+        walk = da.slot_walk(pos, live, block, MAX_LEN)
+    else:
+        q, ck, cv, pos, _, _ = _case(CURSORS["mix"], 1, 8, 8, jnp.float32)
+        q, ck, cv = (jnp.tile(t, (1, 1, 1, 8)) for t in (q, ck, cv))  # D = 128
+        call = lambda **kw: decode_attention(q, ck, cv, pos, **kw)  # noqa: E731
+        block = da.kernel_block(1, ck.shape, ck.dtype)
+        walk = da.slot_walk(pos, live, block, MAX_LEN)
+    assert block is not None
+    every, masked, walked = call(), call(live=live), call(walk=walk)
+    np.testing.assert_array_equal(np.asarray(masked), np.asarray(walked))
+    np.testing.assert_array_equal(np.asarray(masked)[::2], np.asarray(every)[::2])
+    assert (np.asarray(masked)[1::2] == 0).all()
+    assert np.abs(np.asarray(every)[1::2]).max() > 0
+    monkeypatch.setenv("KFT_PALLAS", "off")
+    np.testing.assert_allclose(np.asarray(call(live=live)), np.asarray(every),
+                               rtol=2e-5, atol=2e-5)
 
 
 # -- the lowering for the chip ---------------------------------------------------------
@@ -325,7 +465,7 @@ def test_serving_over_a_mesh_keeps_the_einsum_and_places_no_constraint(
     assert da.KERNEL_NAME not in under_rules
     assert "sharding_constraint" not in under_rules
     assert traced(ServingEngine(cfg, params, slots=2, mesh=mesh)) == under_rules
-    assert sharded._attn_block == {1: cfg.max_len}  # counts the whole cache
+    assert sharded._attn_block == {1: None}  # counts the whole cache
 
     single = ServingEngine(cfg, params, slots=2)
     assert (single.dcfg.attention, single.dcfg.mesh) == ("auto", None)
@@ -339,6 +479,49 @@ def test_serving_over_a_mesh_keeps_the_einsum_and_places_no_constraint(
     T.generate(cfg, params, prompt, 4)
     T.generate(cfg, params, prompt, 4, mesh=mesh)
     assert [(c.attention, c.mesh) for c in seen] == [("auto", None), ("full", None)]
+
+
+@pytest.mark.parametrize("kind", ["per_head", "latent", "latent_shortcut"])
+def test_the_decode_program_builds_the_visit_list_once(kind, monkeypatch):
+    """`TransformerLM` builds the step's walk from the first attention
+    layer's cursors and hands it to every layer: the engine's `_decode` of
+    a two-layer model holds one cumsum (the list's) and one kernel call a
+    layer (a sublayer under the shortcut block), where a list a layer would
+    hold as many cumsums as calls: each layer's cursors are a buffer of its
+    own, and no compiler merges what is computed from different buffers."""
+    from kungfu_tpu.serving import ServingEngine
+
+    monkeypatch.setenv("KFT_PALLAS", "interpret")
+    fields = dict(vocab_size=32, n_layers=2, d_ff=32, max_len=64, rope=True,
+                  dtype=jnp.float32)
+    if kind == "per_head":
+        cfg = TransformerConfig(d_model=1024, n_heads=8, **fields)
+        name, calls = da.KERNEL_NAME, 2
+    else:
+        shortcut = kind == "latent_shortcut"
+        cfg = TransformerConfig(
+            d_model=64, n_heads=4, norm="rms", kv_lora_rank=32, q_lora_rank=24,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            **(dict(block="shortcut_moe", n_experts=4, experts_per_token=2,
+                    moe_every=1, d_ff_expert=16) if shortcut else {}),
+            **fields)
+        name, calls = da.MLA_KERNEL_NAME, 4 if shortcut else 2
+    params = nn.meta.unbox(jax.eval_shape(
+        TransformerLM(cfg).init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 4), jnp.int32))["params"])
+    eng = ServingEngine(cfg, params, slots=2)
+    text = str(jax.make_jaxpr(eng._decode)(
+        eng.params, eng.cache, eng._dev_counters, jnp.zeros((2, 1), jnp.int32),
+        eng._no_prev))
+    assert text.count(f"name={name}") == calls
+    # over the two slots (the expert layer's own run over its experts)
+    assert text.count("i32[2] = cumsum[") == 1
+    # a layer called by itself, without the model's list, builds its own
+    own = str(jax.make_jaxpr(lambda q, c, p: da.mla_decode_attention(
+        q, c, p, 32, 0.2, live=p[:, 0] > 0))(
+        jnp.zeros((2, 1, 4, 40)), jnp.zeros((2, 64, 40)),
+        jnp.zeros((2, 1), jnp.int32)))
+    assert own.count("i32[2] = cumsum[") == 1
 
 
 def test_decode_step_logits_equal_the_einsums(monkeypatch):
@@ -387,7 +570,8 @@ def _latent_case(cursors, L, H, dtype, seed=0):
 @functools.lru_cache(maxsize=None)
 def _latent_interpreted():
     return jax.jit(lambda q, c, pos: da._mla_attn_pallas(
-        q, c, pos, RANK, 0.25, BLOCK, True))
+        q, c, pos, RANK, 0.25, BLOCK, True,
+        da.slot_walk(pos, None, BLOCK, MAX_LEN)))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],

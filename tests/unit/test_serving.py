@@ -271,7 +271,7 @@ class TestEngine:
             assert ending == [3]
         rows = eng.decode_attn_rows()
         assert (rows["written_free"], rows["fetched_free"]) == \
-            _rows_of_idle_slots(eng, ending, cfg.max_len)
+            _rows_of_idle_slots(eng, ending)
 
     def test_warm_resume_matches_uninterrupted(self, model_and_params):
         """prior_tokens (the re-queue warm path) must continue the stream
@@ -582,13 +582,13 @@ def _ending_rows(eng):
     return seen
 
 
-def _rows_of_idle_slots(eng, ending, block):
+def _rows_of_idle_slots(eng, ending, kernel=False):
     """(`written_free`, `fetched_free`) as `decode_rows` and the uploads
-    say they must be: an empty slot has nothing written and reads one
-    block, a slot whose request was ending the blocks up to its cursor."""
-    empty = eng.decode_rows()["free"] - len(ending)
-    return sum(ending), empty * block + sum(
-        (c // block + 1) * block for c in ending)
+    say they must be: an empty slot has nothing written, a slot whose
+    request was ending the rows up to its cursor; the einsum reads the
+    whole slot of either, the kernels' walk (`kernel`) visits neither."""
+    idle = eng.decode_rows()["free"]
+    return sum(ending), 0 if kernel else idle * eng.dcfg.max_len
 
 
 def _staggered_run_with_a_preemption(monkeypatch, pallas, draft=False):
@@ -669,23 +669,62 @@ def test_engine_tokens_and_row_counts_with_the_kernel_and_with_the_einsum(
     _, want, einsum, _ = _staggered_run_with_a_preemption(monkeypatch, "off")
     assert got == want
     k, e = kernel.stats()["decode_attn_rows"], einsum.decode_attn_rows()
-    for rows, eng, block in ((k, kernel, 256), (e, einsum, 512)):
+    for rows, eng in ((k, kernel), (e, einsum)):
         assert 0 < rows["written"] <= rows["fetched"] <= rows["cache"]
-        assert 0 < rows["fetched_free"] <= rows["fetched"]
         # a free slot's cursor stays at 0: no row stands written under one
         # but a slot's whose request was ending
         assert (rows["written_free"], rows["fetched_free"]) == \
-            _rows_of_idle_slots(eng, eng.ending, block)
+            _rows_of_idle_slots(eng, eng.ending, kernel=eng is kernel)
     assert e["fetched"] == e["cache"]          # the einsum reads every row
+    assert 0 < e["fetched_free"] < e["fetched"]
     assert k["fetched"] < k["cache"]           # the kernel the live blocks
+    assert k["fetched_free"] == 0              # ... of the live slots alone
+    # the rows needed, rounded up to blocks a live slot-step: nothing else
+    assert k["fetched"] <= k["written"] - k["written_free"] + 256 * \
+        kernel.decode_rows()["live"]
     # the same steps on both sides: the cursors do not depend on the path
     assert [k[kind] for kind in ("cache", "written", "written_free")] == [
         e[kind] for kind in ("cache", "written", "written_free")]
     assert kernel.decode_rows() == einsum.decode_rows()
     assert k["cache"] % (2 * 512) == 0
-    # every step fetched one or two blocks of 256 rows a slot
+    # every step fetched one or two blocks of 256 rows a live slot
     assert k["fetched"] % 256 == 0
-    assert k["cache"] // 2 <= k["fetched"]
+    assert 256 * kernel.decode_rows()["live"] <= k["fetched"]
+
+
+@pytest.mark.parametrize("before,live,kernel,einsum", [
+    # (cursors a step starts from, who did work) -> (fetched, fetched_free)
+    ([0, 0, 0, 0], [False] * 4, (0, 0), (2048, 2048)),          # nobody live
+    ([255, 0, 0, 0], [True, False, False, False], (256, 0), (2048, 1536)),
+    ([256, 0, 300, 0], [True, False, True, False], (1024, 0), (2048, 1024)),
+    # a request whose last token is in flight: its slot is not visited either
+    ([256, 40, 300, 511], [True, False, False, True], (1024, 0), (2048, 1024)),
+    ([511] * 4, [True] * 4, (2048, 0), (2048, 0)),              # the old walk
+], ids=["no_slot_live", "one_of_four", "two_of_four", "one_ending", "all_full"])
+def test_a_step_counts_the_blocks_of_the_list_the_kernels_walk(
+        monkeypatch, before, live, kernel, einsum):
+    """`_count_step` under the kernels counts `visits` of the step's
+    cursors and `live` mask, as the program builds it: the live slots'
+    blocks of 256 rows and no block of a slot that did no work, so
+    `fetched_free` is 0; under the einsum every slot's 512 rows."""
+    cfg, params = _kernel_sized_model()
+    for pallas, (fetched, fetched_free) in (("interpret", kernel),
+                                            ("off", einsum)):
+        monkeypatch.setenv("KFT_PALLAS", pallas)
+        eng = ServingEngine(cfg, params, slots=4, prefill_buckets=(8,))
+        assert eng._attn_block == {1: 256 if pallas == "interpret" else None}
+        start, did = np.array(before), np.array(live)
+        eng._cursor = start + did
+        for _ in range(2):
+            eng._count_step(start, 1, did)
+        rows = eng.decode_attn_rows()
+        assert (rows["fetched"], rows["fetched_free"]) == (
+            2 * fetched, 2 * fetched_free)
+        assert rows["cache"] == 2 * 4 * 512
+        assert rows["written"] == 2 * int((start + did).sum())
+        assert rows["written_free"] == 2 * int(start[~did].sum())
+        assert eng.decode_rows() == {"live": 2 * int(did.sum()),
+                                     "free": 2 * int((~did).sum())}
 
 
 @pytest.mark.parametrize("draft", [False, True], ids=["plain", "speculative"])
@@ -696,8 +735,9 @@ def test_a_free_slot_does_no_work_and_busy_slots_serve_generates_tokens(
     speculative draft: after every step the device's cursors equal the
     host's, a free slot's is 0 and its overflow flag clear (looked at
     inside the run); no row stands written under a free slot, a free slot's
-    step reads one block, every slot-step is counted live or free; and each
-    request's tokens are its own `generate()`'s, token for token."""
+    step reads no block under the kernels and its whole slot under the
+    einsum, every slot-step is counted live or free; and each request's
+    tokens are its own `generate()`'s, token for token."""
     reqs, got, eng, counters = _staggered_run_with_a_preemption(
         monkeypatch, pallas, draft)
     monkeypatch.setenv("KFT_PALLAS", "off")
@@ -707,9 +747,8 @@ def test_a_free_slot_does_no_work_and_busy_slots_serve_generates_tokens(
     assert kinds["live"] + kinds["free"] == 2 * steps
     assert 0 < kinds["free"] < kinds["live"]
     assert rows["cache"] == steps * 2 * 512
-    block = 256 if pallas == "interpret" else 512  # the einsum: the whole slot
     assert (rows["written_free"], rows["fetched_free"]) == \
-        _rows_of_idle_slots(eng, eng.ending, block)
+        _rows_of_idle_slots(eng, eng.ending, kernel=pallas == "interpret")
     ran = eng.decode_steps()
     assert ran["wasted_rows"] == 0 and eng._flight is None
     if draft:
